@@ -425,13 +425,6 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                              "dp-burn-rate warns when a round burns "
                              "over 2x the uniform budget/comm_round "
                              "rate; 0 = no budget rules")
-    parser.add_argument("--compile_cache", "--compile_cache_dir",
-                        dest="compile_cache_dir", type=str, default=None,
-                        help="persistent XLA compile cache dir (repeat "
-                             "experiments skip the ~30s 3D-CNN round "
-                             "compile); unset falls back to "
-                             "$NIDT_COMPILE_CACHE, then "
-                             "/tmp/nidt_jax_cache; empty string disables")
     parser.add_argument("--client_mesh", type=int, default=0,
                         help="shard the sampled-client axis of every "
                              "jitted round program over a client mesh of "
@@ -551,6 +544,25 @@ def config_from_args(args: argparse.Namespace) -> ExperimentConfig:
         health_stats=args.health_stats, health_rules=args.health_rules,
         health_gate=args.health_gate, metrics_out=args.metrics_out,
         actions=args.actions)
+
+
+def run_mesh(cfg: ExperimentConfig, streaming: bool = False):
+    """The device mesh a run gets. It applies to both residency modes:
+    under --streaming each round's sampled-client buffers are device_put
+    sharded over the client axis — on a two-level (silos, clients) mesh
+    the axis maps over BOTH mesh axes silo-major (data/stream.py::_put),
+    so the engine's silo-first aggregation routing is preserved while
+    the cohort streams from host."""
+    from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
+
+    if streaming and not cfg.mesh_shape and not cfg.fed.client_mesh:
+        return None  # plain single-device streaming feed
+    if cfg.fed.client_mesh > 0 and not cfg.mesh_shape:
+        # --client_mesh N builds the 1-D N-device client mesh it shards
+        # over (an explicit --mesh_shape wins and must agree — the
+        # engine validates the sizes at startup)
+        return make_mesh(num_devices=cfg.fed.client_mesh)
+    return make_mesh(shape=cfg.mesh_shape)  # default: every visible device
 
 
 def build_experiment(cfg: ExperimentConfig, streaming: bool = False,
@@ -865,7 +877,7 @@ def main(argv: list[str] | None = None) -> int:
     from neuroimagedisttraining_tpu.utils.compile_cache import (
         enable_compile_cache,
     )
-    enable_compile_cache(args.compile_cache_dir)
+    enable_compile_cache()
 
     # deterministic seeding (main_sailentgrads.py:264-268)
     random.seed(args.seed)
@@ -878,22 +890,8 @@ def main(argv: list[str] | None = None) -> int:
         args.num_classes = _vision_classes[args.dataset.lower()]
 
     cfg = config_from_args(args)
-    # mesh applies to both residency modes: under --streaming each round's
-    # sampled-client buffers are device_put sharded over the client axis —
-    # on a two-level (silos, clients) mesh the axis maps over BOTH mesh
-    # axes silo-major (data/stream.py::_put), so the engine's silo-first
-    # aggregation routing is preserved while the cohort streams from host
-    from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
-    if args.streaming and not cfg.mesh_shape and not cfg.fed.client_mesh:
-        mesh = None  # plain single-device streaming feed
-    elif cfg.fed.client_mesh > 0 and not cfg.mesh_shape:
-        # --client_mesh N builds the 1-D N-device client mesh it shards
-        # over (an explicit --mesh_shape wins and must agree — the
-        # engine validates the sizes at startup)
-        mesh = make_mesh(num_devices=cfg.fed.client_mesh)
-    else:
-        mesh = make_mesh(shape=cfg.mesh_shape)
-    engine = build_experiment(cfg, streaming=args.streaming, mesh=mesh)
+    engine = build_experiment(cfg, streaming=args.streaming,
+                              mesh=run_mesh(cfg, args.streaming))
     from neuroimagedisttraining_tpu.utils.profiling import (
         failure_context, profile_trace,
     )

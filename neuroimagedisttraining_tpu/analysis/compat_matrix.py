@@ -303,4 +303,12 @@ MATRIX: tuple[dict[str, Any], ...] = (
             ' default server-side aggregation tail for the field fold to '
             'replace; supported: '),
     },
+    {
+        "where": 'neuroimagedisttraining_tpu/engines/base.py',
+        "knobs": ('fused_update', 'optim'),
+        "message": (
+            '--fused_update on a -device TPU mesh needs the cohort-sharde'
+            'd round (--client_mesh ): its Pallas kernel cannot be partit'
+            'ioned by GSPMD. Add '),
+    },
 )

@@ -165,12 +165,10 @@ def init_multihost(coordinator_address: str, num_processes: int,
 
     On real TPU pods this makes every host's chips part of one global mesh
     (libtpu handles cross-host wiring) so the client axis spans hosts and
-    aggregation rides ICI/DCN. NOTE: it cannot be smoke-tested in this
-    build's CPU backend — two CPU processes each come up with
-    process_count=1 (multiprocess CPU clustering is disabled in this jax
-    build; verified empirically), so the cross-process capability test
-    lives in the socket control plane instead
-    (tests/test_distributed.py::test_cross_silo_multiprocess_smoke)."""
+    aggregation rides ICI/DCN. On the CPU backend two processes cluster
+    too (Gloo collectives), which is how the hook is tested without a
+    pod (tests/test_distributed.py::
+    test_init_multihost_two_processes_cluster)."""
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

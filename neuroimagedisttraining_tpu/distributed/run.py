@@ -545,16 +545,9 @@ def main(argv=None) -> int:
                          "model-family default; PROFILE.md)")
     ap.add_argument("--seed", type=int, default=1024)
     ap.add_argument("--force_cpu", action="store_true",
-                    help="pin JAX to the CPU backend (e.g. several silo "
-                         "processes on one machine sharing a tunneled "
-                         "accelerator)")
-    ap.add_argument("--compile_cache", dest="compile_cache", type=str,
-                    default=None,
-                    help="persistent XLA compile cache dir shared by "
-                         "every silo process (each rank pays the model "
-                         "compile once per MACHINE, not per process); "
-                         "unset falls back to $NIDT_COMPILE_CACHE, then "
-                         "/tmp/nidt_jax_cache; empty string disables")
+                    help="pin JAX to the CPU backend (several silo "
+                         "processes on one machine: a chip belongs to "
+                         "one process at a time)")
     ap.add_argument("--rounds_per_dispatch", type=int, default=1,
                     help="accepted for config parity with the main CLI; "
                          "the cross-silo control plane synchronizes with "
@@ -877,7 +870,7 @@ def main(argv=None) -> int:
     from neuroimagedisttraining_tpu.utils.compile_cache import (
         enable_compile_cache,
     )
-    enable_compile_cache(args.compile_cache)
+    enable_compile_cache()
     # observability plane (obs/, ISSUE 9): flight ring + span tracer are
     # per-process; the /metrics endpoint starts on the server rank below
     from neuroimagedisttraining_tpu.obs import flight as obs_flight

@@ -360,6 +360,20 @@ class FederatedEngine:
                     "client_mesh=%d requested; running the unsharded "
                     "round program: %s", cm,
                     round_program.report_fallback(self.name, key))
+        # Mosaic kernels cannot be partitioned automatically (jax refuses
+        # the lowering: "wrap the call in a shard_map"), so on a TPU mesh
+        # of several devices the fused Pallas tail lowers only inside
+        # the cohort-sharded round's shard_map. Refuse the combination
+        # here, with the resolution named, not deep in the first trace.
+        n_mesh = 1 if mesh is None else mesh.devices.size
+        if (cfg.optim.fused_update and n_mesh > 1 and not self._cohort_on
+                and jax.default_backend() == "tpu"):
+            raise ValueError(
+                f"--fused_update on a {n_mesh}-device TPU mesh needs the "
+                f"cohort-sharded round (--client_mesh {n_mesh}): its "
+                "Pallas kernel cannot be partitioned by GSPMD. Add "
+                f"--client_mesh {n_mesh}, or drop --fused_update, or pin "
+                "one device with --mesh_shape 1")
         # fused multi-round dispatch (ISSUE 4): engines that cannot fuse
         # announce the collapse to K=1 ONCE, up front, so a config asking
         # for amortized dispatch never silently degrades

@@ -152,15 +152,19 @@ class SalientGradsEngine(FederatedEngine):
         denom = jnp.maximum(wtot, 1.0)
         return jax.tree.map(lambda t: t / denom, acc)
 
+    def global_scores(self, params, bstats):
+        """Phase-1 scores: the per-client SNIP saliencies averaged over
+        the real clients (resident or streamed cohort)."""
+        if self.stream is not None:
+            return self._scores_streaming(params, bstats)
+        rngs = self.per_client_rngs(-1, np.arange(self.num_clients))
+        return self._scores_jit(params, bstats, self.data, rngs)
+
     def generate_global_mask(self, params, bstats):
         """Phase-1 pipeline (sailentgrads_api.py:47-66)."""
-        if self.stream is not None:
-            scores = self._scores_streaming(params, bstats)
-        else:
-            rngs = self.per_client_rngs(-1, np.arange(self.num_clients))
-            scores = self._scores_jit(params, bstats, self.data, rngs)
         masks, thr = snip_ops.mask_from_scores(
-            scores, keep_ratio=self.cfg.sparsity.dense_ratio)
+            self.global_scores(params, bstats),
+            keep_ratio=self.cfg.sparsity.dense_ratio)
         if not self.cfg.sparsity.snip_mask:
             masks = ones_mask(params)  # dense escape hatch
         return masks, thr

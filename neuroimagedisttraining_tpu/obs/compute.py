@@ -104,9 +104,11 @@ _MAX_STORM_WARNINGS = 8
 def peak_flops_estimate() -> float:
     """Total peak flop/s of the local devices for the MFU denominator:
     ``NIDT_PEAK_FLOPS`` env override (total, not per chip), else the
-    device-kind table x local device count, else 0.0 (unknown backend —
-    CPU harness — the MFU gauge stays unpublished and sustained TFLOPs
-    carry the evidence)."""
+    device-kind table x local device count. The CPU has no honest peak
+    and reads 0.0 by design (the MFU gauge stays unpublished and
+    sustained TFLOPs carry the evidence); an ACCELERATOR whose kind is
+    not in the table is an error that names the kind, never a silent
+    "no MFU"."""
     env = os.environ.get("NIDT_PEAK_FLOPS", "")
     if env:
         try:
@@ -114,17 +116,19 @@ def peak_flops_estimate() -> float:
         except ValueError:
             log.warning("NIDT_PEAK_FLOPS=%r is not a number; ignoring",
                         env)
-    try:
-        import jax
+    import jax
 
-        devs = jax.local_devices()
-        kind = getattr(devs[0], "device_kind", "") or ""
-    except Exception:  # noqa: BLE001 — no backend is a valid state here
+    devs = jax.local_devices()
+    if devs[0].platform == "cpu":
         return 0.0
+    kind = devs[0].device_kind
     for prefix, per_chip in PEAK_FLOPS_BY_DEVICE_KIND:
         if kind.startswith(prefix):
             return per_chip * len(devs)
-    return 0.0
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {kind!r}: add it to "
+        "obs/compute.py PEAK_FLOPS_BY_DEVICE_KIND, or pass --peak_flops "
+        "/ NIDT_PEAK_FLOPS (total flop/s across local devices)")
 
 
 class ComputeProfiler:

@@ -24,8 +24,7 @@ Parity contract (tests/test_precision.py):
   interpreter mode under a tolerance pin (the interpreter's math is the
   fallback's — the pin guards the padding/blocking plumbing).
 
-Template: ops/stemconv.py / ops/topk.py (block conventions, the
-CompilerParams fallback for the pinned jax-0.4.x toolchain). Scalars
+Template: ops/stemconv.py / ops/topk.py (block conventions). Scalars
 ride a (1, 128) f32 operand mapped to every grid step — lr is a traced
 per-round scalar, the clip trigger and global norm are per-step values;
 clip/wd/momentum are config constants baked as static flags so a
@@ -124,17 +123,14 @@ def _leaf_pallas(p, g, t, m, scalars, has_clip: bool, has_wd: bool,
         out_shape.append(jax.ShapeDtypeStruct((rows, _LANES), jnp.float32))
         out_specs.append(blk)
 
-    # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support
-    # both so the kernel imports under the pinned 0.4.x toolchain
-    params_cls = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))
     out = pl.pallas_call(
         _make_kernel(has_clip, has_wd, has_trace, has_mask),
         out_shape=tuple(out_shape),
         grid=(grid,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
-        compiler_params=params_cls(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
 

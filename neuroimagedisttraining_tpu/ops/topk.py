@@ -2,7 +2,8 @@
 
 Replaces the reference's ``torch.topk(all_scores, k)[..., -1]`` global
 threshold (snip.py:91-98) — which materializes a full sorted copy of the
-~61M-element AlexNet3D score vector — with a multi-round histogram-select:
+score vector (2,568,064 elements for this repo's AlexNet3D) — with a
+multi-round histogram-select:
 each round counts ``x >= t`` for a ladder of thresholds and narrows the
 bracket containing the k-th largest value. With 4 rounds x 512 bins the
 bracket shrinks by 512^4 ≈ 7e10 > 2^32, i.e. to float32 resolution: the

@@ -27,7 +27,9 @@ the measurements force:
   selection, masking, weighting, and every semantic choice is
   identical (the masked salientgrads round's mean loss sits exactly 1
   float32 ulp off: the per-step mask multiply adds one more fusion
-  seam) — and trained params/batch stats agree to ~1 ulp of their own
+  seam; on jax 0.9.0 so does the 4-site cohort padded to 8 rows, whose
+  per-client losses are bitwise-equal while the partitioned module
+  orders the 4-term weighted mean differently) — and trained params/batch stats agree to ~1 ulp of their own
   magnitude. The residue is an XLA compile-context artifact, not a
   semantic one (different modules tile a handful of reductions
   differently); over multi-round windows it feeds back through
@@ -57,8 +59,8 @@ a hard CORRECTNESS requirement, not a preference:
   the same bytes per device a reduce-scatter + broadcast pair would;
   what it gives up is only the redundant (cheap, model-sized)
   reduction arithmetic per device.
-- RANDOM-SORT OPS MUST BE HOISTED OUT OF THE PARTITION. On this
-  toolchain (jax 0.4.x CPU SPMD) an argsort-lowered
+- RANDOM-SORT OPS MUST BE HOISTED OUT OF THE PARTITION. Measured on
+  jax 0.4.x CPU SPMD (not re-checked on 0.9.0): an argsort-lowered
   ``jax.random.permutation`` computed INSIDE a shard_map partition and
   CONSUMED by the training scan silently yields different batch
   selections than the same code unpartitioned — while OBSERVING the
@@ -99,12 +101,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 PyTree = Any
 
@@ -161,7 +159,7 @@ def _gather_replicated(x: jax.Array, axis_name: str) -> jax.Array:
     """All-gather one leaf's per-device client blocks back into the full
     replicated ``[C, ...]`` stack. Typed PRNG-key arrays (the trained
     ``ClientState.rng`` leaves) gather through their uint32 key data —
-    collectives do not accept extended dtypes on this toolchain."""
+    collectives do not accept extended dtypes."""
     if jnp.issubdtype(x.dtype, jax.dtypes.prng_key):
         data = jax.lax.all_gather(jax.random.key_data(x), axis_name,
                                   axis=0, tiled=True)
@@ -203,14 +201,8 @@ def cohort_map(mesh: Mesh, fn, *stacked: PyTree) -> PyTree:
         return jax.tree.map(lambda x: _gather_replicated(x, axis), out)
 
     in_specs = tuple(P(axis) for _ in stacked)
-    # out_specs P(): the all-gather leaves every output replicated. The
-    # static replication checker of jax < 0.5 cannot see through a tiled
-    # all_gather, so it is disabled (the gather IS the replication proof;
-    # newer jax drops the kwarg, hence the fallback).
-    try:
-        shmapped = shard_map(block, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_rep=False)
-    except TypeError:  # pragma: no cover - jax >= 0.8 removed check_rep
-        shmapped = shard_map(block, mesh=mesh, in_specs=in_specs,
-                             out_specs=P())
-    return shmapped(*stacked)
+    # out_specs P(): the all-gather leaves every output replicated, but
+    # the varying-manual-axes checker cannot see through a tiled
+    # all_gather, so it is off (the gather IS the replication proof).
+    return shard_map(block, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                     check_vma=False)(*stacked)

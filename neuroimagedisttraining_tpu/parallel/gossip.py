@@ -44,11 +44,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from neuroimagedisttraining_tpu.parallel.mesh import CLIENT_AXIS
 

@@ -22,13 +22,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 SPACE_AXIS = "space"
 
@@ -37,16 +32,6 @@ def make_space_mesh(num_devices: int | None = None) -> Mesh:
     from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
 
     return make_mesh(num_devices=num_devices, axis_name=SPACE_AXIS)
-
-
-def _axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` exists only on jax >= 0.5; under the pinned
-    0.4.x toolchain the axis env lookup returns the size directly."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    from jax import core
-
-    return core.axis_frame(axis_name)
 
 
 def _halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
@@ -59,7 +44,7 @@ def _halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
     """
     if halo == 0:  # 1-wide depth kernel: nothing to exchange (x[:, -0:]
         return x   # would select the WHOLE block, doubling the depth)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     # receive the LAST `halo` rows of the left neighbor (shift right)
     from_left = lax.ppermute(x[:, -halo:], axis_name,

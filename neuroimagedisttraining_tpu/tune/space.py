@@ -47,9 +47,9 @@ __all__ = ["Space", "build_space", "cell_fingerprint", "cell_valid",
 PINNED = {"client_optimizer": "sgd", "loss_scale": 1.0,
           "algorithm": "fedavg"}
 
-#: per-device HBM capacities by device kind (bytes); kinds not listed
-#: (cpu included) are unbounded here — host RAM is not the contract
-#: this bound models
+#: per-device HBM capacities by device kind (bytes). "cpu" is unbounded
+#: here (host RAM is not the contract this bound models); any other
+#: kind missing from the table is an error in :func:`build_space`
 HBM_BYTES_BY_KIND = {
     "TPU v2": 8 << 30,
     "TPU v3": 16 << 30,
@@ -206,8 +206,19 @@ def build_space(device_kind: str = "cpu", n_devices: int = 1,
                 axes: tuple[tuple[str, tuple], ...] | None = None
                 ) -> Space:
     """The default space for a device context: declared axes plus the
-    device-kind HBM bound (None off-TPU — host RAM is not modeled)."""
-    hbm = HBM_BYTES_BY_KIND.get(device_kind)
+    device-kind HBM bound (None on "cpu" — host RAM is not modeled). An
+    accelerator kind the table does not list is an error naming the
+    kind: an unbounded HBM would let the search propose cells that
+    cannot fit."""
+    if device_kind == "cpu":
+        hbm = None
+    elif device_kind in HBM_BYTES_BY_KIND:
+        hbm = HBM_BYTES_BY_KIND[device_kind]
+    else:
+        raise ValueError(
+            f"no HBM capacity known for device_kind {device_kind!r}: "
+            "add it to tune/space.py HBM_BYTES_BY_KIND "
+            f"(have: {sorted(HBM_BYTES_BY_KIND)})")
     return Space(axes=tuple(axes) if axes is not None else DEFAULT_AXES,
                  device_kind=device_kind, n_devices=int(n_devices),
                  shape=tuple(int(s) for s in shape), hbm_bytes=hbm)

@@ -2,14 +2,18 @@
 
 Build-on-first-use: compiles the shared library with g++ into the package's
 ``native/`` directory the first time it's needed (pybind11 is not in this
-image; ctypes + extern "C" needs no Python headers at all). Every entry
-point has a numpy fallback, so the framework runs — just slower on the
-host-streaming path — on boxes without a toolchain.
+image; ctypes + extern "C" needs no Python headers at all). The sha256 of
+``gather.cpp`` is recorded beside the ``.so``; a library whose record is
+missing or differs is rebuilt, so what runs is built from the source as it
+stands — file times do not survive a copy and are never consulted. Every
+entry point has a numpy fallback, so the framework runs — just slower on
+the host-streaming path — on boxes without a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -23,6 +27,7 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)),
                            "native")
 _SRC = os.path.join(_NATIVE_DIR, "gather.cpp")
 _SO = os.path.join(_NATIVE_DIR, "libnidt_gather.so")
+_SO_SRC_HASH = _SO + ".sha256"  # sha256 of the gather.cpp that built _SO
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | bool | None = None  # None = not tried, False = failed
@@ -30,11 +35,33 @@ _lib: ctypes.CDLL | bool | None = None  # None = not tried, False = failed
 DEFAULT_THREADS = min(8, os.cpu_count() or 1)
 
 
+def _src_hash() -> str | None:
+    """sha256 of ``gather.cpp``; None when the source is absent (a
+    binary-only install: the ``.so`` is then used as it is)."""
+    try:
+        with open(_SRC, "rb") as f:
+            return hashlib.sha256(f.read()).hexdigest()
+    except OSError:
+        return None
+
+
+def _built_from(src_hash: str) -> bool:
+    try:
+        with open(_SO_SRC_HASH) as f:
+            return os.path.isfile(_SO) and f.read().strip() == src_hash
+    except OSError:
+        return False
+
+
 def _build() -> bool:
+    # build beside the target and rename: a concurrent silo process
+    # never loads a half-written library
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, _SO)
         return True
     except (OSError, subprocess.SubprocessError) as e:
         # surface WHY the numpy slow path is in use; logged once per
@@ -55,13 +82,16 @@ def load() -> ctypes.CDLL | None:
             return None
         if _lib is not None:
             return _lib
-        try:
-            fresh = (os.path.isfile(_SO)
-                     and os.path.getmtime(_SO) >= os.path.getmtime(_SRC))
-        except OSError:
-            # source missing (e.g. binary-only install): use the .so as-is
+        src_hash = _src_hash()
+        if src_hash is None:
             fresh = os.path.isfile(_SO)
-        if not fresh and not _build():
+        else:
+            fresh = _built_from(src_hash)
+            if not fresh and _build():
+                with open(_SO_SRC_HASH, "w") as f:
+                    f.write(src_hash + "\n")
+                fresh = True
+        if not fresh:
             _lib = False
             return None
         try:

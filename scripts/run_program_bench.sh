@@ -8,8 +8,8 @@
 # on the fused path for the first time (ditto, dpsgd, subavg) and the
 # fedfomo fallback reference. The DISPATCH COUNTS and the
 # one-compiled-program-per-window evidence are the stable claims on this
-# CPU harness; the wall ratio scales with per-dispatch latency and is a
-# TPU-session measurement (PROFILE.md round 2).
+# CPU harness; the wall ratio scales with per-dispatch latency and is
+# not measured on the current chip.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 mkdir -p bench_matrix

@@ -178,3 +178,39 @@ def test_streaming_mesh_pads_nontiling_sample_count(tmp_path):
         assert np.isfinite(result["final_global"]["loss"])
     finally:
         engine.stream.close()
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py: never a result without a TPU
+# ---------------------------------------------------------------------------
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("where", ["in_checkout", "alone"])
+def test_chip_smoke_fails_without_a_tpu(tmp_path, where):
+    """``chip_smoke.py`` must exit non-zero and print no ``"ok": true``
+    when JAX finds no accelerator (it fails at its device phase, it does
+    not carry on over the CPU), and in a directory that holds the script
+    and nothing else of the repo (it is the program's smoke, not a
+    program of its own)."""
+    import os
+    import shutil
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = os.path.join(repo, "chip_smoke.py")
+    cwd = repo
+    if where == "alone":
+        script = shutil.copy(script, tmp_path / "chip_smoke.py")
+        cwd = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, script, "--out", str(tmp_path / "out")],
+        capture_output=True, text=True, timeout=300, cwd=cwd,
+        env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"})
+    assert out.returncode not in (0, 2, 3), out.returncode
+    assert '"ok"' not in out.stdout
+    if where == "in_checkout":
+        assert "no TPU" in out.stderr
+        assert not (tmp_path / "out").exists()  # failed before any work
+    else:
+        assert "neuroimagedisttraining_tpu" in out.stderr  # ImportError

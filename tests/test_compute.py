@@ -281,6 +281,27 @@ def test_peak_flops_env_override(monkeypatch):
     assert obs_compute.peak_flops_estimate() == 0.0
 
 
+@pytest.mark.parametrize("platform,kind,n,want", [
+    ("cpu", "cpu", 8, 0.0),                    # no honest peak: by design
+    ("tpu", "TPU v5 lite", 1, 197e12),
+    ("tpu", "TPU v5 lite", 4, 4 * 197e12),
+    ("tpu", "TPU v9 unheard-of", 1, None),     # an error naming the kind
+])
+def test_peak_flops_by_device_kind(monkeypatch, platform, kind, n, want):
+    """A kind missing from the table is an error on an accelerator,
+    never a silent 0.0 that unpublishes the MFU gauge."""
+    import types
+
+    monkeypatch.delenv("NIDT_PEAK_FLOPS", raising=False)
+    devs = [types.SimpleNamespace(platform=platform, device_kind=kind)] * n
+    monkeypatch.setattr(jax, "local_devices", lambda: devs)
+    if want is None:
+        with pytest.raises(ValueError, match="TPU v9 unheard-of"):
+            obs_compute.peak_flops_estimate()
+    else:
+        assert obs_compute.peak_flops_estimate() == want
+
+
 def test_set_peak_flops_override_sticks_across_arm():
     """--peak_flops must survive the engine's lazy arm_model (the CLI
     sets it before any dispatch)."""

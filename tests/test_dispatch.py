@@ -16,6 +16,8 @@ Three contracts:
     distributed CLI) — and still train.
 """
 
+import json
+import os
 import subprocess
 import sys
 
@@ -356,50 +358,50 @@ def test_distributed_cli_logs_dispatch_collapse(capsys):
 
 
 # ---------------------------------------------------------------------------
-# persistent compile cache (--compile_cache / NIDT_COMPILE_CACHE)
+# persistent compile cache: placed from outside (utils/compile_cache.py)
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_resolution_order(monkeypatch, tmp_path):
-    from neuroimagedisttraining_tpu.utils import compile_cache as cc
-
-    monkeypatch.delenv("NIDT_COMPILE_CACHE", raising=False)
-    # nothing specified anywhere + empty default -> disabled, config
-    # untouched
-    assert cc.enable_compile_cache(None, default="") is None
-    # env fallback only applies when the flag was not given
-    monkeypatch.setenv("NIDT_COMPILE_CACHE", str(tmp_path / "env"))
-    import jax
-
-    prev = jax.config.jax_compilation_cache_dir
-    try:
-        assert cc.enable_compile_cache(None, default="") == \
-            str(tmp_path / "env")
-        assert cc.enable_compile_cache(str(tmp_path / "flag")) == \
-            str(tmp_path / "flag")
-        assert cc.enable_compile_cache("", default="") is None
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+_CACHE_CHILD = """
+import json, jax
+updates = []
+real_update = jax.config.update
+jax.config.update = lambda name, value: (updates.append(name),
+                                         real_update(name, value))[1]
+from neuroimagedisttraining_tpu.utils.compile_cache import enable_compile_cache
+returned = enable_compile_cache()
+real_update("jax_persistent_cache_min_compile_time_secs", 0.0)
+import jax.numpy as jnp
+jax.jit(lambda x: jnp.tanh(x) @ x.T)(jnp.ones((37, 53))).block_until_ready()
+print(json.dumps({"returned": returned, "updates": updates,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
 
 
-@pytest.mark.slow
-def test_compile_cache_writes_entries(tmp_path):
-    """End-to-end smoke in a fresh process (the cache backend binds its
-    directory at first use, so an in-process dir swap would test
-    nothing): NIDT_COMPILE_CACHE alone routes compiles to disk."""
-    cache = tmp_path / "cc"
-    code = (
-        "from neuroimagedisttraining_tpu.utils.compile_cache import "
-        "enable_compile_cache\n"
-        "import jax, jax.numpy as jnp\n"
-        "assert enable_compile_cache(None, default='') is not None\n"
-        "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
-        " 0.0)\n"
-        "f = jax.jit(lambda x: jnp.tanh(x) @ x.T)\n"
-        "f(jnp.ones((37, 53))).block_until_ready()\n"
-    )
-    subprocess.run(
-        [sys.executable, "-c", code], check=True, timeout=300,
-        env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu",
-             "NIDT_COMPILE_CACHE": str(cache),
-             "PYTHONPATH": "."})
-    assert any(p.name.endswith("-cache") for p in cache.iterdir())
+@pytest.mark.parametrize("variable_set", [True, False],
+                         ids=["JAX_COMPILATION_CACHE_DIR-set", "unset"])
+def test_compile_cache_placed_from_outside(tmp_path, variable_set):
+    """One rule, checked in FRESH processes (the cache binds its
+    directory at first use). Variable set: the code never touches
+    ``jax_compilation_cache_dir`` — JAX's own value is the variable's —
+    and compiles land there. Unset: a fixed directory inside the
+    checkout, identical across two fresh processes."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": repo}
+    if variable_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "outside")
+    docs = []
+    for _ in range(2):
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHE_CHILD], check=True, timeout=300,
+            env=env, cwd=str(tmp_path), capture_output=True, text=True)
+        docs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    assert docs[0]["returned"] == docs[1]["returned"] == docs[0]["config"]
+    if variable_set:
+        assert docs[0]["returned"] == str(tmp_path / "outside")
+        assert "jax_compilation_cache_dir" not in docs[0]["updates"]
+        assert any(p.name.endswith("-cache")
+                   for p in (tmp_path / "outside").iterdir())
+    else:
+        assert docs[0]["returned"] == os.path.join(repo, ".jax_cache")
+        assert docs[0]["updates"].count("jax_compilation_cache_dir") == 1

@@ -237,11 +237,9 @@ def test_cross_silo_multiprocess_smoke():
 def test_init_multihost_single_process():
     """Drive the init_multihost hook for real (VERDICT r2 missing #3): a
     1-process jax.distributed runtime comes up, serves devices, and shuts
-    down. Multi-process CPU clustering is disabled in this jax build (see
-    init_multihost docstring), so >1-process coordination is exercised via
-    the socket protocol tests instead; on a real pod this same hook spans
-    hosts. Runs in a subprocess (backend init is irreversible) and SKIPs
-    where the runtime cannot bind."""
+    down (the clustered case is the next test); on a real pod this same
+    hook spans hosts. Runs in a subprocess (backend init is irreversible)
+    and SKIPs where the runtime cannot bind."""
     import subprocess
     import sys
 
@@ -263,6 +261,46 @@ def test_init_multihost_single_process():
         import pytest
 
         pytest.skip(f"jax.distributed unavailable here: {out.stderr[-300:]}")
+
+
+def test_init_multihost_two_processes_cluster():
+    """Two CPU processes join one runtime through the hook and reduce
+    across it: process_count is 2 and a sharded sum sees both processes'
+    rows (jax 0.9.0 clusters CPU processes over Gloo)."""
+    import subprocess
+    import sys
+
+    port = free_port_block(1)
+    code = (
+        "import sys, numpy as np, jax, jax.numpy as jnp\n"
+        "from jax.sharding import Mesh, NamedSharding, PartitionSpec as P\n"
+        "from neuroimagedisttraining_tpu.distributed.cross_silo import "
+        "init_multihost\n"
+        "rank = int(sys.argv[1])\n"
+        f"init_multihost('127.0.0.1:{port}', 2, rank)\n"
+        "assert jax.process_count() == 2, jax.process_count()\n"
+        "mesh = Mesh(np.array(jax.devices()), ('clients',))\n"
+        "x = jax.make_array_from_callback(\n"
+        "    (jax.device_count(),), NamedSharding(mesh, P('clients')),\n"
+        "    lambda idx: np.full((1,), rank + 1.0, np.float32))\n"
+        "total = jax.jit(jnp.sum, out_shardings=NamedSharding(mesh, P()))(x)\n"
+        "assert float(total) == 3.0, float(total)\n"
+        "jax.distributed.shutdown()\n"
+        "print('MULTIHOST_OK')\n")
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", code, str(rank)], stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, text=True,
+        env={**env, "JAX_PLATFORMS": "cpu"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        for rank in range(2)]
+    outs = [p.communicate(timeout=120) for p in procs]
+    if any("MULTIHOST_OK" not in out for out, _ in outs):
+        if any("bind" in err.lower() for _, err in outs):
+            import pytest
+
+            pytest.skip(f"jax.distributed cannot bind here: {outs}")
+        raise AssertionError(outs)
 
 
 def test_cross_silo_secure_aggregation_protocol():

@@ -67,3 +67,32 @@ def test_failed_build_logs_gpp_stderr(tmp_path, monkeypatch, caplog):
     # made it into the log record
     assert any("error" in r.message.lower() or "No such file" in r.message
                for r in caplog.records)
+
+
+def test_stale_library_is_rebuilt_whatever_its_mtime(tmp_path, monkeypatch,
+                                                     lib):
+    """Staleness is the recorded source hash, never a file time: a
+    library that is NEWER than gather.cpp but was built from other
+    source (what a tree copy can fake) is rebuilt; one whose record
+    matches is loaded untouched."""
+    import os
+    import shutil
+
+    src = tmp_path / "gather.cpp"
+    shutil.copy(native._SRC, src)
+    so = tmp_path / "libnidt_gather.so"
+    so.write_bytes(b"not a shared object")          # newer than src
+    os.utime(src, (1, 1))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_SO_SRC_HASH", str(so) + ".sha256")
+    monkeypatch.setattr(native, "_lib", None)
+    assert native.load() is not None                # rebuilt, loadable
+    assert (tmp_path / "libnidt_gather.so.sha256").read_text().strip() \
+        == native._src_hash()
+    built = so.read_bytes()
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build",
+                        lambda: pytest.fail("rebuilt a fresh library"))
+    assert native.load() is not None
+    assert so.read_bytes() == built

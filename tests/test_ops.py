@@ -304,3 +304,33 @@ def test_fast_maxpool_tie_gradient_is_conserved():
                                np.full((3, 3, 3), 0.0), atol=1e-7)
     np.testing.assert_allclose(np.asarray(g[0, :3, :3, :3, 1]),
                                np.full((3, 3, 3), 1.0 / 27), rtol=1e-6)
+
+
+@pytest.mark.parametrize("layout", ["replicated", "sharded"])
+def test_mask_from_scores_ranks_on_one_device(monkeypatch, layout):
+    """Scores that live on a whole mesh (what a sharded phase-1 hands
+    over) are ranked on ONE device — a Pallas kernel cannot lower in a
+    program that spans several — and the mask equals the single-device
+    one."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh()
+    spec = PartitionSpec() if layout == "replicated" \
+        else PartitionSpec(mesh.axis_names[0])
+    scores = {"conv": {"kernel": jax.random.uniform(
+        jax.random.key(3), (8 * 40, 16))}}
+    want, want_thr = S.mask_from_scores(scores, keep_ratio=0.3)
+    placed = jax.device_put(scores, NamedSharding(mesh, spec))
+    seen = []
+    real = S.kth_largest
+    monkeypatch.setattr(
+        S, "kth_largest",
+        lambda x, k: (seen.append(len(x.sharding.device_set)),
+                      real(x, k))[1])
+    got, thr = S.mask_from_scores(placed, keep_ratio=0.3)
+    assert seen == [1]
+    assert float(thr) == float(want_thr)
+    np.testing.assert_array_equal(np.asarray(got["conv"]["kernel"]),
+                                  np.asarray(want["conv"]["kernel"]))

@@ -450,3 +450,16 @@ def test_trainer_cli_rejects_bad_recipe_loudly(tmp_path):
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 2
     assert "invalid JSON" in out.stderr
+
+
+@pytest.mark.parametrize("kind,want", [
+    ("cpu", None),                       # host RAM is not modeled
+    ("TPU v5 lite", 16 << 30),
+    ("TPU v9 unheard-of", ValueError),   # never an unbounded default
+])
+def test_build_space_hbm_bound_by_device_kind(kind, want):
+    if want is ValueError:
+        with pytest.raises(ValueError, match="TPU v9 unheard-of"):
+            tune_space.build_space(kind)
+    else:
+        assert tune_space.build_space(kind).hbm_bytes == want
