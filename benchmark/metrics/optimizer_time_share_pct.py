@@ -1,0 +1,3 @@
+"""Reader of a share of device time by scope class: benchmark/scopes.py."""
+
+from benchmark.scopes import read  # noqa: F401

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import optax
 
 from neuroimagedisttraining_tpu.config import OptimConfig
+from neuroimagedisttraining_tpu.obs import names as obs_names
 
 #: legal ``OptimConfig.precision`` values, in contract order
 PRECISIONS = ("fp32", "bf16_mixed")
@@ -110,19 +111,30 @@ class LocalOptimizer(NamedTuple):
     fused_apply: object | None = None
 
 
+def _clip(max_norm: float) -> optax.GradientTransformation:
+    """``optax.clip_by_global_norm`` (or the identity) under the device
+    scope SCOPE_CLIP: same init, same update, a name on its ops."""
+    tx = (optax.clip_by_global_norm(max_norm) if max_norm > 0
+          else optax.identity())
+
+    def update(updates, state, params=None):
+        with jax.named_scope(obs_names.SCOPE_CLIP):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
+
+
 def make_local_optimizer(cfg: OptimConfig) -> LocalOptimizer:
     if cfg.client_optimizer == "sgd":
         tx = optax.chain(
-            optax.clip_by_global_norm(cfg.grad_clip) if cfg.grad_clip > 0
-            else optax.identity(),
+            _clip(cfg.grad_clip),
             optax.add_decayed_weights(cfg.wd) if cfg.wd > 0 else optax.identity(),
             optax.trace(decay=cfg.momentum) if cfg.momentum > 0
             else optax.identity(),
         )
     elif cfg.client_optimizer == "adam":
         tx = optax.chain(
-            optax.clip_by_global_norm(cfg.grad_clip) if cfg.grad_clip > 0
-            else optax.identity(),
+            _clip(cfg.grad_clip),
             optax.scale_by_adam(),
             optax.add_decayed_weights(cfg.wd) if cfg.wd > 0 else optax.identity(),
         )

@@ -36,6 +36,7 @@ from neuroimagedisttraining_tpu.core.optim import (
     make_local_optimizer, validate_precision,
 )
 from neuroimagedisttraining_tpu.models import primary_logits
+from neuroimagedisttraining_tpu.obs import names as obs_names
 
 PyTree = Any
 
@@ -66,6 +67,15 @@ def epoch_perms_for(rng: jax.Array, epochs: int, max_samples: int,
     parallel/cohort.py). Must mirror local_train's split exactly."""
     _, prng = jax.random.split(rng)
     return epoch_permutations(prng, epochs, max_samples, n_valid)
+
+
+def scan_steps(epochs: int, batch_size: int, max_samples: int) -> int:
+    """SGD steps ``local_train``'s scan runs for ONE client row, real or
+    padded: every row walks ``ceil(max_samples / batch_size)`` steps an
+    epoch, and a row with fewer samples masks the surplus as no-ops. The
+    round driver's ``steps_run`` count (obs/names.py
+    SPAN_DISPATCH_PROGRAM) is this times the rows a program trains."""
+    return epochs * max(1, math.ceil(max_samples / batch_size))
 
 
 def shuffle_batch_indices(perms: jax.Array, t, steps_per_epoch: int,
@@ -135,9 +145,11 @@ class LocalTrainer:
         is exactly one rank short of the model's declared ``input_rank``
         (reference ``unsqueeze(1)``, my_model_trainer.py:216 — ours is
         channels-last)."""
-        x = x.astype(jnp.float32)  # nidt: allow[precision-upcast] -- reference raw-cast parity (my_model_trainer.py:197-198): the uint8 input-quantization boundary, models re-cast to compute dtype
-        if self._input_rank is not None and x.ndim == self._input_rank - 1:
-            x = x[..., None]  # e.g. [B,D,H,W] -> [B,D,H,W,1]
+        with jax.named_scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(jnp.float32)  # nidt: allow[precision-upcast] -- reference raw-cast parity (my_model_trainer.py:197-198): the uint8 input-quantization boundary, models re-cast to compute dtype
+            if self._input_rank is not None \
+                    and x.ndim == self._input_rank - 1:
+                x = x[..., None]  # e.g. [B,D,H,W] -> [B,D,H,W,1]
         return x
 
     def _apply(self, params, batch_stats, x, train: bool, dropout_rng=None):
@@ -178,8 +190,10 @@ class LocalTrainer:
                                       train=True, dropout_rng=drng)
             return self._scaled(self.loss(primary_logits(out), y)), bstats
 
-        (loss, bstats), grads = jax.value_and_grad(f, has_aux=True)(cs.params)
-        loss, grads = self._unscaled(loss, grads)
+        with jax.named_scope(obs_names.SCOPE_FWD_BWD):
+            (loss, bstats), grads = jax.value_and_grad(
+                f, has_aux=True)(cs.params)
+            loss, grads = self._unscaled(loss, grads)
         return loss, grads, bstats, rng
 
     def local_train(self, cs: ClientState, X, y, n_valid, lr, epochs: int,
@@ -220,9 +234,9 @@ class LocalTrainer:
         bitwise pins). The rng stream is identical either way: the split
         that would have fed the permutation is still consumed.
         """
-        steps_per_epoch = max(1, math.ceil(max_samples / batch_size))
+        total = scan_steps(epochs, batch_size, max_samples)
+        steps_per_epoch = total // epochs
         my_steps = jnp.ceil(n_valid / batch_size).astype(jnp.int32)
-        total = epochs * steps_per_epoch
         shuffle = self.optim_cfg.batch_order == "shuffle"
         if shuffle:
             # reference DataLoader semantics: each epoch walks a fresh
@@ -236,15 +250,16 @@ class LocalTrainer:
         def step(carry, t):
             state = carry
             rng, brng, drng = jax.random.split(state.rng, 3)
-            if shuffle:
-                idx, wb = shuffle_batch_indices(perms, t, steps_per_epoch,
-                                                batch_size, n_valid)
-            else:
-                idx = jax.random.randint(brng, (batch_size,), 0,
-                                         jnp.maximum(n_valid, 1))
-                wb = None
-            xb = jnp.take(X, idx, axis=0)
-            yb = jnp.take(y, idx, axis=0)
+            with jax.named_scope(obs_names.SCOPE_BATCH_PREP):
+                if shuffle:
+                    idx, wb = shuffle_batch_indices(
+                        perms, t, steps_per_epoch, batch_size, n_valid)
+                else:
+                    idx = jax.random.randint(brng, (batch_size,), 0,
+                                             jnp.maximum(n_valid, 1))
+                    wb = None
+                xb = jnp.take(X, idx, axis=0)
+                yb = jnp.take(y, idx, axis=0)
 
             def f(params):
                 out, bstats = self._apply(params, state.batch_stats,
@@ -253,37 +268,45 @@ class LocalTrainer:
                 return self._scaled(
                     self.loss(primary_logits(out), yb, weights=wb)), bstats
 
-            (loss, bstats), grads = jax.value_and_grad(f, has_aux=True)(
-                state.params)
-            loss, grads = self._unscaled(loss, grads)
-            if self.opt.fused_apply is not None:
-                # fused clip+wd+momentum+update+mask tail in one pass
-                # (ops/fused_update.py; bit-parity with the chain below)
-                params, opt_state = self.opt.fused_apply(
-                    grads, state.opt_state, state.params, lr, mask)
-            else:
-                updates, opt_state = self.opt.update(grads, state.opt_state,
-                                                     state.params, lr)
-                params = jax.tree.map(jnp.add, state.params, updates)
-                if mask is not None:
-                    params = jax.tree.map(jnp.multiply, params, mask)
-            if prox_lamda is not None:
-                params = jax.tree.map(
-                    lambda w, ref: w - lr * prox_lamda * (w - ref),
-                    params, prox_ref)
+            with jax.named_scope(obs_names.SCOPE_FWD_BWD):
+                (loss, bstats), grads = jax.value_and_grad(
+                    f, has_aux=True)(state.params)
+                loss, grads = self._unscaled(loss, grads)
+            # the optimizer tail carries one name on both paths (the
+            # global-norm clip inside it is SCOPE_CLIP: core/optim.py,
+            # ops/fused_update.py), so a trace reads it fused or not
+            with jax.named_scope(obs_names.SCOPE_UPDATE):
+                if self.opt.fused_apply is not None:
+                    # fused clip+wd+momentum+update+mask tail in one pass
+                    # (ops/fused_update.py; bit-parity with the chain
+                    # below)
+                    params, opt_state = self.opt.fused_apply(
+                        grads, state.opt_state, state.params, lr, mask)
+                else:
+                    updates, opt_state = self.opt.update(
+                        grads, state.opt_state, state.params, lr)
+                    params = jax.tree.map(jnp.add, state.params, updates)
+                    if mask is not None:
+                        with jax.named_scope(obs_names.SCOPE_MASK_APPLY):
+                            params = jax.tree.map(jnp.multiply, params,
+                                                  mask)
+                if prox_lamda is not None:
+                    params = jax.tree.map(
+                        lambda w, ref: w - lr * prox_lamda * (w - ref),
+                        params, prox_ref)
 
-            active = (t % steps_per_epoch) < my_steps
+                active = (t % steps_per_epoch) < my_steps
 
-            def keep(new, old):
-                return jax.tree.map(
-                    lambda a, b: jnp.where(active, a, b), new, old)
+                def keep(new, old):
+                    return jax.tree.map(
+                        lambda a, b: jnp.where(active, a, b), new, old)
 
-            new_state = ClientState(
-                params=keep(params, state.params),
-                batch_stats=keep(bstats, state.batch_stats),
-                opt_state=keep(opt_state, state.opt_state),
-                rng=rng)
-            return new_state, jnp.where(active, loss, 0.0)
+                new_state = ClientState(
+                    params=keep(params, state.params),
+                    batch_stats=keep(bstats, state.batch_stats),
+                    opt_state=keep(opt_state, state.opt_state),
+                    rng=rng)
+                return new_state, jnp.where(active, loss, 0.0)
 
         cs, losses = jax.lax.scan(step, cs, jnp.arange(total))
         denom = jnp.maximum(epochs * my_steps, 1)
@@ -329,6 +352,7 @@ class LocalTrainer:
 
     # ---------- evaluation ----------
 
+    @jax.named_scope(obs_names.SCOPE_EVAL)
     def evaluate(self, params, batch_stats, X, y, valid, batch_size: int = 32):
         """Chunked full-set eval. Returns dict with ``test_correct``,
         ``test_loss`` (sum), ``test_total`` and raw ``scores`` for AUC."""

@@ -31,6 +31,7 @@ import numpy as np
 from neuroimagedisttraining_tpu.data.hdf5 import fetch_rows
 from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.utils import native
 
 
@@ -109,14 +110,22 @@ class StreamingFederation:
                                "bytes": 0.0, "fetches": 0}
         self._stats_lock = threading.Lock()
 
-    def _note_transfer(self, gather_s: float, put_s: float,
+    def _note_transfer(self, t0: float, t1: float, t2: float,
                        nbytes: int, fetches: int = 1) -> None:
-        """Accumulate one work unit's stage timings into
+        """Accumulate one work unit's stage timings (gather ``t0..t1``,
+        device_put ``t1..t2``, on ``time.perf_counter``) into
         ``transfer_stats`` and mirror the totals into the obs registry
         (host/reader-thread only — the registry is thread-safe and this
-        never runs inside a trace). The gauge carries THIS feed's
-        totals; with several concurrent feeds in one process (tests) the
-        last writer wins — a run owns one feed."""
+        never runs inside a trace). The same three clock reads become
+        the reader thread's ``feed_gather`` and ``feed_put`` spans when
+        the tracer is armed. The gauge carries THIS feed's totals; with
+        several concurrent feeds in one process (tests) the last writer
+        wins — a run owns one feed."""
+        gather_s, put_s = t1 - t0, t2 - t1
+        obs_trace.TRACER.record_interval(
+            obs_names.SPAN_FEED_GATHER, t0, t1, bytes=int(nbytes))
+        obs_trace.TRACER.record_interval(
+            obs_names.SPAN_FEED_PUT, t1, t2, bytes=int(nbytes))
         g = obs_metrics.gauge(
             obs_names.STREAM_TRANSFER,
             "cumulative streaming-feed totals (data/stream.py "
@@ -206,7 +215,7 @@ class StreamingFederation:
         out = (self._put(Xs), self._put(ys), self._put(ns))
         jax.block_until_ready(out[0])
         t2 = time.perf_counter()
-        self._note_transfer(t1 - t0, t2 - t1,
+        self._note_transfer(t0, t1, t2,
                             Xs.nbytes + ys.nbytes + ns.nbytes)
         return out
 
@@ -233,7 +242,7 @@ class StreamingFederation:
                self._put(ns, client_axis=1))
         jax.block_until_ready(out[0])
         t2 = time.perf_counter()
-        self._note_transfer(t1 - t0, t2 - t1,
+        self._note_transfer(t0, t1, t2,
                             Xs.nbytes + ys.nbytes + ns.nbytes, fetches=K)
         return out
 
@@ -258,10 +267,17 @@ class StreamingFederation:
         prefetched (already transferred) buffer when it matches."""
         key = ("train", tuple(int(c) for c in client_ids), n_real)
         if self._pending is not None and self._pending[0] == key:
-            out = self._pending[1].result()
+            out = self._wait(self._pending[1])
             self._pending = None
             return out
         return self._fetch_put(np.asarray(client_ids), "train", n_real)
+
+    @staticmethod
+    def _wait(future):
+        """The driver's wait on the reader thread: all of it is time the
+        feed did not hide behind compute (span ``feed_wait``)."""
+        with obs_trace.span(obs_names.SPAN_FEED_WAIT):
+            return future.result()
 
     # ---------- window-granular feed (fused dispatch, ISSUE 10) ----------
 
@@ -295,7 +311,7 @@ class StreamingFederation:
         ``prefetch_train`` contract)."""
         key = self._window_key(ids_per_round, n_real)
         if self._pending is not None and self._pending[0] == key:
-            out = self._pending[1].result()
+            out = self._wait(self._pending[1])
             self._pending = None
             return out
         return self._fetch_put_window(
@@ -336,7 +352,7 @@ class StreamingFederation:
         fut = self._pool.submit(self._fetch_put, metas[0][1], split,
                                 len(metas[0][0]))
         for i, (ids, padded) in enumerate(metas):
-            Xs, ys, ns = fut.result()
+            Xs, ys, ns = self._wait(fut)
             if i + 1 < len(metas):
                 fut = self._pool.submit(self._fetch_put, metas[i + 1][1],
                                         split, len(metas[i + 1][0]))

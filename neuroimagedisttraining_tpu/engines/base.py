@@ -24,7 +24,9 @@ from neuroimagedisttraining_tpu.codec import wire as codec_wire
 from neuroimagedisttraining_tpu.config import ExperimentConfig
 from neuroimagedisttraining_tpu.core import robust
 from neuroimagedisttraining_tpu.core.losses import binary_auc
-from neuroimagedisttraining_tpu.core.trainer import ClientState, LocalTrainer
+from neuroimagedisttraining_tpu.core.trainer import (
+    ClientState, LocalTrainer, scan_steps,
+)
 from neuroimagedisttraining_tpu.core.optim import round_lr
 from neuroimagedisttraining_tpu.data.federate import FederatedData
 from neuroimagedisttraining_tpu.faults import adversary
@@ -320,6 +322,10 @@ class FederatedEngine:
         #: batched device_get as the non-finite counts — never a
         #: per-round sync
         self._health_pending: list = []
+        #: host integers of the round(s) about to be dispatched
+        #: (``_note_round_counts``), taken as arguments by the next
+        #: ``dispatch_program`` span; empty while the tracer is disarmed
+        self._dispatch_counts: dict = {}
         #: monotonic sequence / round watermark of the metrics JSONL
         #: sink (ISSUE 15 satellite: every record carries a round +
         #: seq so run_report joins series without timestamp heuristics)
@@ -396,8 +402,19 @@ class FederatedEngine:
         return jnp.zeros((1,) + tuple(shape), jnp.float32)
 
     def init_global_state(self) -> ClientState:
+        """The initial global state, placed where the round programs
+        leave their outputs: replicated over the mesh and committed. An
+        uncommitted initial state gave the first dispatch another
+        signature than every later one, so the round program was traced,
+        lowered and compiled twice a run (the dispatch rows of a run on
+        the v5e: 9.6 s, then 7.1-8.0 s, then 1 ms; PERF.md, PR 23)."""
         rng = jax.random.key(self.cfg.seed)
-        return self.trainer.init_client_state(rng, self.sample_input())
+        cs = self.trainer.init_client_state(rng, self.sample_input())
+        if self.mesh is None:
+            return cs
+        return jax.device_put(
+            cs, jax.sharding.NamedSharding(self.mesh,
+                                           jax.sharding.PartitionSpec()))
 
     def broadcast_states(self, cs: ClientState, n: int) -> ClientState:
         """Replicate one state across a leading client axis of size n."""
@@ -512,7 +529,8 @@ class FederatedEngine:
                 auc = binary_auc(m["scores"], yc, valid)
                 return m["test_correct"], m["test_loss"], m["test_total"], auc
 
-            return jax.vmap(per_client)(X, y, n)
+            with jax.named_scope(obs_names.SCOPE_EVAL):
+                return jax.vmap(per_client)(X, y, n)
 
         return jax.jit(eval_all)
 
@@ -527,7 +545,8 @@ class FederatedEngine:
                 auc = binary_auc(m["scores"], yc, valid)
                 return m["test_correct"], m["test_loss"], m["test_total"], auc
 
-            return jax.vmap(per_client)(params, bstats, X, y, n)
+            with jax.named_scope(obs_names.SCOPE_EVAL):
+                return jax.vmap(per_client)(params, bstats, X, y, n)
 
         return jax.jit(eval_all)
 
@@ -554,10 +573,14 @@ class FederatedEngine:
         n = getattr(self.data, f"n_{split}")
         if self.cfg.fed.ci:  # CI escape hatch: client 0 only
             X, y, n = X[:1], y[:1], n[:1]
-        # eval is a host boundary (the _summarize numpy reads block on
-        # the device), so it is also a span: dispatch + sync wall time
-        with obs_trace.span("eval_global", split=split):
+        # two host spans, because they are two costs: the enqueue of the
+        # eval program, and the blocking read of its result, which waits
+        # for everything dispatched before it (the round itself)
+        with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
+                            program="eval_global", split=split):
             out = self._eval_global_jit(params, bstats, X, y, n)
+        with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                            program="eval_global"):
             return self._summarize(*out,
                                    n=n if not self.cfg.fed.ci else n[:1])
 
@@ -572,8 +595,11 @@ class FederatedEngine:
             X, y, n = X[:1], y[:1], n[:1]
             params = pt.tree_stack_index(params, slice(0, 1))
             bstats = pt.tree_stack_index(bstats, slice(0, 1))
-        with obs_trace.span("eval_personalized", split=split):
+        with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
+                            program="eval_personalized", split=split):
             out = self._eval_personal_jit(params, bstats, X, y, n)
+        with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                            program="eval_personalized"):
             return self._summarize(*out, n=n)
 
     # ---------- checkpoint / resume (SURVEY §5.4 rebuild requirement) ----------
@@ -871,6 +897,31 @@ class FederatedEngine:
             int(c): stats["epsilon"] for c in range(self.real_clients)}
         self._dp_recorded_through = round_idx
 
+    # ---------- counts at the dispatch boundary (obs/trace.py) ----------
+
+    def _note_round_counts(self, sampled, rows: int) -> None:
+        """What the next dispatched program trains, as host integers the
+        driver already holds (no device read): ``samples_real`` and
+        ``steps_real`` over the sampled clients of the round(s)
+        (``sampled``: one id array per round), and ``steps_run``, the
+        steps the program's scans walk for its ``rows`` client rows a
+        round, padded rows and masked steps included
+        (core/trainer.py ``scan_steps``: the rule ``local_train`` itself
+        uses). They ride on the ``dispatch_program`` span, so a trace
+        reads the padded share where the work is dispatched. A no-op
+        while the tracer is disarmed."""
+        if not obs_trace.TRACER.armed:
+            return
+        o = self.cfg.optim
+        n = np.concatenate([self._n_train_host[np.asarray(s)]
+                            for s in sampled])
+        self._dispatch_counts = {
+            "samples_real": int(o.epochs * n.sum()),
+            "steps_real": int(o.epochs
+                              * np.ceil(n / o.batch_size).sum()),
+            "steps_run": int(len(sampled) * rows * scan_steps(
+                o.epochs, o.batch_size, self._max_samples()))}
+
     # ---------- non-finite upload guard (ISSUE 5 satellite) ----------
 
     def _note_nonfinite(self, n_bad) -> None:
@@ -961,7 +1012,7 @@ class FederatedEngine:
         if self._nonfinite_pending or self._health_pending:
             health_entries = self._health_pending
             self._health_pending = []
-            with obs_trace.span("flush_nonfinite", round=round_idx):
+            with obs_trace.span(obs_names.SPAN_FLUSH_SYNC):
                 counts, health_vals = jax.device_get(
                     (self._nonfinite_pending,
                      [e[2] for e in health_entries]))
@@ -1527,9 +1578,15 @@ class FederatedEngine:
         parts: list[tuple] = []
         ns: list[np.ndarray] = []
         for ch in self.stream.eval_chunks(self._eval_chunk_size(), split):
-            out = self._eval_global_jit(params, bstats, ch.X, ch.y, ch.n)
-            parts.append(tuple(np.asarray(o)[: len(ch.ids)] for o in out))
-            ns.append(np.asarray(jax.device_get(ch.n))[: len(ch.ids)])
+            with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
+                                program="eval_global", split=split):
+                out = self._eval_global_jit(params, bstats, ch.X, ch.y,
+                                            ch.n)
+            with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                                program="eval_global"):
+                parts.append(tuple(np.asarray(o)[: len(ch.ids)]
+                                   for o in out))
+                ns.append(np.asarray(jax.device_get(ch.n))[: len(ch.ids)])
             if self.cfg.fed.ci:
                 break
         cat = [np.concatenate([p[i] for p in parts]) for i in range(4)]
@@ -1578,9 +1635,14 @@ class FederatedEngine:
         for ch in self.stream.eval_chunks(chunk, split):
             p = pt.tree_stack_index(per_params, ch.padded_ids)
             b = pt.tree_stack_index(per_bstats, ch.padded_ids)
-            out = self._eval_personal_jit(p, b, ch.X, ch.y, ch.n)
-            parts.append(tuple(np.asarray(o)[: len(ch.ids)] for o in out))
-            ns.append(np.asarray(jax.device_get(ch.n))[: len(ch.ids)])
+            with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
+                                program="eval_personalized", split=split):
+                out = self._eval_personal_jit(p, b, ch.X, ch.y, ch.n)
+            with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                                program="eval_personalized"):
+                parts.append(tuple(np.asarray(o)[: len(ch.ids)]
+                                   for o in out))
+                ns.append(np.asarray(jax.device_get(ch.n))[: len(ch.ids)])
             if self.cfg.fed.ci:
                 break
         cat = [np.concatenate([p[i] for p in parts]) for i in range(4)]

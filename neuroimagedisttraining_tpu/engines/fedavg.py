@@ -25,6 +25,7 @@ from neuroimagedisttraining_tpu.core import robust
 from neuroimagedisttraining_tpu.core.trainer import ClientState
 from neuroimagedisttraining_tpu.engines import program as round_program
 from neuroimagedisttraining_tpu.engines.base import FederatedEngine
+from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.utils import pytree as pt
 
@@ -261,6 +262,154 @@ class FedAvgEngine(FederatedEngine):
     def _finetune_stream_jit(self):
         return jax.jit(self._finetune_body)
 
+    def _round_iteration(self, round_idx: int, params, bstats, history,
+                         fuse: bool):
+        """One iteration of the round loop, resident or streamed: the
+        host prologue, the dispatch of one round (or of one fused
+        window), and the boundary hooks. Returns ``(next_round_idx,
+        params, bstats, history)``. Each stage is a host span
+        (obs/names.py) that takes its round id from the caller's ``round``
+        span, which covers the whole iteration; only the ``*_sync`` spans
+        and ``feed_wait`` wait for anything."""
+        cfg = self.cfg
+        streaming = self.stream is not None
+        codec_on = self.wire_spec is not None and not streaming
+        with obs_trace.span(obs_names.SPAN_ROUND_PROLOGUE):
+            # elastic compute plane (ISSUE 20): a scheduled device loss
+            # shrinks the mesh mid-run; resume from the donation-safe
+            # checkpoint when one exists, else continue on the live
+            # state over the survivors
+            pre = self._maybe_preempt(round_idx)
+            if pre is not None:
+                if pre[1] is not None:
+                    round_idx, restored = pre
+                    params, bstats = (restored["params"],
+                                      restored["batch_stats"])
+                    history = restored["history"]
+                else:
+                    # no checkpoint: continue on the live state over the
+                    # survivors — off the evicted devices first
+                    params = self._regather_live(params)
+                    bstats = self._regather_live(bstats)
+                if streaming:
+                    # the prefetched shards targeted the pre-preemption
+                    # round; re-key the feed (a key mismatch would
+                    # degrade to a fresh fetch anyway)
+                    self._stream_prefetch_for(round_idx)
+                if pre[1] is not None:
+                    return round_idx, params, bstats, history
+            k = self._dispatch_window(round_idx) if fuse else 1
+            if k > 1:
+                pass  # the window drivers below have their own prologue
+            elif streaming:
+                lr = self.round_lr(round_idx)
+                ids, n_real = self.stream_sampling(round_idx)
+                self.log.info("################ round %d (stream): "
+                              "clients %s", round_idx,
+                              ids[:n_real].tolist())
+                Xs, ys, ns = self.stream.get_train(ids, n_real)
+                # overlap the next dispatch's host read (single round or
+                # whole window) with this round's compute
+                self._stream_prefetch_for(round_idx + 1)
+                rngs = self.per_client_rngs(round_idx, ids)
+                byz = self._byz_round_plan(round_idx, ids)
+                self._note_round_counts([ids[:n_real]],
+                                        len(ids))
+            else:
+                lr = self.round_lr(round_idx)
+                sampled = self.client_sampling(round_idx)
+                self.log.info("################ round %d: clients %s",
+                              round_idx, sampled.tolist())
+                # cohort sharding (ISSUE 6): the sharded program gathers
+                # the mesh-padded set (and takes rngs for it); the EF
+                # rows, byz plan, and byte accounting stay on the REAL
+                # sampled set — the body slices pads off before that tail
+                ids, round_prog = self._cohort_round_prog(sampled)
+                rngs = self.per_client_rngs(round_idx, ids)
+                byz = self._byz_round_plan(round_idx, sampled)
+                idx = jnp.asarray(ids)
+                if codec_on:
+                    # downlink reference snapshot BEFORE dispatch: the
+                    # round donates {params, bstats} and the sampled EF
+                    # rows, so nothing may read them after the call
+                    with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                        ref_host = jax.tree.map(np.asarray,
+                                                {"params": params,
+                                                 "batch_stats": bstats})
+                    efs = (pt.tree_stack_index(self._wire_ef,
+                                               np.asarray(sampled))
+                           if self.wire_spec.needs_ef else None)
+                self._note_round_counts([sampled], len(ids))
+        if k > 1:
+            run = (self._run_fused_stream_window if streaming
+                   else self._run_fused_window)
+            params, bstats, loss, k = run(params, bstats, round_idx, k)
+            round_idx += k - 1  # hooks below fire for the boundary
+        else:
+            if streaming:
+                # efs/byz stay default-bound (None) when there is no
+                # plan: subclasses override the round jits with efs-free
+                # signatures (turboaggregate), and an argument filled
+                # from its default is never donated
+                tail = () if byz is None else (None, byz)
+                params, bstats, loss, n_bad = self._round_stream_jit(
+                    params, bstats, Xs, ys, ns, rngs, lr, *tail)
+            elif codec_on:
+                (params, bstats, loss, n_bad, new_efs, u0) = round_prog(
+                    params, bstats, self.data, idx, rngs, lr, efs, byz)
+                if new_efs is not None:
+                    real = jnp.asarray(self._n_train_host[sampled] > 0)
+                    self._wire_ef = self.scatter_sampled_rows(
+                        self._wire_ef, new_efs, jnp.asarray(sampled),
+                        real)
+                with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                    self.account_wire_bytes(
+                        jax.tree.map(np.asarray, u0), ref_host, None,
+                        len(sampled))
+            else:
+                # byz plans only reach engines whose round accepts them
+                # (supports_byz_faults gates at startup)
+                tail = () if byz is None else (None, byz)
+                params, bstats, loss, n_bad = round_prog(
+                    params, bstats, self.data, idx, rngs, lr, *tail)
+            self._note_nonfinite(n_bad)
+        if round_idx % cfg.fed.frequency_of_the_test == 0 \
+                or round_idx == cfg.fed.comm_round - 1:
+            m = self._eval_g(params, bstats)
+            with obs_trace.span(obs_names.SPAN_ROUND_FLUSH):
+                self._flush_nonfinite(round_idx)
+                # the rule evaluation inside the flush may have fired
+                # freeze_rollback; consume it (or pin healthy state) at
+                # this host boundary, never mid-dispatch
+                params, bstats = self._reflex_boundary(round_idx, params,
+                                                       bstats)
+            with obs_trace.span(obs_names.SPAN_ROUND_LOG):
+                self.stat_info["global_test_acc"].append(m["acc"])
+                self.log.metrics(round_idx, train_loss=loss, **m)
+                history.append({"round": round_idx,
+                                "train_loss": float(loss), **m})
+        with obs_trace.span(obs_names.SPAN_ROUND_CHECKPOINT):
+            self.maybe_checkpoint(round_idx, {
+                "params": params, "batch_stats": bstats,
+                "history": history})
+        return round_idx + 1, params, bstats, history
+
+    def _round_loop(self, start: int, params, bstats, history):
+        """Rounds ``start .. comm_round - 1``: one ``round`` span (the
+        whole iteration, sampling to checkpoint) around each
+        ``_round_iteration``, whose children carry the same round id."""
+        cfg = self.cfg
+        fuse = (cfg.fed.rounds_per_dispatch > 1
+                and self.fused_fallback_reason() is None)
+        round_idx = start
+        while round_idx < cfg.fed.comm_round:
+            with obs_trace.span(obs_names.SPAN_ROUND, round=round_idx):
+                round_idx, params, bstats, history = \
+                    self._round_iteration(round_idx, params, bstats,
+                                          history, fuse)
+        self._flush_nonfinite(cfg.fed.comm_round - 1)
+        return params, bstats, history
+
     def train(self):
         if self.stream is not None:
             return self._train_streaming()
@@ -274,8 +423,7 @@ class FedAvgEngine(FederatedEngine):
             gs = self.init_global_state()
             params, bstats = gs.params, gs.batch_stats
             history = []
-        codec_on = self.wire_spec is not None
-        if codec_on and self.wire_spec.needs_ef:
+        if self.wire_spec is not None and self.wire_spec.needs_ef:
             # per-client error-feedback accumulators over the FULL upload
             # payload (params + batch_stats — what the wire encodes),
             # threaded across rounds: rows for the sampled set ride into
@@ -285,101 +433,8 @@ class FedAvgEngine(FederatedEngine):
                 lambda x: jnp.zeros((self.num_clients,) + x.shape,
                                     jnp.float32),
                 {"params": params, "batch_stats": bstats})
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            # elastic compute plane (ISSUE 20): a scheduled device loss
-            # shrinks the mesh mid-run; resume from the donation-safe
-            # checkpoint when one exists, else continue on the live
-            # state over the survivors
-            pre = self._maybe_preempt(round_idx)
-            if pre is not None:
-                if pre[1] is not None:
-                    round_idx, restored = pre
-                    params, bstats = (restored["params"],
-                                      restored["batch_stats"])
-                    history = restored["history"]
-                    continue
-                # no checkpoint: continue on the live state over the
-                # survivors — off the evicted devices first
-                params = self._regather_live(params)
-                bstats = self._regather_live(bstats)
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                params, bstats, loss, k = self._run_fused_window(
-                    params, bstats, round_idx, k)
-                round_idx += k - 1  # hooks below fire for the boundary
-            else:
-                sampled = self.client_sampling(round_idx)
-                self.log.info("################ round %d: clients %s",
-                              round_idx, sampled.tolist())
-                # cohort sharding (ISSUE 6): the sharded program gathers
-                # the mesh-padded set (and takes rngs for it); the EF
-                # rows, byz plan, and byte accounting stay on the REAL
-                # sampled set — the body slices pads off before that tail
-                ids, round_prog = self._cohort_round_prog(sampled)
-                rngs = self.per_client_rngs(round_idx, ids)
-                byz = self._byz_round_plan(round_idx, sampled)
-                if codec_on:
-                    # downlink reference snapshot BEFORE dispatch: the
-                    # round donates {params, bstats} and the sampled EF
-                    # rows, so nothing may read them after the call
-                    ref_host = jax.tree.map(np.asarray,
-                                            {"params": params,
-                                             "batch_stats": bstats})
-                    efs = (pt.tree_stack_index(self._wire_ef,
-                                               np.asarray(sampled))
-                           if self.wire_spec.needs_ef else None)
-                    with obs_trace.span("round", round=round_idx,
-                                        codec=True):
-                        (params, bstats, loss, n_bad, new_efs,
-                         u0) = round_prog(
-                            params, bstats, self.data, jnp.asarray(ids),
-                            rngs, self.round_lr(round_idx), efs, byz)
-                    if new_efs is not None:
-                        real = jnp.asarray(self._n_train_host[sampled] > 0)
-                        self._wire_ef = self.scatter_sampled_rows(
-                            self._wire_ef, new_efs, jnp.asarray(sampled),
-                            real)
-                    self.account_wire_bytes(jax.tree.map(np.asarray, u0),
-                                            ref_host, None, len(sampled))
-                elif byz is not None:
-                    # byz plans only reach engines whose round accepts
-                    # them (supports_byz_faults gates at startup); efs
-                    # rides its default None
-                    with obs_trace.span("round", round=round_idx):
-                        params, bstats, loss, n_bad = round_prog(
-                            params, bstats, self.data, jnp.asarray(ids),
-                            rngs, self.round_lr(round_idx), None, byz)
-                else:
-                    # efs/byz stay default-bound (None): subclasses
-                    # override _round_jit with efs-free signatures
-                    # (turboaggregate), and an argument filled from its
-                    # default is never donated, so no explicit None is
-                    # needed here
-                    with obs_trace.span("round", round=round_idx):
-                        params, bstats, loss, n_bad = round_prog(
-                            params, bstats, self.data, jnp.asarray(ids),
-                            rngs, self.round_lr(round_idx))
-                self._note_nonfinite(n_bad)
-            if round_idx % cfg.fed.frequency_of_the_test == 0 \
-                    or round_idx == cfg.fed.comm_round - 1:
-                m = self.eval_global(params, bstats)
-                self._flush_nonfinite(round_idx)
-                # the rule evaluation inside the flush may have fired
-                # freeze_rollback; consume it (or pin healthy state) at
-                # this host boundary, never mid-dispatch
-                params, bstats = self._reflex_boundary(round_idx, params,
-                                                       bstats)
-                self.stat_info["global_test_acc"].append(m["acc"])
-                self.log.metrics(round_idx, train_loss=loss, **m)
-                history.append({"round": round_idx, "train_loss": float(loss),
-                                **m})
-            self.maybe_checkpoint(round_idx, {
-                "params": params, "batch_stats": bstats, "history": history})
-            round_idx += 1
-        self._flush_nonfinite(cfg.fed.comm_round - 1)
+        params, bstats, history = self._round_loop(start, params, bstats,
+                                                   history)
         # final fine-tune pass -> personalized models + final eval at "-1"
         rngs = self.per_client_rngs(cfg.fed.comm_round,
                                     np.arange(self.num_clients))
@@ -416,65 +471,9 @@ class FedAvgEngine(FederatedEngine):
         # previous window's scan; hook rounds land on window boundaries
         # exactly as in the resident fused driver, so observable
         # behavior matches the round-granular loop
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
         self._stream_prefetch_for(start)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            pre = self._maybe_preempt(round_idx)
-            if pre is not None:
-                if pre[1] is not None:
-                    round_idx, restored = pre
-                    params, bstats = (restored["params"],
-                                      restored["batch_stats"])
-                    history = restored["history"]
-                    # the prefetched shards targeted the pre-preemption
-                    # round; re-key the feed to the resume point (a key
-                    # mismatch would degrade to a fresh fetch anyway)
-                    self._stream_prefetch_for(round_idx)
-                    continue
-                params = self._regather_live(params)
-                bstats = self._regather_live(bstats)
-                self._stream_prefetch_for(round_idx)
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                params, bstats, loss, k = self._run_fused_stream_window(
-                    params, bstats, round_idx, k)
-                round_idx += k - 1  # hooks below fire for the boundary
-            else:
-                fed_ids, n_real = self.stream_sampling(round_idx)
-                self.log.info("################ round %d (stream): "
-                              "clients %s", round_idx,
-                              fed_ids[:n_real].tolist())
-                Xs, ys, ns = self.stream.get_train(fed_ids, n_real)
-                # overlap the next dispatch's host read (single round or
-                # whole window) with this round's compute
-                self._stream_prefetch_for(round_idx + 1)
-                rngs = self.per_client_rngs(round_idx, fed_ids)
-                byz = self._byz_round_plan(round_idx, fed_ids)
-                if byz is not None:
-                    params, bstats, loss, n_bad = self._round_stream_jit(
-                        params, bstats, Xs, ys, ns, rngs,
-                        self.round_lr(round_idx), None, byz)
-                else:
-                    params, bstats, loss, n_bad = self._round_stream_jit(
-                        params, bstats, Xs, ys, ns, rngs,
-                        self.round_lr(round_idx))
-                self._note_nonfinite(n_bad)
-            if round_idx % cfg.fed.frequency_of_the_test == 0 \
-                    or round_idx == cfg.fed.comm_round - 1:
-                m = self.eval_global_stream(params, bstats)
-                self._flush_nonfinite(round_idx)
-                params, bstats = self._reflex_boundary(round_idx, params,
-                                                       bstats)
-                self.stat_info["global_test_acc"].append(m["acc"])
-                self.log.metrics(round_idx, train_loss=loss, **m)
-                history.append({"round": round_idx,
-                                "train_loss": float(loss), **m})
-            self.maybe_checkpoint(round_idx, {
-                "params": params, "batch_stats": bstats, "history": history})
-            round_idx += 1
-        self._flush_nonfinite(cfg.fed.comm_round - 1)
+        params, bstats, history = self._round_loop(start, params, bstats,
+                                                   history)
         # final fine-tune: chunked over client blocks; personalized models
         # are evaluated per block then discarded (they'd exceed HBM)
         chunk = self._eval_chunk_size()

@@ -329,11 +329,10 @@ class RoundCtx:
         tail call's permutations can be hoisted too. Mirrors
         ``local_train``'s stream exactly: one (rng0, perm) split at
         entry, then one 3-way split per scan step."""
-        import math
+        from neuroimagedisttraining_tpu.core.trainer import scan_steps
 
-        o = self.eng.cfg.optim
-        steps = epochs * max(1, math.ceil(self.eng._max_samples()
-                                          / o.batch_size))
+        steps = scan_steps(epochs, self.eng.cfg.optim.batch_size,
+                           self.eng._max_samples())
 
         def chain(rng):
             r0, _ = jax.random.split(rng)
@@ -872,16 +871,21 @@ class RoundProgram:
     # ---------- the round body, composed from the declared stages ----------
 
     def _gather(self, data, idx):
-        Xs = jnp.take(data.X_train, idx, axis=0)
-        ys = jnp.take(data.y_train, idx, axis=0)
-        ns = jnp.take(data.n_train, idx, axis=0)
+        with jax.named_scope(obs_names.SCOPE_GATHER):
+            Xs = jnp.take(data.X_train, idx, axis=0)
+            ys = jnp.take(data.y_train, idx, axis=0)
+            ns = jnp.take(data.n_train, idx, axis=0)
         return Xs, ys, ns
 
     def _body(self, carry_vals: tuple, data, const_vals: tuple, Xs, ys,
               ns, idx, rngs, lr, efs, byz, per_round_vals, static_key,
               n_real, sharded: bool):
-        """One round: the declared stages in builder order. Returns
-        ``(new_carry: dict, outs: dict, efs_tail: tuple)``."""
+        """One round: the declared stages in builder order, each under
+        its device scope (obs/names.py SCOPE_*: compile-time metadata a
+        profiler trace reads back; the one place every engine built on
+        the builder gets them). Returns ``(new_carry: dict, outs: dict,
+        efs_tail: tuple)``."""
+        scope = jax.named_scope
         eng, st = self.eng, self.stages
         carry = dict(zip(st.carry, carry_vals))
         consts = dict(zip(st.consts, const_vals))
@@ -890,7 +894,8 @@ class RoundProgram:
             ns = cohort.pad_row_weights(ns, n_real)
         ctx = RoundCtx(eng, st, carry, data, consts, Xs, ys, ns, idx,
                        rngs, lr, per_round, static_key, n_real, sharded)
-        tr = st.train(ctx)
+        with scope(obs_names.SCOPE_LOCAL_TRAIN):
+            tr = st.train(ctx)
         S = int(tr.losses.shape[0])
         if n_real is not None and n_real < S:
             # static slice: drop the mesh-pad rows before the
@@ -925,28 +930,32 @@ class RoundProgram:
             # stats — what the wire ships) before any encoding; honest
             # clients ride the plan's identity rows bitwise-untouched
             mult, std, nonfinite, keys = byz
-            upload = adversary.apply_attack_stacked(
-                upload, ctx.upload_ref, mult, std, nonfinite, keys)
+            with scope(obs_names.SCOPE_ATTACK):
+                upload = adversary.apply_attack_stacked(
+                    upload, ctx.upload_ref, mult, std, nonfinite, keys)
         if eng.wire_spec is not None:
-            upload, new_efs, u0 = _codec_stage(eng, st, ctx, upload, efs)
-        if st.aggregate is None:
-            rng_leaf = tr.state.rng if tr.state is not None else None
-            if getattr(eng, "sq_spec", None) is not None:
+            with scope(obs_names.SCOPE_CODEC):
+                upload, new_efs, u0 = _codec_stage(eng, st, ctx, upload,
+                                                   efs)
+        with scope(obs_names.SCOPE_AGGREGATE):
+            if st.aggregate is not None:
+                new_carry, outs = st.aggregate(ctx, upload, w, tr)
+            else:
+                rng_leaf = tr.state.rng if tr.state is not None else None
                 # --secure_quant: the field fold REPLACES the default
                 # tail (the in-process codec-family stage, ROADMAP 1(b))
-                new_params, new_bstats, mean_loss, n_bad = \
-                    secure_quant_aggregate(eng, upload, ctx.upload_ref,
-                                           w, tr.losses, rngs=rng_leaf)
-            else:
-                new_params, new_bstats, mean_loss, n_bad = \
-                    sanitize_defend_aggregate(eng, upload, ctx.upload_ref,
-                                              w, tr.losses, rngs=rng_leaf)
-            new_carry = {"params": new_params, "batch_stats": new_bstats}
-            outs = {"loss": mean_loss, "n_bad": n_bad}
-        else:
-            new_carry, outs = st.aggregate(ctx, upload, w, tr)
+                tail = (secure_quant_aggregate
+                        if getattr(eng, "sq_spec", None) is not None
+                        else sanitize_defend_aggregate)
+                new_params, new_bstats, mean_loss, n_bad = tail(
+                    eng, upload, ctx.upload_ref, w, tr.losses,
+                    rngs=rng_leaf)
+                new_carry = {"params": new_params,
+                             "batch_stats": new_bstats}
+                outs = {"loss": mean_loss, "n_bad": n_bad}
         if st.update is not None:
-            new_carry.update(st.update(ctx, tr, new_carry))
+            with scope(obs_names.SCOPE_STATE_UPDATE):
+                new_carry.update(st.update(ctx, tr, new_carry))
         missing = set(st.carry) - set(new_carry)
         assert not missing, f"stages left carry entries unset: {missing}"
         if self.health_names:
@@ -983,7 +992,8 @@ class RoundProgram:
         st = self.stages
         if st.epilogue is None:
             return ()
-        return tuple(st.epilogue(self.eng, carry, data))
+        with jax.named_scope(obs_names.SCOPE_EPILOGUE):
+            return tuple(st.epilogue(self.eng, carry, data))
 
     def _flat(self, new_carry: dict, epi: tuple, outs: dict,
               efs_tail: tuple) -> tuple:
@@ -1036,9 +1046,15 @@ class RoundProgram:
             # one span per dispatch (disarmed: a shared no-op) — under
             # --profile_dir the span opens a jax.profiler
             # TraceAnnotation, so this exact program invocation is the
-            # shared ruler between the host and XLA timelines
-            with obs_trace.span("dispatch_program", program=label,
-                                engine=eng.name, rounds=rounds):
+            # shared ruler between the host and XLA timelines. The
+            # driver's counts for this dispatch (round, samples_real,
+            # steps_real, steps_run: base._note_round_counts) ride on it
+            counts = eng._dispatch_counts
+            if counts:
+                eng._dispatch_counts = {}
+            with obs_trace.span(obs_names.SPAN_DISPATCH_PROGRAM,
+                                program=label, engine=eng.name,
+                                rounds=rounds, **counts):
                 t0 = time.perf_counter()
                 out = jitted(*args)
                 dur = time.perf_counter() - t0
@@ -1249,6 +1265,9 @@ class RoundProgram:
         with obs_trace.span("window", round=round_idx, k=k):
             with obs_trace.span("window_host_prologue", round=round_idx):
                 wi = self.window_inputs(round_idx, k)
+                if wi.sampled is not None:
+                    eng._note_round_counts(wi.sampled,
+                                           int(wi.idx.shape[-1]))
             with obs_trace.span("dispatch", round=round_idx, k=wi.k):
                 pr = (tuple(wi.per_round[n] for n in st.per_round)
                       if wi.per_round is not None else None)

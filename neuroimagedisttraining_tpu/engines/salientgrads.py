@@ -34,6 +34,7 @@ from neuroimagedisttraining_tpu.core.trainer import ClientState
 from neuroimagedisttraining_tpu.engines import program as round_program
 from neuroimagedisttraining_tpu.engines.base import FederatedEngine
 from neuroimagedisttraining_tpu.obs import health as obs_health
+from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.ops import flops as flops_ops
 from neuroimagedisttraining_tpu.ops import snip as snip_ops
@@ -68,6 +69,7 @@ class SalientGradsEngine(FederatedEngine):
 
     # ---------- phase 1: the global mask ----------
 
+    @jax.named_scope(obs_names.SCOPE_SNIP_SCORES)
     def _scores_body(self, params, bstats, Xs, ys, ns, rngs):
         """Weighted SNIP-score SUM over a block of clients + the block's
         client-weight sum — shared by the resident one-shot program and
@@ -327,18 +329,97 @@ class SalientGradsEngine(FederatedEngine):
                 or round_idx == cfg.fed.comm_round - 1:
             m = self._eval_g(params, bstats)
             mp = self._eval_p(per_params, per_bstats)
-            self._flush_nonfinite(round_idx)
-            self.stat_info["global_test_acc"].append(m["acc"])
-            self.stat_info["person_test_acc"].append(mp["acc"])
-            self.log.metrics(round_idx, train_loss=loss, **m,
-                             personal_acc=mp["acc"])
-            history.append({"round": round_idx,
-                            "train_loss": float(loss), **m,
-                            "personal_acc": mp["acc"]})
-        self.maybe_checkpoint(round_idx, {
-            "params": params, "batch_stats": bstats,
-            "per_params": per_params, "per_bstats": per_bstats,
-            "masks": masks, "history": history})
+            with obs_trace.span(obs_names.SPAN_ROUND_FLUSH):
+                self._flush_nonfinite(round_idx)
+            with obs_trace.span(obs_names.SPAN_ROUND_LOG):
+                self.stat_info["global_test_acc"].append(m["acc"])
+                self.stat_info["person_test_acc"].append(mp["acc"])
+                self.log.metrics(round_idx, train_loss=loss, **m,
+                                 personal_acc=mp["acc"])
+                history.append({"round": round_idx,
+                                "train_loss": float(loss), **m,
+                                "personal_acc": mp["acc"]})
+        with obs_trace.span(obs_names.SPAN_ROUND_CHECKPOINT):
+            self.maybe_checkpoint(round_idx, {
+                "params": params, "batch_stats": bstats,
+                "per_params": per_params, "per_bstats": per_bstats,
+                "masks": masks, "history": history})
+
+    def _round_iteration(self, round_idx: int, state: tuple, masks,
+                         history, fuse: bool, acct: tuple):
+        """One iteration of the round loop (resident or streamed): host
+        prologue, the dispatch of one round or one fused window, the
+        host-side accounting and the boundary hooks. ``state`` is
+        ``(params, bstats, per_params, per_bstats)``; returns
+        ``(next_round_idx, state)``. The caller's ``round`` span covers
+        the whole iteration; the stages here are its children and take
+        their round id from it (obs/names.py)."""
+        cfg = self.cfg
+        flops_per_sample, comm_params_per_client = acct
+        k = self._dispatch_window(round_idx) if fuse else 1
+        if k > 1:
+            # run_window has its own prologue and dispatch spans
+            *state, window_sampled, loss, k = self._run_fused_window(
+                *state, masks, round_idx, k)
+            round_idx += k - 1  # boundary hooks below
+        else:
+            with obs_trace.span(obs_names.SPAN_ROUND_PROLOGUE):
+                sampled = self.client_sampling(round_idx)
+                self.log.info("################ round %d: clients %s",
+                              round_idx, sampled.tolist())
+                lr = self.round_lr(round_idx)
+                if self.stream is not None:
+                    ids, n_real = self.stream_sampling(round_idx, sampled)
+                    byz = self._byz_round_plan(round_idx, ids)
+                    Xs, ys, ns = self.stream.get_train(ids, n_real)
+                    if round_idx + 1 < cfg.fed.comm_round:
+                        # overlap next round's host read with this round
+                        self.stream.prefetch_train(
+                            *self.stream_sampling(round_idx + 1))
+                else:
+                    # cohort sharding (ISSUE 6): padded gather ids for
+                    # the sharded program; byz plan and byte accounting
+                    # stay on the REAL sampled set (the body slices pads
+                    # off)
+                    ids, round_prog = self._cohort_round_prog(sampled)
+                    byz = self._byz_round_plan(round_idx, sampled)
+                    if self.wire_spec is not None:
+                        with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                            ref_host = jax.tree.map(
+                                np.asarray, {"params": state[0],
+                                             "batch_stats": state[1]})
+                rngs = self.per_client_rngs(round_idx, ids)
+                idx = jnp.asarray(ids)
+                self._note_round_counts([sampled], len(ids))
+            if self.stream is not None:
+                *state, loss, n_bad = self._round_stream_jit(
+                    *state, Xs, ys, ns, masks, idx, rngs, lr, byz)
+            elif self.wire_spec is not None:
+                *state, loss, n_bad, u0 = round_prog(
+                    *state, self.data, masks, idx, rngs, lr, byz)
+                with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                    masks_host = {
+                        "params": jax.tree.map(np.asarray, masks),
+                        "batch_stats": jax.tree.map(
+                            np.ones_like, ref_host["batch_stats"])}
+                    self.account_wire_bytes(
+                        jax.tree.map(np.asarray, u0), ref_host,
+                        masks_host=masks_host, n_uploads=len(sampled))
+            else:
+                *state, loss, n_bad = round_prog(
+                    *state, self.data, masks, idx, rngs, lr, byz)
+            self._note_nonfinite(n_bad)
+            window_sampled = [sampled]
+        # per-round host-side accounting (host data only — no device
+        # sync), identical for a single round and a fused window
+        for s in window_sampled:
+            n_samples = float(np.sum(self._n_train_host[s]))
+            self.stat_info["sum_training_flops"] += (
+                flops_per_sample * cfg.optim.epochs * n_samples)
+            self.stat_info["sum_comm_params"] += (
+                comm_params_per_client * len(s))
+        self._eval_ckpt_hooks(round_idx, *state, masks, loss, history)
+        return round_idx + 1, tuple(state)
 
     def train(self):
         cfg = self.cfg
@@ -388,86 +469,16 @@ class SalientGradsEngine(FederatedEngine):
             self.stream.prefetch_train(*self.stream_sampling(start))
         fuse = (cfg.fed.rounds_per_dispatch > 1
                 and self.fused_fallback_reason() is None)
+        state = (params, bstats, per_params, per_bstats)
         round_idx = start
         while round_idx < cfg.fed.comm_round:
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                (params, bstats, per_params, per_bstats, window_sampled,
-                 loss, k) = self._run_fused_window(
-                    params, bstats, per_params, per_bstats, masks,
-                    round_idx, k)
-                # per-round host-side accounting, identical to the
-                # sequential loop's (host data only — no device sync)
-                for off, s in enumerate(window_sampled):
-                    n_samples = float(np.sum(self._n_train_host[s]))
-                    self.stat_info["sum_training_flops"] += (
-                        flops_per_sample * cfg.optim.epochs * n_samples)
-                    self.stat_info["sum_comm_params"] += (
-                        comm_params_per_client * len(s))
-                round_idx += k - 1  # boundary hooks below
-                self._eval_ckpt_hooks(round_idx, params, bstats,
-                                      per_params, per_bstats, masks, loss,
-                                      history)
-                round_idx += 1
-                continue
-            sampled = self.client_sampling(round_idx)
-            self.log.info("################ round %d: clients %s",
-                          round_idx, sampled.tolist())
-            if self.stream is not None:
-                fed_ids, n_real = self.stream_sampling(round_idx, sampled)
-                rngs = self.per_client_rngs(round_idx, fed_ids)
-                byz = self._byz_round_plan(round_idx, fed_ids)
-                Xs, ys, ns = self.stream.get_train(fed_ids, n_real)
-                if round_idx + 1 < cfg.fed.comm_round:
-                    # overlap next round's host read with this round
-                    self.stream.prefetch_train(
-                        *self.stream_sampling(round_idx + 1))
-                (params, bstats, per_params, per_bstats, loss,
-                 n_bad) = self._round_stream_jit(
-                    params, bstats, per_params, per_bstats, Xs, ys, ns,
-                    masks, jnp.asarray(fed_ids), rngs,
-                    self.round_lr(round_idx), byz)
-            else:
-                # cohort sharding (ISSUE 6): padded gather ids for the
-                # sharded program; byz plan and byte accounting stay on
-                # the REAL sampled set (the body slices pads off)
-                ids, round_prog = self._cohort_round_prog(sampled)
-                rngs = self.per_client_rngs(round_idx, ids)
-                byz = self._byz_round_plan(round_idx, sampled)
-                if self.wire_spec is not None:
-                    ref_host = jax.tree.map(
-                        np.asarray, {"params": params,
-                                     "batch_stats": bstats})
-                    with obs_trace.span("round", round=round_idx,
-                                        codec=True):
-                        (params, bstats, per_params, per_bstats, loss,
-                         n_bad, u0) = round_prog(
-                            params, bstats, per_params, per_bstats,
-                            self.data, masks, jnp.asarray(ids), rngs,
-                            self.round_lr(round_idx), byz)
-                    masks_host = {
-                        "params": jax.tree.map(np.asarray, masks),
-                        "batch_stats": jax.tree.map(
-                            np.ones_like, ref_host["batch_stats"])}
-                    self.account_wire_bytes(
-                        jax.tree.map(np.asarray, u0), ref_host,
-                        masks_host=masks_host, n_uploads=len(sampled))
-                else:
-                    with obs_trace.span("round", round=round_idx):
-                        (params, bstats, per_params, per_bstats, loss,
-                         n_bad) = round_prog(
-                            params, bstats, per_params, per_bstats,
-                            self.data, masks, jnp.asarray(ids), rngs,
-                            self.round_lr(round_idx), byz)
-            self._note_nonfinite(n_bad)
-            n_samples = float(np.sum(self._n_train_host[sampled]))
-            self.stat_info["sum_training_flops"] += (
-                flops_per_sample * cfg.optim.epochs * n_samples)
-            self.stat_info["sum_comm_params"] += (comm_params_per_client
-                                                  * len(sampled))
-            self._eval_ckpt_hooks(round_idx, params, bstats, per_params,
-                                  per_bstats, masks, loss, history)
-            round_idx += 1
+            # one span for the whole iteration, sampling to checkpoint;
+            # its children carry the same round id (obs/names.py)
+            with obs_trace.span(obs_names.SPAN_ROUND, round=round_idx):
+                round_idx, state = self._round_iteration(
+                    round_idx, state, masks, history, fuse,
+                    (flops_per_sample, comm_params_per_client))
+        params, bstats, per_params, per_bstats = state
         self._flush_nonfinite(cfg.fed.comm_round - 1)
         m_global = self._eval_g(params, bstats)
         m_person = self._eval_p(per_params, per_bstats)

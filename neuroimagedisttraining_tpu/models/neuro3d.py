@@ -21,9 +21,19 @@ import os
 from typing import Any, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
 Dtype = Any
+
+# Device scopes (obs/names.py): flax names its own modules on every op
+# (f1/conv, layer1_0/bn1, fc1); the pools, the flatten and the head's
+# dropout sit outside any module, and the first stage is named "stem" in
+# both families so that one metric reads it. Scopes are metadata only:
+# no module is renamed and the parameter tree is untouched.
+_scope = jax.named_scope
 
 
 def _pool(x, kind: str, k: int, s: int, pad: int = 0):
@@ -131,26 +141,32 @@ class AlexNet3D_Dropout(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.dtype)
-        x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype,
-                         norm=self.norm, name="f0")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(self.dtype)
+        with _scope(obs_names.SCOPE_STEM):
+            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype,
+                             norm=self.norm, name="f0")(x, train)
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", 3, 3)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype,
                          norm=self.norm, name="f1")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_POOL1):
+            x = _pool(x, "max", 3, 3)
         x = self._blk(2)(192, kernel=3, pad=1, dtype=self.dtype,
                          norm=self.norm, name="f2")(x, train)
         x = self._blk(3)(192, kernel=3, pad=1, dtype=self.dtype,
                          norm=self.norm, name="f3")(x, train)
         x = self._blk(4)(128, kernel=3, pad=1, dtype=self.dtype,
                          norm=self.norm, name="f4")(x, train)
-        x = _pool(x, "max", 3, 3)
-        x = x.reshape((x.shape[0], -1))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
-        return x.astype(jnp.float32)
+        with _scope(obs_names.SCOPE_POOL2):
+            x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_HEAD):
+            x = x.reshape((x.shape[0], -1))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
+            return x.astype(jnp.float32)
 
 
 class AlexNet3D_Deeper_Dropout(nn.Module):
@@ -168,22 +184,28 @@ class AlexNet3D_Deeper_Dropout(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.dtype)
-        x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(self.dtype)
+        with _scope(obs_names.SCOPE_STEM):
+            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", 3, 3)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype, name="f1")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_POOL1):
+            x = _pool(x, "max", 3, 3)
         x = self._blk(2)(192, kernel=3, pad=1, dtype=self.dtype, name="f2")(x, train)
         x = self._blk(3)(384, kernel=3, pad=1, dtype=self.dtype, name="f3")(x, train)
         x = self._blk(4)(256, kernel=3, pad=1, dtype=self.dtype, name="f4")(x, train)
         x = self._blk(5)(256, kernel=3, pad=1, dtype=self.dtype, name="f5")(x, train)
-        x = _pool(x, "max", 3, 3)
-        x = x.reshape((x.shape[0], -1))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
-        x = x.astype(jnp.float32)
+        with _scope(obs_names.SCOPE_POOL2):
+            x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_HEAD):
+            x = x.reshape((x.shape[0], -1))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
+            x = x.astype(jnp.float32)
         return x, x
 
 
@@ -202,21 +224,28 @@ class AlexNet3D_Dropout_Regression(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.dtype)
-        x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(self.dtype)
+        with _scope(obs_names.SCOPE_STEM):
+            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", 3, 3)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype, name="f1")(x, train)
-        x = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_POOL1):
+            x = _pool(x, "max", 3, 3)
         x = self._blk(2)(192, kernel=3, pad=1, dtype=self.dtype, name="f2")(x, train)
         x = self._blk(3)(192, kernel=3, pad=1, dtype=self.dtype, name="f3")(x, train)
         x = self._blk(4)(128, kernel=3, pad=1, dtype=self.dtype, name="f4")(x, train)
-        xp = _pool(x, "max", 3, 3)
-        x = xp.reshape((xp.shape[0], -1))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
-        return jnp.squeeze(x.astype(jnp.float32)), xp.astype(jnp.float32)
+        with _scope(obs_names.SCOPE_POOL2):
+            xp = _pool(x, "max", 3, 3)
+        with _scope(obs_names.SCOPE_HEAD):
+            x = xp.reshape((xp.shape[0], -1))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.relu(nn.Dense(64, dtype=self.dtype, name="fc1")(x))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
+            return (jnp.squeeze(x.astype(jnp.float32)),
+                    xp.astype(jnp.float32))
 
 
 class Tiny3DCNN(nn.Module):
@@ -231,16 +260,21 @@ class Tiny3DCNN(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.dtype)
-        x = ConvBNReLU3D(self.width, kernel=3, dtype=self.dtype, name="f0")(x, train)
-        x = _pool(x, "max", 2, 2)
+        with _scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(self.dtype)
+        with _scope(obs_names.SCOPE_STEM):
+            x = ConvBNReLU3D(self.width, kernel=3, dtype=self.dtype, name="f0")(x, train)
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", 2, 2)
         x = ConvBNReLU3D(self.width * 2, kernel=3, dtype=self.dtype, name="f1")(x, train)
-        x = _pool(x, "max", 2, 2)
-        x = x.reshape((x.shape[0], -1))
-        x = nn.Dropout(0.5, deterministic=not train)(x)
-        x = nn.relu(nn.Dense(32, dtype=self.dtype, name="fc1")(x))
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
-        return x.astype(jnp.float32)
+        with _scope(obs_names.SCOPE_POOL1):
+            x = _pool(x, "max", 2, 2)
+        with _scope(obs_names.SCOPE_HEAD):
+            x = x.reshape((x.shape[0], -1))
+            x = nn.Dropout(0.5, deterministic=not train)(x)
+            x = nn.relu(nn.Dense(32, dtype=self.dtype, name="fc1")(x))
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x)
+            return x.astype(jnp.float32)
 
 
 class BasicBlock3D(nn.Module):
@@ -317,15 +351,18 @@ class ResNet3D_l3(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        x = x.astype(self.dtype)
+        with _scope(obs_names.SCOPE_BATCH_PREP):
+            x = x.astype(self.dtype)
         blk = BasicBlock3D if self.block == "basic" else Bottleneck3D
         expansion = 1 if self.block == "basic" else 4
-        x = nn.Conv(64, (3,) * 3, strides=(2,) * 3, padding=[(3, 3)] * 3,
-                    use_bias=False, dtype=self.dtype, name="conv1")(x)
-        x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                         dtype=jnp.float32, name="bn1")(x)
-        x = nn.relu(x)
-        x = _pool(x, "max", 3, 2, pad=1)
+        with _scope(obs_names.SCOPE_STEM):
+            x = nn.Conv(64, (3,) * 3, strides=(2,) * 3, padding=[(3, 3)] * 3,
+                        use_bias=False, dtype=self.dtype, name="conv1")(x)
+            x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                             dtype=jnp.float32, name="bn1")(x)
+            x = nn.relu(x)
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", 3, 2, pad=1)
         inplanes = 64
         for stage, (planes, blocks) in enumerate(zip((64, 128, 256), self.layers)):
             stride = 1 if stage == 0 else 2
@@ -335,8 +372,10 @@ class ResNet3D_l3(nn.Module):
                 x = blk(planes, stride=s, downsample=ds, dtype=self.dtype,
                         name=f"layer{stage + 1}_{i}")(x, train)
                 inplanes = planes * expansion
-        x = _pool(x, "avg", 3, 3)
-        x = x.reshape((x.shape[0], -1))
-        x1 = nn.Dense(512, dtype=self.dtype, name="fc")(x)
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x1)
-        return x.astype(jnp.float32), x1.astype(jnp.float32)
+        with _scope(obs_names.SCOPE_POOL1):
+            x = _pool(x, "avg", 3, 3)
+        with _scope(obs_names.SCOPE_HEAD):
+            x = x.reshape((x.shape[0], -1))
+            x1 = nn.Dense(512, dtype=self.dtype, name="fc")(x)
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name="fc2")(x1)
+            return x.astype(jnp.float32), x1.astype(jnp.float32)

@@ -123,6 +123,70 @@ ACTIONS_TOTAL = "nidt_actions_total"
 #    scrapeable next to the live nidt_mfu it is compared against --
 RECIPE_SCORE = "nidt_recipe_score"
 
+# ---------------------------------------------------------------------------
+# host span names (obs/trace.py) of the round driver and the streamed
+# feed. Each child of ROUND starts with a prefix benchmark/harness.py's
+# GAP_SPANS admits ("round", "dispatch", "eval_"), so a device-idle gap
+# is named by the layer whose span covers it; a span that blocks on the
+# device ends in "_sync" (round_host_busy_ms subtracts exactly those).
+# ---------------------------------------------------------------------------
+SPAN_ROUND = "round"                      # one whole loop iteration
+SPAN_ROUND_PROLOGUE = "round_prologue"    # sampling, rngs, byz plan, lr
+SPAN_DISPATCH_PROGRAM = "dispatch_program"  # the enqueue of one program
+SPAN_EVAL_DISPATCH = "eval_dispatch"      # the enqueue of an eval jit
+SPAN_EVAL_SYNC = "eval_sync"              # the blocking _summarize read
+SPAN_CODEC_SYNC = "round_codec_sync"      # --wire_codec host snapshots
+SPAN_ROUND_FLUSH = "round_flush"          # _flush_nonfinite + reflexes
+SPAN_FLUSH_SYNC = "round_flush_sync"      # its batched device_get
+SPAN_ROUND_LOG = "round_log"              # log.metrics, history
+SPAN_ROUND_CHECKPOINT = "round_checkpoint"
+SPAN_FEED_WAIT = "feed_wait"              # driver waits on the reader
+SPAN_FEED_GATHER = "feed_gather"          # reader thread: host gather
+SPAN_FEED_PUT = "feed_put"                # reader thread: device_put
+
+#: the children a resident round's iteration is tiled by, in loop order
+ROUND_CHILD_SPANS: tuple[str, ...] = (
+    SPAN_ROUND_PROLOGUE, SPAN_DISPATCH_PROGRAM, SPAN_EVAL_DISPATCH,
+    SPAN_EVAL_SYNC, SPAN_ROUND_FLUSH, SPAN_ROUND_LOG,
+    SPAN_ROUND_CHECKPOINT)
+
+# ---------------------------------------------------------------------------
+# device scope names (jax.named_scope): compile-time metadata on every
+# op traced inside, read back from a profiler trace's op metadata
+# (benchmark/scopes.py classifies by them; benchmark/scopes.json is
+# checked against this table). Flax names its own modules (f1..f4,
+# layer1_0, conv, bn, fc1); these cover what is not a module.
+# ---------------------------------------------------------------------------
+# round program (engines/program.py)
+SCOPE_GATHER = "gather"
+SCOPE_LOCAL_TRAIN = "local_train"
+SCOPE_ATTACK = "attack"
+SCOPE_CODEC = "codec"
+SCOPE_AGGREGATE = "aggregate"
+SCOPE_STATE_UPDATE = "state_update"
+SCOPE_EPILOGUE = "epilogue"
+# local step (core/trainer.py)
+SCOPE_BATCH_PREP = "batch_prep"
+SCOPE_FWD_BWD = "fwd_bwd"
+SCOPE_CLIP = "clip"
+SCOPE_UPDATE = "update"
+SCOPE_MASK_APPLY = "mask_apply"
+# local step, model (models/neuro3d.py)
+SCOPE_STEM = "stem"    # first stage + its pool, both model families
+SCOPE_POOL0 = "pool0"  # the stem's pool, nested inside SCOPE_STEM
+SCOPE_POOL1 = "pool1"
+SCOPE_POOL2 = "pool2"
+SCOPE_HEAD = "head"
+# evaluation, mask pipeline, collectives
+SCOPE_EVAL = "eval"
+SCOPE_SNIP_SCORES = "snip_scores"
+SCOPE_TOPK_MASK = "topk_mask"
+SCOPE_COHORT_GATHER = "cohort_gather"
+
+#: every device scope of the table above
+DEVICE_SCOPES: frozenset[str] = frozenset(
+    v for k, v in list(globals().items()) if k.startswith("SCOPE_"))
+
 #: every declared metric name — the set obs/rules.py validates rule
 #: manifests against at startup (unknown names fail with this list)
 DECLARED: frozenset[str] = frozenset(

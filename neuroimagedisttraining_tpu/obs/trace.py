@@ -19,14 +19,23 @@ Design constraints (ISSUE 9):
   the OS thread id so Perfetto lays threads out as separate tracks.
 - nestable: spans are ordinary context managers; Chrome "X" (complete)
   events nest by time containment per thread, so no explicit parent
-  bookkeeping is needed (``tests/test_obs.py`` pins containment).
+  bookkeeping is needed (``tests/test_obs.py`` pins containment). One
+  identifier is handed down: a span opened inside another on the same
+  thread takes the outer span's ``round`` argument unless it names its
+  own, so the spans of one round share its id without every call site
+  being passed it (``SpanTracer.INHERITED``).
 - off-by-default cheap: disarmed, ``span()`` returns a shared no-op
   context manager — no allocation, no clock read, one attribute test.
 
 Output: ``{"traceEvents": [...], "displayTimeUnit": "ms"}`` with "X"
 events ``{name, ph, ts, dur, pid, tid, args}`` (ts/dur in microseconds
 since arm time, monotonic clock) — the Chrome trace-event format
-Perfetto and ``chrome://tracing`` load directly.
+Perfetto and ``chrome://tracing`` load directly. ``nidtClockAnchor``
+holds the arm instant on both clocks (``perf_counter_ns`` and
+``time_ns``, read back to back): a ``--trace_out`` file and a
+``--profile_dir`` trace of one run, whose "Task Environment" plane
+carries ``profile_start_time`` in unix nanoseconds, lie on one axis
+through it.
 """
 
 from __future__ import annotations
@@ -101,6 +110,13 @@ class _Span:
 
     def __enter__(self):
         t = self._tracer
+        stack = t._open_spans()
+        if stack:
+            outer = stack[-1].args
+            for key in t.INHERITED:
+                if key in outer and key not in self.args:
+                    self.args[key] = outer[key]
+        stack.append(self)
         if t._annotate:
             try:
                 import jax
@@ -115,6 +131,7 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._tracer._open_spans().pop()
         if self._ann is not None:
             try:
                 self._ann.__exit__(*exc)
@@ -136,15 +153,20 @@ class SpanTracer:
     #: flight ring's rule), keeping the PREFIX of the run, which is
     #: what a Perfetto session of a long run gets opened on anyway
     DEFAULT_MAX_EVENTS = 1 << 18
+    #: arguments a span hands down to the spans opened inside it on the
+    #: same thread: the identifier the spans of one round share
+    INHERITED = ("round",)
 
     def __init__(self):
         self._lock = threading.Lock()
+        self._local = threading.local()
         self._events: list[dict] = []
         self._armed = False
         self._annotate = False
         self._path: str | None = None
         self._tags: dict[str, Any] = {}
         self._epoch_ns = time.perf_counter_ns()
+        self._epoch_unix_ns = time.time_ns()
         self._max_events = self.DEFAULT_MAX_EVENTS
         self._dropped = 0
 
@@ -175,6 +197,7 @@ class SpanTracer:
             self._annotate = bool(annotate)
             self._tags = dict(tags or {})
             self._epoch_ns = time.perf_counter_ns()
+            self._epoch_unix_ns = time.time_ns()
             self._events.clear()
             self._max_events = (self.DEFAULT_MAX_EVENTS
                                 if max_events is None
@@ -188,6 +211,14 @@ class SpanTracer:
             self._annotate = False
 
     # ---- recording ----
+
+    def _open_spans(self) -> list:
+        """This thread's stack of live spans (armed path only)."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     def span(self, name: str, **args: Any):
         """Context manager for one host span. Disarmed: a shared no-op
@@ -230,6 +261,15 @@ class SpanTracer:
                 self._dropped += 1
                 return
             self._events.append(ev)
+
+    def record_interval(self, name: str, t0_s: float, t1_s: float,
+                        **args: Any) -> None:
+        """One span from two ``time.perf_counter`` readings the caller
+        already took (the streamed feed times its stages for
+        ``transfer_stats``; the same reads become its spans, no second
+        timer). Disarmed: one attribute test."""
+        if self._armed:
+            self._record(name, int(t0_s * 1e9), int(t1_s * 1e9), args)
 
     def flow(self, name: str, flow_id: int, phase: str,
              **args: Any) -> None:
@@ -286,7 +326,10 @@ class SpanTracer:
             if not out:
                 return None
             doc = {"traceEvents": list(self._events),
-                   "displayTimeUnit": "ms"}
+                   "displayTimeUnit": "ms",
+                   "nidtClockAnchor": {
+                       "perf_counter_ns": self._epoch_ns,
+                       "time_ns": self._epoch_unix_ns}}
             if self._dropped:
                 # Perfetto ignores unknown top-level keys; the count
                 # keeps a truncated long run honest

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
 _LANES = 128
 _MAX_BLOCK_ROWS = 512   # [512, 128] f32 block = 256 KiB VMEM per operand
 
@@ -180,8 +182,9 @@ def fused_sgd_step(params, grads, trace, mask, *, clip: float, wd: float,
         use_pallas = jax.default_backend() == "tpu"
     ok = gnorm = None
     if clip > 0:
-        gnorm = optax.global_norm(grads)          # the shared reduction
-        ok = jnp.squeeze(gnorm < clip)
+        with jax.named_scope(obs_names.SCOPE_CLIP):
+            gnorm = optax.global_norm(grads)      # the shared reduction
+            ok = jnp.squeeze(gnorm < clip)
 
     leaves_p, treedef = jax.tree.flatten(params)
     leaves_g = treedef.flatten_up_to(grads)

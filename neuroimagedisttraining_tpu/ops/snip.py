@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from neuroimagedisttraining_tpu.core.trainer import ClientState, LocalTrainer
+from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.ops.masks import is_weight_kernel
 from neuroimagedisttraining_tpu.ops.topk import kth_largest
 from neuroimagedisttraining_tpu.utils.pytree import (
@@ -150,6 +151,7 @@ def _on_one_device(x: jax.Array) -> jax.Array:
     return jax.device_put(x, x.addressable_shards[0].device)
 
 
+@jax.named_scope(obs_names.SCOPE_TOPK_MASK)
 def mask_from_scores(scores: PyTree, keep_ratio: float) -> tuple[PyTree, jax.Array]:
     """Normalize scores by global sum, keep the top ``keep_ratio`` fraction
     globally (cross-layer), ones for non-maskable leaves (snip.py:80-116)."""

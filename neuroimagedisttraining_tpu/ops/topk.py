@@ -24,6 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
 _BLOCK_ROWS = 256          # x block = [256, 128] floats = 128 KiB VMEM
 _LANES = 128
 _BIN_CHUNK = 128
@@ -93,6 +95,7 @@ def _pad_to_blocks(x: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("k", "rounds", "nbins",
                                              "use_pallas"))
+@jax.named_scope(obs_names.SCOPE_TOPK_MASK)
 def kth_largest(x: jax.Array, k: int, rounds: int = 4, nbins: int = 512,
                 use_pallas: bool | None = None) -> jax.Array:
     """Exact (to float32 resolution) k-th largest value of a 1-D vector.

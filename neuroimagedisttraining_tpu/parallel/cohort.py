@@ -104,6 +104,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
 PyTree = Any
 
 
@@ -198,7 +200,9 @@ def cohort_map(mesh: Mesh, fn, *stacked: PyTree) -> PyTree:
 
     def block(*blocks):
         out = sequential_map(fn, *blocks)
-        return jax.tree.map(lambda x: _gather_replicated(x, axis), out)
+        with jax.named_scope(obs_names.SCOPE_COHORT_GATHER):
+            return jax.tree.map(lambda x: _gather_replicated(x, axis),
+                                out)
 
     in_specs = tuple(P(axis) for _ in stacked)
     # out_specs P(): the all-gather leaves every output replicated, but

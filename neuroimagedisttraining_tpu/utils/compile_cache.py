@@ -15,6 +15,16 @@ code that may set ``jax_compilation_cache_dir``:
   fresh processes of one checkout always share it.
 
 ``JAX_ENABLE_COMPILATION_CACHE=0`` (JAX's own switch) turns it off.
+
+The key holds the program's debug metadata (``op_name`` paths, source
+lines). JAX strips them by default, and two programs that differ only in
+``jax.named_scope`` names then share one entry: the process that comes
+second is handed the first one's executable, whose ops a profiler trace
+names by the OLD scopes (measured: a scopes-only change got 68 hits of 68
+on its parent's cache and ran without one of its scopes; PERF.md, PR 23).
+The trace is how this repo finds its time, so the names must be the
+running code's. The price: an edit that moves lines in a traced file
+recompiles the programs traced through it.
 """
 
 from __future__ import annotations
@@ -39,6 +49,8 @@ def enable_compile_cache() -> str:
     # floor skips trivial op-by-op executables whose disk round-trip
     # costs more than recompiling
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    # scopes are part of the program (module docstring)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get(ENV_VAR)
     if env:
         return env

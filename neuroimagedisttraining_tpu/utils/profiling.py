@@ -35,13 +35,6 @@ def profile_trace(log_dir: str | None, enabled: bool = True):
         yield
 
 
-def annotate(name: str):
-    """Named sub-span inside a trace (shows up on the timeline)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
 @contextlib.contextmanager
 def failure_context(logger: logging.Logger | None = None,
                     teardown: Callable[[], None] | None = None,

@@ -16,6 +16,7 @@ import json
 import logging
 import re
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -114,7 +115,12 @@ def test_chrome_trace_event_schema(tmp_path):
         pass
     t.instant("mark", k="v")
     doc = json.load(open(t.dump()))
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
+    assert set(doc) == {"traceEvents", "displayTimeUnit",
+                        "nidtClockAnchor"}
+    # the arm instant on both clocks, read back to back
+    anchor = doc["nidtClockAnchor"]
+    assert anchor["perf_counter_ns"] == t.epoch_ns
+    assert abs(anchor["time_ns"] - time.time_ns()) < 60e9
     for e in doc["traceEvents"]:
         assert e["ph"] in ("X", "i")
         assert isinstance(e["name"], str)

@@ -3,11 +3,12 @@
 import logging
 import os
 
+import jax
 import jax.numpy as jnp
 import pytest
 
 from neuroimagedisttraining_tpu.utils.profiling import (
-    annotate, failure_context, profile_trace,
+    failure_context, profile_trace,
 )
 
 
@@ -15,7 +16,7 @@ from neuroimagedisttraining_tpu.utils.profiling import (
 def test_profile_trace_writes_artifacts(tmp_path):
     d = str(tmp_path / "trace")
     with profile_trace(d):
-        with annotate("toy-span"):
+        with jax.profiler.TraceAnnotation("toy-span"):
             x = jnp.arange(128.0)
             (x * 2).block_until_ready()
     found = [f for _, _, fs in os.walk(d) for f in fs]
