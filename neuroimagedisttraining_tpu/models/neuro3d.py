@@ -56,9 +56,10 @@ def _pool(x, kind: str, k: int, s: int, pad: int = 0):
 
 
 class _StemConv(nn.Module):
-    """Drop-in for the stem ``nn.Conv`` (same "conv" param tree: kernel +
-    bias) routing through ``ops.stemconv.stem_conv3d`` — the custom
-    weight-gradient path. Constructed only when ``NIDT_FAST_STEM=1``."""
+    """The stem's ``nn.Conv(features, (5, 5, 5), strides 2, VALID)`` on a
+    single-channel input, computed by ``ops.stemconv.stem_conv3d``: same
+    "conv" parameter tree (kernel ``[5, 5, 5, 1, features]`` + bias), same
+    initializers, same result."""
 
     features: int
     dtype: Dtype = jnp.float32
@@ -91,12 +92,9 @@ class ConvBNReLU3D(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        fast_stem = (os.environ.get("NIDT_FAST_STEM") == "1"
-                     and self.kernel == 5 and self.stride == 2
-                     and self.pad == 0 and x.shape[-1] == 1)
-        if fast_stem:
-            # opt-in Pallas weight-gradient for the C_in=1 stride-2 stem
-            # (ops/stemconv.py); same param tree as nn.Conv ("conv")
+        if (self.kernel, self.stride, self.pad, x.shape[-1]) == (5, 2, 0, 1):
+            # the C_in = 1 stride-2 stem: XLA's own lowering leaves the MXU
+            # nearly empty (ops/stemconv.py); same "conv" parameters
             x = _StemConv(self.features, dtype=self.dtype, name="conv")(x)
         else:
             x = nn.Conv(self.features, (self.kernel,) * 3,

@@ -24,7 +24,7 @@ Parity contract (tests/test_precision.py):
   interpreter mode under a tolerance pin (the interpreter's math is the
   fallback's — the pin guards the padding/blocking plumbing).
 
-Template: ops/stemconv.py / ops/topk.py (block conventions). Scalars
+Template: ops/topk.py (block conventions). Scalars
 ride a (1, 128) f32 operand mapped to every grid step — lr is a traced
 per-round scalar, the clip trigger and global norm are per-step values;
 clip/wd/momentum are config constants baked as static flags so a

@@ -1,41 +1,44 @@
-"""Alternative weight-gradient for the C_in=1 stride-2 stem conv
-(Pallas split-K; opt-in via ``NIDT_FAST_STEM=1``).
+"""Weight gradient of the stem convolution, on the MXU when a client
+axis is batched.
 
-The flagship 3D CNNs open with ``Conv3d(1, 64, kernel_size=5, stride=2)``
-(salient_models.py:147), and its kernel-gradient — a contraction of ~4M
-patch rows onto a tiny 125x64 output — dominates the whole training
-step: per-stage bisection puts stage f0's fwd+bwd at ~44 ms of a ~40 ms
-full-model step, i.e. everything after the stem is free (PROFILE.md
-round 2). Every XLA formulation measured lands 13-40 ms (conv emitter,
-im2col+dot, k-split batched dot, parity-decomposed convs), far from the
-shape's compute cost.
+The flagship 3D CNNs open with ``Conv3d(1, C, kernel_size=5, stride=2)``
+(salient_models.py:147). XLA lowers its kernel gradient as a convolution
+that contracts over the batch alone, 16 of the MXU's 128 rows at the
+reference batch; under the engines' client-axis ``vmap`` the grouped
+form of it cost 18.1 ms a client step on a v5e (and 2.3 ms more for a
+padded copy of ``x``) against 7.4 ms unbatched: a third of the FedAvg
+cell's device time (PERF.md, PR 24).
 
-This module is the Pallas alternative. It is OFF by default: its one
-measurement (PROFILE.md round 2, 80-96 ms against XLA's 13-40) was
-taken on a chip that delivered ~75-200 GB/s of HBM bandwidth (nominal
-v5e is 819), where the extra patch materialization made it NET SLOWER
-despite the clean MXU contraction. Not measured on the current chip:
-the split puts ~2.2 GB of traffic behind a canonical [128, K]x[K, 64]
-MXU stream, so measure before enabling (ROADMAP D3).
+The output column and the sample are independent of each other in this
+convolution, so both can be the contracted batch:
 
-Design (see ``_dw_pallas``): XLA builds one contiguous patch row per
-tap from stride-2 parity sub-volumes, stacked to [128, R]; Pallas runs
-the [128, R] x [R, C] contraction as a split-K grid of canonical MXU
-dots with per-block f32 partials (no program_id, no cross-step
-accumulation — composes with the engines' client-axis ``vmap``); a
-ragged K tail falls to a tiny XLA dot.
+    x'[d, h, kw, (ow, n)] = x[n, d, h, 2 ow + kw]          (2.5x the input)
+    dW[kd, kh, kw, c] = sum_{od, oh, (ow, n)} x'[2 od + kd, 2 oh + kh, kw, (ow, n)]
+                                              * g[n, od, oh, ow, c]
 
-``stem_conv3d`` wraps forward (plain XLA conv — fine on MXU) and this
-backward in a ``custom_vjp``; dx falls back to the standard transposed
-conv (dead-code-eliminated in training, where the input is data). On
-non-TPU backends the whole op falls back to XLA autodiff. Gradient
-products run in the training compute dtype (bf16 models -> bf16 dW,
-matching XLA's own bf16 kernel-grad; f32 models keep f32).
+which is XLA's own kernel gradient of a k5 x5, stride-2 convolution over
+(D, H) with five input channels and a batch of ``W_out * N`` (944 at the
+flagship's shape): one contraction 944 deep that reads ``g`` in the tiles
+it already lies in (1.9 ms a client), no patch matrix, no Pallas, no
+``tpu_custom_call``.
+
+Which form runs is decided by what the code can see. ``stem_conv3d`` is a
+``custom_vjp`` whose forward and input gradient are the plain
+convolution; its weight gradient is a ``custom_vmap``. Called unbatched
+(``cohort_map``'s ``shard_map`` + ``lax.map``, a single client) it is XLA's
+own kernel gradient, which fuses the norm's backward into the contraction
+and never writes ``g``: the compiled program is what plain autodiff gives
+(the re-expressed form, which has to have ``g`` written out and builds
+``x'``, measured 2.8 ms a step slower there). Batched, each client's
+contraction is the re-expressed one, one client after another: a client's
+``g`` is one strided copy out of the stack (XLA keeps the client axis
+between ``ow`` and ``n``, so a grouped form cannot merge ``(ow, n)``
+without relaying all of ``g`` out twice: 22.8 ms a 4-client step against
+12.4 for the copies). 8.4 ms a client step all told, ``g`` written out
+included. The parameter stays ``[5, 5, 5, 1, C]`` throughout.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -44,8 +47,11 @@ from jax import lax
 _DN = ("NDHWC", "DHWIO", "NDHWC")
 _K = 5       # kernel size per spatial dim
 _S = 2       # stride
-_KB = 3      # parity-block taps per dim (ceil(K/S))
-_P = 8       # parities (S^3)
+_LANES = 128
+#: x' [D, H, kw, B] (batch kw, feature B) * g' [od, oh, B, C] -> [kd, kh, kw, C]
+_DN_DW = lax.ConvDimensionNumbers(lhs_spec=(2, 3, 0, 1),
+                                  rhs_spec=(3, 2, 0, 1),
+                                  out_spec=(2, 3, 0, 1))
 
 
 def _conv(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -53,85 +59,54 @@ def _conv(x: jax.Array, w: jax.Array) -> jax.Array:
                                     dimension_numbers=_DN)
 
 
-_BLK = 8192   # split-K block columns per grid step
-_MROWS = 128  # tap rows padded to one MXU/lane tile
+def _to_lanes(x: jax.Array) -> jax.Array:
+    """``[N, D, H, W, 1] -> x' [D, H, 5, W_out * N]``. One stride-2
+    split of W; every tap is then a contiguous slice."""
+    n, _, _, w = x.shape[:4]
+    ow = (w - _K) // _S + 1
+    xt = jnp.transpose(x[..., 0], (1, 2, 3, 0))               # [D, H, W, N]
+    par = [xt[:, :, p::_S].reshape(xt.shape[:2] + (-1,)) for p in range(_S)]
+    return jnp.stack([par[kw % _S][:, :, (kw // _S) * n:(kw // _S + ow) * n]
+                      for kw in range(_K)], axis=2)
 
 
-def _dw_kernel(p_ref, g_ref, out_ref):
-    """One split-K block: out = P_blk @ g_blk, canonical [M,K]x[K,N] MXU
-    orientation, f32 accumulate."""
-    out_ref[0] = lax.dot_general(
-        p_ref[...], g_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _dw_lanes(x: jax.Array, g: jax.Array) -> jax.Array:
+    """dW ``[5, 5, 5, 1, C]`` of ``conv3d(x, W, stride 2, VALID)`` as one
+    contraction over ``(od, oh, (ow, n))``; f32 accumulation."""
+    n, od, oh, ow, c = g.shape
+    gp = jnp.transpose(g, (1, 2, 3, 0, 4)).reshape(od, oh, ow * n, c)
+    # With at least 128 output channels XLA reads g' channel-minor, the
+    # tiles g already lies in; with 64 it wants (ow, n) minor and relays
+    # all of g out for that. The pad fuses into the contraction's operand
+    # (no bytes, no further MXU passes: 64 channels fill half a pass), and
+    # the barrier keeps the simplifier from slicing it away again.
+    gp = jnp.pad(gp, ((0, 0),) * 3 + ((0, -c % _LANES),))
+    dw = lax.optimization_barrier(lax.conv_general_dilated(
+        _to_lanes(x), gp, (1, 1), "VALID", rhs_dilation=(_S, _S),
+        dimension_numbers=_DN_DW, preferred_element_type=jnp.float32))
+    # an even extent leaves one more window position than the kernel has
+    return dw[:_K, :_K, :, None, :c].astype(x.dtype)
 
 
-def _dw_pallas(x: jax.Array, g: jax.Array,
-               interpret: bool = False) -> jax.Array:
-    """dW [5,5,5,1,C] for y = conv3d(x, W, stride 2, VALID).
+@jax.custom_batching.custom_vmap
+def _dw(x: jax.Array, g: jax.Array) -> jax.Array:
+    """Unbatched: XLA's own kernel gradient (module docstring)."""
+    kernel = jnp.zeros((_K, _K, _K, 1, g.shape[-1]), x.dtype)
+    _, vjp = jax.vjp(lambda w: _conv(x, w), kernel)
+    return vjp(g)[0]
 
-    Build: 8 parity sub-volumes of x (stride-2 slices), then one
-    CONTIGUOUS row per tap — ``P[t] = flatten(x_par[p][block slice])`` —
-    stacked to [128, R] (125 real taps + zero rows). Pure block copies;
-    no conv emitter, no interleaving. Pallas then grids a split-K
-    [128, blk] x [blk, C] MXU matmul over R with per-block f32 partials
-    (summed by XLA); the ragged tail of R is a tiny XLA dot. Per-block
-    partial outputs keep the kernel free of program_id/accumulation, so
-    it composes with the engines' client-axis vmap."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    od, oh, ow = g.shape[1:4]
-    c_out = g.shape[4]
-    # products run in the training compute dtype: bf16 models get bf16
-    # dW (matching XLA's own bf16 kernel-grad); f32 models keep f32
-    cdtype = (x.dtype if x.dtype in (jnp.float32, jnp.bfloat16)
-              else jnp.bfloat16)
-    xb = x[..., 0].astype(cdtype)
-    rows = []
-    for kd in range(_K):
-        for kh in range(_K):
-            for kw in range(_K):
-                par = xb[:, kd % _S::_S, kh % _S::_S, kw % _S::_S]
-                sl = par[:, kd // _S:kd // _S + od,
-                         kh // _S:kh // _S + oh,
-                         kw // _S:kw // _S + ow]
-                rows.append(sl.reshape(-1))
-    r = rows[0].shape[0]
-    taps = len(rows)                                     # 125
-    p2 = jnp.stack(
-        rows + [jnp.zeros((r,), cdtype)] * (_MROWS - taps))
-    g2 = g.astype(cdtype).reshape(-1, c_out)             # [R, C]
-
-    nblk = r // _BLK
-    rmain = nblk * _BLK
-    if nblk == 0:  # tiny inputs (tests): the ragged-tail dot covers all of R
-        dw = lax.dot_general(p2[:taps], g2, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        return dw.reshape(_K, _K, _K, 1, c_out)
-    part = pl.pallas_call(
-        _dw_kernel,
-        out_shape=jax.ShapeDtypeStruct((nblk, _MROWS, c_out), jnp.float32),
-        grid=(nblk,),
-        in_specs=[pl.BlockSpec((_MROWS, _BLK), lambda i: (0, i)),
-                  pl.BlockSpec((_BLK, c_out), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, _MROWS, c_out), lambda i: (i, 0, 0)),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(p2[:, :rmain], g2[:rmain])
-
-    dw = jnp.sum(part, axis=0)[:taps]                    # [125, C]
-    if rmain < r:                                        # ragged K tail
-        dw = dw + lax.dot_general(
-            p2[:taps, rmain:], g2[rmain:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    return dw.reshape(_K, _K, _K, 1, c_out)
+@_dw.def_vmap
+def _dw_batched(axis_size, in_batched, x, g):
+    x, g = (a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+            for a, b in zip((x, g), in_batched))
+    return lax.map(lambda t: _dw_lanes(*t), (x, g)), True
 
 
 @jax.custom_vjp
 def stem_conv3d(x: jax.Array, w: jax.Array) -> jax.Array:
-    """``conv3d(x, w, stride 2, VALID)`` for single-channel NDHWC input
-    with a Pallas weight-gradient on TPU (XLA autodiff elsewhere)."""
+    """``conv3d(x, w, stride 2, VALID)`` for ``x [N, D, H, W, 1]`` and
+    ``w [5, 5, 5, 1, C]`` of one dtype, NDHWC."""
     return _conv(x, w)
 
 
@@ -141,25 +116,9 @@ def _fwd(x, w):
 
 def _bwd(res, g):
     x, w = res
-    # dx via the standard transposed conv — XLA DCEs it when the input is
-    # training data (nothing consumes the cotangent)
-    _, vjp = jax.vjp(lambda x_: _conv(x_, w), x)
-    (dx,) = vjp(g)
-    if jax.default_backend() == "tpu":
-        dw = _dw_pallas(x, g).astype(w.dtype)
-    else:
-        _, vjp_w = jax.vjp(lambda w_: _conv(x, w_), w)
-        (dw,) = vjp_w(g)
-    return dx, dw
+    # XLA removes dx when the input is data (nothing reads its cotangent)
+    _, vjp_x = jax.vjp(lambda x_: _conv(x_, w), x)
+    return vjp_x(g)[0], _dw(x, g)
 
 
 stem_conv3d.defvjp(_fwd, _bwd)
-
-
-@functools.partial(jax.jit, static_argnames=())
-def _dw_reference(x, g):
-    """XLA kernel-grad (for tests): dW of sum(conv * g)."""
-    _, vjp_w = jax.vjp(lambda w_: _conv(x, w_),
-                       jnp.zeros((_K, _K, _K, 1, g.shape[-1]), x.dtype))
-    (dw,) = vjp_w(g)
-    return dw

@@ -1,6 +1,8 @@
 """Sparsity ops tests: top-k selection vs numpy, ERK sparsities, mask init
 exact counts, fire/regrow semantics, SNIP identity, FLOPs counter."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -240,52 +242,180 @@ def test_fast_maxpool_matches_xla_fwd_and_bwd():
                                atol=1e-6)
 
 
-def test_stemconv_pallas_dw_matches_xla():
-    """ops/stemconv.py split-K weight-gradient == XLA kernel-grad
-    (interpret mode exercises the real kernel grid incl. the ragged-K
-    tail; shapes sized so R > one 8192 block)."""
+def _stem_grads(conv, x, w):
+    """(y, dx, dw) of ``sum(sin(conv(x, w)))`` — a cotangent that differs
+    at every output element."""
+    y, vjp = jax.vjp(conv, x, w)
+    return (y,) + vjp(jnp.cos(y.astype(jnp.float32)).astype(y.dtype))
+
+
+def _stem_case(shape, c_out, dtype, seed=5):
+    kx, kw = jax.random.split(jax.random.key(seed))
+    x = jax.random.normal(kx, shape + (1,), jnp.float32).astype(dtype)
+    w = (0.2 * jax.random.normal(kw, (5, 5, 5, 1, c_out))).astype(dtype)
+    return x, w
+
+
+def _rel(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
+
+
+@pytest.mark.parametrize("case", [
+    "f32", "bf16", "even_extents", "one_window", "batch1_odd_channels",
+    "vmap_distinct_kernels", "vmap_shared_input", "lax_map", "remat"])
+def test_stemconv_grads_match_plain_conv(case):
+    """``ops.stemconv``'s weight gradient (the stem's kernel gradient as a
+    2-D convolution that contracts over (od, oh, (ow, n))) against
+    ``jax.vjp`` of the plain 3-D convolution, called directly and through
+    ``stem_conv3d``: unbatched (XLA's own form), under ``vmap`` with a
+    kernel a client (the re-expressed form, a client at a time), under
+    ``lax.map`` (``cohort_map``'s per-row loop) and inside
+    ``jax.checkpoint``; the
+    output and ``dx`` are the plain (transposed) convolution's. bf16
+    operands, as ``bf16_mixed`` runs them, accumulate in f32 in both."""
     from neuroimagedisttraining_tpu.ops import stemconv as SC
 
-    kx, kg = jax.random.split(jax.random.key(3))
-    x = jax.random.normal(kx, (4, 29, 31, 29, 1), jnp.float32)
-    w = jax.random.normal(kg, (5, 5, 5, 1, 64), jnp.float32)
-    g = jax.random.normal(jax.random.key(4), SC._conv(x, w).shape,
-                          jnp.float32)
-    dw_ref = np.asarray(SC._dw_reference(x, g))
-    dw_pal = np.asarray(SC._dw_pallas(x, g, interpret=True))
-    err = np.max(np.abs(dw_pal - dw_ref)) / np.max(np.abs(dw_ref))
-    assert err < 2e-2, err  # bf16 products, f32 accumulation
+    dtype = jnp.bfloat16 if case == "bf16" else jnp.float32
+    tol = 2e-2 if case == "bf16" else 1e-4
+    shape = {"even_extents": (2, 12, 14, 16),   # W even: equal parity halves,
+             "one_window": (3, 5, 5, 5),        # ... one window more; 1x1x1
+             "batch1_odd_channels": (1, 9, 7, 11)}.get(case, (2, 13, 15, 11))
+    c_out = 3 if case == "batch1_odd_channels" else 8
+    x, w = _stem_case(shape, c_out, dtype)
+    xs = jnp.stack([x, 2.0 * x, x[::-1]])
+    ws = jnp.stack([w, -w, 0.5 * w + 0.1])
+
+    def both(run):
+        return run(SC.stem_conv3d), run(SC._conv)
+
+    if case == "vmap_distinct_kernels":   # the engines' client axis
+        got, want = both(lambda f: jax.vmap(
+            lambda a, b: _stem_grads(f, a, b))(xs, ws))
+    elif case == "vmap_shared_input":     # only the cotangent is batched
+        got, want = both(lambda f: jax.vmap(
+            lambda b: _stem_grads(f, x, b))(ws))
+    elif case == "lax_map":
+        got, want = both(lambda f: jax.lax.map(
+            lambda t: _stem_grads(f, *t), (xs, ws)))
+    elif case == "remat":                 # --remat stem wraps the block
+        got = jax.vmap(lambda a, b: _stem_grads(
+            jax.checkpoint(SC.stem_conv3d), a, b))(xs, ws)
+        want = jax.vmap(lambda a, b: _stem_grads(SC._conv, a, b))(xs, ws)
+    else:
+        got = jax.jit(lambda a, b: _stem_grads(SC.stem_conv3d, a, b))(x, w)
+        want = _stem_grads(SC._conv, x, w)
+        # the re-expressed contraction itself, on the same cotangent
+        cot = jnp.cos(want[0].astype(jnp.float32)).astype(dtype)
+        lanes = jax.jit(SC._dw_lanes)(x, cot)
+        assert lanes.shape == w.shape and lanes.dtype == w.dtype
+        assert _rel(lanes, want[2]) < tol, (case, _rel(lanes, want[2]))
+    for name, g, r in zip(("y", "dx", "dw"), got, want):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        assert _rel(g, r) < tol, (case, name, _rel(g, r))
 
 
-def test_stemconv_custom_vjp_grads(monkeypatch):
-    """stem_conv3d's custom VJP returns the same (dx, dw) as plain XLA
-    autodiff (the CPU fallback path IS autodiff for dw; dx always the
-    transposed conv), and the NIDT_FAST_STEM=1 module keeps the nn.Conv
-    param tree."""
+def test_stemconv_form_follows_the_client_axis():
+    """Which contraction runs is decided by what ``custom_vmap`` sees:
+    unbatched, the backward holds the plain 3-D kernel gradient (the
+    cohort-sharded round's program stays what autodiff gives); under
+    ``vmap`` it holds the 2-D one whose batch is ``W_out * N``."""
+    from neuroimagedisttraining_tpu.ops import stemconv as SC
+
+    x, w = _stem_case((2, 13, 15, 11), 8, jnp.float32)
+
+    def dw_of(conv):
+        return lambda x, w: jax.grad(
+            lambda w_: jnp.sum(conv(x, w_) ** 2))(w)
+
+    def dw_forms(fn, *args):
+        """(plain 3-D kernel gradients, 2-D re-expressed ones) lowered."""
+        text = jax.jit(fn).lower(*args).as_text()
+        convs = [ln for ln in text.splitlines() if "stablehlo.convolution" in ln]
+        return (sum("[f, 0, 1, 2, b]x[i, 0, 1, 2, o]" in ln for ln in convs),
+                sum("[0, 1, b, f]x[0, 1, i, o]" in ln for ln in convs))
+
+    def program(conv):
+        """Compiled instructions of the unbatched gradient, less metadata."""
+        text = jax.jit(dw_of(conv)).lower(x, w).compile().as_text()
+        return [re.sub(r", metadata=\{[^}]*\}", "", ln)
+                for ln in text.splitlines()
+                if re.match(r"\s+(ROOT )?%|ENTRY", ln)]
+
+    dw = dw_of(SC.stem_conv3d)
+    assert dw_forms(dw, x, w) == (1, 0)
+    assert program(SC.stem_conv3d) == program(SC._conv)
+    assert dw_forms(jax.vmap(dw), jnp.stack([x, x]), jnp.stack([w, w])) \
+        == (0, 1)
+    assert dw_forms(lambda a, b: jax.lax.map(lambda t: dw(*t), (a, b)),
+                    x[None], w[None]) == (1, 0)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "nn_remat"])
+def test_stem_block_keeps_the_conv_parameters(remat):
+    """The stem block's parameter tree and output are ``nn.Conv``'s: the
+    same ``conv/kernel [5,5,5,1,C]`` + ``conv/bias``, so checkpoints,
+    SalientGrads' masks and the benchmark's float32 reference see one
+    tensor; and its gradient through ``nn.remat`` equals the plain one."""
+    import flax.linen as nn
+
+    from neuroimagedisttraining_tpu.models.neuro3d import (
+        ConvBNReLU3D,
+        RematConvBNReLU3D,
+    )
+
+    x, _ = _stem_case((2, 13, 15, 13), 8, jnp.float32)
+    blk = (RematConvBNReLU3D if remat else ConvBNReLU3D)(
+        features=8, kernel=5, stride=2, pad=0)
+    variables = blk.init(jax.random.key(6), x, False)
+    conv = variables["params"]["conv"]
+    assert set(conv) == {"kernel", "bias"}
+    assert conv["kernel"].shape == (5, 5, 5, 1, 8)
+
+    class Ref(nn.Module):  # the block as it was: nn.Conv + the same norm
+        @nn.compact
+        def __call__(self, x, train):
+            x = nn.Conv(8, (5, 5, 5), strides=(2, 2, 2), padding="VALID",
+                        name="conv")(x)
+            x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                             epsilon=1e-5, name="bn")(x)
+            return nn.relu(x)
+
+    def loss(mod):
+        def f(params):
+            out, _ = mod.apply({**variables, "params": params}, x, True,
+                               mutable=["batch_stats"])
+            return jnp.sum(out ** 2)
+        return f
+
+    np.testing.assert_allclose(
+        np.asarray(blk.apply(variables, x, False)),
+        np.asarray(Ref().apply(variables, x, False)), atol=1e-5)
+    got = jax.grad(loss(blk))(variables["params"])
+    want = jax.grad(loss(Ref()))(variables["params"])
+    # one scale for the tree: the conv bias's gradient is zero in exact
+    # arithmetic (the norm subtracts the mean) and rounding noise in both
+    scale = max(float(jnp.max(jnp.abs(r))) for r in jax.tree.leaves(want))
+    for g, r in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-4 * scale)
+
+
+@pytest.mark.parametrize("k,s,pad,c_in", [
+    (5, 2, 0, 1), (5, 2, 0, 2), (5, 2, 1, 1), (5, 1, 0, 1), (3, 2, 0, 1),
+    (3, 1, 0, 1)])
+def test_stem_routing_is_decided_by_shape(k, s, pad, c_in):
+    """``ConvBNReLU3D`` takes the re-expressed stem exactly when it can
+    see ``(kernel, stride, pad, C_in) == (5, 2, 0, 1)``: no flag, no
+    model name (Tiny3DCNN's k3 s1 stem keeps ``nn.Conv``)."""
     from neuroimagedisttraining_tpu.models.neuro3d import ConvBNReLU3D
-    from neuroimagedisttraining_tpu.ops import stemconv as SC
 
-    kx, kw = jax.random.split(jax.random.key(5))
-    x = jax.random.normal(kx, (2, 13, 15, 13, 1), jnp.float32)
-    w = jax.random.normal(kw, (5, 5, 5, 1, 8), jnp.float32)
-
-    def loss(f):
-        return lambda x, w: jnp.sum(f(x, w) ** 2)
-
-    gx, gw = jax.grad(loss(SC.stem_conv3d), argnums=(0, 1))(x, w)
-    rx, rw = jax.grad(loss(SC._conv), argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(gw), np.asarray(rw), atol=1e-4)
-
-    blk = ConvBNReLU3D(features=8, kernel=5, stride=2, pad=0)
-    monkeypatch.setenv("NIDT_FAST_STEM", "1")
-    params = blk.init(jax.random.key(6), x, train=False)
-    assert set(params["params"]["conv"]) == {"kernel", "bias"}
-    out_fast = blk.apply(params, x, train=False)  # env read at apply time
-    monkeypatch.delenv("NIDT_FAST_STEM")
-    out_ref = blk.apply(params, x, train=False)
-    np.testing.assert_allclose(np.asarray(out_fast), np.asarray(out_ref),
-                               atol=1e-5)
+    x = jnp.zeros((1, 9, 9, 9, c_in))
+    blk = ConvBNReLU3D(features=4, kernel=k, stride=s, pad=pad)
+    variables = blk.init(jax.random.key(0), x, False)
+    text = str(jax.make_jaxpr(lambda v: blk.apply(v, x, False))(variables))
+    assert ("custom_vjp_call" in text) == ((k, s, pad, c_in) == (5, 2, 0, 1))
+    assert variables["params"]["conv"]["kernel"].shape == (k, k, k, c_in, 4)
 
 
 def test_fast_maxpool_tie_gradient_is_conserved():

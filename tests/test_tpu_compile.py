@@ -1,4 +1,4 @@
-"""The main path's Pallas kernels compile for the chip, asked of the TPU
+"""The main path's kernels compile for the chip, asked of the TPU
 compiler without a chip (on-chip-measurement guide, section 2, step 3).
 
 The installed libtpu compiles for a DESCRIBED ``v5e:2x2`` topology with
@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from neuroimagedisttraining_tpu.models import create_model
 from neuroimagedisttraining_tpu.ops import fused_update as fu
-from neuroimagedisttraining_tpu.ops.stemconv import _dw_pallas
+from neuroimagedisttraining_tpu.ops.stemconv import stem_conv3d
 from neuroimagedisttraining_tpu.ops.topk import kth_largest
 from neuroimagedisttraining_tpu.utils.pytree import tree_map_with_path_names
 
@@ -98,13 +98,38 @@ def test_fused_update_compiles_over_every_flagship_leaf(chip,
     assert text.count(KERNEL_MARK) >= len(jax.tree.leaves(tree))
 
 
-def test_stem_dw_compiles_at_full_volume(chip):
-    """The opt-in stem weight-gradient (NIDT_FAST_STEM=1) at the real
-    volume and channel widths. Batch 1: the program is the same 125-tap
-    patch build and split-K grid at any batch, and batch 16 takes the
-    compiler ~90 s (PR 21's rehearsal compiled it once)."""
+@pytest.mark.parametrize("clients", [0, 2], ids=["unbatched", "vmap2"])
+def test_stem_dw_compiles_at_full_volume(chip, clients):
+    """The stem's weight gradient (``ops/stemconv.py``) at the real volume
+    and channel widths, unbatched (``cohort_map``'s per-row loop: XLA's
+    own form) and under the engines' client-axis ``vmap`` (the
+    re-expressed contraction, a client at a time): plain XLA (a Mosaic call would fail the
+    mesh cell's ``program_check`` and GSPMD refuses one), an MXU
+    convolution, and no patch matrix: the temporaries are ``x'`` (2.5x
+    the input; the tiling pads its five taps to sixteen rows) and the
+    pieces it is built from. The retired split-K form stacked 125 tap
+    rows, 63 MB a sample. Batch 2 keeps the compile short; the program is
+    the same at any batch."""
     d, h, w = ((s - 5) // 2 + 1 for s in SHAPE)
-    text = jax.jit(_dw_pallas).lower(
-        _on(chip, (1,) + SHAPE + (1,), jnp.bfloat16),
-        _on(chip, (1, d, h, w, 64), jnp.bfloat16)).compile().as_text()
-    assert KERNEL_MARK in text
+    lead = (clients,) if clients else ()
+    x = _on(chip, lead + (2,) + SHAPE + (1,), jnp.bfloat16)
+    k = _on(chip, lead + (5, 5, 5, 1, 64), jnp.bfloat16)
+    g = _on(chip, lead + (2, d, h, w, 64), jnp.bfloat16)
+
+    def dw(x, k, g):
+        return jax.vjp(stem_conv3d, x, k)[1](g)[1]
+
+    compiled = jax.jit(jax.vmap(dw) if clients else dw).lower(x, k, g) \
+        .compile()
+    text = compiled.as_text()
+    assert KERNEL_MARK not in text
+    assert " convolution(" in text
+    # XLA's own form contracts over a 59x71x59 window; the re-expressed
+    # one has (ow, n) in its batch and no third window extent of 59
+    assert ("window={size=59x71x59 " in text) == (not clients)
+    if clients:
+        # measured 157 MiB: one client's x' (69 MiB as tiled), the five tap
+        # slices it is built from, one client's g copied out of the stack;
+        # a client's patch rows alone would be 126 MiB. (XLA's own form,
+        # unbatched, takes 1,157 MiB for its padded copy of x.)
+        assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
