@@ -58,7 +58,8 @@ def _is_builder_file(path: str) -> bool:
                for b in _BUILDER_FILES)
 
 _SCAN_CALLS = ("jax.lax.scan", "lax.scan")
-_KEY_METHODS = ("fused_fallback_key", "cohort_fallback_key")
+_KEY_METHODS = ("fused_fallback_key", "cohort_fallback_key",
+                "fold_refusal_key")
 
 
 @functools.lru_cache(maxsize=None)

@@ -35,7 +35,7 @@ from neuroimagedisttraining_tpu.core.losses import make_loss, predictions
 from neuroimagedisttraining_tpu.core.optim import (
     make_local_optimizer, validate_precision,
 )
-from neuroimagedisttraining_tpu.models import primary_logits
+from neuroimagedisttraining_tpu.models import aux_outputs, primary_logits
 from neuroimagedisttraining_tpu.obs import names as obs_names
 
 PyTree = Any
@@ -128,6 +128,12 @@ class LocalTrainer:
         # so a 4-D [B,H,W,C] CIFAR batch is never mistaken for an
         # unchanneled volumetric one.
         self._input_rank = getattr(model, "input_rank", None)
+        #: the model returns ``(logits, aux)`` with a weighted auxiliary
+        #: loss and integer expert counts (models/olmoe3d.py); the task
+        #: loss of such a model gains ``aux["loss"]`` inside the grad
+        #: function. A model without the declaration traces exactly the
+        #: program it traced before the declaration existed.
+        self.has_aux = bool(getattr(model, "returns_aux", False))
 
     # ---------- init ----------
 
@@ -180,6 +186,18 @@ class LocalTrainer:
         inv = self._loss_scale
         return loss / inv, jax.tree.map(lambda g: g / inv, grads)
 
+    def _objective(self, out, y, weights=None):
+        """``(scaled objective, task loss, aux)`` of one batch's model
+        output: the task loss plus the model's own weighted auxiliary
+        term (before the loss scale), the task loss alone for the
+        report, and the auxiliary dict (None for a logits-only model,
+        whose objective IS the scaled task loss)."""
+        loss = self.loss(primary_logits(out), y, weights=weights)
+        aux = aux_outputs(out) if self.has_aux else None
+        if aux is None:
+            return self._scaled(loss), loss, None
+        return self._scaled(loss + aux["loss"]), loss, aux
+
     def loss_and_grad(self, cs: ClientState, x, y):
         """One batch's (loss, grads, new batch_stats); used directly by SNIP
         scoring and gradient probes as well as by ``local_train``."""
@@ -188,13 +206,16 @@ class LocalTrainer:
         def f(params):
             out, bstats = self._apply(params, cs.batch_stats, self._prep(x),
                                       train=True, dropout_rng=drng)
-            return self._scaled(self.loss(primary_logits(out), y)), bstats
+            obj, task, aux = self._objective(out, y)
+            return obj, (bstats, task, aux)
 
         with jax.named_scope(obs_names.SCOPE_FWD_BWD):
-            (loss, bstats), grads = jax.value_and_grad(
+            (loss, (bstats, task, aux)), grads = jax.value_and_grad(
                 f, has_aux=True)(cs.params)
             loss, grads = self._unscaled(loss, grads)
-        return loss, grads, bstats, rng
+        # the reported loss stays the task loss (for a logits-only model
+        # the objective is the task loss, read as it always was)
+        return (loss if aux is None else task), grads, bstats, rng
 
     def local_train(self, cs: ClientState, X, y, n_valid, lr, epochs: int,
                     batch_size: int, max_samples: int,
@@ -219,6 +240,12 @@ class LocalTrainer:
         the wrapped filler rows (real samples, zero loss weight) that a
         genuinely smaller torch batch would not contain.
         ``"replacement"`` draws i.i.d. uniform batches.
+
+        A model that declares an auxiliary output (``has_aux``) trains on
+        task loss + ``aux["loss"]``; the returned mean loss stays the task
+        loss, and such a model's call returns a third value, its
+        ``expert_tokens`` summed over the client's REAL steps (masked
+        padded steps add nothing).
 
         ``prox_lamda``/``prox_ref``: Ditto's personalized proximal pull,
         applied after each optimizer step: ``w -= lr * lamda * (w - ref)``
@@ -265,13 +292,15 @@ class LocalTrainer:
                 out, bstats = self._apply(params, state.batch_stats,
                                           self._prep(xb), train=True,
                                           dropout_rng=drng)
-                return self._scaled(
-                    self.loss(primary_logits(out), yb, weights=wb)), bstats
+                obj, task, aux = self._objective(out, yb, weights=wb)
+                return obj, (bstats, task, aux)
 
             with jax.named_scope(obs_names.SCOPE_FWD_BWD):
-                (loss, bstats), grads = jax.value_and_grad(
+                (loss, (bstats, task, aux)), grads = jax.value_and_grad(
                     f, has_aux=True)(state.params)
                 loss, grads = self._unscaled(loss, grads)
+                if aux is not None:
+                    loss = task
             # the optimizer tail carries one name on both paths (the
             # global-norm clip inside it is SCOPE_CLIP: core/optim.py,
             # ops/fused_update.py), so a trace reads it fused or not
@@ -306,11 +335,19 @@ class LocalTrainer:
                     batch_stats=keep(bstats, state.batch_stats),
                     opt_state=keep(opt_state, state.opt_state),
                     rng=rng)
-                return new_state, jnp.where(active, loss, 0.0)
+                if aux is None:
+                    return new_state, jnp.where(active, loss, 0.0)
+                tokens = aux["expert_tokens"]
+                return new_state, (jnp.where(active, loss, 0.0),
+                                   jnp.where(active, tokens,
+                                             jnp.zeros_like(tokens)))
 
-        cs, losses = jax.lax.scan(step, cs, jnp.arange(total))
+        cs, outs = jax.lax.scan(step, cs, jnp.arange(total))
         denom = jnp.maximum(epochs * my_steps, 1)
-        return cs, jnp.sum(losses) / denom
+        if not self.has_aux:
+            return cs, jnp.sum(outs) / denom
+        losses, tokens = outs
+        return cs, jnp.sum(losses) / denom, jnp.sum(tokens, axis=0)
 
     def lower_train_step(self, input_shape: tuple[int, ...],
                          batch_size: int):
@@ -343,7 +380,7 @@ class LocalTrainer:
         (DisPFL/my_model_trainer.py:165-188, model.eval() + one batch)."""
         def f(p):
             out, _ = self._apply(p, batch_stats, self._prep(x), train=False)
-            return self._scaled(self.loss(primary_logits(out), y))
+            return self._objective(out, y)[0]
 
         grads = jax.grad(f)(params)
         if self._loss_scale != 1.0:

@@ -530,7 +530,7 @@ class FederatedEngine:
                 return m["test_correct"], m["test_loss"], m["test_total"], auc
 
             with jax.named_scope(obs_names.SCOPE_EVAL):
-                return jax.vmap(per_client)(X, y, n)
+                return self._per_client(per_client, X, y, n)
 
         return jax.jit(eval_all)
 
@@ -546,7 +546,8 @@ class FederatedEngine:
                 return m["test_correct"], m["test_loss"], m["test_total"], auc
 
             with jax.named_scope(obs_names.SCOPE_EVAL):
-                return jax.vmap(per_client)(params, bstats, X, y, n)
+                return self._per_client(per_client, params, bstats, X, y,
+                                        n)
 
         return jax.jit(eval_all)
 
@@ -774,6 +775,42 @@ class FederatedEngine:
     #: BEFORE the first program access; the jits read it at build time)
     _cohort_sequential = False
 
+    #: bytes one round's stacked client states may take before the round
+    #: program folds its clients (engines/program.py ``placement``).
+    #: None asks the device; a test sets a number BEFORE the first
+    #: program access to force either placement on the CPU.
+    _fold_budget_bytes: int | None = None
+
+    def fold_budget_bytes(self) -> int | None:
+        """The device's ``bytes_limit`` less the resident cohort, or None
+        where the device reports no limit (the CPU): the stacked
+        placement then stands, as it always did."""
+        if self._fold_budget_bytes is not None:
+            return int(self._fold_budget_bytes)
+        device = (jax.devices()[0] if self.mesh is None
+                  else self.mesh.devices.flat[0])
+        limit = (device.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            return None
+        resident = (round_program.tree_bytes(self.data)
+                    if self.data is not None else 0)
+        n_dev = 1 if self.mesh is None else int(self.mesh.devices.size)
+        return int(limit) - resident // n_dev
+
+    @property
+    def folded(self) -> bool:
+        """Clients run one after another (the round program's FOLDED
+        placement): evaluation and the final fine-tune pass then loop
+        over clients too, and never hold a state per client."""
+        return self.program.placement == round_program.FOLDED
+
+    def _per_client(self, fn, *stacked):
+        """``fn`` over the client axis outside the round program:
+        ``vmap``, or one client after another when the round folds."""
+        if self.folded:
+            return jax.lax.map(lambda row: fn(*row), stacked)
+        return jax.vmap(fn)(*stacked)
+
     def _cohort_map(self, fn, *stacked):
         """The round body's local-training stage on the sharded path:
         the unbatched per-client loop, shard_mapped over the client mesh
@@ -920,7 +957,8 @@ class FederatedEngine:
             "steps_real": int(o.epochs
                               * np.ceil(n / o.batch_size).sum()),
             "steps_run": int(len(sampled) * rows * scan_steps(
-                o.epochs, o.batch_size, self._max_samples()))}
+                o.epochs, o.batch_size, self._max_samples())),
+            "placement": self.program.placement}
 
     # ---------- non-finite upload guard (ISSUE 5 satellite) ----------
 

@@ -22,12 +22,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from neuroimagedisttraining_tpu.core import robust
+from neuroimagedisttraining_tpu.core.losses import binary_auc
 from neuroimagedisttraining_tpu.core.trainer import ClientState
 from neuroimagedisttraining_tpu.engines import program as round_program
 from neuroimagedisttraining_tpu.engines.base import FederatedEngine
 from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.utils import pytree as pt
+
+
+#: the round program's extra output for a model that declares an
+#: auxiliary output (core/trainer.py ``has_aux``): int32 [experts], the
+#: slots routed to each expert over the round's real steps
+EXPERT_TOKENS = "expert_tokens"
+
+
+def expert_load(expert_tokens) -> dict:
+    """The round's expert-load counters from the round program's
+    ``expert_tokens`` output, as host numbers for the ``round_log``
+    span: slots routed, and the busiest and the idlest expert's load
+    over the mean (1.0 = perfectly balanced)."""
+    tokens = np.asarray(expert_tokens, np.float64)
+    mean = max(float(tokens.mean()), 1e-12)
+    return {"tokens_routed": int(tokens.sum()),
+            "expert_load_max_over_mean": float(tokens.max()) / mean,
+            "expert_load_min_over_mean": float(tokens.min()) / mean}
 
 
 class FedAvgEngine(FederatedEngine):
@@ -58,12 +77,20 @@ class FedAvgEngine(FederatedEngine):
         global model, train the sampled cohort, and let the builder run
         the attack -> codec (with EF) -> sanitize -> defend -> aggregate
         tail. The compiled programs are bitwise-equal to the pre-builder
-        hand-written paths (tests/test_dispatch.py, test_cohort.py)."""
+        hand-written paths (tests/test_dispatch.py, test_cohort.py).
+        The stage works on a one-client stack and routes the default
+        tail, so it declares the folded placement; a model with an
+        auxiliary output adds its expert-load counter to the outputs."""
+        outputs = ("loss", "n_bad")
+        if self.trainer.has_aux:
+            outputs += (EXPERT_TOKENS,)
         return round_program.RoundStages(
             carry=("params", "batch_stats"),
             train=self._train_stage,
+            outputs=outputs,
             uses_ef=True,
             supports_attack=True,
+            folds=True,
         )
 
     def _train_stage(self, ctx) -> round_program.TrainOut:
@@ -98,13 +125,13 @@ class FedAvgEngine(FederatedEngine):
                 batch_size=o.batch_size, max_samples=max_samples,
                 perms=perms_c, **prox)
 
-        cs, losses = ctx.client_map(
+        cs, losses, *tokens = ctx.client_map(
             local, cs, Xs, ys, ns,
             hoisted=(lambda: ctx.local_perms(ctx.rngs, ns, o.epochs),))
         return round_program.TrainOut(
             losses=losses,
             upload={"params": cs.params, "batch_stats": cs.batch_stats},
-            state=cs)
+            state=cs, extra=dict(zip((EXPERT_TOKENS,), tokens)))
 
     # ---------- legacy-signature program adapters ----------
     # The builder's compiled programs take structured (carry, data,
@@ -244,10 +271,10 @@ class FedAvgEngine(FederatedEngine):
         # hoisted out of the shard_map like the round's —
         # program.cohort_local_stage)
         if self._cohort_on and C % self.mesh.devices.size == 0:
-            cs, _ = round_program.cohort_local_stage(self, local, cs,
-                                                     X, y, n)
+            cs, *_ = round_program.cohort_local_stage(self, local, cs,
+                                                      X, y, n)
         else:
-            cs, _ = jax.vmap(local)(cs, X, y, n)
+            cs, *_ = jax.vmap(local)(cs, X, y, n)
         return cs
 
     @functools.cached_property
@@ -259,8 +286,39 @@ class FedAvgEngine(FederatedEngine):
         return jax.jit(ft)
 
     @functools.cached_property
-    def _finetune_stream_jit(self):
-        return jax.jit(self._finetune_body)
+    def _finetune_eval_jit(self):
+        """The final pass over a block of clients whose personalized
+        models are not kept (a streamed chunk, or the whole cohort in
+        the FOLDED placement): each client is fine-tuned from the
+        aggregated model and evaluated on its own test rows, under
+        ``_per_client``, so in the folded placement a client's state is
+        discarded before the next starts (stacked, they would be one
+        model state per client). Returns the four per-client metric
+        arrays ``_eval_personal_jit`` returns."""
+        trainer = self.trainer
+        o = self.cfg.optim
+        max_samples = self._max_samples()
+
+        def ft_eval(params, bstats, Xtr, ytr, ntr, Xte, yte, nte, rngs, lr):
+            opt0 = trainer.opt.init(params)
+
+            def one(Xc, yc, nc, Xt, yt, nt, rng):
+                cs, *_ = trainer.local_train(
+                    ClientState(params=params, batch_stats=bstats,
+                                opt_state=opt0, rng=rng),
+                    Xc, yc, nc, lr, epochs=o.epochs,
+                    batch_size=o.batch_size, max_samples=max_samples)
+                valid = jnp.arange(Xt.shape[0]) < nt
+                with jax.named_scope(obs_names.SCOPE_EVAL):
+                    m = trainer.evaluate(cs.params, cs.batch_stats, Xt,
+                                         yt, valid)
+                return (m["test_correct"], m["test_loss"],
+                        m["test_total"], binary_auc(m["scores"], yt, valid))
+
+            return self._per_client(one, Xtr, ytr, ntr, Xte, yte, nte,
+                                    rngs)
+
+        return jax.jit(ft_eval)
 
     def _round_iteration(self, round_idx: int, params, bstats, history,
                          fuse: bool):
@@ -340,6 +398,7 @@ class FedAvgEngine(FederatedEngine):
                                                np.asarray(sampled))
                            if self.wire_spec.needs_ef else None)
                 self._note_round_counts([sampled], len(ids))
+        counters = []  # a fused window reports no per-round counter
         if k > 1:
             run = (self._run_fused_stream_window if streaming
                    else self._run_fused_window)
@@ -352,11 +411,13 @@ class FedAvgEngine(FederatedEngine):
                 # signatures (turboaggregate), and an argument filled
                 # from its default is never donated
                 tail = () if byz is None else (None, byz)
-                params, bstats, loss, n_bad = self._round_stream_jit(
-                    params, bstats, Xs, ys, ns, rngs, lr, *tail)
+                params, bstats, loss, n_bad, *counters = \
+                    self._round_stream_jit(params, bstats, Xs, ys, ns,
+                                           rngs, lr, *tail)
             elif codec_on:
-                (params, bstats, loss, n_bad, new_efs, u0) = round_prog(
-                    params, bstats, self.data, idx, rngs, lr, efs, byz)
+                (params, bstats, loss, n_bad, *counters, new_efs,
+                 u0) = round_prog(params, bstats, self.data, idx, rngs,
+                                  lr, efs, byz)
                 if new_efs is not None:
                     real = jnp.asarray(self._n_train_host[sampled] > 0)
                     self._wire_ef = self.scatter_sampled_rows(
@@ -370,7 +431,7 @@ class FedAvgEngine(FederatedEngine):
                 # byz plans only reach engines whose round accepts them
                 # (supports_byz_faults gates at startup)
                 tail = () if byz is None else (None, byz)
-                params, bstats, loss, n_bad = round_prog(
+                params, bstats, loss, n_bad, *counters = round_prog(
                     params, bstats, self.data, idx, rngs, lr, *tail)
             self._note_nonfinite(n_bad)
         if round_idx % cfg.fed.frequency_of_the_test == 0 \
@@ -383,9 +444,13 @@ class FedAvgEngine(FederatedEngine):
                 # this host boundary, never mid-dispatch
                 params, bstats = self._reflex_boundary(round_idx, params,
                                                        bstats)
-            with obs_trace.span(obs_names.SPAN_ROUND_LOG):
+            with obs_trace.span(obs_names.SPAN_ROUND_LOG) as log_span:
                 self.stat_info["global_test_acc"].append(m["acc"])
                 self.log.metrics(round_idx, train_loss=loss, **m)
+                if counters and obs_trace.TRACER.armed:
+                    # read where the round's loss is read: the round has
+                    # finished (eval_sync waited for it), so no new sync
+                    log_span.args.update(expert_load(counters[0]))
                 history.append({"round": round_idx,
                                 "train_loss": float(loss), **m})
         with obs_trace.span(obs_names.SPAN_ROUND_CHECKPOINT):
@@ -440,10 +505,28 @@ class FedAvgEngine(FederatedEngine):
                                     np.arange(self.num_clients))
         # reference passes round=-1 for this pass (fedavg_api.py:85), so the
         # fine-tune lr is lr * decay^-1, not the decayed end-of-training lr
-        per_states = self._finetune_jit(params, bstats, self.data, rngs,
-                                        self.round_lr(-1))
-        m_global = self.eval_global(params, bstats)
-        m_person = self.eval_personalized(per_states)
+        if self.folded:
+            # fine-tuned, evaluated and discarded a client at a time, as
+            # the streamed pass does per chunk: no stack of personalized
+            # states ("personal" is None there too)
+            d = self.data
+            per_states = None
+            with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
+                                program="finetune_eval", split="test"):
+                out = self._finetune_eval_jit(
+                    params, bstats, d.X_train, d.y_train, d.n_train,
+                    d.X_test, d.y_test, d.n_test, rngs, self.round_lr(-1))
+            m_global = self.eval_global(params, bstats)
+            with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                                program="finetune_eval"):
+                ci = slice(0, 1) if cfg.fed.ci else slice(None)
+                m_person = self._summarize(*(o[ci] for o in out),
+                                           n=d.n_test[ci])
+        else:
+            per_states = self._finetune_jit(params, bstats, self.data,
+                                            rngs, self.round_lr(-1))
+            m_global = self.eval_global(params, bstats)
+            m_person = self.eval_personalized(per_states)
         self.stat_info["person_test_acc"].append(m_person["acc"])
         self.log.metrics(-1, global_=m_global, personal=m_person)
         return {"params": params, "batch_stats": bstats,
@@ -484,12 +567,10 @@ class FedAvgEngine(FederatedEngine):
             if self.cfg.fed.ci and per_parts:
                 break  # CI escape hatch: first chunk only
             rngs = self.per_client_rngs(cfg.fed.comm_round, ch.padded_ids)
-            states = self._finetune_stream_jit(params, bstats, ch.X, ch.y,
-                                               ch.n, rngs, ft_lr)
             che = next(test_iter)
             assert np.array_equal(ch.ids, che.ids)
-            out = self._eval_personal_jit(states.params, states.batch_stats,
-                                          che.X, che.y, che.n)
+            out = self._finetune_eval_jit(params, bstats, ch.X, ch.y, ch.n,
+                                          che.X, che.y, che.n, rngs, ft_lr)
             per_parts.append(tuple(np.asarray(o)[: len(ch.ids)]
                                    for o in out))
             per_ns.append(np.asarray(jax.device_get(che.n))[: len(ch.ids)])

@@ -49,6 +49,7 @@ grep-able.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Callable
 
@@ -129,6 +130,41 @@ REASONS: dict[str, tuple[str, str]] = {
         "the distributed transport has no in-process client axis to "
         "shard (each rank trains its own silo) — flag accepted for "
         "config parity with the main CLI only")),
+    # -- clients in time, the aggregate folded (plane "fold", PR 25) --
+    # The folded placement holds ONE client's state and folds each
+    # upload into a running weighted sum, so whatever needs the whole
+    # upload stack at once cannot run there. A model whose stack does
+    # not fit has no stacked program to fall back to: the "fold-*"
+    # refusals below RAISE at program build with their message;
+    # "fold-not-declared" alone is a fallback (the engine keeps the
+    # stacked program and the device decides).
+    "fold-not-declared": ("fold", (
+        "the stacked client states exceed the device's memory budget, "
+        "but this engine's round stages do not declare the folded "
+        "placement (RoundStages.folds): the stacked round program is "
+        "kept")),
+    "fold-order-statistic-defense": ("fold", (
+        "order-statistic defenses (trimmed_mean, median, krum, "
+        "multi_krum, geometric_median) select over every client's "
+        "upload at once; the folded round holds one upload at a time. "
+        "Use a clip-family defense (norm_diff_clipping, weak_dp), which "
+        "acts per client")),
+    "fold-byz-attack-plan": ("fold", (
+        "the Byzantine attack plan is applied to the stacked upload "
+        "payload (faults/adversary.py apply_attack_stacked); the folded "
+        "round never materializes that stack")),
+    "fold-codec-error-feedback": ("fold", (
+        "--wire_codec runs its lossy roundtrip, its per-client error "
+        "feedback rows and the byte-accounting sample over the stacked "
+        "uploads; the folded round holds one upload at a time")),
+    "fold-health-stats": ("fold", (
+        "--health_stats measures per-client update norms and "
+        "leave-one-out cosines over the upload stack; the folded round "
+        "has no stack to measure")),
+    "fold-secure-quant": ("fold", (
+        "--secure_quant normalises its integer fold weights by the "
+        "cohort's largest and sums field residues over the stacked "
+        "uploads; the folded round holds one upload at a time")),
     # -- autotuner recipes (plane "recipe", tune/recipe.py) --
     "recipe-override": ("recipe", (
         "an explicit CLI flag overrides the loaded recipe's value for "
@@ -157,6 +193,34 @@ def report_fallback(engine_name: str, key: str) -> str:
         labelnames=("plane", "engine", "reason"),
     ).labels(plane=plane, engine=engine_name, reason=key).inc()
     return msg
+
+
+# ---------------------------------------------------------------------------
+# placement of the per-client work (ISSUE 6, PR 25)
+# ---------------------------------------------------------------------------
+
+#: how a round program places its clients: ``stacked`` (state broadcast
+#: over a client axis and ``vmap``ped), ``sharded`` (the client axis
+#: over the ``--client_mesh`` devices, ``lax.map`` within each), or
+#: ``folded`` (clients one after another in a ``lax.scan``, a single
+#: client state alive, the weighted sum of the uploads as the carry)
+STACKED, SHARDED, FOLDED = "stacked", "sharded", "folded"
+
+
+def tree_bytes(tree) -> int:
+    """Bytes of a pytree of arrays or ``ShapeDtypeStruct``s."""
+    return sum(int(np.prod(x.shape)) * jnp.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree)
+               if hasattr(x, "shape") and hasattr(x, "dtype"))
+
+
+def stacked_state_bytes(params, opt_state, rows: int) -> int:
+    """What ``rows`` stacked clients hold beside the global model: each
+    its parameters, its optimizer state, and its row of the upload stack
+    (``rows x (2 x params + opt_state)``). Gradients and activations are
+    left out: they are the compiler's temporaries, and one client's are
+    there in every placement."""
+    return rows * (2 * tree_bytes(params) + tree_bytes(opt_state))
 
 
 # ---------------------------------------------------------------------------
@@ -237,6 +301,15 @@ class RoundStages:
     scalar stats named by ``health_outputs`` (the masked engines emit
     ``obs/health.py MASK_STAT_NAMES``); traced with the round, emitted
     only when ``--health_stats`` arms the leg.
+    ``folds``: the round may run in the FOLDED placement (clients in
+    time, the builder's default aggregate folded into the client loop;
+    :meth:`RoundProgram._fold_body`). Only a declaration whose train
+    stage works on a one-client stack and which routes the default
+    sanitize/defend/aggregate tail (no custom ``aggregate`` / ``update``
+    stage) may declare it.
+    A declared output the aggregate stage does not produce is the sum
+    over clients of the train stage's ``extra`` entry of that name (the
+    expert-load counter of a sparse-expert model).
     """
 
     carry: tuple[str, ...]
@@ -255,6 +328,7 @@ class RoundStages:
     extra_hooked: Callable | None = None
     health: Callable | None = None
     health_outputs: tuple[str, ...] = ()
+    folds: bool = False
 
 
 @dataclasses.dataclass
@@ -281,7 +355,8 @@ class RoundCtx:
 
     def __init__(self, eng, stages: RoundStages, carry: dict, data,
                  consts: dict, Xs, ys, ns, sampled_idx, rngs, lr,
-                 per_round: dict, static_key, n_real, sharded: bool):
+                 per_round: dict, static_key, n_real, sharded: bool,
+                 folded: bool = False):
         self.eng = eng
         self.stages = stages
         self.carry = carry
@@ -295,6 +370,7 @@ class RoundCtx:
         self.static = static_key
         self.n_real = n_real
         self.sharded = sharded
+        self.folded = folded
 
     # -- the local-train placement contract (ISSUE 6) --
 
@@ -308,7 +384,13 @@ class RoundCtx:
         epoch-permutation hoist that keeps argsort-lowered RNG out of the
         shard_map partition (the measured miscompile,
         parallel/cohort.py); ``fn`` takes them as trailing defaulted
-        params."""
+        params. In the FOLDED placement the operands are a one-client
+        stack and ``fn`` is applied to that client unbatched (no
+        ``vmap``: a grouped matmul under a client axis is what the
+        placement exists to avoid)."""
+        if self.folded:
+            out = fn(*jax.tree.map(lambda x: x[0], stacked))
+            return jax.tree.map(lambda x: x[None], out)
         if self.sharded:
             extra = tuple(h() for h in hoisted)
             return self.eng._cohort_map(fn, *stacked, *extra)
@@ -383,6 +465,30 @@ def cohort_local_stage(eng, fn, cs, Xs, ys, ns):
     return eng._cohort_map(fn, cs, Xs, ys, ns, perms)
 
 
+def _sanitize(upload, ref):
+    """``(upload with every non-finite client's row swapped for ref,
+    finite [C])``: step 1 of the default tail, stacked or folded."""
+    finite = robust.finite_per_client(upload)
+    return robust.replace_nonfinite_clients(upload, ref, finite), finite
+
+
+def _clip_defend(eng, upload, ref, defense, rngs):
+    """The clip family's per-client transform of the uploaded params
+    (batch_stats are never clipped): step 2 of the default tail where
+    no order statistic replaces the mean."""
+    f = eng.cfg.fed
+    return robust.defend_stacked(
+        upload["params"], ref["params"], defense=defense,
+        norm_bound=f.norm_bound, stddev=f.stddev, rngs=rngs)
+
+
+def _weighted_loss(losses, w):
+    """The round's training loss: the clients' mean losses under the
+    aggregation weights, a non-finite loss counted as zero."""
+    safe = jnp.where(jnp.isfinite(losses), losses, 0.0)
+    return jnp.sum(safe * w) / jnp.maximum(jnp.sum(w), 1e-9)
+
+
 def sanitize_defend_aggregate(eng, upload, ref, w, losses, rngs=None):
     """The shared tail of a defended round body (trace-safe; the builder
     runs it for every engine without a custom aggregate stage):
@@ -406,8 +512,7 @@ def sanitize_defend_aggregate(eng, upload, ref, w, losses, rngs=None):
     unstacked); ``rngs`` are the per-client keys weak_dp noise draws
     from. Returns ``(new_params, new_bstats, mean_loss, n_bad)``."""
     f = eng.cfg.fed
-    finite = robust.finite_per_client(upload)
-    upload = robust.replace_nonfinite_clients(upload, ref, finite)
+    upload, finite = _sanitize(upload, ref)
     n_bad = jnp.sum(~finite).astype(jnp.int32)
     w = w * finite.astype(jnp.float32)
     C = int(jax.tree.leaves(upload)[0].shape[0])
@@ -422,14 +527,10 @@ def sanitize_defend_aggregate(eng, upload, ref, w, losses, rngs=None):
             geomed_iters=f.geomed_iters)
         new_params, new_bstats = agg["params"], agg["batch_stats"]
     else:
-        client_params = robust.defend_stacked(
-            upload["params"], ref["params"], defense=defense,
-            norm_bound=f.norm_bound, stddev=f.stddev, rngs=rngs)
+        client_params = _clip_defend(eng, upload, ref, defense, rngs)
         new_params = eng.aggregate(client_params, w)
         new_bstats = eng.aggregate(upload["batch_stats"], w)
-    safe_losses = jnp.where(jnp.isfinite(losses), losses, 0.0)
-    mean_loss = jnp.sum(safe_losses * w) / jnp.maximum(jnp.sum(w), 1e-9)
-    return new_params, new_bstats, mean_loss, n_bad
+    return new_params, new_bstats, _weighted_loss(losses, w), n_bad
 
 
 def sq_integer_weights(w, shift: int):
@@ -752,6 +853,72 @@ class RoundProgram:
             return "cohort-not-tiling"
         return None
 
+    # ---------- placement: stacked, sharded or folded (PR 25) ----------
+
+    def _stack_fits(self) -> bool:
+        """The stacked client states of one round against the engine's
+        budget (``FederatedEngine.fold_budget_bytes``: what the device
+        reports, less the resident cohort). Decided from shapes alone:
+        nothing is placed on the device to ask."""
+        eng = self.eng
+        budget = eng.fold_budget_bytes()
+        if budget is None:
+            return True
+        cs = jax.eval_shape(eng.trainer.init_client_state,
+                            jax.random.key(0), eng.sample_input())
+        n_dev = 1 if eng.mesh is None else int(eng.mesh.devices.size)
+        rows = -(-int(eng.cfg.fed.client_num_per_round) // n_dev)
+        return stacked_state_bytes(cs.params, cs.opt_state, rows) <= budget
+
+    def fold_refusal_key(self) -> str | None:
+        """What the configuration asks for that needs every upload at
+        once — a :data:`REASONS` key the folded program is REFUSED with
+        (there is no stacked program to fall back to: it does not fit),
+        or None."""
+        eng = self.eng
+        if eng.active_defense() in robust.ROBUST_AGGREGATORS:
+            return "fold-order-statistic-defense"
+        if eng._byz_on():
+            return "fold-byz-attack-plan"
+        if eng.wire_spec is not None:
+            return "fold-codec-error-feedback"
+        if self.health_names:
+            return "fold-health-stats"
+        if getattr(eng, "sq_spec", None) is not None:
+            return "fold-secure-quant"
+        return None
+
+    @functools.cached_property
+    def placement(self) -> str:
+        """Where this engine's round programs place their clients,
+        decided once, at the first program build, by what the program
+        can observe (bytes), never by a flag: SHARDED when
+        ``--client_mesh`` armed, FOLDED when the stacked client states
+        exceed the budget and the stages declare the fold (refused with
+        its reason when the configuration needs the whole stack),
+        STACKED otherwise."""
+        eng = self.eng
+        if eng._cohort_on:
+            return SHARDED
+        if self.stages is None or self._stack_fits():
+            return STACKED
+        if not self.stages.folds:
+            eng.log.info(
+                "stacked client states exceed the device budget; %s",
+                report_fallback(eng.name, "fold-not-declared"))
+            return STACKED
+        key = self.fold_refusal_key()
+        if key is not None:
+            raise ValueError(
+                f"{eng.name}: the round must fold its clients (their "
+                "stacked states exceed the device's memory budget), and "
+                f"cannot: {reason(key)}")
+        eng.log.info(
+            "stacked client states exceed the device budget: clients run "
+            "one after another, each upload folded into the running "
+            "weighted sum (placement %s)", FOLDED)
+        return FOLDED
+
     # ---------- window planning (ISSUE 4, absorbed from base.py) ----------
 
     def dispatch_window(self, round_idx: int) -> int:
@@ -872,6 +1039,9 @@ class RoundProgram:
 
     def _gather(self, data, idx):
         with jax.named_scope(obs_names.SCOPE_GATHER):
+            if self.placement == FOLDED:
+                # the client loop takes one client's rows at a time
+                return None, None, jnp.take(data.n_train, idx, axis=0)
             Xs = jnp.take(data.X_train, idx, axis=0)
             ys = jnp.take(data.y_train, idx, axis=0)
             ns = jnp.take(data.n_train, idx, axis=0)
@@ -890,6 +1060,11 @@ class RoundProgram:
         carry = dict(zip(st.carry, carry_vals))
         consts = dict(zip(st.consts, const_vals))
         per_round = dict(zip(st.per_round, per_round_vals or ()))
+        if self.placement == FOLDED:
+            new_carry, outs = self._fold_body(
+                carry, data, consts, Xs, ys, ns, idx, rngs, lr, per_round,
+                static_key, byz)
+            return new_carry, outs, ()
         if n_real is not None:
             ns = cohort.pad_row_weights(ns, n_real)
         ctx = RoundCtx(eng, st, carry, data, consts, Xs, ys, ns, idx,
@@ -953,6 +1128,9 @@ class RoundProgram:
                 new_carry = {"params": new_params,
                              "batch_stats": new_bstats}
                 outs = {"loss": mean_loss, "n_bad": n_bad}
+            for name in st.outputs:
+                if name not in outs:  # a counter of the train stage
+                    outs[name] = jnp.sum(tr.extra[name], axis=0)
         if st.update is not None:
             with scope(obs_names.SCOPE_STATE_UPDATE):
                 new_carry.update(st.update(ctx, tr, new_carry))
@@ -988,6 +1166,92 @@ class RoundProgram:
             efs_tail = (new_efs, u0) if st.uses_ef else (u0,)
         return new_carry, outs, efs_tail
 
+    def _fold_body(self, carry: dict, data, consts: dict, Xs, ys, ns, idx,
+                   rngs, lr, per_round: dict, static_key, byz):
+        """One round in the FOLDED placement: the declared train stage
+        runs for one sampled client at a time inside a ``lax.scan``, each
+        from the broadcast global state, and the train and aggregate
+        stages fuse. The carry is what the builder's default tail
+        (:func:`sanitize_defend_aggregate`) would reduce the upload stack
+        to: the running ``sum_i w_i * upload_i`` (parameters and batch
+        statistics, float32), ``sum_i w_i``, and the count of non-finite
+        clients; a client's upload is sanitized and (clip family only)
+        defended alone, inside the loop, then dropped. A bad client adds
+        nothing and counts in ``n_bad``. The result is the stacked
+        path's weighted mean up to float32 summation order (``sum w x /
+        sum w`` for ``sum (w / sum w) x``; tests/test_round_fold.py).
+        Scopes are the stacked body's, so a trace reads both alike.
+        Resident data is gathered a client at a time inside the loop
+        (``Xs`` None); streamed shards arrive stacked and are scanned."""
+        scope = jax.named_scope
+        eng, st = self.eng, self.stages
+        if byz is not None:
+            raise ValueError(reason("fold-byz-attack-plan"))
+        defense = eng.active_defense()
+        if defense in robust.ROBUST_AGGREGATORS:
+            # the reflex plane can escalate the defense mid-run: the
+            # re-traced program refuses here, as the first build would
+            raise ValueError(reason("fold-order-statistic-defense"))
+        ref = {"params": carry["params"],
+               "batch_stats": carry["batch_stats"]}
+        f32 = jnp.float32
+
+        def one_client(acc, row):
+            if Xs is None:
+                with scope(obs_names.SCOPE_GATHER):
+                    Xc = jnp.take(data.X_train, row["i"], axis=0)
+                    yc = jnp.take(data.y_train, row["i"], axis=0)
+            else:
+                Xc, yc = row["X"], row["y"]
+            one = lambda x: None if x is None else x[None]
+            ctx = RoundCtx(eng, st, carry, data, consts, Xc[None], yc[None],
+                           row["n"][None], one(row.get("i")),
+                           row["rng"][None], lr, per_round, static_key,
+                           None, False, folded=True)
+            with scope(obs_names.SCOPE_LOCAL_TRAIN):
+                tr = st.train(ctx)
+            with scope(obs_names.SCOPE_AGGREGATE):
+                upload, finite = _sanitize(tr.upload, ref)
+                params = _clip_defend(
+                    eng, upload, ref, defense,
+                    tr.state.rng if tr.state is not None else None)
+                w = row["n"].astype(f32) * finite[0].astype(f32)
+                add = lambda a, x: a + w * x[0].astype(f32)
+                acc = {
+                    "params": jax.tree.map(add, acc["params"], params),
+                    "batch_stats": jax.tree.map(add, acc["batch_stats"],
+                                                upload["batch_stats"]),
+                    "w": acc["w"] + w,
+                    "n_bad": acc["n_bad"] + (~finite[0]).astype(jnp.int32),
+                }
+            extra = {k: v[0] for k, v in tr.extra.items()
+                     if k in st.outputs}
+            return acc, (tr.losses[0], w, extra)
+
+        rows = {"n": ns, "rng": rngs}
+        if idx is not None:
+            rows["i"] = idx
+        if Xs is not None:
+            rows.update(X=Xs, y=ys)
+        zeros = lambda t: jax.tree.map(
+            lambda x: jnp.zeros(x.shape, f32), t)
+        acc0 = {"params": zeros(ref["params"]),
+                "batch_stats": zeros(ref["batch_stats"]),
+                "w": f32(0.0), "n_bad": jnp.int32(0)}
+        acc, (losses, w, extra) = jax.lax.scan(one_client, acc0, rows)
+        with scope(obs_names.SCOPE_AGGREGATE):
+            total = jnp.maximum(acc["w"], 1e-12)
+            mean = lambda a, r: (a / total).astype(r.dtype)
+            new_carry = {
+                "params": jax.tree.map(mean, acc["params"],
+                                       ref["params"]),
+                "batch_stats": jax.tree.map(mean, acc["batch_stats"],
+                                            ref["batch_stats"])}
+            outs = {"loss": _weighted_loss(losses, w),
+                    "n_bad": acc["n_bad"]}
+            outs.update({k: jnp.sum(v, axis=0) for k, v in extra.items()})
+        return new_carry, outs
+
     def _epilogue(self, carry: dict, data) -> tuple:
         st = self.stages
         if st.epilogue is None:
@@ -1011,6 +1275,7 @@ class RoundProgram:
         (one measurement — tests/test_program.py pins them equal). A
         rebuild of the same exact plan-cache ``key`` is a recompile
         (warning-logged + flight-recorded by the profiler)."""
+        _ = self.placement  # decided (or refused) before anything traces
         self.built += 1
         n = self._build_counts[key] = self._build_counts.get(key, 0) + 1
         obs_compute.note_compile(self.eng.name, label, recompile=n > 1)

@@ -39,6 +39,7 @@ from neuroimagedisttraining_tpu.models.neuro3d import (  # noqa: F401
     ResNet3D_l3,
     Tiny3DCNN,
 )
+from neuroimagedisttraining_tpu.models.olmoe3d import OLMoE3D  # noqa: F401
 from neuroimagedisttraining_tpu.models.resnet2d import (  # noqa: F401
     ResNet18,
     customized_resnet18,
@@ -97,6 +98,11 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         return Tiny3DCNN(num_classes=num_classes, dtype=dtype)
     if name in ("resnet3d", "resnet_l3", "resnet3d_l3"):
         return ResNet3D_l3(num_classes=num_classes, dtype=dtype)
+    if name == "olmoe3d":
+        # added here, not ported (models/olmoe3d.py): OLMoE-1B-7B's
+        # sparse-expert block at its published widths over 3D patch tokens
+        return OLMoE3D(num_classes=num_classes, dtype=dtype,
+                       remat=remat is True)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":
@@ -142,3 +148,14 @@ def primary_logits(out):
     if isinstance(out, (tuple, list)):
         return out[0]
     return out
+
+
+def aux_outputs(out) -> dict | None:
+    """The auxiliary dict of a model that declares one (``returns_aux``:
+    ``(logits, {"loss": weighted scalar, "expert_tokens": int32 [E]})``,
+    models/olmoe3d.py), or None: the reference models' second output is
+    a feature tensor, never a dict."""
+    if isinstance(out, (tuple, list)) and len(out) == 2 \
+            and isinstance(out[1], dict):
+        return out[1]
+    return None

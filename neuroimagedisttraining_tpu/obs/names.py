@@ -187,6 +187,26 @@ SCOPE_COHORT_GATHER = "cohort_gather"
 DEVICE_SCOPES: frozenset[str] = frozenset(
     v for k, v in list(globals().items()) if k.startswith("SCOPE_"))
 
+# ---------------------------------------------------------------------------
+# device scopes of the sparse-expert transformer block (models/olmoe3d.py,
+# PR 25). A second table: benchmark/scopes.json's scope_names is pinned to
+# DEVICE_SCOPES above and its classes know nothing of these, so in the
+# existing partitions the block's ops read as forward / backward (phase)
+# and none (stage); benchmark/metrics/olmoe_scopes.json gives them classes
+# of their own. The patch embedding reuses SCOPE_STEM, the read-out
+# SCOPE_HEAD. The next benchmark PR folds both tables into one.
+# ---------------------------------------------------------------------------
+SCOPE_ATTN = "attn"          # norm'd q/k/v, RoPE, causal softmax, o_proj
+SCOPE_ROUTER = "router"      # float32 logits, softmax, top-k, aux loss
+SCOPE_DISPATCH = "dispatch"  # sort of the k*T slots by expert + gather
+SCOPE_EXPERTS = "experts"    # the three grouped matmuls and the SiLU gate
+SCOPE_COMBINE = "combine"    # un-sort and the weighted sum over k
+
+#: the model scopes of the table above (disjoint from DEVICE_SCOPES)
+MODEL_SCOPES: frozenset[str] = frozenset(
+    (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
+     SCOPE_COMBINE))
+
 #: every declared metric name — the set obs/rules.py validates rule
 #: manifests against at startup (unknown names fail with this list)
 DECLARED: frozenset[str] = frozenset(

@@ -1,0 +1,236 @@
+"""The FOLDED placement (PR 25): clients in time, the aggregate folded.
+
+A round program whose stacked client states exceed the device's memory
+budget runs its clients one after another in a ``lax.scan`` with a single
+client state alive, each upload folded into a running weighted sum
+(engines/program.py ``_fold_body``). These tests pin it against the
+stacked round on ``3dcnn_tiny`` with three clients of unequal size: the
+same clients, the same rngs, the same data; only the placement differs.
+
+Tolerance: the stacked tail computes ``sum_i (w_i / W) x_i`` and the fold
+``(sum_i w_i x_i) / W`` in float32, and ``vmap`` lowers a client's
+convolutions batched where the fold runs them alone, so the two differ by
+float32 summation order and nothing else: ``rtol`` 2e-5 on values of
+order 0.01-1 (``atol`` 2e-6 for the entries near zero). A wrong weight, a
+dropped client or a stale state is off by orders of magnitude more.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuroimagedisttraining_tpu.config import (
+    DataConfig, ExperimentConfig, FedConfig, OptimConfig,
+)
+from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
+from neuroimagedisttraining_tpu.data.federate import federate_cohort
+from neuroimagedisttraining_tpu.data.synthetic import generate_synthetic_abcd
+from neuroimagedisttraining_tpu.engines import create_engine
+from neuroimagedisttraining_tpu.engines import program as round_program
+from neuroimagedisttraining_tpu.models import create_model
+from neuroimagedisttraining_tpu.obs import trace as obs_trace
+from neuroimagedisttraining_tpu.utils.logging import ExperimentLogger
+
+RTOL, ATOL = 2e-5, 2e-6
+SITES = (40, 24, 12)  # unequal: the weights and the padded steps differ
+
+
+@pytest.fixture(scope="module")
+def cohort3():
+    cohort = generate_synthetic_abcd(num_subjects=sum(SITES),
+                                     shape=(12, 14, 12), num_sites=3,
+                                     seed=3)
+    # sites of unequal size, in order
+    cohort["site"] = np.repeat(np.arange(3), SITES).astype(
+        cohort["site"].dtype)
+    return cohort
+
+
+def _engine(tmp_path, cohort, *, budget, tag, algorithm="fedavg",
+            comm_round=2, **fed_kw):
+    cfg = ExperimentConfig(
+        model="3dcnn_tiny", num_classes=1, algorithm=algorithm,
+        data=DataConfig(dataset="synthetic", partition_method="site"),
+        optim=OptimConfig(lr=1e-2, batch_size=8, epochs=2),
+        fed=FedConfig(client_num_in_total=3, comm_round=comm_round,
+                      frequency_of_the_test=1, **fed_kw),
+        log_dir=str(tmp_path), tag=tag)
+    trainer = LocalTrainer(create_model(cfg.model, num_classes=1),
+                           cfg.optim, num_classes=1)
+    log = ExperimentLogger(str(tmp_path), "synthetic", cfg.identity(),
+                           console=False)
+    fed, _ = federate_cohort(cohort, partition_method="site", mesh=None)
+    eng = create_engine(algorithm, cfg, fed, trainer, mesh=None,
+                        logger=log)
+    eng._fold_budget_bytes = budget
+    return eng
+
+
+def _close(a, b):
+    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_allclose(np.asarray(x, np.float64),
+                                   np.asarray(y, np.float64),
+                                   rtol=RTOL, atol=ATOL)
+
+
+def _one_round(eng, params=None, bstats=None, poison=None):
+    gs = eng.init_global_state()
+    params = gs.params if params is None else params
+    bstats = gs.batch_stats if bstats is None else bstats
+    if poison is not None:
+        # a client whose data makes its upload non-finite
+        X = np.asarray(eng.data.X_train).astype(np.float32)
+        eng.data = dataclasses.replace(
+            eng.data, X_train=jnp.asarray(X).at[poison].set(jnp.inf))
+    sampled = eng.client_sampling(0)
+    rngs = eng.per_client_rngs(0, sampled)
+    return eng._round_jit(params, bstats, eng.data, jnp.asarray(sampled),
+                          rngs, eng.round_lr(0))
+
+
+def test_placement_follows_the_budget(tmp_path, cohort3):
+    """The fold is chosen when the budget is under the stacked states,
+    and not otherwise; with no budget reported (the CPU) the stacked
+    path stands."""
+    big = _engine(tmp_path, cohort3, budget=1 << 40, tag="big")
+    assert big.program.placement == round_program.STACKED
+    assert not big.folded
+    small = _engine(tmp_path, cohort3, budget=1, tag="small")
+    assert small.program.placement == round_program.FOLDED
+    none = _engine(tmp_path, cohort3, budget=None, tag="none")
+    assert none.fold_budget_bytes() is None  # CPU: no bytes_limit
+    assert none.program.placement == round_program.STACKED
+    # the rule itself: rows x (2 x params + opt_state) against the budget
+    cs = jax.eval_shape(big.trainer.init_client_state, jax.random.key(0),
+                        big.sample_input())
+    need = round_program.stacked_state_bytes(cs.params, cs.opt_state, 3)
+    p = round_program.tree_bytes(cs.params)
+    assert need == 3 * (2 * p + round_program.tree_bytes(cs.opt_state))
+    at = _engine(tmp_path, cohort3, budget=need, tag="at")
+    assert at.program.placement == round_program.STACKED
+    under = _engine(tmp_path, cohort3, budget=need - 1, tag="under")
+    assert under.program.placement == round_program.FOLDED
+
+
+def test_folded_round_equals_stacked_round(tmp_path, cohort3):
+    """New global parameters and batch statistics equal to float32
+    summation order; the round's loss and n_bad equal."""
+    st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s"))
+    fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f"))
+    _close(st[0], fo[0])
+    _close(st[1], fo[1])
+    np.testing.assert_allclose(float(st[2]), float(fo[2]), rtol=RTOL)
+    assert int(st[3]) == int(fo[3]) == 0
+    # the round moved the model (the comparison is not of two no-ops)
+    gs = _engine(tmp_path, cohort3, budget=None,
+                 tag="g").init_global_state()
+    moved = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+        jax.tree.leaves(gs.params), jax.tree.leaves(fo[0])))
+    assert moved > 1e-4
+
+
+def test_nonfinite_client_dropped_in_both(tmp_path, cohort3):
+    """A client whose upload is non-finite adds nothing and counts in
+    n_bad, in both placements; the survivors' mean is the same."""
+    st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s"),
+                    poison=1)
+    fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f"),
+                    poison=1)
+    assert int(st[3]) == int(fo[3]) == 1
+    assert all(bool(jnp.all(jnp.isfinite(x)))
+               for x in jax.tree.leaves(fo[0]))
+    _close(st[0], fo[0])
+    np.testing.assert_allclose(float(st[2]), float(fo[2]), rtol=RTOL)
+
+
+def test_clip_defense_folds_per_client(tmp_path, cohort3):
+    """A clip-family defense acts per client and folds: same result."""
+    kw = dict(defense_type="norm_diff_clipping", norm_bound=0.05)
+    st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s",
+                            **kw))
+    fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f", **kw))
+    _close(st[0], fo[0])
+
+
+@pytest.mark.parametrize("key,kw", [
+    ("fold-order-statistic-defense", dict(defense_type="median")),
+    ("fold-byz-attack-plan", dict(fault_spec="byz:1@0:sign_flip")),
+    ("fold-codec-error-feedback", dict(wire_codec="delta+sparse+quant")),
+    ("fold-secure-quant", dict(secure_quant=True, secure_quant_field_bits=32)),
+])
+def test_refused_combinations_raise_their_reason(tmp_path, cohort3, key,
+                                                 kw):
+    """What needs the whole upload stack at once refuses at program
+    build with its REASONS message; the same configuration builds when
+    the stack fits."""
+    eng = _engine(tmp_path, cohort3, budget=1, tag="r" + key[5:9], **kw)
+    assert eng.program.fold_refusal_key() == key
+    with pytest.raises(ValueError) as e:
+        eng.program.placement
+    assert round_program.reason(key) in str(e.value)
+    ok = _engine(tmp_path, cohort3, budget=1 << 40, tag="k" + key[5:9],
+                 **kw)
+    assert ok.program.placement == round_program.STACKED
+
+
+def test_health_stats_refuse_the_fold(tmp_path, cohort3):
+    eng = _engine(tmp_path, cohort3, budget=1, tag="h")
+    eng.cfg = dataclasses.replace(eng.cfg, health_stats=True)
+    assert eng.program.fold_refusal_key() == "fold-health-stats"
+    with pytest.raises(ValueError, match="health_stats"):
+        eng.program.placement
+
+
+def test_undeclared_engine_keeps_the_stacked_program(tmp_path, cohort3):
+    """An engine whose stages do not declare the fold keeps its stacked
+    program, with a counted fallback reason."""
+    from neuroimagedisttraining_tpu.obs import metrics as obs_metrics
+    from neuroimagedisttraining_tpu.obs import names as obs_names
+
+    counter = obs_metrics.counter(
+        obs_names.FALLBACK_TOTAL, labelnames=("plane", "engine", "reason"))
+    labels = dict(plane="fold", engine="ditto", reason="fold-not-declared")
+    before = counter.get(**labels)
+    eng = _engine(tmp_path, cohort3, budget=1, tag="d", algorithm="ditto")
+    assert not eng.program.stages.folds
+    assert eng.program.placement == round_program.STACKED
+    assert counter.get(**labels) == before + 1.0
+
+
+def test_train_end_to_end_matches_stacked(tmp_path, cohort3):
+    """engine.train() folded: rounds, evaluation (clients scanned, not
+    vmapped), the final fine-tune pass (fine-tuned, evaluated and
+    discarded one at a time) give the stacked run's metrics; the folded
+    run returns no stack of personalized states; the dispatch span
+    carries the placement and the counts keep their meaning."""
+    obs_trace.arm()
+    try:
+        st = _engine(tmp_path, cohort3, budget=1 << 40, tag="s").train()
+        n0 = len(obs_trace.TRACER.events())
+        fo = _engine(tmp_path, cohort3, budget=1, tag="f").train()
+        events = obs_trace.TRACER.events()
+    finally:
+        obs_trace.disarm()
+    assert fo["personal"] is None and st["personal"] is not None
+    _close(st["params"], fo["params"])
+    for a, b in zip(st["history"], fo["history"]):
+        for k in ("train_loss", "loss", "acc", "auc"):
+            np.testing.assert_allclose(a[k], b[k], rtol=1e-4, atol=1e-6)
+    for part in ("final_global", "final_personal"):
+        for k in ("loss", "acc", "auc"):
+            np.testing.assert_allclose(st[part][k], fo[part][k],
+                                       rtol=1e-4, atol=1e-6)
+    dispatch = [e["args"] for e in events
+                if e.get("name") == "dispatch_program"
+                and e["args"].get("steps_run")]
+    assert {a["placement"] for a in dispatch[:2]} == {"stacked"}
+    assert {a["placement"] for a in dispatch[2:]} == {"folded"}
+    assert n0 > 0
+    assert dispatch[0]["samples_real"] == dispatch[2]["samples_real"]
+    assert dispatch[0]["steps_real"] == dispatch[2]["steps_real"]
+    assert dispatch[0]["steps_run"] == dispatch[2]["steps_run"]

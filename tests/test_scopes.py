@@ -301,3 +301,32 @@ def test_names_table_is_complete():
     assert len(spans) == len(set(spans))
     for s in names.ROUND_CHILD_SPANS:
         assert s.startswith(("round", "dispatch", "eval_"))
+
+
+def test_model_scopes_table():
+    """MODEL_SCOPES (the sparse-expert block's stages, PR 25) is a second
+    table, disjoint from DEVICE_SCOPES (which benchmark/scopes.json pins);
+    every constant is used at least once in models/, none is spelled as a
+    literal there, and the benchmark's own rules file names each."""
+    import glob
+    import json
+    import os
+
+    consts = {k: v for k, v in vars(names).items()
+              if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
+    assert len(consts) == len(names.MODEL_SCOPES) == 5
+    assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sources = {p: open(p).read() for p in glob.glob(os.path.join(
+        root, "neuroimagedisttraining_tpu", "models", "*.py"))}
+    for const, value in consts.items():
+        assert any(f"obs_names.{const}" in s for s in sources.values()), const
+        for path, s in sources.items():
+            assert not re.search(rf"""["']{value}["']""", s), (path, value)
+    rules = json.load(open(os.path.join(
+        root, "benchmark", "metrics", "olmoe_scopes.json")))
+    named = {s for k, v in rules["scope_names"].items() if k != "what"
+             for s in v}
+    assert named == set(names.MODEL_SCOPES)
+    classes = {r["class"] for r in rules["block"]}
+    assert set(rules["scope_names"]) - {"what"} <= classes
