@@ -195,14 +195,16 @@ def compiled_round(engine):
     import numpy as np
 
     gs = engine.init_global_state()
-    ids, n_real = engine.client_sampling(0), None
+    ids, n_real, deal = engine.client_sampling(0), None, None
     if engine.cfg.fed.client_mesh:
         ids, n_real = engine._cohort_pad(ids)
+        deal, _ = engine._cohort_deal(ids, n_real)
+        ids, deal = ids[deal], jnp.asarray(deal)
     rngs = engine.per_client_rngs(0, np.asarray(ids))
     prog = engine.program.round_jit(n_real=n_real)
     return prog.jit.lower(
         (gs.params, gs.batch_stats), engine.data, (), jnp.asarray(ids),
-        rngs, engine.round_lr(0), None, None).compile()
+        rngs, engine.round_lr(0), None, None, None, deal).compile()
 
 
 # ---------- phases ----------

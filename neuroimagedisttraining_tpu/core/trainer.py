@@ -12,7 +12,9 @@ client axis and sharded over a TPU mesh:
 - Per-client *step counts* are preserved under vmap: every client scans the
   same static number of steps, but steps beyond ``ceil(n_i/B)`` per epoch are
   masked no-ops, so small clients do exactly as many updates as the
-  reference's DataLoader would give them.
+  reference's DataLoader would give them. A row that runs alone (a
+  placement's ``LocalTrainer.rows_alone``) executes those updates and no
+  masked one: same state, same loss, same rng stream.
 - ``evaluate``: full-cohort chunked eval returning correct/loss/total plus
   raw scores for AUC (metrics dict parity: my_model_trainer.py:245-274).
 
@@ -23,6 +25,7 @@ dtype=float32)`` with no rescale).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any
 
@@ -70,11 +73,16 @@ def epoch_perms_for(rng: jax.Array, epochs: int, max_samples: int,
 
 
 def scan_steps(epochs: int, batch_size: int, max_samples: int) -> int:
-    """SGD steps ``local_train``'s scan runs for ONE client row, real or
-    padded: every row walks ``ceil(max_samples / batch_size)`` steps an
-    epoch, and a row with fewer samples masks the surplus as no-ops. The
-    round driver's ``steps_run`` count (obs/names.py
-    SPAN_DISPATCH_PROGRAM) is this times the rows a program trains."""
+    """The LENGTH of ``local_train``'s step loop for one client row, real
+    or padded: ``ceil(max_samples / batch_size)`` iterations an epoch,
+    whatever the row holds, because the rng stream and the per-step loss
+    vector are laid out over it. It is the work done only where rows are
+    batched (``vmap``): there a row with fewer samples computes the
+    surplus and a ``where`` discards it, and the round driver's
+    ``steps_run`` (obs/names.py SPAN_DISPATCH_PROGRAM) is this times the
+    rows. A row that runs alone (:meth:`LocalTrainer.rows_alone`)
+    executes its own ``epochs * ceil(n / batch_size)`` of them and no
+    other; ``steps_skipped`` counts the rest."""
     return epochs * max(1, math.ceil(max_samples / batch_size))
 
 
@@ -134,6 +142,27 @@ class LocalTrainer:
         #: function. A model without the declaration traces exactly the
         #: program it traced before the declaration existed.
         self.has_aux = bool(getattr(model, "returns_aux", False))
+        #: set by :meth:`rows_alone` while a placement traces rows
+        #: unbatched; ``local_train`` reads it at trace time
+        self._rows_alone = False
+
+    @contextlib.contextmanager
+    def rows_alone(self):
+        """For the duration of a trace, ``local_train`` is applied to one
+        client row at a time (``lax.map`` / ``lax.scan`` over clients, a
+        ``shard_map`` block's loop, a single folded client) and never
+        under a client-axis ``vmap``: its step predicate is then a
+        scalar, and the row stops at its own last step. Entered where a
+        placement is realised (``RoundCtx.client_map``,
+        ``FederatedEngine._cohort_map`` / ``_per_client``), never by a
+        ``local_train`` call site. Under ``vmap`` the loop it selects
+        would still be right (JAX batches a ``while`` into selects), only
+        slower than the batched form."""
+        was, self._rows_alone = self._rows_alone, True
+        try:
+            yield
+        finally:
+            self._rows_alone = was
 
     # ---------- init ----------
 
@@ -227,7 +256,10 @@ class LocalTrainer:
 
         Returns ``(new_state, mean_loss)``. ``n_valid`` is the client's true
         sample count; steps beyond its per-epoch quota are masked no-ops so
-        vmapped clients keep reference-parity update counts.
+        vmapped clients keep reference-parity update counts. Inside
+        :meth:`rows_alone` (the row is unbatched: its predicate is a
+        scalar) the surplus iterations are not executed at all, with the
+        same trained state, mean loss, ``expert_tokens`` and ``cs.rng``.
 
         Batch selection follows ``optim.batch_order``: ``"shuffle"``
         (default) walks a fresh per-epoch permutation in ``batch_size``
@@ -274,9 +306,11 @@ class LocalTrainer:
                 perms = epoch_permutations(prng, epochs, max_samples,
                                            n_valid)
 
-        def step(carry, t):
-            state = carry
-            rng, brng, drng = jax.random.split(state.rng, 3)
+        def advance(state, t, brng, drng):
+            """Iteration ``t`` computed: its batch, forward, backward and
+            the optimizer tail. ``(params, batch_stats, opt_state, out)``
+            with ``out`` the step's loss, or ``(loss, expert_tokens)``
+            of a model with an auxiliary output."""
             with jax.named_scope(obs_names.SCOPE_BATCH_PREP):
                 if shuffle:
                     idx, wb = shuffle_batch_indices(
@@ -323,7 +357,16 @@ class LocalTrainer:
                     params = jax.tree.map(
                         lambda w, ref: w - lr * prox_lamda * (w - ref),
                         params, prox_ref)
+            out = (loss, aux["expert_tokens"]) if self.has_aux else loss
+            return params, bstats, opt_state, out
 
+        def step(carry, t):
+            # rows batched over a client axis: every row computes every
+            # iteration, and a surplus one keeps its old state
+            state = carry
+            rng, brng, drng = jax.random.split(state.rng, 3)
+            params, bstats, opt_state, out = advance(state, t, brng, drng)
+            with jax.named_scope(obs_names.SCOPE_UPDATE):
                 active = (t % steps_per_epoch) < my_steps
 
                 def keep(new, old):
@@ -335,14 +378,56 @@ class LocalTrainer:
                     batch_stats=keep(bstats, state.batch_stats),
                     opt_state=keep(opt_state, state.opt_state),
                     rng=rng)
-                if aux is None:
-                    return new_state, jnp.where(active, loss, 0.0)
-                tokens = aux["expert_tokens"]
+                if not self.has_aux:
+                    return new_state, jnp.where(active, out, 0.0)
+                loss, tokens = out
                 return new_state, (jnp.where(active, loss, 0.0),
                                    jnp.where(active, tokens,
                                              jnp.zeros_like(tokens)))
 
-        cs, outs = jax.lax.scan(step, cs, jnp.arange(total))
+        def real_steps(cs):
+            """The row runs alone: its ``epochs * my_steps`` real
+            iterations in a loop to that traced bound, each writing its
+            state with nothing selected against the old one; a surplus
+            iteration runs nothing. The keys are today's stream, split
+            ahead for the whole loop length (a skipped iteration's
+            split is still consumed: ``RoundCtx.rng_after_local_train``
+            replays it and the trained ``cs.rng`` is an output), and
+            each iteration's ``out`` lands at its own ``t`` of a
+            zero-filled ``[total]`` vector, so the sums below add what
+            the batched form adds."""
+
+            def keys_of(rng, _):
+                rng, brng, drng = jax.random.split(rng, 3)
+                return rng, (brng, drng)
+
+            rng_end, (brngs, drngs) = jax.lax.scan(keys_of, cs.rng, None,
+                                                   length=total)
+            out0 = jax.eval_shape(
+                lambda: advance(cs, 0, brngs[0], drngs[0])[3])
+            outs0 = jax.tree.map(
+                lambda o: jnp.zeros((total, *o.shape), o.dtype), out0)
+
+            per = jnp.maximum(my_steps, 1)  # the loop is empty at 0
+
+            def body(j, carry):
+                state, outs = carry
+                t = (j // per) * steps_per_epoch + j % per
+                params, bstats, opt_state, out = advance(
+                    state, t, brngs[t], drngs[t])
+                state = ClientState(params=params, batch_stats=bstats,
+                                    opt_state=opt_state, rng=state.rng)
+                return state, jax.tree.map(
+                    lambda v, o: v.at[t].set(o), outs, out)
+
+            cs, outs = jax.lax.fori_loop(0, epochs * my_steps, body,
+                                         (cs, outs0))
+            return cs.replace(rng=rng_end), outs
+
+        if self._rows_alone:
+            cs, outs = real_steps(cs)
+        else:
+            cs, outs = jax.lax.scan(step, cs, jnp.arange(total))
         denom = jnp.maximum(epochs * my_steps, 1)
         if not self.has_aux:
             return cs, jnp.sum(outs) / denom

@@ -488,7 +488,11 @@ class FederatedEngine:
         [real_clients, num_clients), n_train == 0) and then repeat the
         last sampled id; either way the feed zeroes their fetched sample
         counts (``n_real``), so pads train as masked no-ops and weigh 0 in
-        aggregation. Engines that scatter per-client state by sampled id
+        aggregation. The streamed feed keeps the sampler's order: its
+        rows are batched over the mesh (``vmap`` under GSPMD; cohort
+        sharding does not arm under streaming), every batched row walks
+        the longest row's steps wherever it sits, and so there is
+        nothing for ``cohort.deal_rows`` to balance here. Engines that scatter per-client state by sampled id
         must route through ``scatter_sampled_rows`` (pad entries dropped).
         Pass ``sampled`` when the round's set was already computed."""
         if sampled is None:
@@ -757,15 +761,34 @@ class FederatedEngine:
         return cohort.pad_cohort(np.asarray(sampled), self.real_clients,
                                  self.num_clients, self.mesh.devices.size)
 
+    def _cohort_deal(self, ids: np.ndarray, n_real: int
+                     ) -> tuple[np.ndarray, np.ndarray]:
+        """``(deal, chip_steps)``: this round's deal of the mesh-padded
+        set ``ids`` to the chips (``cohort.deal_rows`` over ``ceil(n /
+        batch)``, pad rows at zero steps), an index array computed per
+        round from host integers, an operand of the sharded round program
+        and never a constant of it; and the steps an epoch each chip then
+        holds."""
+        n = self._n_train_host[np.asarray(ids)].copy()
+        n[n_real:] = 0  # by position: a pad may repeat a real client's id
+        steps = np.ceil(n / self.cfg.optim.batch_size).astype(np.int64)
+        chips = self.mesh.devices.size
+        deal = cohort.deal_rows(steps, chips)
+        return deal, steps[deal].reshape(chips, -1).sum(axis=1)
+
     def _cohort_round_prog(self, sampled: np.ndarray):
         """``(gather_ids, round_prog)`` for one resident round: the
-        mesh-padded id set + the sharded round program when cohort
-        sharding is armed; the sampled set + the unsharded
-        ``_round_jit`` otherwise (shared by the fedavg-family and
-        salientgrads drivers)."""
+        mesh-padded id set in DEALT order (``_cohort_deal``; the caller
+        folds its rngs from these ids, so they follow) + the sharded
+        round program with the deal bound, which undoes it after the
+        all-gather, when cohort sharding is armed; the sampled set + the
+        unsharded ``_round_jit`` otherwise (shared by the fedavg-family
+        and salientgrads drivers)."""
         if self._cohort_on:
             ids, n_real = self._cohort_pad(sampled)
-            return ids, self._sharded_round_jit(n_real)
+            deal, _ = self._cohort_deal(ids, n_real)
+            return ids[deal], functools.partial(
+                self._sharded_round_jit(n_real), deal=jnp.asarray(deal))
         return sampled, self._round_jit
 
     #: when True, the sharded round programs lower their local-training
@@ -806,9 +829,11 @@ class FederatedEngine:
 
     def _per_client(self, fn, *stacked):
         """``fn`` over the client axis outside the round program:
-        ``vmap``, or one client after another when the round folds."""
+        ``vmap``, or one client after another when the round folds (each
+        then runs alone: ``LocalTrainer.rows_alone``)."""
         if self.folded:
-            return jax.lax.map(lambda row: fn(*row), stacked)
+            with self.trainer.rows_alone():
+                return cohort.sequential_map(fn, *stacked)
         return jax.vmap(fn)(*stacked)
 
     def _cohort_map(self, fn, *stacked):
@@ -817,10 +842,14 @@ class FederatedEngine:
         and all-gathered back to replicated full stacks — or the same
         loop on one device when ``_cohort_sequential`` asks for the
         sequential reference (~1-ulp-equal with bitwise first-round
-        losses — the full contract in parallel/cohort.py)."""
-        if self._cohort_sequential:
-            return cohort.sequential_map(fn, *stacked)
-        return cohort.cohort_map(self.mesh, fn, *stacked)
+        losses — the full contract in parallel/cohort.py). Either way a
+        row runs alone, and the trainer is told so while this traces
+        (``LocalTrainer.rows_alone``): both take the same unbatched
+        step, which stops at the row's own last one."""
+        with self.trainer.rows_alone():
+            if self._cohort_sequential:
+                return cohort.sequential_map(fn, *stacked)
+            return cohort.cohort_map(self.mesh, fn, *stacked)
 
     # ---------- Byzantine value faults (faults/adversary.py, ISSUE 5) ----------
 
@@ -940,25 +969,43 @@ class FederatedEngine:
         """What the next dispatched program trains, as host integers the
         driver already holds (no device read): ``samples_real`` and
         ``steps_real`` over the sampled clients of the round(s)
-        (``sampled``: one id array per round), and ``steps_run``, the
-        steps the program's scans walk for its ``rows`` client rows a
-        round, padded rows and masked steps included
-        (core/trainer.py ``scan_steps``: the rule ``local_train`` itself
-        uses). They ride on the ``dispatch_program`` span, so a trace
-        reads the padded share where the work is dispatched. A no-op
-        while the tracer is disarmed."""
+        (``sampled``: one id array per round); ``steps_run``, the steps
+        the program's loops execute for its ``rows`` client rows a round
+        under its placement (stacked: every row walks
+        core/trainer.py ``scan_steps``, padded rows and masked steps
+        included; rows that run alone, sharded or folded: the real steps
+        and no other); ``steps_skipped``, the surplus iterations of that
+        loop length not executed; and ``chip_steps_max`` /
+        ``chip_steps_mean``, the busiest chip's and the mean chip's
+        share of ``steps_run`` as the rows are dealt
+        (``_cohort_deal``; equal on one chip), each round's summed over
+        a window. They ride on the ``dispatch_program`` span, so a trace
+        reads the padded share and the deal where the work is
+        dispatched. A no-op while the tracer is disarmed."""
         if not obs_trace.TRACER.armed:
             return
         o = self.cfg.optim
+        placement = self.program.placement
         n = np.concatenate([self._n_train_host[np.asarray(s)]
                             for s in sampled])
+        real = int(o.epochs * np.ceil(n / o.batch_size).sum())
+        walked = int(len(sampled) * rows * scan_steps(
+            o.epochs, o.batch_size, self._max_samples()))
+        run = walked if placement == round_program.STACKED else real
+        busiest, chips = run, 1
+        if placement == round_program.SHARDED:
+            chips = int(self.mesh.devices.size)
+            busiest = int(o.epochs * sum(
+                self._cohort_deal(*self._cohort_pad(s))[1].max()
+                for s in sampled))
         self._dispatch_counts = {
             "samples_real": int(o.epochs * n.sum()),
-            "steps_real": int(o.epochs
-                              * np.ceil(n / o.batch_size).sum()),
-            "steps_run": int(len(sampled) * rows * scan_steps(
-                o.epochs, o.batch_size, self._max_samples())),
-            "placement": self.program.placement}
+            "steps_real": real,
+            "steps_run": run,
+            "steps_skipped": walked - run,
+            "chip_steps_max": busiest,
+            "chip_steps_mean": run / chips,
+            "placement": placement}
 
     # ---------- non-finite upload guard (ISSUE 5 satellite) ----------
 

@@ -149,9 +149,10 @@ class DittoEngine(FederatedEngine):
         prog = self.program.round_jit(n_real=n_real)
 
         def sharded_round_call(params, bstats, per_params, per_bstats,
-                               data, sampled_idx, rngs, lr, byz=None):
+                               data, sampled_idx, rngs, lr, byz=None,
+                               deal=None):
             return prog((params, bstats, per_params, per_bstats), data,
-                        (), sampled_idx, rngs, lr, None, byz)
+                        (), sampled_idx, rngs, lr, None, byz, None, deal)
 
         return sharded_round_call
 

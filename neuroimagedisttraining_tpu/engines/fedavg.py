@@ -163,13 +163,14 @@ class FedAvgEngine(FederatedEngine):
         cover the MESH-PADDED sampled set and the builder shards the
         local-training stage over the client mesh (``n_real`` static —
         fault-schedule cohort shrinkage re-specializes via the plan
-        cache)."""
+        cache). ``deal``: the order the padded set arrives in
+        (``_cohort_round_prog`` binds it; None is the sampler's)."""
         prog = self.program.round_jit(n_real=n_real)
 
         def sharded_round_call(params, bstats, data, sampled_idx, rngs,
-                               lr, efs=None, byz=None):
+                               lr, efs=None, byz=None, deal=None):
             return prog((params, bstats), data, (), sampled_idx, rngs,
-                        lr, efs, byz)
+                        lr, efs, byz, None, deal)
 
         return sharded_round_call
 

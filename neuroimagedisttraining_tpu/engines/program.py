@@ -345,6 +345,9 @@ class WindowInputs:
     n_real: int | None
     static_key: Any = None
     per_round: dict | None = None
+    #: cohort sharding: each round's deal of its mesh-padded set
+    #: (``[K, P]``; ``idx`` and ``rngs`` are in dealt order)
+    deal: jax.Array | None = None
 
 
 class RoundCtx:
@@ -387,9 +390,13 @@ class RoundCtx:
         params. In the FOLDED placement the operands are a one-client
         stack and ``fn`` is applied to that client unbatched (no
         ``vmap``: a grouped matmul under a client axis is what the
-        placement exists to avoid)."""
+        placement exists to avoid). Sharded or folded, a row runs alone,
+        and the trainer is told so for the duration of the trace
+        (``LocalTrainer.rows_alone``: the row stops at its own last
+        step; here for the fold, in ``_cohort_map`` for the mesh)."""
         if self.folded:
-            out = fn(*jax.tree.map(lambda x: x[0], stacked))
+            with self.eng.trainer.rows_alone():
+                out = fn(*jax.tree.map(lambda x: x[0], stacked))
             return jax.tree.map(lambda x: x[None], out)
         if self.sharded:
             extra = tuple(h() for h in hoisted)
@@ -972,9 +979,11 @@ class RoundProgram:
         device inputs for the scan — including the [K, C]-stacked
         Byzantine attack plan when the fault schedule carries value
         faults. With cohort sharding armed, ``idx`` and ``rngs`` cover
-        the mesh-padded per-round sets ([K, P]) while the byz plan stays
-        on the REAL sampled sets (the sharded round body slices pad rows
-        off before the attack/defense tail); ``n_real`` is the static
+        the mesh-padded per-round sets ([K, P]), each in its own round's
+        dealt order with ``deal`` beside them (``_cohort_deal``), while
+        the byz plan stays on the REAL sampled sets in the sampler's
+        order (the sharded round body puts the rows back and drops the
+        pad rows before the attack/defense tail); ``n_real`` is the static
         real cohort size (None when unsharded). Engines with
         ``window_extras`` (per-round operands, no cohort sampling) build
         their own."""
@@ -985,9 +994,13 @@ class RoundProgram:
         for off, s in enumerate(sampled):
             eng.log.info("################ round %d: clients %s (fused "
                          "window of %d)", round_idx + off, s.tolist(), k)
+        deal = None
         if eng._cohort_on:
-            ids = [eng._cohort_pad(s)[0] for s in sampled]
             n_real = len(sampled[0])
+            ids = [eng._cohort_pad(s)[0] for s in sampled]
+            deals = [eng._cohort_deal(i, n_real)[0] for i in ids]
+            ids = [i[d] for i, d in zip(ids, deals)]
+            deal = jnp.asarray(np.stack(deals))
         else:
             ids, n_real = sampled, None
         idx = jnp.asarray(np.stack(ids))
@@ -1002,7 +1015,7 @@ class RoundProgram:
             byz = tuple(jnp.stack([p[i] for p in plans])
                         for i in range(4))
         return WindowInputs(sampled=sampled, idx=idx, rngs=rngs, lrs=lrs,
-                            byz=byz, k=k, n_real=n_real)
+                            byz=byz, k=k, n_real=n_real, deal=deal)
 
     def stream_window_inputs(self, round_idx: int, k: int):
         """Host prologue of a fused STREAMED window (ISSUE 10): the
@@ -1049,12 +1062,16 @@ class RoundProgram:
 
     def _body(self, carry_vals: tuple, data, const_vals: tuple, Xs, ys,
               ns, idx, rngs, lr, efs, byz, per_round_vals, static_key,
-              n_real, sharded: bool):
+              n_real, sharded: bool, deal=None):
         """One round: the declared stages in builder order, each under
         its device scope (obs/names.py SCOPE_*: compile-time metadata a
         profiler trace reads back; the one place every engine built on
-        the builder gets them). Returns ``(new_carry: dict, outs: dict,
-        efs_tail: tuple)``."""
+        the builder gets them). ``deal`` (cohort sharding): the rows
+        arrive dealt to the chips by step count, ``deal[k]`` the place
+        of row ``k`` in the sampler's padded set; the train stage runs
+        on them as dealt and everything after it sees the sampler's
+        order. Returns ``(new_carry: dict, outs: dict, efs_tail:
+        tuple)``."""
         scope = jax.named_scope
         eng, st = self.eng, self.stages
         carry = dict(zip(st.carry, carry_vals))
@@ -1066,28 +1083,33 @@ class RoundProgram:
                 static_key, byz)
             return new_carry, outs, ()
         if n_real is not None:
-            ns = cohort.pad_row_weights(ns, n_real)
+            ns = cohort.pad_row_weights(ns, n_real, deal)
         ctx = RoundCtx(eng, st, carry, data, consts, Xs, ys, ns, idx,
                        rngs, lr, per_round, static_key, n_real, sharded)
         with scope(obs_names.SCOPE_LOCAL_TRAIN):
             tr = st.train(ctx)
         S = int(tr.losses.shape[0])
-        if n_real is not None and n_real < S:
-            # static slice: drop the mesh-pad rows before the
-            # attack/codec/defense/aggregate/update tail — it executes
-            # the identical operations the sequential C-loop executes
+        if deal is not None or (n_real is not None and n_real < S):
+            # the real rows, in the sampler's order, before the
+            # attack/codec/defense/aggregate/update tail: a static slice
+            # drops the mesh-pad rows, or, where the rows were dealt, one
+            # take over model-sized leaves undoes the deal and drops
+            # them. The tail executes the identical operations on the
+            # identical rows the sequential C-loop executes
             # (parallel/cohort.py contract)
-            sl = lambda t: jax.tree.map(lambda x: x[:n_real], t)
+            real = (slice(n_real) if deal is None
+                    else jnp.argsort(deal)[:n_real])
+            sl = lambda t: jax.tree.map(lambda x: x[real], t)
             tr = TrainOut(losses=sl(tr.losses),
                           upload=sl(tr.upload) if tr.upload is not None
                           else None,
                           state=sl(tr.state) if tr.state is not None
                           else None,
                           extra=sl(tr.extra))
-            ns = ns[:n_real]
+            ns = ns[real]
             ctx.ns = ns
             if idx is not None:
-                ctx.sampled_idx = idx[:n_real]
+                ctx.sampled_idx = idx[real]
         w = ns.astype(jnp.float32)
         upload = tr.upload
         new_efs = u0 = None
@@ -1313,7 +1335,8 @@ class RoundProgram:
             # TraceAnnotation, so this exact program invocation is the
             # shared ruler between the host and XLA timelines. The
             # driver's counts for this dispatch (round, samples_real,
-            # steps_real, steps_run: base._note_round_counts) ride on it
+            # steps_real, steps_run, steps_skipped, chip_steps_max,
+            # chip_steps_mean: base._note_round_counts) ride on it
             counts = eng._dispatch_counts
             if counts:
                 eng._dispatch_counts = {}
@@ -1344,10 +1367,12 @@ class RoundProgram:
                   sharded: bool | None = None):
         """The single-round program:
         ``f(carry, data, consts, idx, rngs, lr, efs=None, byz=None,
-        per_round=None)``. ``carry`` (argnum 0) and ``efs`` (argnum 6)
-        are donated; ``n_real`` marks the cohort-sharded variant over the
-        mesh-padded sampled set (static — fault-schedule cohort
-        shrinkage re-specializes via the plan cache)."""
+        per_round=None, deal=None)``. ``carry`` (argnum 0) and ``efs``
+        (argnum 6) are donated; ``n_real`` marks the cohort-sharded
+        variant over the mesh-padded sampled set (static —
+        fault-schedule cohort shrinkage re-specializes via the plan
+        cache), whose ``idx`` and ``rngs`` are in the order of that
+        round's ``deal`` (an operand: another deal is no recompile)."""
         shard = sharded if sharded is not None else (n_real is not None)
         key = ("round", n_real, static_key, shard)
         label = "round_sharded" if shard else "round"
@@ -1356,14 +1381,14 @@ class RoundProgram:
             self._note_build(label, key)
 
             def round_fn(carry, data, consts, idx, rngs, lr, efs=None,
-                         byz=None, per_round=None):
+                         byz=None, per_round=None, deal=None):
                 if self.stages.gathers_cohort:
                     Xs, ys, ns = self._gather(data, idx)
                 else:
                     Xs, ys, ns = data.X_train, data.y_train, data.n_train
                 new_carry, outs, efs_tail = self._body(
                     carry, data, consts, Xs, ys, ns, idx, rngs, lr, efs,
-                    byz, per_round, static_key, n_real, shard)
+                    byz, per_round, static_key, n_real, shard, deal)
                 epi = self._epilogue(new_carry, data)
                 return self._flat(new_carry, epi, outs, efs_tail)
 
@@ -1392,7 +1417,7 @@ class RoundProgram:
             self._note_build(label, key)
 
             def fused_round_fn(carry, data, consts, idx, rngs, lrs,
-                               byz=None, per_round=None):
+                               byz=None, per_round=None, deal=None):
                 def one_round(c, xs):
                     if self.stages.gathers_cohort:
                         Xs, ys, ns = self._gather(data, xs["idx"])
@@ -1405,7 +1430,7 @@ class RoundProgram:
                     new_carry, outs, _ = self._body(
                         c, data, consts, Xs, ys, ns, xs.get("idx"),
                         xs["rngs"], xs["lr"], None, xs.get("byz"), pr,
-                        static_key, n_real, shard)
+                        static_key, n_real, shard, xs.get("deal"))
                     return (tuple(new_carry[n]
                                   for n in self.stages.carry),
                             tuple(outs[o] for o in self.stages.outputs
@@ -1416,6 +1441,8 @@ class RoundProgram:
                     xs["byz"] = byz
                 if per_round is not None:
                     xs["pr"] = per_round
+                if deal is not None:
+                    xs["deal"] = deal
                 carry, outs = jax.lax.scan(one_round, tuple(carry), xs)
                 epi = self._epilogue(dict(zip(self.stages.carry, carry)),
                                      data)
@@ -1544,7 +1571,7 @@ class RoundProgram:
                 out = self.fused_jit(wi.k, wi.n_real, wi.static_key,
                                      sharded=shard)(
                     carry, eng.data, consts, wi.idx, wi.rngs, wi.lrs,
-                    wi.byz, pr)
+                    wi.byz, pr, wi.deal)
         n_carry = len(st.carry)
         n_epi = len(out) - n_carry - len(st.outputs)
         new_carry = out[:n_carry]

@@ -286,9 +286,10 @@ class SalientGradsEngine(FederatedEngine):
 
         def sharded_round_call(params, bstats, per_params, per_bstats,
                                data, masks, sampled_idx, rngs, lr,
-                               byz=None):
+                               byz=None, deal=None):
             return prog((params, bstats, per_params, per_bstats), data,
-                        (masks,), sampled_idx, rngs, lr, None, byz)
+                        (masks,), sampled_idx, rngs, lr, None, byz,
+                        None, deal)
 
         return sharded_round_call
 

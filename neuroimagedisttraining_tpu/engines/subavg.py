@@ -226,9 +226,9 @@ class SubFedAvgEngine(FederatedEngine):
         prog = self.program.round_jit(n_real=n_real)
 
         def sharded_round_call(params, bstats, mask_pers, data,
-                               sampled_idx, rngs, lr):
+                               sampled_idx, rngs, lr, deal=None):
             return prog((params, bstats, mask_pers), data, (),
-                        sampled_idx, rngs, lr)
+                        sampled_idx, rngs, lr, None, None, None, deal)
 
         return sharded_round_call
 

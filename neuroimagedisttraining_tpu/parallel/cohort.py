@@ -17,6 +17,13 @@ SysML-2018 compile-once/dispatch-once premise in PAPERS.md):
   (21 sites on 8 devices -> 24 rows) with zero-weight pad rows, and
   :func:`pad_row_weights` is THE one place pad-row weights are zeroed
   (nidtlint's ``mesh-pad-weights`` rule rejects ad-hoc reconstructions).
+- :func:`deal_rows` orders the padded set so that the contiguous blocks
+  ``shard_map`` cuts are balanced in SGD steps (PR 26): a row trained
+  here runs alone, so it stops at its own last step
+  (``LocalTrainer.rows_alone``; the engine's ``_cohort_map`` enters it
+  around both the mesh loop and the sequential reference), a chip's share
+  of a round is the sum of its rows' real steps, and the round ends when
+  the busiest chip reaches the all-gather.
 
 Numerical contract (tests/test_cohort.py), stated with the precision
 the measurements force:
@@ -87,11 +94,31 @@ deployment's one-site-per-core layout.
 Pad-row semantics: pad ids prefer the federation's zero-sample padding
 clients (rows ``[real_clients, num_clients)`` — ``n_train == 0``), then
 repeat the last sampled id; either way :func:`pad_row_weights` zeroes
-their sample counts before local training, so pads train as zero-weight
-no-ops, and the engine round bodies STATICALLY SLICE the pad rows off
-after the gather — the aggregation/defense tail never sees them (the
-robust aggregators additionally ignore zero-weight rows, so even an
-unsliced consumer is safe).
+their sample counts before local training, so a pad row runs NO step at
+all (its loop bound is zero) and weighs nothing, and the engine round
+bodies drop the pad rows after the gather — the aggregation/defense tail
+never sees them (the robust aggregators additionally ignore zero-weight
+rows, so even an unsliced consumer is safe).
+
+The dealt order, and where it is undone: the host computes the deal per
+round from integers it already holds (``FederatedEngine._cohort_deal``:
+``ceil(n / batch)`` of the padded ids, pads at zero) and hands the round
+program the ids in dealt order (so ``X_train[idx]`` is gathered as
+dealt, the rngs, folded from client ids, follow, and the hoisted
+permutations are derived from both) together with the deal itself, an
+operand and never a constant: another sampled set, or another round of a
+fused window, is another index array and no recompile. After the
+all-gather, one ``take`` over model-sized leaves
+(``RoundProgram._body``: ``argsort(deal)[:n_real]``) puts the trained
+stacks back in the sampler's order and drops the pad rows, so the
+attack / codec / defense / aggregate / update tail sees the rows it
+always saw in the order it saw them and the weighted sum is the same sum:
+a dealt round equals the undealt one bitwise (tests/test_rows_alone.py).
+Phase 1 of SalientGrads and the final fine-tune pass
+(``cohort_local_stage``) go through :func:`cohort_map` in the data's own
+order; the streamed sharded feed keeps the sampler's order too (cohort
+sharding does not arm under streaming: its rows are batched, and batched
+rows all walk the longest row's steps wherever they sit).
 """
 
 from __future__ import annotations
@@ -132,16 +159,45 @@ def pad_cohort(sampled: np.ndarray, real_clients: int, num_clients: int,
         len(sampled)
 
 
-def pad_row_weights(ns: jax.Array, n_real: int) -> jax.Array:
-    """Zero the per-client sample counts of mesh-pad rows (index >=
-    ``n_real``). THE shared helper for pad-row zero-weight construction:
-    a pad entry may DUPLICATE a real client id (``pad_cohort`` repeats
-    the last sampled id once the zero-sample pool runs dry), so gathering
-    ``n_train`` rows is not enough — the position mask is what guarantees
-    pads train as zero-weight no-ops. nidtlint's ``mesh-pad-weights``
-    rule keeps every call site on this function."""
-    return jnp.where(jnp.arange(ns.shape[0]) < n_real, ns,
-                     jnp.zeros_like(ns))
+def pad_row_weights(ns: jax.Array, n_real: int,
+                    deal: jax.Array | None = None) -> jax.Array:
+    """Zero the per-client sample counts of mesh-pad rows (position >=
+    ``n_real`` of the padded set; ``deal`` gives each row's position
+    there when the rows arrive dealt, :func:`deal_rows`). THE shared
+    helper for pad-row zero-weight construction: a pad entry may
+    DUPLICATE a real client id (``pad_cohort`` repeats the last sampled
+    id once the zero-sample pool runs dry), so gathering ``n_train`` rows
+    is not enough — the position mask is what guarantees a pad row runs
+    no step and weighs nothing. nidtlint's ``mesh-pad-weights`` rule
+    keeps every call site on this function."""
+    pos = jnp.arange(ns.shape[0]) if deal is None else deal
+    return jnp.where(pos < n_real, ns, jnp.zeros_like(ns))
+
+
+def deal_rows(steps: np.ndarray, n_devices: int) -> np.ndarray:
+    """Deal the rows of a mesh-tiling set to the chips by their step
+    counts: ``order`` with ``order[k]`` the row (position in ``steps``)
+    that goes to dealt position ``k``, so that the contiguous blocks of
+    ``len(steps) / n_devices`` rows ``shard_map`` cuts carry balanced
+    sums. Longest first, each next row to the chip with the smallest sum
+    that still has a free slot. A chip's share of a round is the sum of
+    its rows' real steps once a row stops at its own last step
+    (``LocalTrainer.rows_alone``), and the round ends when the busiest
+    chip does. The sampler's order is kept (the identity) where dealing
+    makes the busiest chip no lighter: equal sites, one row a chip."""
+    steps = np.asarray(steps)
+    rows = len(steps) // n_devices
+    sums = np.zeros(n_devices, np.int64)
+    dealt: list[list[int]] = [[] for _ in range(n_devices)]
+    for r in np.argsort(-steps, kind="stable"):
+        d = min((d for d in range(n_devices) if len(dealt[d]) < rows),
+                key=lambda d: (sums[d], d))
+        dealt[d].append(int(r))
+        sums[d] += int(steps[r])
+    as_sampled = steps.reshape(n_devices, rows).sum(axis=1).max()
+    if sums.max() >= as_sampled:
+        return np.arange(len(steps))
+    return np.asarray([r for d in dealt for r in sorted(d)])
 
 
 def sequential_map(fn, *stacked: PyTree) -> PyTree:

@@ -173,7 +173,10 @@ def test_cohort_map_rejects_non_tiling_and_two_level():
 def _one_sharded_round(eng, round_idx=0, efs=None, masks=None):
     gs = eng.init_global_state()
     sampled = eng.client_sampling(round_idx)
-    ids, n_real = eng._cohort_pad(sampled)
+    # the program train() dispatches: the mesh-padded set dealt to the
+    # chips by step count, the deal bound as an operand
+    ids, round_prog = eng._cohort_round_prog(sampled)
+    n_real = len(sampled)
     rngs = eng.per_client_rngs(round_idx, ids)
     byz = eng._byz_round_plan(round_idx, sampled)
     lr = eng.round_lr(round_idx)
@@ -182,7 +185,7 @@ def _one_sharded_round(eng, round_idx=0, efs=None, masks=None):
             masks, _ = eng.generate_global_mask(gs.params,
                                                 gs.batch_stats)
         per = eng.broadcast_states(gs, eng.num_clients)
-        out = eng._sharded_round_jit(n_real)(
+        out = round_prog(
             gs.params, gs.batch_stats, per.params, per.batch_stats,
             eng.data, masks, jnp.asarray(ids), rngs, lr, byz)
         return out
@@ -190,7 +193,7 @@ def _one_sharded_round(eng, round_idx=0, efs=None, masks=None):
         efs = jax.tree.map(
             lambda x: jnp.zeros((n_real,) + x.shape, jnp.float32),
             {"params": gs.params, "batch_stats": gs.batch_stats})
-    out = eng._sharded_round_jit(n_real)(
+    out = round_prog(
         gs.params, gs.batch_stats, eng.data, jnp.asarray(ids), rngs, lr,
         efs, byz)
     return out
@@ -345,8 +348,8 @@ def test_sharded_fused_k4_window_bitwise(tmp_path, cohort21):
     losses = []
     for r in range(4):
         sampled = eng.client_sampling(r)
-        ids, n_real = eng._cohort_pad(sampled)
-        p, b, loss, _ = eng._sharded_round_jit(n_real)(
+        ids, round_prog = eng._cohort_round_prog(sampled)
+        p, b, loss, _ = round_prog(
             p, b, eng.data, jnp.asarray(ids),
             eng.per_client_rngs(r, ids), eng.round_lr(r))
         losses.append(float(loss))

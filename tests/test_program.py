@@ -253,10 +253,9 @@ def _one_sharded_round(eng, r=0):
                                         jnp.asarray(M_np), rngs, lr,
                                         plan_arrays)
     sampled = eng.client_sampling(r)
-    ids, n_real = eng._cohort_pad(sampled)
+    ids, round_prog = eng._cohort_round_prog(sampled)  # dealt, as train()
     rngs = eng.per_client_rngs(r, ids)
-    return eng._sharded_round_jit(n_real)(*carry, eng.data,
-                                          jnp.asarray(ids), rngs, lr)
+    return round_prog(*carry, eng.data, jnp.asarray(ids), rngs, lr)
 
 
 @pytest.mark.parametrize("algorithm,loss_i,epochs", [

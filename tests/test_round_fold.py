@@ -207,7 +207,8 @@ def test_train_end_to_end_matches_stacked(tmp_path, cohort3):
     vmapped), the final fine-tune pass (fine-tuned, evaluated and
     discarded one at a time) give the stacked run's metrics; the folded
     run returns no stack of personalized states; the dispatch span
-    carries the placement and the counts keep their meaning."""
+    carries the placement, and ``steps_run`` what that placement
+    executes."""
     obs_trace.arm()
     try:
         st = _engine(tmp_path, cohort3, budget=1 << 40, tag="s").train()
@@ -233,4 +234,11 @@ def test_train_end_to_end_matches_stacked(tmp_path, cohort3):
     assert n0 > 0
     assert dispatch[0]["samples_real"] == dispatch[2]["samples_real"]
     assert dispatch[0]["steps_real"] == dispatch[2]["steps_real"]
-    assert dispatch[0]["steps_run"] == dispatch[2]["steps_run"]
+    # stacked, every row walks the loop's whole length; folded, a row
+    # runs alone and executes its own real steps and no other
+    st0, fo0 = dispatch[0], dispatch[2]
+    assert st0["steps_skipped"] == 0 and st0["steps_run"] > st0["steps_real"]
+    assert fo0["steps_run"] == fo0["steps_real"]
+    assert fo0["steps_run"] + fo0["steps_skipped"] == st0["steps_run"]
+    for a in (st0, fo0):
+        assert a["chip_steps_max"] == a["chip_steps_mean"] == a["steps_run"]
