@@ -48,15 +48,9 @@ GFLOP/sample x V100 fp32 roofline x assumed Conv3d MFU range);
 Prints exactly one JSON line:
   {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...extras}
 
-Fused-dispatch cell (ISSUE 4): ``rounds_per_dispatch`` times K
-sequential single-round dispatches against ONE K-round ``lax.scan``
-program (the ``--rounds_per_dispatch`` driver mode; bitwise equality of
-the two is pinned in tests/test_dispatch.py) and reports the speedup —
-the dispatch-amortization win PROFILE.md round 2 measured at 2.4x.
-
 Env knobs: BENCH_BATCH (default 128), BENCH_CLIENTS (1), BENCH_LOCAL
 (512), BENCH_ROUNDS (3), BENCH_REPS (3 — best-of-N timed repeats),
-BENCH_DISPATCH_K (4; <= 1 skips the fused-dispatch cell), BENCH_SHAPE /
+BENCH_SHAPE /
 BENCH_MODEL (CPU smoke runs of the harness itself). The persistent
 compile cache follows utils/compile_cache.py's one rule
 (JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache).
@@ -93,25 +87,21 @@ def cohort_sharding_cell(n_devices: int) -> dict:
     the sequential C-loop (the reference's client-at-a-time simulation as
     ONE ``lax.map`` program), the cohort-SHARDED program
     (parallel/cohort.py), and the shipped vmapped unsharded round —
-    plus the flagship 21-site fedavg + salientgrads cells, the K=4
-    fused-window compile-count pin (one compiled program, one dispatch
-    per window), and ``salientgrads_mask_ms`` under the sharded phase-1
+    plus the flagship 21-site fedavg + salientgrads cells and
+    ``salientgrads_mask_ms`` under the sharded phase-1
     driver (PROFILE.md round 7 / ROADMAP item 4 reconciliation).
 
     Env: BENCH_COHORT_DEVICES=D arms this cell (main() then prints ONLY
     it); BENCH_COHORT_VIRTUAL=1 provisions D virtual CPU devices first
     (the committed bench_matrix/cohort_sharding.json artifact runs this
-    way on the 2-core harness — treat the SLOPES and the one-dispatch
-    pin as the stable claims there; the absolute speedup is a
-    TPU-session measurement). BENCH_COHORT_CLIENTS overrides the C
-    sweep."""
+    way on the 2-core harness — treat the SLOPES as the stable claim
+    there; the absolute speedup is a TPU-session measurement).
+    BENCH_COHORT_CLIENTS overrides the C sweep."""
     if os.environ.get("BENCH_COHORT_VIRTUAL", "0") == "1":
         from neuroimagedisttraining_tpu.parallel.mesh import (
             provision_virtual_devices,
         )
         provision_virtual_devices(n_devices)
-
-    import dataclasses
 
     import jax
     import jax.numpy as jnp
@@ -256,17 +246,6 @@ def cohort_sharding_cell(n_devices: int) -> dict:
         gs.params, gs.batch_stats, per.params, per.batch_stats,
         sg_sh.data, masks, jnp.asarray(ids), rngs, sg_sh.round_lr(0)))
 
-    # K=4 fused window: ONE compiled program, ONE dispatch per window
-    fz = engine_for(21, "sharded")
-    fz.cfg = dataclasses.replace(
-        fz.cfg, fed=dataclasses.replace(fz.cfg.fed, comm_round=4,
-                                        rounds_per_dispatch=4))
-    gsf = fz.init_global_state()
-    w_s = bestof(lambda: fz._run_fused_window(
-        jax.tree.map(jnp.copy, gsf.params),
-        jax.tree.map(jnp.copy, gsf.batch_stats), 0, 4)[2])
-    fused_cache = len(fz.__dict__.get("_fused_round_jit_cache", {}))
-
     return {
         "metric": "cohort_sharding",
         "devices": D,
@@ -280,17 +259,11 @@ def cohort_sharding_cell(n_devices: int) -> dict:
             "sharded_round_s": round(sg_round_s, 4),
             "mask_ms": t_mask,
         },
-        "fused_k4_window": {
-            "window_s": round(w_s, 4),
-            "per_round_s": round(w_s / 4, 4),
-            "compiled_programs": fused_cache,
-            "dispatches_per_window": 1,
-        },
         "timing": f"best of {reps} repeats",
         "caveat": ("virtual-CPU-mesh numbers when BENCH_COHORT_VIRTUAL=1 "
-                   "(2-core harness): the slope ratio and the one-"
-                   "dispatch pin are the stable claims; the absolute "
-                   "sharded speedup is a TPU-session measurement"),
+                   "(2-core harness): the slope ratio is the stable "
+                   "claim; the absolute sharded speedup is a "
+                   "TPU-session measurement"),
     }
 
 
@@ -582,16 +555,12 @@ def precision_cell() -> dict:
 
 def round_program_cell() -> dict:
     """Round-program builder bench cell (ISSUE 11): per-engine dispatch
-    counts and per-round wall, fused (K=4 windows through
-    engines/program.py) vs the K=1 per-round loop — including the
-    engines the builder put on the fused path for the FIRST time (ditto,
-    dpsgd, subavg) and a fallback reference (fedfomo: per-dispatch count
-    unchanged, the logged + counted reason fires). The dispatch counts
-    are exact (program.dispatches / program.built); on this CPU harness
-    the WALL delta is dominated by host Python + dispatch overhead
-    (per-dispatch latency on the current chip: not measured), so treat
-    counts and the one-compiled-program-per-window pin as the
-    stable claims and the wall ratio as harness-local.
+    and compile counts and per-round wall of the round loop through
+    engines/program.py (fedavg, ditto, dpsgd, subavg) beside an engine
+    that drives its own per-round jits (fedfomo). The counts are exact
+    (program.dispatches / program.built: one dispatch a round, one
+    compiled program a run) and are the stable claim; the wall numbers
+    are this CPU harness's, compile included.
 
     Env: BENCH_ROUND_PROGRAM=1 arms this cell (main() prints ONLY it);
     BENCH_RP_ROUNDS (default 8), BENCH_RP_ENGINES, BENCH_BATCH /
@@ -625,7 +594,7 @@ def round_program_cell() -> dict:
     cohort = generate_synthetic_abcd(
         num_subjects=4 * n_local, shape=shape, num_sites=4, seed=0)
 
-    def run(algorithm: str, K: int):
+    def run(algorithm: str):
         cfg = ExperimentConfig(
             model=model_name, num_classes=1, algorithm=algorithm,
             data=DataConfig(dataset="synthetic", partition_method="site",
@@ -633,9 +602,8 @@ def round_program_cell() -> dict:
                             else 0.0),
             optim=OptimConfig(lr=1e-3, batch_size=batch, epochs=1),
             fed=FedConfig(client_num_in_total=4, comm_round=rounds,
-                          frequency_of_the_test=10 ** 9,
-                          rounds_per_dispatch=K),
-            log_dir="/tmp/nidt_bench", tag=f"rp-{algorithm}-{K}")
+                          frequency_of_the_test=10 ** 9),
+            log_dir="/tmp/nidt_bench", tag=f"rp-{algorithm}")
         mesh = make_mesh()
         trainer = LocalTrainer(create_model(model_name, num_classes=1),
                                cfg.optim, num_classes=1)
@@ -660,22 +628,9 @@ def round_program_cell() -> dict:
                            else rounds),
             "programs_built": (prog.built if prog.stages is not None
                                else None),
-            "fused": eng.fused_fallback_reason() is None,
-            "fallback_reason": eng.fused_fallback_key(),
         }
 
-    engines = {}
-    for algorithm in names:
-        k1 = run(algorithm, 1)
-        k4 = run(algorithm, 4)
-        engines[algorithm] = {
-            "k1": k1, "k4": k4,
-            "dispatch_reduction": (
-                round(k1["dispatches"] / k4["dispatches"], 2)
-                if k4["dispatches"] else None),
-            "wall_ratio_k1_over_k4": round(
-                k1["wall_s"] / max(k4["wall_s"], 1e-9), 3),
-        }
+    engines = {algorithm: run(algorithm) for algorithm in names}
     return {
         "metric": "round_program",
         "model": model_name, "shape": "x".join(map(str, shape)),
@@ -685,17 +640,11 @@ def round_program_cell() -> dict:
         "engines": engines,
         "notes": ("dispatches counts compiled-program invocations "
                   "(engines/program.py RoundProgram.dispatches; train "
-                  "rounds only — eval/fine-tune jits are separate). "
-                  "K=4 windows collapse ~rounds dispatches toward "
-                  "rounds/4 + boundary singles for every engine whose "
-                  "stages are declared; fedfomo stays per-round with "
-                  "the counted fallback reason. CPU-harness wall "
-                  "numbers INCLUDE compile (the K=4 leg compiles one "
-                  "program per distinct window length, so it reads "
-                  "SLOWER here); the dispatch counts are the stable "
-                  "claim — the amortized wall win is per-dispatch "
-                  "latency x dispatches saved (not measured on the "
-                  "current chip)."),
+                  "rounds only — eval/fine-tune jits are separate): "
+                  "one a round, one compiled program a run, for every "
+                  "engine whose stages are declared. CPU-harness wall "
+                  "numbers INCLUDE compile; the counts are the stable "
+                  "claim."),
     }
 
 
@@ -832,68 +781,12 @@ def main() -> None:
     peak = _chip_peak_tflops()
     mfu = (sustained / (peak * 1e12)) if peak else None
 
-    # ---- fused multi-round dispatch cell (ISSUE 4) ----
-    # K single-round dispatches (the shipped K=1 loop) vs ONE K-round
-    # lax.scan program (--rounds_per_dispatch K), same host-precomputed
-    # sampling/rng/lr per round — the bitwise-equality of the two is
-    # pinned in tests/test_dispatch.py; this cell measures the dispatch
-    # amortization. Donation is live on both paths, so every timed rep
-    # consumes fresh copies of the starting state (the copy is µs against
-    # a multi-second round).
-    K_disp = int(os.environ.get("BENCH_DISPATCH_K", 4))
-    dispatch_cell = None
-    if K_disp > 1:
-        copy_tree = lambda t: jax.tree.map(jnp.copy, t)
-        samp_list = [engine.client_sampling(r) for r in range(K_disp)]
-        rngs_list = [engine.per_client_rngs(r, s)
-                     for r, s in enumerate(samp_list)]
-        lrs_list = [engine.round_lr(r) for r in range(K_disp)]
-        k_samples = K_disp * n_clients * epochs * steps * batch
-
-        def seq_chain(p, b):
-            for r in range(K_disp):
-                p, b, l, _ = engine._round_jit(
-                    p, b, fed, jnp.asarray(samp_list[r]), rngs_list[r],
-                    lrs_list[r])
-            return float(l)
-
-        seq_chain(copy_tree(params), copy_tree(bstats))  # warm
-        seq_best = float("inf")
-        for _ in range(reps):
-            p, b = copy_tree(params), copy_tree(bstats)
-            t0 = time.perf_counter()
-            seq_chain(p, b)
-            seq_best = min(seq_best, time.perf_counter() - t0)
-
-        fused = engine._fused_round_jit(K_disp)
-        samp_k = jnp.asarray(np.stack(samp_list))
-        rngs_k = jnp.stack(rngs_list)
-        lrs_k = jnp.asarray(lrs_list, jnp.float32)
-
-        def fused_chain(p, b):
-            p, b, losses, _ = fused(p, b, fed, samp_k, rngs_k, lrs_k)
-            return float(losses[-1])
-
-        fused_chain(copy_tree(params), copy_tree(bstats))  # compile+warm
-        fused_best = float("inf")
-        for _ in range(reps):
-            p, b = copy_tree(params), copy_tree(bstats)
-            t0 = time.perf_counter()
-            fused_chain(p, b)
-            fused_best = min(fused_best, time.perf_counter() - t0)
-        dispatch_cell = {
-            "k": K_disp,
-            "sequential_samples_per_sec": round(k_samples / seq_best, 2),
-            "fused_samples_per_sec": round(k_samples / fused_best, 2),
-            "speedup_x": round(seq_best / fused_best, 3),
-        }
-
     # ---- phase 2: SalientGrads mask pipeline + Pallas/XLA agreement ----
     # (phase-2/3 engines replay the SAME {params, bstats, per-client}
     # buffers through their round programs across timed repeats, so
     # donation is disabled on them — it affects memory residency, not
     # the round math being timed; the donated path is what the phase-1
-    # loop above and the dispatch cell measure)
+    # loop above measures)
     sg = create_engine("salientgrads", cfg, fed, trainer, logger=log)
     sg._donate = False
 
@@ -1161,7 +1054,6 @@ def main() -> None:
         "peak_tflops_assumed": peak,
         "mfu": round(mfu, 4) if mfu is not None else None,
         "salientgrads_mask_ms": round(mask_ms, 1),
-        "rounds_per_dispatch": dispatch_cell,
         "algo_round_s": {k: round(v, 3) for k, v in algo_round_s.items()}
         or None,
         "algo_round_samples_per_sec": {

@@ -232,9 +232,7 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                              "order-statistic family (core/robust.py, "
                              "ISSUE 5) replaces the mean and tolerates "
                              "up to --byz_f Byzantine clients. Runs "
-                             "inside the jitted round body, so fused "
-                             "--rounds_per_dispatch windows stay bitwise-"
-                             "equal to the sequential loop")
+                             "inside the jitted round body")
     parser.add_argument("--norm_bound", type=float, default=5.0)
     parser.add_argument("--stddev", type=float, default=0.05)
     parser.add_argument("--byz_f", type=int, default=1,
@@ -317,7 +315,7 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
     # observability plane (obs/, ISSUE 9)
     parser.add_argument("--trace_out", type=str, default="",
                         help="write the run's host-span timeline "
-                             "(round/window/eval spans at dispatch "
+                             "(round/eval spans at dispatch "
                              "boundaries) as Chrome trace-event JSON, "
                              "Perfetto-loadable (obs/trace.py); with "
                              "--profile_dir each span also opens a "
@@ -440,19 +438,6 @@ def add_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                              "(nidt_fallback_total on /metrics). "
                              "Combine with --virtual_devices N to "
                              "simulate without TPU hardware")
-    parser.add_argument("--rounds_per_dispatch", type=int, default=1,
-                        help="fuse up to K rounds into ONE lax.scan "
-                             "dispatch when the federation is resident "
-                             "and host-free between rounds (sampling/rng/"
-                             "lr precomputed per round; eval/checkpoint "
-                             "hooks fire at window boundaries). The "
-                             "round-program builder (engines/program.py) "
-                             "compiles the window for every engine with "
-                             "declared stages — fedavg/fedprox/"
-                             "salientgrads/ditto/dpsgd/subavg; engines "
-                             "that cross the host each round fall back "
-                             "to 1 with a logged + counted reason "
-                             "(nidt_fallback_total)")
     parser.add_argument("--recipe", type=str, default="",
                         help="apply a committed autotune recipe "
                              "(tune/recipe.py) as config DEFAULTS "
@@ -518,7 +503,6 @@ def config_from_args(args: argparse.Namespace) -> ExperimentConfig:
             defense_type=args.defense_type,
             norm_bound=args.norm_bound, stddev=args.stddev,
             byz_f=args.byz_f, geomed_iters=args.geomed_iters,
-            rounds_per_dispatch=args.rounds_per_dispatch,
             client_mesh=args.client_mesh,
             frequency_of_the_test=args.frequency_of_the_test,
             ci=bool(args.ci)),

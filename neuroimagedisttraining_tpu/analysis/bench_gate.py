@@ -114,11 +114,12 @@ SPECS: dict[str, tuple[Check, ...]] = {
               "clean-run AUC (seeded, should be near-deterministic)"),
     ),
     "round_program.json": (
-        Check("engines.fedavg.dispatch_reduction", "eq",
+        Check("engines.fedavg.dispatches", "eq",
               note="dispatch counts are deterministic compile facts"),
-        Check("engines.ditto.dispatch_reduction", "eq"),
-        Check("engines.dpsgd.dispatch_reduction", "eq"),
-        Check("engines.subavg.dispatch_reduction", "eq"),
+        Check("engines.fedavg.programs_built", "eq"),
+        Check("engines.ditto.dispatches", "eq"),
+        Check("engines.dpsgd.dispatches", "eq"),
+        Check("engines.subavg.dispatches", "eq"),
     ),
     "cohort_sharding.json": (
         Check("slope_s_per_client.sharded_over_sequential", "ratio_max",
@@ -242,9 +243,8 @@ SPECS: dict[str, tuple[Check, ...]] = {
                    "nidt_mfu/nidt_sustained_tflops samples"),
         Check("session.healthz_compute_ok", "true",
               note="/healthz compute block (dispatch liveness)"),
-        Check("probes.fused_dispatch_k4.dispatches", "eq",
+        Check("probes.fp32_baseline.dispatches", "eq",
               note="dispatch counts are deterministic compile facts"),
-        Check("probes.fused_dispatch_k4.compiles", "eq"),
         Check("probes.fp32_baseline.compiles", "eq"),
         Check("probes.fp32_baseline.round_ms", "ratio_max", 2.0,
               "per-round wall tripwire (box drift tolerated)"),

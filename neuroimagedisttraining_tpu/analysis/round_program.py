@@ -1,16 +1,17 @@
 """Round-program-discipline rules: engines declare stages, the builder
-owns the fused machinery (ISSUE 11).
+owns the round machinery (ISSUE 11).
 
-The declarative round-program builder (engines/program.py) exists so the
-fused ``lax.scan`` dispatch, cohort sharding, donation, defenses, and
-codec knobs are written ONCE. Two lexical rules keep it that way:
+The declarative round-program builder (engines/program.py) exists so
+cohort sharding, the folded client loop, donation, defenses, and codec
+knobs are written ONCE. Two lexical rules keep it that way:
 
-- ``round-program-fused-body`` — no engine module may hand-roll a fused
-  round body again: a ``lax.scan`` call lexically inside a
+- ``round-program-fused-body`` — no engine module may hand-roll a
+  multi-round scan: a ``lax.scan`` call lexically inside a
   ``*round*``/``*fused*``-named method of a ``FederatedEngine`` subclass
   (outside engines/program.py itself) is the copy-the-machinery-back
-  regression this rule exists to stop. Engines express K-round windows
-  by declaring :class:`RoundStages`; the builder scans.
+  regression this rule exists to stop. Round programs come from
+  engines/program.py, one round a dispatch; engines declare
+  :class:`RoundStages`.
 - ``round-program-reason`` — fallback reasons come from the single
   source of truth: a ``*_fallback_key`` override must return ``None`` or
   a string literal that is a key of ``engines/program.py``'s ``REASONS``
@@ -46,9 +47,9 @@ _PACKAGED_PROGRAM = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "engines", "program.py")
 
-#: path suffixes allowed to contain scan-fused round bodies / reason
+#: path suffixes allowed to contain round-body scans / reason
 #: literals — suffix-matched, not basename-matched, so a future
-#: pkg/<other>/program.py with a hand-rolled fused body is NOT exempt
+#: pkg/<other>/program.py with a hand-rolled round body is NOT exempt
 _BUILDER_FILES = ("engines/program.py",)
 
 
@@ -58,8 +59,7 @@ def _is_builder_file(path: str) -> bool:
                for b in _BUILDER_FILES)
 
 _SCAN_CALLS = ("jax.lax.scan", "lax.scan")
-_KEY_METHODS = ("fused_fallback_key", "cohort_fallback_key",
-                "fold_refusal_key")
+_KEY_METHODS = ("cohort_fallback_key", "fold_refusal_key")
 
 
 @functools.lru_cache(maxsize=None)
@@ -101,7 +101,7 @@ def _scan_calls_in(fn: ast.AST, aliases: dict) -> Iterator[ast.Call]:
 class RoundProgramRule(Rule):
     rule_ids = ("round-program-fused-body", "round-program-reason")
     description = ("engines declare round stages through the builder "
-                   "(engines/program.py): no hand-rolled lax.scan fused "
+                   "(engines/program.py): no hand-rolled lax.scan "
                    "round bodies in engine classes, and *_fallback_key "
                    "overrides return keys from the REASONS table")
 
@@ -146,11 +146,12 @@ class RoundProgramRule(Rule):
             for call in _scan_calls_in(fn, mod.aliases):
                 yield Finding(
                     mod.path, call.lineno, "round-program-fused-body",
-                    f"{cls.name}.{fn.name} hand-rolls a lax.scan fused "
-                    "round body; engines declare RoundStages and the "
-                    "builder (engines/program.py) owns the K-round scan "
-                    "— hand-rolled copies drift from the "
-                    "donation/sharding/window contracts")
+                    f"{cls.name}.{fn.name} hand-rolls a lax.scan "
+                    "round body; engines declare RoundStages and round "
+                    "programs come from the builder "
+                    "(engines/program.py), one round a dispatch — "
+                    "hand-rolled copies drift from the "
+                    "donation/sharding/fold contracts")
         if fn.name in _KEY_METHODS and keys:
             for node in ast.walk(fn):
                 if isinstance(node, ast.Return) \

@@ -129,7 +129,7 @@ class FedConfig:
     # per side (trimmed_mean), Krum's score neighborhood (needs the
     # sampled cohort n >= f + 3; trimmed_mean/median need 2f < n)
     geomed_iters: int = 8          # fixed Weiszfeld iterations
-    # (geometric_median; trace-static so fused dispatch stays one program)
+    # (geometric_median; trace-static so the round stays one program)
     # TurboAggregate secure aggregation (additive shares over GF(p))
     mpc_n_shares: int = 3          # shares per client update (paper: one
     # per neighbor group)
@@ -202,18 +202,6 @@ class FedConfig:
     max_staleness: int = 20        # admission bound (and codec-ref ring depth)
     heartbeat_interval: float = 0.0  # s; >0 makes silo clients beat liveness
     heartbeat_timeout: float = 0.0   # s; >0 marks silent clients suspect
-    # Fused multi-round dispatch (ISSUE 4): when > 1 and the federation
-    # is resident, non-streaming, and host-free between rounds, the
-    # driver precomputes up to this many rounds of sampling indices /
-    # per-round rngs / lr schedule on the host and runs them as ONE
-    # lax.scan over the engine's round body — eval/checkpoint/logging
-    # hooks fire at window boundaries (the window planner shrinks so
-    # every hook round lands on a boundary, preserving the sequential
-    # loop's observable behavior bitwise). Engines that cross the host
-    # each round (fedfomo pair lists, turboaggregate MPC, mask/topology
-    # evolution, streaming, --wire_codec byte accounting) transparently
-    # fall back to one round per dispatch with a logged reason.
-    rounds_per_dispatch: int = 1
     # Cohort sharding (ISSUE 6, parallel/cohort.py): when > 0, the
     # sampled-client axis of every jitted round program shards over a
     # client mesh of exactly this many devices (one shard_map per round:

@@ -111,7 +111,7 @@ class StreamingFederation:
         self._stats_lock = threading.Lock()
 
     def _note_transfer(self, t0: float, t1: float, t2: float,
-                       nbytes: int, fetches: int = 1) -> None:
+                       nbytes: int) -> None:
         """Accumulate one work unit's stage timings (gather ``t0..t1``,
         device_put ``t1..t2``, on ``time.perf_counter``) into
         ``transfer_stats`` and mirror the totals into the obs registry
@@ -136,28 +136,24 @@ class StreamingFederation:
             st["host_gather_ms"] += gather_s * 1e3
             st["device_put_ms"] += put_s * 1e3
             st["bytes"] += float(nbytes)
-            st["fetches"] += fetches
+            st["fetches"] += 1
             # publish INSIDE the lock: a main-thread fetch racing the
             # reader-thread prefetch must not interleave per-key sets
             # from two snapshots (the dict==gauge parity pin)
             for k, v in st.items():
                 g.labels(key=k).set(float(v))
 
-    def _put(self, x: np.ndarray, client_axis: int = 0):
-        """Host -> device; sharded over the CLIENT axis when a mesh is
-        attached (the jitted round program then runs SPMD over the
-        client axis with no resharding). On a two-level mesh the client
-        axis maps over (silos, clients) silo-major. ``client_axis``:
-        where the client axis sits — 0 for round/eval buffers, 1 for
-        window-stacked ``[K, S, ...]`` buffers (the K axis replicates,
-        each scanned round stays client-sharded)."""
+    def _put(self, x: np.ndarray):
+        """Host -> device; sharded over the CLIENT axis (axis 0) when a
+        mesh is attached (the jitted round program then runs SPMD over
+        the client axis with no resharding). On a two-level mesh the
+        client axis maps over (silos, clients) silo-major."""
         if self.mesh is None:
             return jax.device_put(x)
         from jax.sharding import NamedSharding, PartitionSpec
 
-        spec = PartitionSpec(*([None] * client_axis),
-                             tuple(self.mesh.axis_names),
-                             *([None] * (x.ndim - 1 - client_axis)))
+        spec = PartitionSpec(tuple(self.mesh.axis_names),
+                             *([None] * (x.ndim - 1)))
         return jax.device_put(x, NamedSharding(self.mesh, spec))
 
     # ---------- raw fetch (host thread) ----------
@@ -174,11 +170,15 @@ class StreamingFederation:
             return self.val_map, self.nmax_val
         raise ValueError(f"unknown split {split!r}")
 
-    def _fill(self, Xs, ys, ns, client_ids: np.ndarray, split: str,
-              n_real: int | None = None) -> None:
-        """Fill one round's ``[S, nmax, ...]`` padded buffers (views into
-        a larger window stack are fine) from the lazy source."""
-        idx_map, _ = self._split_maps(split)
+    def _fetch(self, client_ids: np.ndarray, split: str,
+               n_real: int | None = None):
+        """One round's ``[S, nmax, ...]`` padded buffers, filled from the
+        lazy source."""
+        idx_map, nmax = self._split_maps(split)
+        S = len(client_ids)
+        Xs = np.zeros((S, nmax) + self.sample_shape, self.dtype)
+        ys = np.zeros((S, nmax), np.int32)
+        ns = np.zeros((S,), np.int32)
         for j, c in enumerate(client_ids):
             if n_real is not None and j >= n_real:
                 break  # mesh-tiling pads: zero buffers, never gathered
@@ -192,15 +192,6 @@ class StreamingFederation:
                     Xs[j, : len(idx)] = fetch_rows(self.X, idx)
                 ys[j, : len(idx)] = self.y[idx]
             ns[j] = len(idx)
-
-    def _fetch(self, client_ids: np.ndarray, split: str,
-               n_real: int | None = None):
-        _, nmax = self._split_maps(split)
-        S = len(client_ids)
-        Xs = np.zeros((S, nmax) + self.sample_shape, self.dtype)
-        ys = np.zeros((S, nmax), np.int32)
-        ns = np.zeros((S,), np.int32)
-        self._fill(Xs, ys, ns, client_ids, split, n_real)
         return Xs, ys, ns
 
     def _fetch_put(self, client_ids: np.ndarray, split: str,
@@ -217,33 +208,6 @@ class StreamingFederation:
         t2 = time.perf_counter()
         self._note_transfer(t0, t1, t2,
                             Xs.nbytes + ys.nbytes + ns.nbytes)
-        return out
-
-    def _fetch_put_window(self, ids_per_round: list[np.ndarray],
-                          n_real: int | None = None):
-        """Reader-thread work unit for a FUSED DISPATCH WINDOW (ISSUE
-        10): every round's train shards of the window gathered into ONE
-        ``[K, S, nmax, ...]`` stack and device_put once — window k+1's
-        gather AND transfer ride behind window k's K-round scan exactly
-        as the per-round feed hides behind one round. The client axis
-        (axis 1) shards over the mesh when attached; the K axis
-        replicates (the scan consumes one round per step)."""
-        K, S = len(ids_per_round), len(ids_per_round[0])
-        t0 = time.perf_counter()
-        Xs = np.zeros((K, S, self.nmax_train) + self.sample_shape,
-                      self.dtype)
-        ys = np.zeros((K, S, self.nmax_train), np.int32)
-        ns = np.zeros((K, S), np.int32)
-        for k, ids in enumerate(ids_per_round):
-            self._fill(Xs[k], ys[k], ns[k], np.asarray(ids), "train",
-                       n_real)
-        t1 = time.perf_counter()
-        out = (self._put(Xs, client_axis=1), self._put(ys, client_axis=1),
-               self._put(ns, client_axis=1))
-        jax.block_until_ready(out[0])
-        t2 = time.perf_counter()
-        self._note_transfer(t0, t1, t2,
-                            Xs.nbytes + ys.nbytes + ns.nbytes, fetches=K)
         return out
 
     # ---------- double-buffered round feed ----------
@@ -278,44 +242,6 @@ class StreamingFederation:
         feed did not hide behind compute (span ``feed_wait``)."""
         with obs_trace.span(obs_names.SPAN_FEED_WAIT):
             return future.result()
-
-    # ---------- window-granular feed (fused dispatch, ISSUE 10) ----------
-
-    @staticmethod
-    def _window_key(ids_per_round, n_real):
-        return ("train_window",
-                tuple(tuple(int(c) for c in ids) for ids in ids_per_round),
-                n_real)
-
-    def prefetch_window(self, ids_per_round: list[np.ndarray],
-                        n_real: int | None = None) -> None:
-        """Kick off a whole dispatch window's read + transfer on the
-        background thread — the window-granular analog of
-        ``prefetch_train`` for the ``--rounds_per_dispatch K`` streamed
-        driver: window k+1's ``[K, S, ...]`` stack lands on device while
-        window k's fused scan computes. HBM note: a window holds K
-        rounds' shards simultaneously — size K accordingly (the same
-        trade the resident fused driver makes with compile time)."""
-        key = self._window_key(ids_per_round, n_real)
-        if self._pending is not None and self._pending[0] == key:
-            return
-        self._pending = (key, self._pool.submit(
-            self._fetch_put_window,
-            [np.asarray(ids) for ids in ids_per_round], n_real))
-
-    def get_window(self, ids_per_round: list[np.ndarray],
-                   n_real: int | None = None):
-        """Device-resident ``[K, S, nmax, ...]`` stacks for a fused
-        window; serves the prefetched buffers when the key matches
-        (mismatches fetch fresh, never serve stale — the
-        ``prefetch_train`` contract)."""
-        key = self._window_key(ids_per_round, n_real)
-        if self._pending is not None and self._pending[0] == key:
-            out = self._wait(self._pending[1])
-            self._pending = None
-            return out
-        return self._fetch_put_window(
-            [np.asarray(ids) for ids in ids_per_round], n_real)
 
     # ---------- resident val shards (FedFomo) ----------
 

@@ -66,22 +66,6 @@ import sys
 import numpy as np
 
 
-def dispatch_fallback_note(k: int) -> str | None:
-    """Why ``--rounds_per_dispatch`` collapses to 1 on the distributed
-    transport (logged once at startup; None when k <= 1 — nothing to
-    say). The fused lax.scan driver (ISSUE 4) requires K host-free
-    rounds; the cross-silo protocol is a host round-trip PER ROUND by
-    construction (broadcast -> silo train -> upload -> aggregate over
-    real sockets)."""
-    if k <= 1:
-        return None
-    from neuroimagedisttraining_tpu.engines import program as round_program
-
-    return (f"rounds_per_dispatch={k} requested; "
-            + round_program.report_fallback("distributed",
-                                            "distributed-control-plane"))
-
-
 def cohort_fallback_note(n: int) -> str | None:
     """Why ``--client_mesh`` (ISSUE 6) has nothing to shard on the
     distributed transport (printed once at startup; None when n <= 0).
@@ -548,11 +532,6 @@ def main(argv=None) -> int:
                     help="pin JAX to the CPU backend (several silo "
                          "processes on one machine: a chip belongs to "
                          "one process at a time)")
-    ap.add_argument("--rounds_per_dispatch", type=int, default=1,
-                    help="accepted for config parity with the main CLI; "
-                         "the cross-silo control plane synchronizes with "
-                         "every silo each round, so rounds always "
-                         "dispatch one at a time here")
     # observability (obs/, ISSUE 9)
     ap.add_argument("--metrics_port", type=int, default=0,
                     help="serve /metrics (Prometheus text) + /healthz "
@@ -693,9 +672,6 @@ def main(argv=None) -> int:
             check_headroom(quant_spec, args.num_clients)
         except ValueError as e:
             ap.error(str(e))
-    if args.rounds_per_dispatch > 1:
-        print(f"[dispatch] {dispatch_fallback_note(args.rounds_per_dispatch)}",
-              flush=True)
     if args.client_mesh > 0:
         print(f"[cohort] {cohort_fallback_note(args.client_mesh)}",
               flush=True)

@@ -77,7 +77,7 @@ class FederatedEngine:
     #: engines whose round body can run its local-training stage under
     #: the cohort-sharded client mesh (``--client_mesh``, ISSUE 6,
     #: parallel/cohort.py); others fall back to the unsharded round with
-    #: a logged reason (same pattern as fused-dispatch fallback)
+    #: a logged reason
     supports_cohort_sharding = False
     #: engines whose round program realizes the --dp_clip/--dp_sigma
     #: round-level DP transform (clip each client's update delta, add
@@ -93,14 +93,6 @@ class FederatedEngine:
     #: stage (or none) have no server fold for the field algebra to
     #: protect and must reject the flag loudly
     supports_secure_quant = False
-    #: engines whose STREAMING driver can run fused K-round windows
-    #: (ISSUE 10): the window's shards are prefetched as one [K, S, ...]
-    #: stack (data/stream.py prefetch_window) and the scanned round body
-    #: consumes one round per step — window k+1's host read + device_put
-    #: ride behind window k's scan. Others keep the round-granular
-    #: streamed feed and collapse to K=1 with the logged streaming
-    #: reason.
-    supports_fused_streaming = False
 
     def __init__(self, cfg: ExperimentConfig, fed_data: FederatedData | None,
                  trainer: LocalTrainer, mesh=None,
@@ -317,12 +309,12 @@ class FederatedEngine:
         #: in one batched device_get at host boundaries (_flush_nonfinite)
         self._nonfinite_pending: list = []
         #: in-dispatch training-health stats queued per dispatch (ISSUE
-        #: 15): ``(k, stacked, {stat: device array})`` entries the
+        #: 15): one ``{stat: device array}`` entry a round, which the
         #: builder's dispatch wrapper appends; drained in the SAME
         #: batched device_get as the non-finite counts — never a
         #: per-round sync
         self._health_pending: list = []
-        #: host integers of the round(s) about to be dispatched
+        #: host integers of the round about to be dispatched
         #: (``_note_round_counts``), taken as arguments by the next
         #: ``dispatch_program`` span; empty while the tracer is disarmed
         self._dispatch_counts: dict = {}
@@ -333,7 +325,7 @@ class FederatedEngine:
         self._metrics_last_round: int | None = None
         # cohort sharding (--client_mesh, ISSUE 6): hard config errors
         # fail here; engines/modes whose rounds cannot shard announce the
-        # unsharded fallback ONCE, up front (the fused-dispatch pattern)
+        # unsharded fallback ONCE, up front
         self._cohort_on = False
         cm = int(cfg.fed.client_mesh)
         if cm > 0:
@@ -380,17 +372,6 @@ class FederatedEngine:
                 "Pallas kernel cannot be partitioned by GSPMD. Add "
                 f"--client_mesh {n_mesh}, or drop --fused_update, or pin "
                 "one device with --mesh_shape 1")
-        # fused multi-round dispatch (ISSUE 4): engines that cannot fuse
-        # announce the collapse to K=1 ONCE, up front, so a config asking
-        # for amortized dispatch never silently degrades
-        if cfg.fed.rounds_per_dispatch > 1:
-            key = self.fused_fallback_key()
-            if key is not None:
-                self.log.info(
-                    "rounds_per_dispatch=%d requested; dispatching one "
-                    "round at a time: %s",
-                    cfg.fed.rounds_per_dispatch,
-                    round_program.report_fallback(self.name, key))
 
     # ---------- state init ----------
 
@@ -683,8 +664,8 @@ class FederatedEngine:
         secure-quant stage, derived ONCE from the seed-deterministic
         init model (privacy.leaf_scales — BatchNorm raw-moment leaves
         would otherwise saturate the small field). Static for the run —
-        the fused scan's carry changes per round, so per-round reference
-        scales would force a host boundary; the fixed-scale contract is
+        per-round reference scales would force a host boundary; the
+        fixed-scale contract is
         the async one-phase protocol's (frames fold unscaled against a
         startup bound there; scaled against the init here)."""
         from neuroimagedisttraining_tpu.privacy import leaf_scales
@@ -697,8 +678,8 @@ class FederatedEngine:
     @functools.cached_property
     def program(self) -> "round_program.RoundProgram":
         """The engine's compiled round-program builder
-        (engines/program.py): every fused/sharded/donated dispatch
-        variant, window planning, and fallback reporting. Built from the
+        (engines/program.py): every sharded/folded/donated dispatch
+        variant and the fallback reporting. Built from the
         engine's :meth:`round_stages` declaration (None for engines that
         keep hand-driven per-round loops — they still get the unified
         fallback reporting)."""
@@ -708,33 +689,9 @@ class FederatedEngine:
         """The engine's declared round stages
         (:class:`engines.program.RoundStages`), or None when the engine
         has no declarable round body (host-side state between rounds).
-        Declaring stages is what puts an engine on the fused/sharded/
+        Declaring stages is what puts an engine on the sharded/folded/
         donated fast path — the builder owns the machinery."""
         return None
-
-    # ---------- fused multi-round dispatch (ISSUE 4) ----------
-
-    def fused_fallback_key(self) -> str | None:
-        """REASONS key for why this engine dispatches one round at a
-        time even when ``--rounds_per_dispatch K`` asks for fused
-        windows — or None when the declared stages support the K-round
-        ``lax.scan`` driver. Engines with genuinely host-driven rounds
-        override with their table key (engines/program.py REASONS is the
-        single source of truth; ad-hoc reason strings are a lint
-        finding)."""
-        return self.program.fused_fallback_key()
-
-    def fused_fallback_reason(self) -> str | None:
-        """The logged message for :meth:`fused_fallback_key` (None when
-        the fused driver arms) — kept for drivers and tests that match
-        on the message text."""
-        key = self.fused_fallback_key()
-        return None if key is None else round_program.reason(key)
-
-    def _dispatch_window(self, round_idx: int) -> int:
-        """Window length starting at ``round_idx`` (delegates to the
-        program's planner — hooks land on window boundaries)."""
-        return self.program.dispatch_window(round_idx)
 
     # ---------- cohort sharding (--client_mesh, ISSUE 6) ----------
 
@@ -968,36 +925,33 @@ class FederatedEngine:
     def _note_round_counts(self, sampled, rows: int) -> None:
         """What the next dispatched program trains, as host integers the
         driver already holds (no device read): ``samples_real`` and
-        ``steps_real`` over the sampled clients of the round(s)
-        (``sampled``: one id array per round); ``steps_run``, the steps
-        the program's loops execute for its ``rows`` client rows a round
-        under its placement (stacked: every row walks
+        ``steps_real`` over the round's ``sampled`` clients;
+        ``steps_run``, the steps the program's loops execute for its
+        ``rows`` client rows under its placement (stacked: every row walks
         core/trainer.py ``scan_steps``, padded rows and masked steps
         included; rows that run alone, sharded or folded: the real steps
         and no other); ``steps_skipped``, the surplus iterations of that
         loop length not executed; and ``chip_steps_max`` /
         ``chip_steps_mean``, the busiest chip's and the mean chip's
         share of ``steps_run`` as the rows are dealt
-        (``_cohort_deal``; equal on one chip), each round's summed over
-        a window. They ride on the ``dispatch_program`` span, so a trace
-        reads the padded share and the deal where the work is
-        dispatched. A no-op while the tracer is disarmed."""
+        (``_cohort_deal``; equal on one chip). They ride on the
+        ``dispatch_program`` span, so a trace reads the padded share and
+        the deal where the work is dispatched. A no-op while the tracer
+        is disarmed."""
         if not obs_trace.TRACER.armed:
             return
         o = self.cfg.optim
         placement = self.program.placement
-        n = np.concatenate([self._n_train_host[np.asarray(s)]
-                            for s in sampled])
+        n = self._n_train_host[np.asarray(sampled)]
         real = int(o.epochs * np.ceil(n / o.batch_size).sum())
-        walked = int(len(sampled) * rows * scan_steps(
+        walked = int(rows * scan_steps(
             o.epochs, o.batch_size, self._max_samples()))
         run = walked if placement == round_program.STACKED else real
         busiest, chips = run, 1
         if placement == round_program.SHARDED:
             chips = int(self.mesh.devices.size)
-            busiest = int(o.epochs * sum(
-                self._cohort_deal(*self._cohort_pad(s))[1].max()
-                for s in sampled))
+            busiest = int(o.epochs * self._cohort_deal(
+                *self._cohort_pad(sampled))[1].max())
         self._dispatch_counts = {
             "samples_real": int(o.epochs * n.sum()),
             "steps_real": real,
@@ -1018,17 +972,14 @@ class FederatedEngine:
 
     # ---------- training-health plane (obs/health.py, ISSUE 15) ----------
 
-    def _note_health(self, stats: dict, k: int = 1,
-                     stacked: bool = False) -> None:
-        """Queue one dispatch's health-stats pytree (device arrays —
-        the builder's dispatch wrapper calls this, never a driver).
-        ``k`` rounds per dispatch; ``stacked`` marks scan-fused values
-        with a leading [K] round axis. Drained at ``_flush_nonfinite``
-        in the same batched device_get as the non-finite counts."""
-        self._health_pending.append((int(k), bool(stacked), stats))
+    def _note_health(self, stats: dict) -> None:
+        """Queue one round's health-stats pytree (device arrays — the
+        builder's dispatch wrapper calls this, never a driver). Drained
+        at ``_flush_nonfinite`` in the same batched device_get as the
+        non-finite counts."""
+        self._health_pending.append(stats)
 
-    def _drain_health(self, entries: list, host_vals: list,
-                      round_idx: int) -> None:
+    def _drain_health(self, host_vals: list, round_idx: int) -> None:
         """Publish the drained health stats round by round. Dispatches
         between two host boundaries cover CONTIGUOUS rounds ending at
         the flush round (the drivers' loop invariant), so the round
@@ -1036,28 +987,20 @@ class FederatedEngine:
         ``round_idx`` — no per-dispatch round plumbing through the
         legacy adapters. Each published round also lands one metrics
         JSONL record and one rule-engine boundary evaluation."""
-        total = sum(k for k, _, _ in entries)
-        r = round_idx - total + 1
-        for (k, stacked, _), host in zip(entries, host_vals):
-            for i in range(k):
-                if stacked:
-                    row = {n: np.asarray(v)[i] for n, v in host.items()}
-                else:
-                    row = host
-                obs_health.publish_round_stats(self.name, r, row)
-                # stash the host row BEFORE the boundary evaluation:
-                # a divergence alert fired at this round must be able
-                # to attribute the offender from its h_cos vector
-                # (the reflex quarantine handler, ISSUE 20)
-                self._stash_bounded(self._last_health_rows, int(r),
-                                    dict(row))
-                if r < round_idx:
-                    # the flush round itself dumps/evaluates in
-                    # publish_stat_info, AFTER the stat/DP gauges of
-                    # this boundary are set
-                    self._dump_metrics_jsonl(r)
-                    obs_rules.observe_boundary(r)
-                r += 1
+        for r, row in enumerate(host_vals,
+                                round_idx - len(host_vals) + 1):
+            obs_health.publish_round_stats(self.name, r, row)
+            # stash the host row BEFORE the boundary evaluation: a
+            # divergence alert fired at this round must be able to
+            # attribute the offender from its h_cos vector (the reflex
+            # quarantine handler, ISSUE 20)
+            self._stash_bounded(self._last_health_rows, int(r), dict(row))
+            if r < round_idx:
+                # the flush round itself dumps/evaluates in
+                # publish_stat_info, AFTER the stat/DP gauges of this
+                # boundary are set
+                self._dump_metrics_jsonl(r)
+                obs_rules.observe_boundary(r)
 
     def _dump_metrics_jsonl(self, round_idx: int) -> None:
         """One metrics JSONL record per round (``--metrics_out``), each
@@ -1095,13 +1038,11 @@ class FederatedEngine:
         points to a run."""
         self.record_privacy(round_idx)
         if self._nonfinite_pending or self._health_pending:
-            health_entries = self._health_pending
-            self._health_pending = []
             with obs_trace.span(obs_names.SPAN_FLUSH_SYNC):
                 counts, health_vals = jax.device_get(
-                    (self._nonfinite_pending,
-                     [e[2] for e in health_entries]))
+                    (self._nonfinite_pending, self._health_pending))
             self._nonfinite_pending.clear()
+            self._health_pending = []
             total = int(sum(np.sum(np.asarray(c)) for c in counts))
             if total:
                 self.stat_info["nonfinite_uploads"] += total
@@ -1111,9 +1052,8 @@ class FederatedEngine:
                     "offending clients were zero-weighted for their "
                     "rounds (%d rejected so far this run)", round_idx,
                     total, int(self.stat_info["nonfinite_uploads"]))
-            if health_entries:
-                self._drain_health(health_entries, health_vals,
-                                   round_idx)
+            if health_vals:
+                self._drain_health(health_vals, round_idx)
         self.publish_stat_info(round_idx)
 
     def publish_stat_info(self, round_idx: int) -> None:
@@ -1212,7 +1152,7 @@ class FederatedEngine:
         cached-properties / plan dicts in ``__dict__`` — popping them
         is the whole invalidation."""
         for name in ("program", "_round_jit", "_round_stream_jit",
-                     "_round_prog_cache", "_fused_round_jit_cache"):
+                     "_round_prog_cache"):
             self.__dict__.pop(name, None)
 
     def _register_reflexes(self) -> None:
@@ -1377,15 +1317,14 @@ class FederatedEngine:
 
     def _maybe_preempt(self, round_idx: int):
         """Elastic compute plane (ISSUE 20): consume any scheduled
-        ``preempt:NDEV@ROUND`` whose round has arrived (``<=`` — fused
-        windows skip indices), shrink the training mesh to the NDEV
-        survivors, re-plan every compiled program, and return
-        ``(resume_round, restored_state | None)`` from the last
-        donation-safe checkpoint. Returns None when nothing fired.
-        Deliberately NOT gated by ``--actions``: an explicitly injected
-        device loss is an event, not a reflex policy — the armed bus
-        records it with the device-loss event as provenance either
-        way."""
+        ``preempt:NDEV@ROUND`` whose round has arrived, shrink the
+        training mesh to the NDEV survivors, re-plan every compiled
+        program, and return ``(resume_round, restored_state | None)``
+        from the last donation-safe checkpoint. Returns None when
+        nothing fired. Deliberately NOT gated by ``--actions``: an
+        explicitly injected device loss is an event, not a reflex policy
+        — the armed bus records it with the device-loss event as
+        provenance either way."""
         if self.fault_schedule is None:
             return None
         hits = [(at, nd) for (at, nd)

@@ -13,12 +13,11 @@ ditto/my_model_trainer.py:38-68:
 
 Both tracks run inside one jitted round program, DECLARED through the
 round-program builder (engines/program.py, ISSUE 11): the builder
-supplies fused ``--rounds_per_dispatch K`` windows, ``--client_mesh``
-cohort sharding of both training tracks, buffer donation, the Byzantine
-attack plan + non-finite guard + ``--defense`` dispatch on the global
-track's uploads (the personal track keeps each client's honest local
-result), all as config knobs — none of which this engine had before the
-builder.
+supplies ``--client_mesh`` cohort sharding of both training tracks,
+buffer donation, the Byzantine attack plan + non-finite guard +
+``--defense`` dispatch on the global track's uploads (the personal track
+keeps each client's honest local result), all as config knobs — none of
+which this engine had before the builder.
 """
 
 from __future__ import annotations
@@ -185,22 +184,8 @@ class DittoEngine(FederatedEngine):
             history = restored["history"]
         if self.stream is not None:
             self.stream.prefetch_train(*self.stream_sampling(start))
-        # fused K-round windows (builder-owned, ISSUE 11): the window
-        # planner pins eval/checkpoint rounds to boundaries, so the
-        # fused driver's observable behavior matches the per-round loop
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                ((params, bstats, per_params, per_bstats), _, outs,
-                 wi) = self.program.run_window(
-                    (params, bstats, per_params, per_bstats), round_idx,
-                    k)
-                loss, k = outs["loss"][-1], wi.k
-                round_idx += k - 1
-            elif self.stream is not None:
+        for round_idx in range(start, cfg.fed.comm_round):
+            if self.stream is not None:
                 sampled = self.client_sampling(round_idx)
                 fed_ids, n_real = self.stream_sampling(round_idx, sampled)
                 rngs = self.per_client_rngs(round_idx, fed_ids)
@@ -248,7 +233,6 @@ class DittoEngine(FederatedEngine):
                 "params": params, "batch_stats": bstats,
                 "per_params": per_params, "per_bstats": per_bstats,
                 "history": history})
-            round_idx += 1
         self._flush_nonfinite(cfg.fed.comm_round - 1)
         m = self._eval_p(per_params, per_bstats)
         return {"params": params, "personal_params": per_params,

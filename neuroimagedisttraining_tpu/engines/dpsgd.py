@@ -29,14 +29,10 @@ The round is DECLARED through the round-program builder
 stage (the mixing matrix and the sparse plan's routing arrays are
 ``per_round`` operands; the hashable plan spec keys the compiled
 program), the all-real mean over trained stacks is a custom aggregate
-stage, and ``w_global`` is an epilogue — computed once per dispatch from
-the final stacks, which over a fused window is bitwise-identical to the
-last round's (same op on the same values). The builder supplies fused
-``--rounds_per_dispatch K`` windows (shrunk to the maximal equal-plan
-prefix when per-round gossip plans change shape) and ``--client_mesh``
-sharding of the local-train stage (the gossip consensus itself already
-runs mesh collectives); the every-100-rounds fine-tune pass is declared
-as an extra window-boundary hook.
+stage, and ``w_global`` is an epilogue, computed from the round's new
+stacks. The builder supplies ``--client_mesh`` sharding of the
+local-train stage (the gossip consensus itself already runs mesh
+collectives).
 """
 
 from __future__ import annotations
@@ -159,14 +155,7 @@ class DPSGDEngine(FederatedEngine):
             outputs=("loss",),
             per_round=("M", "plan_arrays"),
             gathers_cohort=False,
-            window_extras=self._window_extras,
-            extra_hooked=self._finetune_hooked,
         )
-
-    def _finetune_hooked(self, r: int) -> bool:
-        """The every-100-rounds fine-tune-from-global evaluation pass is
-        a host-side hook — the window planner pins it to a boundary."""
-        return r % 100 == 99
 
     def _train_stage(self, ctx) -> round_program.TrainOut:
         """Consensus over last round's models (per-round mixing matrix /
@@ -210,62 +199,11 @@ class DPSGDEngine(FederatedEngine):
 
     def _epilogue_stage(self, eng, carry, data) -> tuple:
         """``w_global`` — the plain mean of all personal models
-        (dpsgd_api.py:161-167), computed once per dispatch from the
-        final stacks (bitwise the last round's: same op, same values)."""
+        (dpsgd_api.py:161-167), from the round's new stacks."""
         wp, wb, _, _ = self._global_mean(carry["per_params"],
                                          carry["per_bstats"],
                                          data.n_train)
         return (wp, wb)
-
-    def _window_extras(self, round_idx: int, k: int
-                       ) -> round_program.WindowInputs:
-        """Window prologue: per-round mixing matrices + gossip plans.
-        The scan needs ONE compiled consensus, so the window shrinks to
-        the maximal prefix whose plan spec (the program's static key)
-        and routing-array shapes match round 0's — ring/full topologies
-        are round-invariant (full windows), random topologies fuse while
-        their sparse bucketing stays shape-stable."""
-        Ms, plans, arrays = [], [], []
-        for off in range(k):
-            M_np = self.mixing_matrix(round_idx + off)
-            plan, pa = self.gossip_plan(M_np)
-            Ms.append(M_np)
-            plans.append(plan)
-            arrays.append(pa)
-
-        def compatible(i: int) -> bool:
-            if plans[i] != plans[0]:
-                return False
-            a0 = jax.tree.leaves(arrays[0])
-            ai = jax.tree.leaves(arrays[i])
-            return (jax.tree.structure(arrays[i])
-                    == jax.tree.structure(arrays[0])
-                    and all(np.shape(x) == np.shape(y)
-                            for x, y in zip(ai, a0)))
-
-        keep = 1
-        while keep < k and compatible(keep):
-            keep += 1
-        k = keep
-        for off in range(k):
-            self.log.info("################ round %d: decentralized "
-                          "cohort (fused window of %d)", round_idx + off,
-                          k)
-        C = self.num_clients
-        M = jnp.asarray(np.stack(Ms[:k]))
-        if jax.tree.leaves(arrays[0]):
-            pa = jax.tree.map(lambda *xs: jnp.stack(xs), *arrays[:k])
-        else:
-            pa = arrays[0]
-        rngs = jnp.stack([self.per_client_rngs(round_idx + off,
-                                               np.arange(C))
-                          for off in range(k)])
-        lrs = jnp.asarray([self.round_lr(round_idx + off)
-                           for off in range(k)], jnp.float32)
-        return round_program.WindowInputs(
-            sampled=None, idx=None, rngs=rngs, lrs=lrs, byz=None, k=k,
-            n_real=None, static_key=plans[0],
-            per_round={"M": M, "plan_arrays": pa})
 
     # ---------- legacy-signature program adapters ----------
 
@@ -414,35 +352,23 @@ class DPSGDEngine(FederatedEngine):
             g_params, g_bstats = (restored["g_params"],
                                   restored["g_bstats"])
             history = restored["history"]
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                ((per_params, per_bstats), (g_params, g_bstats), outs,
-                 wi) = self.program.run_window(
-                    (per_params, per_bstats), round_idx, k)
-                loss, k = outs["loss"][-1], wi.k
-                round_idx += k - 1
+        for round_idx in range(start, cfg.fed.comm_round):
+            M_np = self.mixing_matrix(round_idx)
+            plan, plan_arrays = self.gossip_plan(M_np)
+            M = jnp.asarray(M_np)
+            rngs = self.per_client_rngs(round_idx,
+                                        np.arange(self.num_clients))
+            if self.stream is not None:
+                per_params, per_bstats, g_params, g_bstats, loss = \
+                    self._round_streaming(per_params, per_bstats, M,
+                                          rngs, self.round_lr(round_idx),
+                                          plan=plan,
+                                          plan_arrays=plan_arrays)
             else:
-                M_np = self.mixing_matrix(round_idx)
-                plan, plan_arrays = self.gossip_plan(M_np)
-                M = jnp.asarray(M_np)
-                rngs = self.per_client_rngs(round_idx,
-                                            np.arange(self.num_clients))
-                if self.stream is not None:
-                    per_params, per_bstats, g_params, g_bstats, loss = \
-                        self._round_streaming(per_params, per_bstats, M,
-                                              rngs,
-                                              self.round_lr(round_idx),
-                                              plan=plan,
-                                              plan_arrays=plan_arrays)
-                else:
-                    per_params, per_bstats, g_params, g_bstats, loss = \
-                        self._round_jit_for(plan)(
-                            per_params, per_bstats, self.data, M, rngs,
-                            self.round_lr(round_idx), plan_arrays)
+                per_params, per_bstats, g_params, g_bstats, loss = \
+                    self._round_jit_for(plan)(
+                        per_params, per_bstats, self.data, M, rngs,
+                        self.round_lr(round_idx), plan_arrays)
             if round_idx % cfg.fed.frequency_of_the_test == 0 \
                     or round_idx == cfg.fed.comm_round - 1:
                 # the shared OBS/health boundary: record_privacy runs
@@ -472,8 +398,7 @@ class DPSGDEngine(FederatedEngine):
                 # this DIAGNOSTIC pass (the fine-tuned models are
                 # evaluated then discarded, dpsgd_api.py:101 w_per_tmp —
                 # no training state depends on it); the per-round metrics
-                # above stream fine. The window planner pins this round
-                # to a boundary (round_stages.extra_hooked).
+                # above stream fine.
                 ft_rngs = self.per_client_rngs(-1,
                                                np.arange(self.num_clients))
                 ft_p, ft_b = self._finetune_jit(g_params, g_bstats, self.data,
@@ -486,7 +411,6 @@ class DPSGDEngine(FederatedEngine):
                 "per_params": per_params, "per_bstats": per_bstats,
                 "g_params": g_params, "g_bstats": g_bstats,
                 "history": history})
-            round_idx += 1
         return {"personal_params": per_params, "global_params": g_params,
                 "history": history,
                 "final_global": self._eval_g(g_params, g_bstats)}

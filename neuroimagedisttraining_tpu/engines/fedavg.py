@@ -61,8 +61,6 @@ class FedAvgEngine(FederatedEngine):
     # attack stage when the schedule carries byz: value faults
     supports_cohort_sharding = True  # the declared local-train stage
     # runs under the --client_mesh shard_map (ISSUE 6)
-    supports_fused_streaming = True  # the streamed driver fuses K-round
-    # windows over one prefetched [K, S, ...] shard stack (ISSUE 10)
     supported_defenses = robust.DEFENSES
 
     def _prox_kwargs(self, global_params) -> dict:
@@ -185,61 +183,12 @@ class FedAvgEngine(FederatedEngine):
 
         return stream_round_call
 
-    # ---------- fused multi-round dispatch (ISSUE 4) ----------
-
-    def _run_fused_window(self, params, bstats, round_idx: int, k: int):
-        """Dispatch rounds ``[round_idx, round_idx + k)`` as one scan
-        (program.run_window: host prologue + ONE compiled program).
-        Returns ``(params, bstats, last_round_loss, k_actual)`` —
-        ``k_actual`` may shrink when the fault schedule varies the
-        cohort size."""
-        (params, bstats), _, outs, wi = self.program.run_window(
-            (params, bstats), round_idx, k)
-        return params, bstats, outs["loss"][-1], wi.k
-
     def _stream_prefetch_for(self, round_idx: int) -> None:
-        """Kick off the streamed feed for whatever the driver will
-        dispatch AT ``round_idx``: the whole fused window's shard stack
-        when the fused streamed driver is armed and the window planner
-        gives more than one round, the single round's shards otherwise.
-        The key-matching get (``get_window``/``get_train``) re-derives
-        the identical ids — sampling is deterministic in the round
-        index — so a planner disagreement degrades to a fresh fetch,
-        never a stale serve."""
-        if round_idx >= self.cfg.fed.comm_round:
-            return
-        fuse = (self.cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        if fuse:
-            k = self._dispatch_window(round_idx)
-            if k > 1:
-                sampled, k = self.program.window_sampling(round_idx, k)
-                pads = [self.stream_sampling(round_idx + off, sampled=s)
-                        for off, s in enumerate(sampled)]
-                self.stream.prefetch_window([p[0] for p in pads],
-                                            pads[0][1])
-                return
-        self.stream.prefetch_train(*self.stream_sampling(round_idx))
-
-    def _run_fused_stream_window(self, params, bstats, round_idx: int,
-                                 k: int):
-        """Dispatch streamed rounds ``[round_idx, round_idx + k)`` as one
-        scan over the prefetched window stack (ISSUE 10), then
-        immediately queue the NEXT window's host read + device transfer
-        behind this window's compute (the dispatch returns
-        asynchronously; the boundary hooks block later). Returns
-        ``(params, bstats, last_round_loss, k_actual)``."""
-        with obs_trace.span("window", round=round_idx, k=k, stream=True):
-            with obs_trace.span("window_host_prologue", round=round_idx):
-                (ids_per_round, rngs, lrs, byz, k,
-                 n_real) = self.program.stream_window_inputs(round_idx, k)
-                Xs, ys, ns = self.stream.get_window(ids_per_round, n_real)
-                self._stream_prefetch_for(round_idx + k)
-            with obs_trace.span("dispatch", round=round_idx, k=k):
-                params, bstats, losses, bads = self.program.fused_stream_jit(
-                    k)((params, bstats), (), Xs, ys, ns, rngs, lrs, byz)
-        self._note_nonfinite(bads)
-        return params, bstats, losses[-1], k
+        """Queue the streamed feed for round ``round_idx`` behind the
+        current round's compute (``get_train`` re-derives the identical
+        ids: sampling is deterministic in the round index)."""
+        if round_idx < self.cfg.fed.comm_round:
+            self.stream.prefetch_train(*self.stream_sampling(round_idx))
 
     def _finetune_body(self, params, bstats, X, y, n, rngs, lr):
         """Per-client fine-tune from the aggregated model over a block of
@@ -321,15 +270,14 @@ class FedAvgEngine(FederatedEngine):
 
         return jax.jit(ft_eval)
 
-    def _round_iteration(self, round_idx: int, params, bstats, history,
-                         fuse: bool):
+    def _round_iteration(self, round_idx: int, params, bstats, history):
         """One iteration of the round loop, resident or streamed: the
-        host prologue, the dispatch of one round (or of one fused
-        window), and the boundary hooks. Returns ``(next_round_idx,
-        params, bstats, history)``. Each stage is a host span
-        (obs/names.py) that takes its round id from the caller's ``round``
-        span, which covers the whole iteration; only the ``*_sync`` spans
-        and ``feed_wait`` wait for anything."""
+        host prologue, the dispatch of one round, and the boundary
+        hooks. Returns ``(next_round_idx, params, bstats, history)``.
+        Each stage is a host span (obs/names.py) that takes its round id
+        from the caller's ``round`` span, which covers the whole
+        iteration; only the ``*_sync`` spans and ``feed_wait`` wait for
+        anything."""
         cfg = self.cfg
         streaming = self.stream is not None
         codec_on = self.wire_spec is not None and not streaming
@@ -357,23 +305,19 @@ class FedAvgEngine(FederatedEngine):
                     self._stream_prefetch_for(round_idx)
                 if pre[1] is not None:
                     return round_idx, params, bstats, history
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                pass  # the window drivers below have their own prologue
-            elif streaming:
+            if streaming:
                 lr = self.round_lr(round_idx)
                 ids, n_real = self.stream_sampling(round_idx)
                 self.log.info("################ round %d (stream): "
                               "clients %s", round_idx,
                               ids[:n_real].tolist())
                 Xs, ys, ns = self.stream.get_train(ids, n_real)
-                # overlap the next dispatch's host read (single round or
-                # whole window) with this round's compute
+                # overlap the next round's host read with this round's
+                # compute
                 self._stream_prefetch_for(round_idx + 1)
                 rngs = self.per_client_rngs(round_idx, ids)
                 byz = self._byz_round_plan(round_idx, ids)
-                self._note_round_counts([ids[:n_real]],
-                                        len(ids))
+                self._note_round_counts(ids[:n_real], len(ids))
             else:
                 lr = self.round_lr(round_idx)
                 sampled = self.client_sampling(round_idx)
@@ -398,43 +342,36 @@ class FedAvgEngine(FederatedEngine):
                     efs = (pt.tree_stack_index(self._wire_ef,
                                                np.asarray(sampled))
                            if self.wire_spec.needs_ef else None)
-                self._note_round_counts([sampled], len(ids))
-        counters = []  # a fused window reports no per-round counter
-        if k > 1:
-            run = (self._run_fused_stream_window if streaming
-                   else self._run_fused_window)
-            params, bstats, loss, k = run(params, bstats, round_idx, k)
-            round_idx += k - 1  # hooks below fire for the boundary
+                self._note_round_counts(sampled, len(ids))
+        if streaming:
+            # efs/byz stay default-bound (None) when there is no plan:
+            # subclasses override the round jits with efs-free signatures
+            # (turboaggregate), and an argument filled from its default
+            # is never donated
+            tail = () if byz is None else (None, byz)
+            params, bstats, loss, n_bad, *counters = \
+                self._round_stream_jit(params, bstats, Xs, ys, ns,
+                                       rngs, lr, *tail)
+        elif codec_on:
+            (params, bstats, loss, n_bad, *counters, new_efs,
+             u0) = round_prog(params, bstats, self.data, idx, rngs,
+                              lr, efs, byz)
+            if new_efs is not None:
+                real = jnp.asarray(self._n_train_host[sampled] > 0)
+                self._wire_ef = self.scatter_sampled_rows(
+                    self._wire_ef, new_efs, jnp.asarray(sampled),
+                    real)
+            with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                self.account_wire_bytes(
+                    jax.tree.map(np.asarray, u0), ref_host, None,
+                    len(sampled))
         else:
-            if streaming:
-                # efs/byz stay default-bound (None) when there is no
-                # plan: subclasses override the round jits with efs-free
-                # signatures (turboaggregate), and an argument filled
-                # from its default is never donated
-                tail = () if byz is None else (None, byz)
-                params, bstats, loss, n_bad, *counters = \
-                    self._round_stream_jit(params, bstats, Xs, ys, ns,
-                                           rngs, lr, *tail)
-            elif codec_on:
-                (params, bstats, loss, n_bad, *counters, new_efs,
-                 u0) = round_prog(params, bstats, self.data, idx, rngs,
-                                  lr, efs, byz)
-                if new_efs is not None:
-                    real = jnp.asarray(self._n_train_host[sampled] > 0)
-                    self._wire_ef = self.scatter_sampled_rows(
-                        self._wire_ef, new_efs, jnp.asarray(sampled),
-                        real)
-                with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
-                    self.account_wire_bytes(
-                        jax.tree.map(np.asarray, u0), ref_host, None,
-                        len(sampled))
-            else:
-                # byz plans only reach engines whose round accepts them
-                # (supports_byz_faults gates at startup)
-                tail = () if byz is None else (None, byz)
-                params, bstats, loss, n_bad, *counters = round_prog(
-                    params, bstats, self.data, idx, rngs, lr, *tail)
-            self._note_nonfinite(n_bad)
+            # byz plans only reach engines whose round accepts them
+            # (supports_byz_faults gates at startup)
+            tail = () if byz is None else (None, byz)
+            params, bstats, loss, n_bad, *counters = round_prog(
+                params, bstats, self.data, idx, rngs, lr, *tail)
+        self._note_nonfinite(n_bad)
         if round_idx % cfg.fed.frequency_of_the_test == 0 \
                 or round_idx == cfg.fed.comm_round - 1:
             m = self._eval_g(params, bstats)
@@ -465,14 +402,12 @@ class FedAvgEngine(FederatedEngine):
         whole iteration, sampling to checkpoint) around each
         ``_round_iteration``, whose children carry the same round id."""
         cfg = self.cfg
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
         round_idx = start
         while round_idx < cfg.fed.comm_round:
             with obs_trace.span(obs_names.SPAN_ROUND, round=round_idx):
                 round_idx, params, bstats, history = \
                     self._round_iteration(round_idx, params, bstats,
-                                          history, fuse)
+                                          history)
         self._flush_nonfinite(cfg.fed.comm_round - 1)
         return params, bstats, history
 
@@ -550,11 +485,6 @@ class FedAvgEngine(FederatedEngine):
             gs = self.init_global_state()
             params, bstats = gs.params, gs.batch_stats
             history = []
-        # fused streamed windows (ISSUE 10): when the window planner can
-        # fuse, whole K-round shard stacks are prefetched behind the
-        # previous window's scan; hook rounds land on window boundaries
-        # exactly as in the resident fused driver, so observable
-        # behavior matches the round-granular loop
         self._stream_prefetch_for(start)
         params, bstats, history = self._round_loop(start, params, bstats,
                                                    history)

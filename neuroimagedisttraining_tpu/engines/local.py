@@ -10,8 +10,7 @@ item 1(a)): the carry is the per-client state stacks, the train stage is
 the vmapped/sharded local pass, and a custom aggregate stage simply
 promotes the trained stacks to next round's carry (there is no server
 aggregation in a local-only run). The declaration is what buys the
-engine fused ``--rounds_per_dispatch K`` windows and ``--client_mesh``
-cohort sharding — K=4 fused == 4x K=1 BITWISE (tests/test_program.py)."""
+engine ``--client_mesh`` cohort sharding (tests/test_program.py)."""
 
 from __future__ import annotations
 
@@ -46,7 +45,6 @@ class LocalEngine(FederatedEngine):
             aggregate=self._aggregate_stage,
             outputs=("loss",),
             gathers_cohort=False,
-            window_extras=self._window_extras,
         )
 
     def _train_stage(self, ctx) -> round_program.TrainOut:
@@ -84,23 +82,6 @@ class LocalEngine(FederatedEngine):
         return ({"per_params": tr.extra["new_p"],
                  "per_bstats": tr.extra["new_b"]},
                 {"loss": mean_loss})
-
-    def _window_extras(self, round_idx: int, k: int
-                       ) -> round_program.WindowInputs:
-        """Window prologue: no sampling (every client trains every
-        round), just the stacked per-round rngs/lrs."""
-        C = self.num_clients
-        for off in range(k):
-            self.log.info("################ round %d: local-only cohort "
-                          "(fused window of %d)", round_idx + off, k)
-        rngs = jnp.stack([self.per_client_rngs(round_idx + off,
-                                               np.arange(C))
-                          for off in range(k)])
-        lrs = jnp.asarray([self.round_lr(round_idx + off)
-                           for off in range(k)], jnp.float32)
-        return round_program.WindowInputs(
-            sampled=None, idx=None, rngs=rngs, lrs=lrs, byz=None, k=k,
-            n_real=None)
 
     # ---------- legacy-signature program adapters ----------
 
@@ -155,30 +136,14 @@ class LocalEngine(FederatedEngine):
             per_params, per_bstats = (restored["per_params"],
                                       restored["per_bstats"])
             history = restored["history"]
-        # fused K-round windows (builder-owned, ROADMAP 1(a)): the
-        # window planner pins eval/checkpoint rounds to boundaries, so
-        # the fused driver's observable behavior matches the per-round
-        # loop
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                ((per_params, per_bstats), _, outs,
-                 wi) = self.program.run_window(
-                    (per_params, per_bstats), round_idx, k)
-                loss, k = outs["loss"][-1], wi.k
-                round_idx += k - 1
-            elif self.stream is not None:
-                rngs = self.per_client_rngs(round_idx,
-                                            np.arange(self.num_clients))
+        for round_idx in range(start, cfg.fed.comm_round):
+            rngs = self.per_client_rngs(round_idx,
+                                        np.arange(self.num_clients))
+            if self.stream is not None:
                 per_params, per_bstats, loss = self._round_streaming(
                     per_params, per_bstats, rngs,
                     self.round_lr(round_idx))
             else:
-                rngs = self.per_client_rngs(round_idx,
-                                            np.arange(self.num_clients))
                 per_params, per_bstats, loss = self._round_jit(
                     per_params, per_bstats, self.data, rngs,
                     self.round_lr(round_idx))
@@ -195,7 +160,6 @@ class LocalEngine(FederatedEngine):
             self.maybe_checkpoint(round_idx, {
                 "per_params": per_params, "per_bstats": per_bstats,
                 "history": history})
-            round_idx += 1
         m = self._eval_p(per_params, per_bstats)
         self.log.metrics(-1, personal=m)
         return {"personal_params": per_params,

@@ -6,13 +6,10 @@ Every federated round in this tree has the same skeleton:
     defend -> aggregate -> [update persistent state] -> privacy-account
 
 but until this module each engine hand-rolled the skeleton into its own
-``_round_jit`` / ``_fused_round_jit`` / ``_sharded_round_jit`` bodies, so
-the fast-path machinery built over ISSUEs 4-10 — fused K-round
-``lax.scan`` dispatch, ``--client_mesh`` cohort sharding, buffer
-donation, Byzantine defenses, the wire codec — reached only the engines
-that had copied the machinery in (fedavg/fedprox/salientgrads), and
-every other engine collapsed to K=1 unfused sequential dispatch with a
-logged reason.
+``_round_jit`` / ``_sharded_round_jit`` bodies, so the fast-path
+machinery built over ISSUEs 4-10 — ``--client_mesh`` cohort sharding,
+buffer donation, Byzantine defenses, the wire codec — reached only the
+engines that had copied the machinery in (fedavg/fedprox/salientgrads).
 
 This module inverts the ownership. An engine DECLARES its round as a
 :class:`RoundStages` value — which pytrees it carries between rounds,
@@ -23,8 +20,6 @@ produced, with the orthogonal knobs applied by the BUILDER:
 
 - buffer donation of the carried state (+ codec EF rows) on every
   compiled program (ISSUE 4 contract, donation-discipline lint);
-- ``--rounds_per_dispatch K`` window planning and the fused
-  ``lax.scan`` driver, hooks pinned to window boundaries (ISSUE 4);
 - ``--client_mesh`` cohort sharding of the local-train stage with the
   epoch-permutation hoist the toolchain requires (ISSUE 6,
   parallel/cohort.py — in-partition argsort miscompiles);
@@ -35,8 +30,9 @@ produced, with the orthogonal knobs applied by the BUILDER:
 fedavg/fedprox/salientgrads ride the builder with BITWISE parity against
 their pre-builder paths (the regression oracle: tests/test_dispatch.py,
 test_cohort.py, test_byzantine.py pins are unchanged); ditto, dpsgd and
-subavg are expressed as stage declarations and gain fused windows and
-cohort sharding for the first time (tests/test_program.py).
+subavg are expressed as stage declarations and gain cohort sharding
+for the first time (tests/test_program.py). A dispatch holds ONE round:
+the driver loops call ``round_jit`` (or ``stream_jit``) once a round.
 
 Fallback reporting is unified here too: :data:`REASONS` is the single
 source of truth for every "falls back with a logged reason" site, and
@@ -77,16 +73,6 @@ PyTree = Any
 #: ``*_fallback_key`` hooks with keys from this table, never ad-hoc
 #: strings (tests/test_program.py asserts no orphaned or unknown keys).
 REASONS: dict[str, tuple[str, str]] = {
-    # -- fused multi-round dispatch (plane "fused") --
-    "no-fused-body": ("fused", (
-        "engine has no fused round body (host-side state between "
-        "rounds)")),
-    "streaming-host-data": ("fused", (
-        "streaming rounds cross the host for data every round")),
-    "wire-codec-host-bytes": ("fused", (
-        "--wire_codec accounts encoded bytes on the host every round")),
-    "mpc-host-stage": ("fused", (
-        "the MPC aggregation stage is host-driven between rounds")),
     # -- cohort sharding (plane "sharding") --
     "no-sharded-body": ("sharding", (
         "engine has no cohort-sharded round body (its round crosses the "
@@ -122,10 +108,6 @@ REASONS: dict[str, tuple[str, str]] = {
         "layer pads resident cohorts to a device multiple; this one is "
         "not)")),
     # -- the distributed transport (distributed/run.py startup notes) --
-    "distributed-control-plane": ("fused", (
-        "the distributed transport dispatches one round at a time "
-        "(every round crosses the control plane: broadcast/upload/"
-        "aggregate over sockets)")),
     "distributed-no-client-axis": ("sharding", (
         "the distributed transport has no in-process client axis to "
         "shard (each rank trains its own silo) — flag accepted for "
@@ -187,9 +169,9 @@ def report_fallback(engine_name: str, key: str) -> str:
     plane, msg = REASONS[key]
     obs_metrics.counter(
         obs_names.FALLBACK_TOTAL,
-        "fast-path fallback announcements by plane (fused dispatch / "
-        "cohort sharding / fused streaming), engine, and reason key "
-        "(engines/program.py REASONS)",
+        "fast-path fallback announcements by plane (cohort sharding / "
+        "fold / recipe), engine, and reason key (engines/program.py "
+        "REASONS)",
         labelnames=("plane", "engine", "reason"),
     ).labels(plane=plane, engine=engine_name, reason=key).inc()
     return msg
@@ -259,16 +241,15 @@ class TrainOut:
 class RoundStages:
     """An engine's declared round: the builder compiles this (and only
     this) into every dispatch variant — single-round, cohort-sharded,
-    fused K-round windows, streamed — with donation, window planning and
-    the attack/codec/defense stages applied by the builder.
+    streamed — with donation and the attack/codec/defense stages applied
+    by the builder.
 
     ``carry``: names of the device pytrees carried round to round, in
     program-argument (and return) order; all are donated.
     ``consts``: loop-constant operands after the federation data (e.g.
     salientgrads' phase-1 mask).
     ``per_round``: per-round operand names beyond the builder-owned
-    sampling/rng/lr (e.g. dpsgd's mixing matrix) — stacked along K in
-    fused windows.
+    sampling/rng/lr (e.g. dpsgd's mixing matrix).
     ``train``: the local-train stage, ``(ctx: RoundCtx) -> TrainOut``.
     ``aggregate``: custom aggregation stage
     ``(ctx, upload, w, tr) -> (new_carry: dict, outs: dict)``; None
@@ -276,12 +257,9 @@ class RoundStages:
     tail (:func:`sanitize_defend_aggregate`).
     ``update``: persistent per-client state stage
     ``(ctx, tr, new_carry) -> dict`` of carry updates (scatters).
-    ``epilogue``: window-final outputs derived from the carry
-    ``(eng, carry: dict, data) -> tuple`` (e.g. dpsgd's ``w_global``) —
-    computed once per dispatch, after the scan.
-    ``outputs``: names of the per-round scalar outputs, stacked ``[K]``
-    over fused windows. ``"n_bad"`` wires into the engine's non-finite
-    accounting automatically.
+    ``epilogue``: outputs derived from the new carry
+    ``(eng, carry: dict, data) -> tuple`` (e.g. dpsgd's ``w_global``).
+    ``outputs``: names of the per-round scalar outputs.
     ``gathers_cohort``: the builder gathers the sampled clients' shards
     from the federation data by ``sampled_idx`` (False: the train stage
     consumes the full data, dpsgd-style).
@@ -292,10 +270,6 @@ class RoundStages:
     attack and applies it to ``upload`` before codec/defense.
     ``codec_masks``: ``(ctx) -> masks_full`` handed to the codec
     roundtrip (salientgrads' phase-1 mask handoff), or None.
-    ``window_extras``: custom window prologue for engines whose rounds
-    consume ``per_round`` operands, ``(round_idx, k) -> WindowInputs``.
-    ``extra_hooked``: extra host-boundary predicate for the window
-    planner (e.g. dpsgd's every-100-rounds fine-tune pass).
     ``health``: engine-private health-stats stage for the in-dispatch
     training-health leg (ISSUE 15), ``(ctx, tr, new_carry) -> dict`` of
     scalar stats named by ``health_outputs`` (the masked engines emit
@@ -324,30 +298,9 @@ class RoundStages:
     uses_ef: bool = False
     supports_attack: bool = False
     codec_masks: Callable | None = None
-    window_extras: Callable | None = None
-    extra_hooked: Callable | None = None
     health: Callable | None = None
     health_outputs: tuple[str, ...] = ()
     folds: bool = False
-
-
-@dataclasses.dataclass
-class WindowInputs:
-    """Host prologue of one fused window (see
-    :meth:`RoundProgram.window_inputs`)."""
-
-    sampled: list | None
-    idx: jax.Array | None
-    rngs: jax.Array
-    lrs: jax.Array
-    byz: tuple | None
-    k: int
-    n_real: int | None
-    static_key: Any = None
-    per_round: dict | None = None
-    #: cohort sharding: each round's deal of its mesh-padded set
-    #: (``[K, P]``; ``idx`` and ``rngs`` are in dealt order)
-    deal: jax.Array | None = None
 
 
 class RoundCtx:
@@ -637,10 +590,10 @@ def health_update_stats(upload, ref, new_params, w) -> dict:
     cosine similarity of each client update to the aggregated update,
     update-norm dispersion, and the global param / aggregate-update
     norms — all pure jnp on values the round body already holds, traced
-    with the round and threaded through the fused-K scan like any other
-    output. Names/semantics: ``obs/health.py UPDATE_STAT_NAMES`` (the
-    host-side publisher); batch_stats are running moments, not an
-    optimization direction, so the geometry is measured on params only.
+    with the round like any other output. Names/semantics:
+    ``obs/health.py UPDATE_STAT_NAMES`` (the host-side publisher);
+    batch_stats are running moments, not an optimization direction, so
+    the geometry is measured on params only.
 
     ``upload`` is the post-attack/post-codec payload the aggregation
     consumed — the wire's truth, which is exactly what a divergence
@@ -772,16 +725,15 @@ def _codec_stage(eng, stages: RoundStages, ctx: RoundCtx, upload, efs):
 
 class RoundProgram:
     """Compiles an engine's :class:`RoundStages` declaration into every
-    dispatch variant and owns the window planning + fallback reporting
-    that drives them. One instance per engine
-    (``FederatedEngine.program``); compiled programs are cached on the
-    ENGINE under the historic cache names
-    (``_fused_round_jit_cache`` etc.), so the one-compiled-program-per-
-    window pins keep reading the same place.
+    dispatch variant (``round_jit``, ``stream_jit``; each under the
+    stacked, sharded or folded placement) and owns the fallback
+    reporting. One instance per engine (``FederatedEngine.program``);
+    compiled programs are cached on the ENGINE (``_round_prog_cache``).
+    A dispatch holds one round.
 
     ``built`` counts program compilations (cache misses); ``dispatches``
-    counts compiled-program invocations — the bench's
-    dispatch-amortization evidence (bench.py ``round_program`` cell).
+    counts compiled-program invocations (bench.py ``round_program``
+    cell).
     """
 
     def __init__(self, eng, stages: RoundStages | None):
@@ -822,28 +774,11 @@ class RoundProgram:
 
     # ---------- fallback reporting ----------
 
-    def fused_fallback_key(self) -> str | None:
-        """Why the engine dispatches one round at a time even when
-        ``--rounds_per_dispatch K`` asks for fused windows — a
-        :data:`REASONS` key, or None when the declared stages support
-        the K-round scan driver. Resident-mode checks shared by every
-        declared engine: streaming feeds cross the host per round
-        (unless the engine fuses streamed windows), and the wire codec
-        accounts bytes on the host per round."""
-        if self.stages is None:
-            return "no-fused-body"
-        if self.eng.stream is not None \
-                and not self.eng.supports_fused_streaming:
-            return "streaming-host-data"
-        if self.eng.wire_spec is not None:
-            return "wire-codec-host-bytes"
-        return None
-
     def cohort_fallback_key(self) -> str | None:
         """Why the engine runs unsharded even when ``--client_mesh``
         asks for the cohort-sharded mesh — a :data:`REASONS` key, or
         None when the sharded path arms (mode checks shared by every
-        capable engine; mirrors the fused contract)."""
+        capable engine)."""
         eng = self.eng
         if self.stages is None or not eng.supports_cohort_sharding:
             return eng.cohort_fallback_key()
@@ -925,128 +860,6 @@ class RoundProgram:
             "one after another, each upload folded into the running "
             "weighted sum (placement %s)", FOLDED)
         return FOLDED
-
-    # ---------- window planning (ISSUE 4, absorbed from base.py) ----------
-
-    def dispatch_window(self, round_idx: int) -> int:
-        """Length of the fused window starting at ``round_idx``: grows
-        up to ``rounds_per_dispatch`` but stops so that any round with a
-        host-side hook — eval (``frequency_of_the_test``), checkpoint
-        (``checkpoint_every``), the final round, an engine-declared
-        extra hook — lands on the WINDOW BOUNDARY, where the driver runs
-        the hooks exactly as the sequential loop would have. Interior
-        rounds are hook-free by construction, so fusing changes no
-        observable behavior."""
-        eng = self.eng
-        f = eng.cfg.fed
-        K = max(1, int(f.rounds_per_dispatch))
-        extra = self.stages.extra_hooked if self.stages else None
-
-        def hooked(r: int) -> bool:
-            return (r % f.frequency_of_the_test == 0
-                    or r == f.comm_round - 1
-                    or (eng._ckpt_active()
-                        and (r + 1) % eng.cfg.checkpoint_every == 0)
-                    or (extra is not None and extra(r)))
-
-        k = 1
-        while (k < K and round_idx + k < f.comm_round
-               and not hooked(round_idx + k - 1)):
-            k += 1
-        return k
-
-    def window_sampling(self, round_idx: int, k: int
-                        ) -> tuple[list[np.ndarray], int]:
-        """Host-precomputed per-round cohorts for a fused window,
-        preserving the reference's ``np.random.seed(round_idx)``
-        sampling contract round by round. The scan needs one static
-        cohort size, so when a fault schedule varies the survivor count
-        mid-window the window shrinks to the maximal equal-size prefix
-        (still fused, still bit-identical cohorts)."""
-        eng = self.eng
-        sampled = [eng.client_sampling(r)
-                   for r in range(round_idx, round_idx + k)]
-        keep = 1
-        while keep < len(sampled) and \
-                len(sampled[keep]) == len(sampled[0]):
-            keep += 1
-        return sampled[:keep], keep
-
-    def window_inputs(self, round_idx: int, k: int) -> WindowInputs:
-        """Host prologue of a fused window: per-round cohorts (via
-        ``window_sampling``, which may shrink ``k``), the per-round log
-        lines the sequential loop would have emitted, and the stacked
-        device inputs for the scan — including the [K, C]-stacked
-        Byzantine attack plan when the fault schedule carries value
-        faults. With cohort sharding armed, ``idx`` and ``rngs`` cover
-        the mesh-padded per-round sets ([K, P]), each in its own round's
-        dealt order with ``deal`` beside them (``_cohort_deal``), while
-        the byz plan stays on the REAL sampled sets in the sampler's
-        order (the sharded round body puts the rows back and drops the
-        pad rows before the attack/defense tail); ``n_real`` is the static
-        real cohort size (None when unsharded). Engines with
-        ``window_extras`` (per-round operands, no cohort sampling) build
-        their own."""
-        if self.stages is not None and self.stages.window_extras:
-            return self.stages.window_extras(round_idx, k)
-        eng = self.eng
-        sampled, k = self.window_sampling(round_idx, k)
-        for off, s in enumerate(sampled):
-            eng.log.info("################ round %d: clients %s (fused "
-                         "window of %d)", round_idx + off, s.tolist(), k)
-        deal = None
-        if eng._cohort_on:
-            n_real = len(sampled[0])
-            ids = [eng._cohort_pad(s)[0] for s in sampled]
-            deals = [eng._cohort_deal(i, n_real)[0] for i in ids]
-            ids = [i[d] for i, d in zip(ids, deals)]
-            deal = jnp.asarray(np.stack(deals))
-        else:
-            ids, n_real = sampled, None
-        idx = jnp.asarray(np.stack(ids))
-        rngs = jnp.stack([eng.per_client_rngs(round_idx + off, s)
-                          for off, s in enumerate(ids)])
-        lrs = jnp.asarray([eng.round_lr(round_idx + off)
-                           for off in range(k)], jnp.float32)
-        byz = None
-        if eng._byz_on():
-            plans = [eng._byz_round_plan(round_idx + off, s)
-                     for off, s in enumerate(sampled)]
-            byz = tuple(jnp.stack([p[i] for p in plans])
-                        for i in range(4))
-        return WindowInputs(sampled=sampled, idx=idx, rngs=rngs, lrs=lrs,
-                            byz=byz, k=k, n_real=n_real, deal=deal)
-
-    def stream_window_inputs(self, round_idx: int, k: int):
-        """Host prologue of a fused STREAMED window (ISSUE 10): the
-        per-round cohorts (``window_sampling`` — may shrink ``k``), each
-        round's mesh-tiling padded id set (``stream_sampling`` — pads
-        train as zero-weight no-ops exactly like the round-granular
-        feed), the stacked per-round rngs/lrs over the PADDED ids (what
-        the streamed round body consumes), and the [K, P]-stacked byz
-        plan over the padded ids. Returns
-        ``(ids_per_round, rngs, lrs, byz, k, n_real)``."""
-        eng = self.eng
-        sampled, k = self.window_sampling(round_idx, k)
-        padded = [eng.stream_sampling(round_idx + off, sampled=s)
-                  for off, s in enumerate(sampled)]
-        ids_per_round = [p[0] for p in padded]
-        n_real = padded[0][1]
-        for off, s in enumerate(sampled):
-            eng.log.info("################ round %d (stream): clients %s "
-                         "(fused window of %d)", round_idx + off,
-                         s.tolist(), k)
-        rngs = jnp.stack([eng.per_client_rngs(round_idx + off, ids)
-                          for off, ids in enumerate(ids_per_round)])
-        lrs = jnp.asarray([eng.round_lr(round_idx + off)
-                           for off in range(k)], jnp.float32)
-        byz = None
-        if eng._byz_on():
-            plans = [eng._byz_round_plan(round_idx + off, ids)
-                     for off, ids in enumerate(ids_per_round)]
-            byz = tuple(jnp.stack([p[i] for p in plans])
-                        for i in range(4))
-        return ids_per_round, rngs, lrs, byz, k, n_real
 
     # ---------- the round body, composed from the declared stages ----------
 
@@ -1303,26 +1116,23 @@ class RoundProgram:
         obs_compute.note_compile(self.eng.name, label, recompile=n > 1)
 
     def _count_dispatches(self, jitted, label: str = "round",
-                          rounds: int = 1,
-                          health_stacked: bool = False):
+                          rounds: int = 1):
         """Wrap a compiled program so invocations count toward
         ``dispatches`` (the bench's per-engine dispatch evidence) and
-        feed the dispatch-boundary profiler (obs/compute.py): host
-        wall around the call — compile-dominated on the first
-        invocation (jit compiles at first call), enqueue thereafter —
-        plus ``rounds`` (K for fused windows) toward the MFU
-        numerator. No sync is added anywhere: the clock brackets the
-        ENQUEUE, and MFU divides by boundary-to-boundary wall where
-        the driver already blocked. ``.jit``/``.lower`` expose the
-        underlying executable for compile-text tests.
+        feed the dispatch-boundary profiler (obs/compute.py): host wall
+        around the call — compile-dominated on the first invocation (jit
+        compiles at first call), enqueue thereafter — plus ``rounds``
+        toward the MFU numerator. No sync is added anywhere: the clock
+        brackets the ENQUEUE, and MFU divides by boundary-to-boundary
+        wall where the driver already blocked. ``.jit``/``.lower``
+        expose the underlying executable for compile-text tests.
 
         When the training-health leg is armed, the program's trailing
         ``health_names`` outputs are stripped HERE and queued on the
         engine as device arrays (``_note_health`` — drained in the
         batched ``device_get`` at the next host boundary, never synced
         per dispatch), so every legacy driver/adapter sees its historic
-        arity. ``health_stacked`` marks the scan-fused variants whose
-        health outputs carry a leading [K] round axis."""
+        arity."""
         state = {"first": True}
         eng = self.eng
         health_names = self.health_names
@@ -1352,8 +1162,7 @@ class RoundProgram:
             state["first"] = False
             if health_names:
                 n_h = len(health_names)
-                eng._note_health(dict(zip(health_names, out[-n_h:])),
-                                 k=rounds, stacked=health_stacked)
+                eng._note_health(dict(zip(health_names, out[-n_h:])))
                 out = out[:-n_h]
             return out
 
@@ -1399,81 +1208,18 @@ class RoundProgram:
 
         return self.eng._plan_cached("_round_prog_cache", key, build)
 
-    def fused_jit(self, k: int, n_real: int | None = None,
-                  static_key=None, sharded: bool | None = None):
-        """K rounds as ONE dispatched program: a ``lax.scan`` over the
-        exact per-round body, consuming host-precomputed stacks of
-        sampling indices / per-client rngs / round lrs (+ the byz plan
-        and any declared per-round operands). Amortizes the per-dispatch
-        latency the sequential loop pays K times (PROFILE.md round 2).
-        Donates the carry; cached on the engine as
-        ``_fused_round_jit_cache`` (the one-compiled-program-per-window
-        pin reads it)."""
-        shard = sharded if sharded is not None else (n_real is not None)
-        key = (k, n_real, static_key, shard)
-        label = (f"fused_sharded_k{k}" if shard else f"fused_k{k}")
-
-        def build():
-            self._note_build(label, key)
-
-            def fused_round_fn(carry, data, consts, idx, rngs, lrs,
-                               byz=None, per_round=None, deal=None):
-                def one_round(c, xs):
-                    if self.stages.gathers_cohort:
-                        Xs, ys, ns = self._gather(data, xs["idx"])
-                    else:
-                        Xs, ys, ns = (data.X_train, data.y_train,
-                                      data.n_train)
-                    # per-step slices of the [K]-stacked per-round
-                    # operands, already in st.per_round order
-                    pr = tuple(xs["pr"]) if "pr" in xs else None
-                    new_carry, outs, _ = self._body(
-                        c, data, consts, Xs, ys, ns, xs.get("idx"),
-                        xs["rngs"], xs["lr"], None, xs.get("byz"), pr,
-                        static_key, n_real, shard, xs.get("deal"))
-                    return (tuple(new_carry[n]
-                                  for n in self.stages.carry),
-                            tuple(outs[o] for o in self.stages.outputs
-                                  + self.health_names))
-
-                xs = {"idx": idx, "rngs": rngs, "lr": lrs}
-                if byz is not None:
-                    xs["byz"] = byz
-                if per_round is not None:
-                    xs["pr"] = per_round
-                if deal is not None:
-                    xs["deal"] = deal
-                carry, outs = jax.lax.scan(one_round, tuple(carry), xs)
-                epi = self._epilogue(dict(zip(self.stages.carry, carry)),
-                                     data)
-                return (*carry, *epi, *outs)
-
-            return self._count_dispatches(jax.jit(
-                fused_round_fn,
-                donate_argnums=self.eng._donate_argnums(0)),
-                label=label, rounds=k, health_stacked=True)
-
-        return self.eng._plan_cached("_fused_round_jit_cache", key,
-                                     build)
-
-    def _reject_streamed_epilogue(self):
-        """The streamed programs have no resident federation data to
-        hand an epilogue stage (``_epilogue`` would receive data=None
-        and the fused scan drops the epilogue outputs entirely) — fail
-        loudly instead of miscompiling the declaration. An engine that
-        needs both keeps its streaming outside the builder (dpsgd's
-        chunked ``_round_streaming`` is the precedent)."""
+    def stream_jit(self):
+        """The streamed single-round program: shards arrive pre-gathered
+        (data/stream.py feeds the sampled clients' padded arrays), the
+        federation data never enters the program. It has no resident
+        data to hand an epilogue stage, so a declaration with one is
+        refused (an engine that needs both keeps its streaming outside
+        the builder: dpsgd's chunked ``_round_streaming``)."""
         if self.stages is not None and self.stages.epilogue is not None:
             raise ValueError(
                 f"{type(self.eng).__name__} declares an epilogue stage "
                 "and streams through the builder: the streamed round "
                 "program has no resident data for the epilogue")
-
-    def stream_jit(self):
-        """The streamed single-round program: shards arrive pre-gathered
-        (data/stream.py feeds the sampled clients' padded arrays), the
-        federation data never enters the program."""
-        self._reject_streamed_epilogue()
 
         def build():
             self._note_build("stream", ("stream",))
@@ -1493,90 +1239,3 @@ class RoundProgram:
 
         return self.eng._plan_cached("_round_prog_cache", ("stream",),
                                      build)
-
-    def fused_stream_jit(self, k: int):
-        """K STREAMED rounds as one dispatched program (ISSUE 10): a
-        ``lax.scan`` over the exact streamed per-round body, consuming
-        the window's prefetched ``[K, S, nmax, ...]`` shard stacks one
-        round per step. The carried state is donated like every round
-        program's; the uint8/int32 shard stacks are NOT — no output
-        shares their dtype/shape, so the donation would be unusable (XLA
-        warns and ignores it) and the buffers die at end of dispatch
-        anyway."""
-        self._reject_streamed_epilogue()
-        label = f"fused_stream_k{k}"
-
-        def build():
-            self._note_build(label, ("stream", k))
-
-            def fused_stream_round_fn(carry, consts, Xs, ys, ns, rngs,
-                                      lrs, byz=None):
-                def one_round(c, xs):
-                    new_carry, outs, _ = self._body(
-                        c, None, consts, xs["X"], xs["y"], xs["n"], None,
-                        xs["rngs"], xs["lr"], None, xs.get("byz"), None,
-                        None, None, False)
-                    return (tuple(new_carry[n]
-                                  for n in self.stages.carry),
-                            tuple(outs[o] for o in self.stages.outputs
-                                  + self.health_names))
-
-                xs = {"X": Xs, "y": ys, "n": ns, "rngs": rngs, "lr": lrs}
-                if byz is not None:
-                    xs["byz"] = byz
-                carry, outs = jax.lax.scan(one_round, tuple(carry), xs)
-                return (*carry, *outs)
-
-            return self._count_dispatches(jax.jit(
-                fused_stream_round_fn,
-                donate_argnums=self.eng._donate_argnums(0)),
-                label=label, rounds=k, health_stacked=True)
-
-        return self.eng._plan_cached("_fused_round_jit_cache",
-                                     ("stream", k), build)
-
-    # ---------- the fused window driver ----------
-
-    def run_window(self, carry: tuple, round_idx: int, k: int,
-                   consts: tuple = ()):
-        """Dispatch rounds ``[round_idx, round_idx + k)`` as one scan.
-        Sampling/rng/lr — and the Byzantine attack plan when the fault
-        schedule carries value faults — are precomputed on the host
-        round by round (the ``np.random.seed(round_idx)`` contract is
-        untouched). Returns ``(new_carry: tuple, epilogue: tuple,
-        outs: dict of [k]-stacked arrays, wi: WindowInputs)`` —
-        ``wi.k`` may shrink when the fault schedule varies the cohort
-        size (or an engine's per-round operands change shape). Queues
-        any ``n_bad`` output into the engine's batched non-finite
-        accounting."""
-        eng, st = self.eng, self.stages
-        # the window IS a host boundary pair (ISSUE 9): the prologue and
-        # the dispatch are separate host spans — "dispatch" measures the
-        # enqueue only (async dispatch races ahead; the sync lands at
-        # the next eval/flush boundary, never here)
-        with obs_trace.span("window", round=round_idx, k=k):
-            with obs_trace.span("window_host_prologue", round=round_idx):
-                wi = self.window_inputs(round_idx, k)
-                if wi.sampled is not None:
-                    eng._note_round_counts(wi.sampled,
-                                           int(wi.idx.shape[-1]))
-            with obs_trace.span("dispatch", round=round_idx, k=wi.k):
-                pr = (tuple(wi.per_round[n] for n in st.per_round)
-                      if wi.per_round is not None else None)
-                # engines that train the FULL cohort (gathers_cohort
-                # False) shard without mesh padding — n_real stays None
-                # and the armed mesh alone selects the sharded variant
-                shard = (wi.n_real is not None
-                         or (not st.gathers_cohort and eng._cohort_on))
-                out = self.fused_jit(wi.k, wi.n_real, wi.static_key,
-                                     sharded=shard)(
-                    carry, eng.data, consts, wi.idx, wi.rngs, wi.lrs,
-                    wi.byz, pr, wi.deal)
-        n_carry = len(st.carry)
-        n_epi = len(out) - n_carry - len(st.outputs)
-        new_carry = out[:n_carry]
-        epi = out[n_carry:n_carry + n_epi]
-        outs = dict(zip(st.outputs, out[n_carry + n_epi:]))
-        if "n_bad" in outs:
-            eng._note_nonfinite(outs["n_bad"])
-        return new_carry, epi, outs, wi

@@ -306,26 +306,69 @@ class SalientGradsEngine(FederatedEngine):
 
         return stream_round_call
 
-    # ---------- fused multi-round dispatch (ISSUE 4) ----------
-
-    def _run_fused_window(self, params, bstats, per_params, per_bstats,
-                          masks, round_idx: int, k: int):
-        """Dispatch rounds ``[round_idx, round_idx + k)`` as one scan
-        (program.run_window). Returns the new state, per-round sampled
-        sets (for the host-side stat accounting), the boundary round's
-        loss, and the actual window length."""
-        carry, _, outs, wi = self.program.run_window(
-            (params, bstats, per_params, per_bstats), round_idx, k,
-            consts=(masks,))
-        return (*carry, wi.sampled, outs["loss"][-1], wi.k)
-
-    def _eval_ckpt_hooks(self, round_idx, params, bstats, per_params,
-                         per_bstats, masks, loss, history):
-        """The sequential loop's per-round hook tail (eval cadence +
-        checkpoint), shared verbatim by the fused windows — which, by the
-        window planner's construction, reach here exactly on the rounds
-        the sequential loop would have evaluated/checkpointed."""
+    def _round_iteration(self, round_idx: int, state: tuple, masks,
+                         history, acct: tuple) -> tuple:
+        """One iteration of the round loop (resident or streamed): host
+        prologue, the dispatch of one round, the host-side accounting
+        and the boundary hooks (eval cadence + checkpoint). ``state`` is
+        ``(params, bstats, per_params, per_bstats)``; returns the new
+        one. The caller's ``round`` span covers the whole iteration; the
+        stages here are its children and take their round id from it
+        (obs/names.py)."""
         cfg = self.cfg
+        flops_per_sample, comm_params_per_client = acct
+        with obs_trace.span(obs_names.SPAN_ROUND_PROLOGUE):
+            sampled = self.client_sampling(round_idx)
+            self.log.info("################ round %d: clients %s",
+                          round_idx, sampled.tolist())
+            lr = self.round_lr(round_idx)
+            if self.stream is not None:
+                ids, n_real = self.stream_sampling(round_idx, sampled)
+                byz = self._byz_round_plan(round_idx, ids)
+                Xs, ys, ns = self.stream.get_train(ids, n_real)
+                if round_idx + 1 < cfg.fed.comm_round:
+                    # overlap next round's host read with this round
+                    self.stream.prefetch_train(
+                        *self.stream_sampling(round_idx + 1))
+            else:
+                # cohort sharding (ISSUE 6): padded gather ids for the
+                # sharded program; byz plan and byte accounting stay on
+                # the REAL sampled set (the body slices pads off)
+                ids, round_prog = self._cohort_round_prog(sampled)
+                byz = self._byz_round_plan(round_idx, sampled)
+                if self.wire_spec is not None:
+                    with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                        ref_host = jax.tree.map(
+                            np.asarray, {"params": state[0],
+                                         "batch_stats": state[1]})
+            rngs = self.per_client_rngs(round_idx, ids)
+            idx = jnp.asarray(ids)
+            self._note_round_counts(sampled, len(ids))
+        if self.stream is not None:
+            *state, loss, n_bad = self._round_stream_jit(
+                *state, Xs, ys, ns, masks, idx, rngs, lr, byz)
+        elif self.wire_spec is not None:
+            *state, loss, n_bad, u0 = round_prog(
+                *state, self.data, masks, idx, rngs, lr, byz)
+            with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
+                masks_host = {
+                    "params": jax.tree.map(np.asarray, masks),
+                    "batch_stats": jax.tree.map(
+                        np.ones_like, ref_host["batch_stats"])}
+                self.account_wire_bytes(
+                    jax.tree.map(np.asarray, u0), ref_host,
+                    masks_host=masks_host, n_uploads=len(sampled))
+        else:
+            *state, loss, n_bad = round_prog(
+                *state, self.data, masks, idx, rngs, lr, byz)
+        self._note_nonfinite(n_bad)
+        # host-side accounting (host data only — no device sync)
+        n_samples = float(np.sum(self._n_train_host[sampled]))
+        self.stat_info["sum_training_flops"] += (
+            flops_per_sample * cfg.optim.epochs * n_samples)
+        self.stat_info["sum_comm_params"] += (
+            comm_params_per_client * len(sampled))
+        params, bstats, per_params, per_bstats = state
         if round_idx % cfg.fed.frequency_of_the_test == 0 \
                 or round_idx == cfg.fed.comm_round - 1:
             m = self._eval_g(params, bstats)
@@ -345,82 +388,7 @@ class SalientGradsEngine(FederatedEngine):
                 "params": params, "batch_stats": bstats,
                 "per_params": per_params, "per_bstats": per_bstats,
                 "masks": masks, "history": history})
-
-    def _round_iteration(self, round_idx: int, state: tuple, masks,
-                         history, fuse: bool, acct: tuple):
-        """One iteration of the round loop (resident or streamed): host
-        prologue, the dispatch of one round or one fused window, the
-        host-side accounting and the boundary hooks. ``state`` is
-        ``(params, bstats, per_params, per_bstats)``; returns
-        ``(next_round_idx, state)``. The caller's ``round`` span covers
-        the whole iteration; the stages here are its children and take
-        their round id from it (obs/names.py)."""
-        cfg = self.cfg
-        flops_per_sample, comm_params_per_client = acct
-        k = self._dispatch_window(round_idx) if fuse else 1
-        if k > 1:
-            # run_window has its own prologue and dispatch spans
-            *state, window_sampled, loss, k = self._run_fused_window(
-                *state, masks, round_idx, k)
-            round_idx += k - 1  # boundary hooks below
-        else:
-            with obs_trace.span(obs_names.SPAN_ROUND_PROLOGUE):
-                sampled = self.client_sampling(round_idx)
-                self.log.info("################ round %d: clients %s",
-                              round_idx, sampled.tolist())
-                lr = self.round_lr(round_idx)
-                if self.stream is not None:
-                    ids, n_real = self.stream_sampling(round_idx, sampled)
-                    byz = self._byz_round_plan(round_idx, ids)
-                    Xs, ys, ns = self.stream.get_train(ids, n_real)
-                    if round_idx + 1 < cfg.fed.comm_round:
-                        # overlap next round's host read with this round
-                        self.stream.prefetch_train(
-                            *self.stream_sampling(round_idx + 1))
-                else:
-                    # cohort sharding (ISSUE 6): padded gather ids for
-                    # the sharded program; byz plan and byte accounting
-                    # stay on the REAL sampled set (the body slices pads
-                    # off)
-                    ids, round_prog = self._cohort_round_prog(sampled)
-                    byz = self._byz_round_plan(round_idx, sampled)
-                    if self.wire_spec is not None:
-                        with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
-                            ref_host = jax.tree.map(
-                                np.asarray, {"params": state[0],
-                                             "batch_stats": state[1]})
-                rngs = self.per_client_rngs(round_idx, ids)
-                idx = jnp.asarray(ids)
-                self._note_round_counts([sampled], len(ids))
-            if self.stream is not None:
-                *state, loss, n_bad = self._round_stream_jit(
-                    *state, Xs, ys, ns, masks, idx, rngs, lr, byz)
-            elif self.wire_spec is not None:
-                *state, loss, n_bad, u0 = round_prog(
-                    *state, self.data, masks, idx, rngs, lr, byz)
-                with obs_trace.span(obs_names.SPAN_CODEC_SYNC):
-                    masks_host = {
-                        "params": jax.tree.map(np.asarray, masks),
-                        "batch_stats": jax.tree.map(
-                            np.ones_like, ref_host["batch_stats"])}
-                    self.account_wire_bytes(
-                        jax.tree.map(np.asarray, u0), ref_host,
-                        masks_host=masks_host, n_uploads=len(sampled))
-            else:
-                *state, loss, n_bad = round_prog(
-                    *state, self.data, masks, idx, rngs, lr, byz)
-            self._note_nonfinite(n_bad)
-            window_sampled = [sampled]
-        # per-round host-side accounting (host data only — no device
-        # sync), identical for a single round and a fused window
-        for s in window_sampled:
-            n_samples = float(np.sum(self._n_train_host[s]))
-            self.stat_info["sum_training_flops"] += (
-                flops_per_sample * cfg.optim.epochs * n_samples)
-            self.stat_info["sum_comm_params"] += (
-                comm_params_per_client * len(s))
-        self._eval_ckpt_hooks(round_idx, *state, masks, loss, history)
-        return round_idx + 1, tuple(state)
+        return tuple(state)
 
     def train(self):
         cfg = self.cfg
@@ -468,16 +436,13 @@ class SalientGradsEngine(FederatedEngine):
             history = restored["history"]
         if self.stream is not None:
             self.stream.prefetch_train(*self.stream_sampling(start))
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
         state = (params, bstats, per_params, per_bstats)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
+        for round_idx in range(start, cfg.fed.comm_round):
             # one span for the whole iteration, sampling to checkpoint;
             # its children carry the same round id (obs/names.py)
             with obs_trace.span(obs_names.SPAN_ROUND, round=round_idx):
-                round_idx, state = self._round_iteration(
-                    round_idx, state, masks, history, fuse,
+                state = self._round_iteration(
+                    round_idx, state, masks, history,
                     (flops_per_sample, comm_params_per_client))
         params, bstats, per_params, per_bstats = state
         self._flush_nonfinite(cfg.fed.comm_round - 1)

@@ -30,9 +30,8 @@ The round is DECLARED through the round-program builder
 the train stage, the overlap-count average is a CUSTOM aggregate stage
 (it replaces the weighted mean — order-statistic defenses have nothing
 to select over a count-quotient), and the personal-mask scatter is the
-update stage. The builder supplies fused ``--rounds_per_dispatch K``
-windows (per-round ``up_nnz``/dist/accept scalars come back [K]-stacked)
-and ``--client_mesh`` cohort sharding of the per-client composite — the
+update stage. The builder supplies ``--client_mesh`` cohort sharding of
+the per-client composite — the
 two-call epoch split hoists BOTH calls' permutations out of the
 partition (ctx.rng_after_local_train replays the rng chain).
 """
@@ -294,21 +293,6 @@ class SubFedAvgEngine(FederatedEngine):
 
     # ---------- driver ----------
 
-    def _account_round(self, sampled, up_nnz, n_params, flops_per_sample
-                       ) -> None:
-        """Per-round host-side stat accounting, shared by the per-round
-        and fused-window drivers. ``up_nnz`` is the round's device
-        scalar (already synced by the caller)."""
-        n_samples = float(np.sum(self._n_train_host[sampled]))
-        self.stat_info["sum_training_flops"] += (
-            flops_per_sample * self.cfg.optim.epochs * n_samples)
-        # down: the dense w_global per sampled client; up: the pruned
-        # client models' TRUE nonzero count (reference nonzero-comm
-        # metric, model_trainer.py:49-53) — computed inside the round
-        # program, so the "device pull" is one scalar per round
-        self.stat_info["sum_comm_params"] += (
-            n_params * len(sampled) + float(up_nnz))
-
     def train(self):
         cfg = self.cfg
         gs = self.init_global_state()
@@ -327,61 +311,46 @@ class SubFedAvgEngine(FederatedEngine):
             mask_pers, history = restored["mask_pers"], restored["history"]
         if self.stream is not None:
             self.stream.prefetch_train(*self.stream_sampling(start))
-        fuse = (cfg.fed.rounds_per_dispatch > 1
-                and self.fused_fallback_reason() is None)
-        round_idx = start
-        while round_idx < cfg.fed.comm_round:
-            k = self._dispatch_window(round_idx) if fuse else 1
-            if k > 1:
-                ((params, bstats, mask_pers), _, outs,
-                 wi) = self.program.run_window(
-                    (params, bstats, mask_pers), round_idx, k)
-                k = wi.k
-                loss, mean_dist = outs["loss"][-1], outs["mean_dist"][-1]
-                n_accept = outs["n_accept"][-1]
-                # one batched sync for the window's K per-round upload
-                # nnz scalars (the sequential loop syncs one per round)
-                nnz_rounds = np.asarray(jax.device_get(outs["up_nnz"]))
-                for off, s in enumerate(wi.sampled):
-                    self._account_round(s, nnz_rounds[off], n_params,
-                                        flops_per_sample)
-                round_idx += k - 1
+        for round_idx in range(start, cfg.fed.comm_round):
+            sampled = self.client_sampling(round_idx)
+            self.log.info("################ round %d: clients %s",
+                          round_idx, sampled.tolist())
+            if self.stream is not None:
+                fed_ids, n_real = self.stream_sampling(round_idx, sampled)
+                rngs = self.per_client_rngs(round_idx, fed_ids)
+                Xs, ys, ns = self.stream.get_train(fed_ids, n_real)
+                if round_idx + 1 < cfg.fed.comm_round:
+                    self.stream.prefetch_train(
+                        *self.stream_sampling(round_idx + 1))
+                (params, bstats, mask_pers, loss, mean_dist, n_accept,
+                 up_nnz) = self._round_stream_jit(
+                    params, bstats, mask_pers, Xs, ys, ns,
+                    jnp.asarray(fed_ids), rngs, self.round_lr(round_idx))
             else:
-                sampled = self.client_sampling(round_idx)
-                self.log.info("################ round %d: clients %s",
-                              round_idx, sampled.tolist())
-                if self.stream is not None:
-                    fed_ids, n_real = self.stream_sampling(round_idx,
-                                                           sampled)
-                    rngs = self.per_client_rngs(round_idx, fed_ids)
-                    Xs, ys, ns = self.stream.get_train(fed_ids, n_real)
-                    if round_idx + 1 < cfg.fed.comm_round:
-                        self.stream.prefetch_train(
-                            *self.stream_sampling(round_idx + 1))
-                    (params, bstats, mask_pers, loss, mean_dist, n_accept,
-                     up_nnz) = self._round_stream_jit(
-                        params, bstats, mask_pers, Xs, ys, ns,
-                        jnp.asarray(fed_ids), rngs,
-                        self.round_lr(round_idx))
-                else:
-                    # cohort sharding (ISSUE 6): the sharded program
-                    # gathers the mesh-padded set; the accounting stays
-                    # on the REAL sampled set
-                    ids, round_prog = self._cohort_round_prog(sampled)
-                    rngs = self.per_client_rngs(round_idx, ids)
-                    (params, bstats, mask_pers, loss, mean_dist, n_accept,
-                     up_nnz) = round_prog(
-                        params, bstats, mask_pers, self.data,
-                        jnp.asarray(ids), rngs, self.round_lr(round_idx))
-                self._account_round(sampled, up_nnz, n_params,
-                                    flops_per_sample)
+                # cohort sharding (ISSUE 6): the sharded program gathers
+                # the mesh-padded set; the accounting stays on the REAL
+                # sampled set
+                ids, round_prog = self._cohort_round_prog(sampled)
+                rngs = self.per_client_rngs(round_idx, ids)
+                (params, bstats, mask_pers, loss, mean_dist, n_accept,
+                 up_nnz) = round_prog(
+                    params, bstats, mask_pers, self.data,
+                    jnp.asarray(ids), rngs, self.round_lr(round_idx))
+            # host-side stat accounting. down: the dense w_global per
+            # sampled client; up: the pruned client models' TRUE nonzero
+            # count (reference nonzero-comm metric,
+            # model_trainer.py:49-53) — computed inside the round
+            # program, so the device pull is one scalar per round
+            n_samples = float(np.sum(self._n_train_host[sampled]))
+            self.stat_info["sum_training_flops"] += (
+                flops_per_sample * cfg.optim.epochs * n_samples)
+            self.stat_info["sum_comm_params"] += (
+                n_params * len(sampled) + float(up_nnz))
             self._mask_pers = mask_pers
             # NaN-poisoned-mask diagnosability (ADVICE r5): a NaN in the
             # trained params poisons fake_prune's percentile into an
             # all-False m2; if the accept-test then fires, the client's
             # personal mask collapses — make it visible immediately
-            # (fused windows check once per window, at the boundary the
-            # driver already syncs)
             self.warn_if_masks_collapsed(mask_pers, round_idx)
             if round_idx % cfg.fed.frequency_of_the_test == 0 \
                     or round_idx == cfg.fed.comm_round - 1:
@@ -405,7 +374,6 @@ class SubFedAvgEngine(FederatedEngine):
             self.maybe_checkpoint(round_idx, {
                 "params": params, "batch_stats": bstats,
                 "mask_pers": mask_pers, "history": history})
-            round_idx += 1
         self._flush_nonfinite(cfg.fed.comm_round - 1)
         m_person = self.eval_masked_global(params, bstats, mask_pers)
         self.log.metrics(-1, personal=m_person)

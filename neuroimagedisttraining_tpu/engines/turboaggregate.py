@@ -69,8 +69,8 @@ class TurboAggregateEngine(FedAvgEngine):
     def round_stages(self):
         # no declared stages: the round is a host-driven two-stage
         # dispatch (train program -> MPC share/aggregate program with a
-        # per-round host-side mask seed), which the scan-fused builder
-        # cannot express — the overrides below name the table reasons
+        # per-round host-side mask seed), which the builder cannot
+        # express — the override below names the table reason
         return None
 
     def cohort_fallback_key(self) -> str | None:
@@ -152,13 +152,6 @@ class TurboAggregateEngine(FedAvgEngine):
     def _train_only_stream_jit(self):
         return jax.jit(self._train_only_body,
                        donate_argnums=self._donate_argnums(1))
-
-    def fused_fallback_key(self) -> str | None:
-        # overrides FedAvg's: even the device MPC backend is a host-driven
-        # two-stage dispatch (train program -> share/aggregate program with
-        # a per-round host-side mask seed), and the host backend crosses
-        # the process boundary by design
-        return "mpc-host-stage"
 
     @functools.cached_property
     def _secure_agg_jit(self):

@@ -6,10 +6,10 @@ Every chip session so far re-ran a prose checklist (PROFILE.md
 rounds 8/9: "run precision bench at flagship shape", "re-read mask_ms",
 "sweep remat x batch") by hand and pasted numbers back into markdown.
 This module makes the session a FUNCTION: each :class:`Probe` names one
-config cell (precision x remat x fused x client_mesh x
-rounds_per_dispatch), the driver runs it through the SHIPPED engine
-driver (``engine.train()`` — the same window planner / fused scan /
-sharded dispatch path production runs, not a bench-only loop) with the
+config cell (precision x remat x fused x client_mesh x batch), the
+driver runs it through the SHIPPED engine driver (``engine.train()`` —
+the same round loop / sharded dispatch path production runs, not a
+bench-only loop) with the
 dispatch-boundary profiler armed (obs/compute.py), and the session
 emits ``bench_matrix/profile_session.json``:
 
@@ -60,7 +60,7 @@ __all__ = ["Probe", "default_manifest", "load_manifest", "run_probe",
 #: is a spelling error and fails loudly at load (declarative probes
 #: must not silently ignore a knob)
 CELL_KEYS = ("precision", "fused_update", "remat", "client_mesh",
-             "rounds_per_dispatch", "batch")
+             "batch")
 
 #: legal remat spellings in a cell: the CLI policy strings plus the
 #: historic manifest booleans (True -> full remat, False -> off)
@@ -97,9 +97,6 @@ def validate_cell_value(key: str, value) -> None:
     elif key == "client_mesh":
         if isinstance(value, bool) or not isinstance(value, int) or value < 0:
             die("an int >= 0")
-    elif key == "rounds_per_dispatch":
-        if isinstance(value, bool) or not isinstance(value, int) or value < 1:
-            die("an int >= 1")
     elif key == "batch":
         if isinstance(value, bool) or not isinstance(value, int) or value < 1:
             die("an int >= 1")
@@ -133,16 +130,14 @@ class Probe:
 def default_manifest(n_devices: int = 1) -> tuple[Probe, ...]:
     """PROFILE.md's queued probe list, declared (round-9 items 1/2/4):
     the precision step-ratio pair, the fused-update delta, the remat
-    product, the fused-dispatch amortization, and — when a client mesh
-    is available — the cohort-sharded dispatch. One cell each."""
+    product, and — when a client mesh is available — the
+    cohort-sharded dispatch. One cell each."""
     probes = [
         Probe("fp32_baseline", {"precision": "fp32"}),
         Probe("bf16", {"precision": "bf16_mixed"}),
         Probe("bf16_fused", {"precision": "bf16_mixed",
                              "fused_update": True}),
         Probe("bf16_remat", {"precision": "bf16_mixed", "remat": True}),
-        Probe("fused_dispatch_k4", {"precision": "fp32",
-                                    "rounds_per_dispatch": 4}),
     ]
     if n_devices > 1:
         probes.append(Probe("cohort_sharded",
@@ -229,8 +224,6 @@ def run_probe(probe: Probe, meta: dict, fed, log) -> dict:
         data=DataConfig(dataset="synthetic"), optim=optim,
         fed=FedConfig(client_num_in_total=meta["clients"],
                       comm_round=meta["rounds"],
-                      rounds_per_dispatch=int(
-                          cell.get("rounds_per_dispatch", 1)),
                       client_mesh=cm,
                       frequency_of_the_test=10 ** 9),
         log_dir="/tmp/nidt_profile", tag=f"probe-{probe.name}")

@@ -4,7 +4,7 @@ Frostig et al. 2018 (PAPERS.md, JAX/SysML): under asynchronous dispatch
 the host thread races ahead of the accelerator, so host observability is
 only meaningful at the host<->XLA seams the dispatch model defines — a
 span here measures HOST time between dispatch boundaries (enqueue a
-fused window, block on an eval result), never device time, and must
+round program, block on an eval result), never device time, and must
 never ADD a sync to read a clock. The complementary device timeline is
 ``jax.profiler`` (``--profile_dir``); the adapter below opens a matching
 ``jax.profiler.TraceAnnotation`` per span so the two line up in one

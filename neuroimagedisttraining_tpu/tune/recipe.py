@@ -54,7 +54,6 @@ RECIPE_KEYS = {
     "fused_update": "--fused_update",
     "remat": "--remat",
     "client_mesh": "--client_mesh",
-    "rounds_per_dispatch": "--rounds_per_dispatch",
     "batch": "--batch_size",
 }
 

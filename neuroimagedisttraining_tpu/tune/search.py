@@ -112,9 +112,9 @@ def virtual_measure(cell: dict, fidelity: int, seed: int) -> dict:
     cell alone plus sha256-seeded noise that SHRINKS with fidelity
     (short screens are noisier than committed windows — the property
     successive halving exists to exploit). Prices the measured
-    effects: bf16's step ratio, the fused SGD tail, dispatch
-    amortization, near-linear client-mesh scaling, batch saturation,
-    and remat's recompute tax."""
+    effects: bf16's step ratio, the fused SGD tail, near-linear
+    client-mesh scaling, batch saturation, and remat's recompute
+    tax."""
     fp = cell_fingerprint(cell)
     h = hashlib.sha256(
         f"virtual:{int(seed)}:{fp}:{int(fidelity)}".encode()).digest()
@@ -124,8 +124,6 @@ def virtual_measure(cell: dict, fidelity: int, seed: int) -> dict:
         score *= 1.55
     if cell.get("fused_update"):
         score *= 1.12
-    rpd = int(cell.get("rounds_per_dispatch", 1))
-    score *= 1.0 + 0.06 * (rpd - 1)
     cm = int(cell.get("client_mesh", 0))
     if cm > 1:
         score *= 1.0 + 0.45 * (cm - 1)

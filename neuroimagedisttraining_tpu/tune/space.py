@@ -67,7 +67,6 @@ DEFAULT_AXES: tuple[tuple[str, tuple], ...] = (
     ("fused_update", (False, True)),
     ("remat", ("none", "stem")),
     ("client_mesh", (0, 2)),
-    ("rounds_per_dispatch", (1, 4)),
     ("batch", (4, 8, 16)),
 )
 

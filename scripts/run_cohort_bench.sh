@@ -3,11 +3,11 @@
 #
 # Runs bench.py in its BENCH_COHORT_DEVICES mode: per-round wall time vs C
 # for the sequential C-loop / the cohort-SHARDED program / the shipped
-# vmapped round, the flagship 21-site fedavg+salientgrads cells, the K=4
-# one-dispatch-per-window pin, and salientgrads_mask_ms under the sharded
-# phase-1 driver. Defaults provision an 8-VIRTUAL-device CPU mesh on this
-# host — treat the SLOPES and the one-dispatch pin as the stable claims
-# (the absolute sharded speedup is a TPU-session measurement); override
+# vmapped round, the flagship 21-site fedavg+salientgrads cells, and
+# salientgrads_mask_ms under the sharded phase-1 driver. Defaults
+# provision an 8-VIRTUAL-device CPU mesh on this host — treat the SLOPES
+# as the stable claim (the absolute sharded speedup is a TPU-session
+# measurement); override
 # BENCH_COHORT_VIRTUAL=0 and the shape/model knobs on a real chip.
 set -euo pipefail
 cd "$(dirname "$0")/.."

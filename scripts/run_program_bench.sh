@@ -2,14 +2,11 @@
 # Round-program builder bench cell (ISSUE 11) ->
 # bench_matrix/round_program.json
 #
-# Runs bench.py in its BENCH_ROUND_PROGRAM mode: per-engine dispatch
-# counts and per-round wall for K=1 per-round loops vs K=4 fused windows
-# compiled by engines/program.py — including the engines the builder put
-# on the fused path for the first time (ditto, dpsgd, subavg) and the
-# fedfomo fallback reference. The DISPATCH COUNTS and the
-# one-compiled-program-per-window evidence are the stable claims on this
-# CPU harness; the wall ratio scales with per-dispatch latency and is
-# not measured on the current chip.
+# Runs bench.py in its BENCH_ROUND_PROGRAM mode: per-engine dispatch and
+# compile counts and per-round wall of the round loop compiled by
+# engines/program.py (fedavg, ditto, dpsgd, subavg) beside fedfomo's own
+# per-round jits. The counts (one dispatch a round, one compiled program
+# a run) are the stable claim on this CPU harness.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 mkdir -p bench_matrix

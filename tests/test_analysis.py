@@ -1317,13 +1317,13 @@ def test_round_program_reason_must_be_table_key():
             def train(self):
                 pass
 
-            def fused_fallback_key(self):
+            def cohort_fallback_key(self):
                 return {key}
         """
     fs = lint(base.format(key="'my ad-hoc reason string'"),
               path="pkg/engines/mod.py", rules=["round-program-reason"])
     assert rules_of(fs) == ["round-program-reason"]
-    assert lint(base.format(key="'mpc-host-stage'"),
+    assert lint(base.format(key="'mpc-host-boundary'"),
                 path="pkg/engines/mod.py",
                 rules=["round-program-reason"]) == []
     assert lint(base.format(key="None"),
@@ -1337,6 +1337,7 @@ def test_round_program_reason_keys_parse_from_source():
     )
 
     keys = _reason_keys()
-    assert "no-fused-body" in keys
-    assert "mpc-host-stage" in keys
+    assert "no-sharded-body" in keys
+    assert "mpc-host-boundary" in keys
+    assert not any("fused" in k for k in keys)
     assert "gossip-mesh-collectives" in keys

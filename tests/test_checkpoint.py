@@ -80,22 +80,36 @@ def _kill_after_round(ckpt_dir, keep_round):
             os.unlink(os.path.join(ckpt_dir, f"ckpt_{r:08d}.msgpack"))
 
 
-def test_resume_bitwise_identical_fedavg(tmp_path, synthetic_cohort):
+@pytest.mark.parametrize("algorithm,keys", [
+    ("fedavg", ("params", "batch_stats")),
+    ("salientgrads", ("params", "batch_stats", "masks")),
+    ("ditto", ("params", "personal_params")),
+    ("subavg", ("params", "batch_stats", "mask_pers")),
+    ("dpsgd", ("personal_params", "global_params")),
+    ("local", ("personal_params", "personal_batch_stats")),
+], ids=["fedavg", "salientgrads", "ditto", "subavg", "dpsgd", "local"])
+def test_resume_bitwise_identical(tmp_path, synthetic_cohort, algorithm,
+                                  keys):
     """Run 4 rounds checkpointed, 'kill' back to the round-1 checkpoint,
-    resume rounds 2-3; final params must be BITWISE identical."""
+    restore into a fresh engine and finish rounds 2-3: every engine on
+    the one round loop ends on BITWISE the uninterrupted run's state,
+    with the same history (the restored rounds + the replayed ones)."""
     ckpt_dir = str(tmp_path / "ck")
     eng_a = _engine_with_ckpt(tmp_path, synthetic_cohort, ckpt_dir, 4,
-                              "fedavg")
+                              algorithm)
     res_a = eng_a.train()
     assert ckpt.list_checkpoints(ckpt_dir) == [1, 3]
     _kill_after_round(ckpt_dir, 1)
     eng_b = _engine_with_ckpt(tmp_path, synthetic_cohort, ckpt_dir, 4,
-                              "fedavg")
+                              algorithm)
     res_b = eng_b.train()
     assert len(res_b["history"]) == 4  # restored history + replayed rounds
-    for leaf_b, leaf_a in zip(jax.tree.leaves(res_b["params"]),
-                              jax.tree.leaves(res_a["params"])):
-        np.testing.assert_array_equal(np.asarray(leaf_b), np.asarray(leaf_a))
+    assert res_b["history"] == res_a["history"]
+    for key in keys:
+        for leaf_b, leaf_a in zip(jax.tree.leaves(res_b[key]),
+                                  jax.tree.leaves(res_a[key])):
+            np.testing.assert_array_equal(np.asarray(leaf_b),
+                                          np.asarray(leaf_a))
 
 
 @pytest.mark.slow

@@ -16,14 +16,13 @@ measurements force):
     away by design and would resurface here as 1e-0-level loss
     divergence if it regressed).
 (b) MESH-WIDTH INDEPENDENCE to the same ~1 ulp through different pad
-    counts (21 real sites pad to 22 rows on 2 devices, 24 on 8);
-    exactly-bitwise equality holds where the compiled module is shared:
-    a K=4 fused window == four single sharded dispatches, BITWISE.
-(c) K=4 fused windows, the Byzantine attack/defense tail, and the wire
-    codec's EF stacks all compose on the sharded path under (a)/(b).
+    counts (21 real sites pad to 22 rows on 2 devices, 24 on 8).
+(c) Round after round, each with its own deal, is ONE compiled program;
+    the Byzantine attack/defense tail and the wire codec's EF stacks
+    compose on the sharded path under (a)/(b).
 (d) Engines/modes without a sharded round body fall back to the
-    unsharded round with a logged reason (the fused-dispatch pattern);
-    config mismatches fail loudly at startup.
+    unsharded round with a logged reason; config mismatches fail loudly
+    at startup.
 """
 
 import jax
@@ -332,59 +331,43 @@ def test_sharded_train_mesh_width_independent_salientgrads(tmp_path,
 
 
 # ---------------------------------------------------------------------------
-# (c) K=4 fused windows on the sharded path
+# (c) four sharded rounds, four deals, one program
 # ---------------------------------------------------------------------------
 
-def test_sharded_fused_k4_window_bitwise(tmp_path, cohort21):
-    """ONE dispatched program per fused window on the sharded path: a
-    K=4 window equals four single sharded dispatches bitwise (same
-    compile context), and its losses equal the sequential C-loop's
-    bitwise. frac=0.5 keeps per-round sampling (and the mesh pad of each
-    10-client cohort to 16 rows) load-bearing."""
-    eng = _engine(tmp_path, cohort21, "fedavg", comm_round=4,
-                  freq=4, frac=0.5, rounds_per_dispatch=4, tag="fk")
-    gs = eng.init_global_state()
-    p, b = gs.params, gs.batch_stats
-    losses = []
+def test_sharded_rounds_one_program_across_deals(tmp_path, cohort21):
+    """Four sharded rounds on a 4-device mesh, each with its own sampled
+    set (frac=0.5: 10 clients padded to 12 rows) and its own deal, are
+    four dispatches of ONE compiled program (the deal is an operand),
+    and their losses follow the stacked single-device run's: round 0
+    from identical state to the loss ulp, the later rounds to the float
+    noise the ~1-ulp state residue feeds back through training."""
+    eng = _engine(tmp_path, cohort21, "fedavg", client_mesh=4, n_dev=4,
+                  comm_round=4, freq=4, frac=0.5, tag="d4")
+    ref = _engine(tmp_path, cohort21, "fedavg", client_mesh=0, n_dev=1,
+                  comm_round=4, freq=4, frac=0.5, tag="d1")
+    assert eng._cohort_on and not ref._cohort_on
+    gs, gr = eng.init_global_state(), ref.init_global_state()
+    p, b, rp, rb = gs.params, gs.batch_stats, gr.params, gr.batch_stats
+    deals, losses, ref_losses = [], [], []
     for r in range(4):
         sampled = eng.client_sampling(r)
+        deals.append(tuple(eng._cohort_deal(
+            *eng._cohort_pad(sampled))[0].tolist()))
         ids, round_prog = eng._cohort_round_prog(sampled)
         p, b, loss, _ = round_prog(
             p, b, eng.data, jnp.asarray(ids),
             eng.per_client_rngs(r, ids), eng.round_lr(r))
         losses.append(float(loss))
-
-    fz = _engine(tmp_path, cohort21, "fedavg", comm_round=4, freq=4,
-                 frac=0.5, rounds_per_dispatch=4, tag="fk2")
-    gs2 = fz.init_global_state()
-    fp, fb, last_loss, k = fz._run_fused_window(gs2.params,
-                                                gs2.batch_stats, 0, 4)
-    assert k == 4
-    assert float(last_loss) == losses[-1]
-    _assert_trees_bitwise((p, b), (fp, fb))
-    # the window is ONE compiled program: exactly one cache entry for
-    # this (k, n_real) plan, dispatched once
-    assert len(fz.__dict__["_fused_round_jit_cache"]) == 1
-
-
-@pytest.mark.slow
-def test_sharded_fused_window_losses_match_sequential(tmp_path, cohort21):
-    """Across a K=4 window the per-round ~1-ulp state residue feeds back
-    through training, so the window's LAST loss matches the sequential
-    C-loop's to float noise rather than bitwise (round-1-from-identical-
-    state losses are pinned bitwise above)."""
-    sq = _engine(tmp_path, cohort21, "fedavg", comm_round=4, freq=4,
-                 frac=0.5, rounds_per_dispatch=4, seq=True, tag="fsq")
-    gs = sq.init_global_state()
-    _, _, loss_sq, k = sq._run_fused_window(gs.params, gs.batch_stats,
-                                            0, 4)
-    sh = _engine(tmp_path, cohort21, "fedavg", comm_round=4, freq=4,
-                 frac=0.5, rounds_per_dispatch=4, tag="fsh")
-    gs2 = sh.init_global_state()
-    _, _, loss_sh, k2 = sh._run_fused_window(gs2.params, gs2.batch_stats,
-                                             0, 4)
-    assert k == k2 == 4
-    np.testing.assert_allclose(float(loss_sq), float(loss_sh), rtol=1e-4)
+        rp, rb, rloss, _ = ref._round_jit(
+            rp, rb, ref.data, jnp.asarray(sampled),
+            ref.per_client_rngs(r, sampled), ref.round_lr(r))
+        ref_losses.append(float(rloss))
+    assert len(set(deals)) > 1  # the rounds were dealt differently
+    assert eng.program.dispatches == 4
+    assert eng.program.built == 1
+    np.testing.assert_allclose(losses[0], ref_losses[0],
+                               rtol=LOSS_ULP_RTOL)
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -118,13 +118,12 @@ def test_lower_train_step_memory_analysis_smoke():
 # ---------------------------------------------------------------------------
 
 
-def _tiny_engine(tmp_path, tag, rounds=2, K=1):
+def _tiny_engine(tmp_path, tag, rounds=2):
     cfg = ExperimentConfig(
         model="3dcnn_tiny", num_classes=1, algorithm="fedavg",
         data=DataConfig(dataset="synthetic"),
         optim=OptimConfig(lr=1e-3, batch_size=8, epochs=1),
         fed=FedConfig(client_num_in_total=2, comm_round=rounds,
-                      rounds_per_dispatch=K,
                       frequency_of_the_test=10 ** 9),
         log_dir=str(tmp_path), tag=tag)
     kx, ky = jax.random.split(jax.random.key(3))
@@ -403,9 +402,7 @@ def test_profile_session_end_to_end(tmp_path, monkeypatch):
     monkeypatch.setenv("PROFILE_ROUNDS", "2")
     manifest = (
         obs_probe.Probe("fp32_baseline", {"precision": "fp32"}),
-        obs_probe.Probe("fused_dispatch_k4",
-                        {"precision": "fp32",
-                         "rounds_per_dispatch": 4}),
+        obs_probe.Probe("bf16", {"precision": "bf16_mixed"}),
     )
     out = tmp_path / "profile_session.json"
     doc = obs_probe.run_session(manifest, str(out))

@@ -68,7 +68,7 @@ def cohort64():
                                    num_sites=4, seed=0)
 
 
-def _engine(tmp_path, cohort, algorithm="fedavg", health=True, K=1,
+def _engine(tmp_path, cohort, algorithm="fedavg", health=True,
             comm_round=2, freq=2, client_mesh=0, tag="h", seed=1024,
             metrics_out="", **fed_kw):
     cfg = ExperimentConfig(
@@ -77,8 +77,7 @@ def _engine(tmp_path, cohort, algorithm="fedavg", health=True, K=1,
         data=DataConfig(dataset="synthetic", partition_method="site"),
         optim=OptimConfig(lr=1e-3, batch_size=8, epochs=1),
         fed=FedConfig(client_num_in_total=4, comm_round=comm_round,
-                      frequency_of_the_test=freq,
-                      rounds_per_dispatch=K, client_mesh=client_mesh,
+                      frequency_of_the_test=freq, client_mesh=client_mesh,
                       **fed_kw),
         log_dir=str(tmp_path), tag=tag, health_stats=health,
         metrics_out=metrics_out)
@@ -184,15 +183,38 @@ def test_armed_vs_disarmed_bitwise_same_counts(tmp_path, cohort64):
     assert _gauge_value(N.HEALTH_ROUND, engine="fedavg") == 1.0
 
 
-def test_fused_k4_matches_k1_with_health_armed(tmp_path, cohort64):
-    r1 = _engine(tmp_path, cohort64, health=True, K=1, comm_round=4,
-                 freq=4, tag="k1").train()
-    e4 = _engine(tmp_path, cohort64, health=True, K=4, comm_round=4,
-                 freq=4, tag="k4")
-    r4 = e4.train()
-    _bitwise(r1["params"], r4["params"])
-    _bitwise(r1["batch_stats"], r4["batch_stats"])
-    # the fused window drained per-round health rows up to the boundary
+def test_four_rounds_queue_one_unstacked_entry_each(tmp_path, cohort64):
+    """Four rounds between two host boundaries: armed, every dispatch
+    queues ONE entry of that round's own statistics (scalars and [C]
+    vectors, no leading round axis) without touching the carried state
+    (bitwise the disarmed rounds'), and the flush publishes them round
+    by round, indices reconstructed backward from the flush round."""
+    def four_rounds(eng):
+        gs = eng.init_global_state()
+        p, b = gs.params, gs.batch_stats
+        for r in range(4):
+            sampled = eng.client_sampling(r)
+            p, b, _, _ = eng._round_jit(
+                p, b, eng.data, jnp.asarray(sampled),
+                eng.per_client_rngs(r, sampled), eng.round_lr(r))
+            yield p, b
+
+    off = _engine(tmp_path, cohort64, health=False, comm_round=4,
+                  freq=4, frac=0.5, tag="q0")
+    on = _engine(tmp_path, cohort64, health=True, comm_round=4, freq=4,
+                 frac=0.5, tag="q1")
+    for r, (s_off, s_on) in enumerate(zip(four_rounds(off),
+                                          four_rounds(on))):
+        assert len(on._health_pending) == r + 1
+        assert off._health_pending == []
+    entry = on._health_pending[-1]
+    assert set(entry) == set(on.program.health_names)
+    assert entry["h_gnorm"].shape == ()
+    assert entry["h_up_norms"].shape == (2,)  # this round's two clients
+    _bitwise(s_off, s_on)
+    on._flush_nonfinite(3)
+    assert on._health_pending == []
+    assert sorted(on._last_health_rows) == [0, 1, 2, 3]
     assert _gauge_value(N.HEALTH_ROUND, engine="fedavg") == 3.0
 
 
@@ -595,9 +617,9 @@ def test_run_report_build_join():
                     {"labels": {"source": "weak_dp"}, "value": 0.2}]},
             N.FALLBACK_TOTAL: {"kind": "counter", "help": "",
                                "values": [{"labels": {
-                                   "plane": "fused",
+                                   "plane": "sharding",
                                    "engine": "fedavg",
-                                   "reason": "no-fused-body"},
+                                   "reason": "no-sharded-body"},
                                    "value": 1.0}]}}},
     ]
     verdict = {"status": "ok", "worst_status": "degraded",
@@ -615,7 +637,7 @@ def test_run_report_build_join():
     assert rep["rounds"][0]["cos_min"] == 0.3
     assert rep["epsilon_ledger"]["sources"]["weak_dp"] == {
         "epsilon": 1.5, "epsilon_per_round": 0.2}
-    assert rep["dispatch"]["fallbacks"][0]["reason"] == "no-fused-body"
+    assert rep["dispatch"]["fallbacks"][0]["reason"] == "no-sharded-body"
     # the flight alert deduped against the verdict's (same key)
     assert len(rep["alerts"]) == 1
     md = render_markdown(rep)
@@ -628,12 +650,12 @@ def test_run_report_build_join():
 
 
 def test_fallback_block_shape():
-    round_program.report_fallback("fedavg", "no-fused-body")
+    round_program.report_fallback("fedavg", "no-sharded-body")
     block = obs_health.fallback_block()
     assert block["total"] >= 1
-    assert block["by_plane"].get("fused", 0) >= 1
+    assert block["by_plane"].get("sharding", 0) >= 1
     rows = [r for r in block["announcements"]
-            if r["reason"] == "no-fused-body"]
+            if r["reason"] == "no-sharded-body"]
     assert rows and rows[0]["engine"] == "fedavg"
 
 

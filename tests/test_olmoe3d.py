@@ -351,3 +351,55 @@ def test_fedavg_round_reports_expert_load(tmp_path):
     assert load["tokens_routed"] == tokens.sum()
     assert load["expert_load_max_over_mean"] >= 1.0 \
         >= load["expert_load_min_over_mean"]
+
+
+def test_folded_train_logs_expert_load_every_round(tmp_path):
+    """Three rounds of the folded ``train()``, tracer armed: every
+    round's ``round_log`` span carries that round's expert-load
+    counters (the round program's ``expert_tokens`` output read where
+    the loss is read), each accounting for the round's real steps."""
+    from neuroimagedisttraining_tpu.config import (
+        DataConfig, ExperimentConfig, FedConfig,
+    )
+    from neuroimagedisttraining_tpu.data.federate import federate_cohort
+    from neuroimagedisttraining_tpu.data.synthetic import (
+        generate_synthetic_abcd,
+    )
+    from neuroimagedisttraining_tpu.engines import create_engine
+    from neuroimagedisttraining_tpu.obs import names as obs_names
+    from neuroimagedisttraining_tpu.obs import trace as obs_trace
+    from neuroimagedisttraining_tpu.utils.logging import ExperimentLogger
+
+    cohort = generate_synthetic_abcd(num_subjects=30, shape=SHAPE,
+                                     num_sites=2, seed=0)
+    cohort["site"] = np.repeat(np.arange(2), (20, 10)).astype(
+        cohort["site"].dtype)
+    cfg = ExperimentConfig(
+        model="olmoe3d", num_classes=1, algorithm="fedavg",
+        data=DataConfig(dataset="synthetic", partition_method="site"),
+        optim=OptimConfig(lr=1e-2, batch_size=4, epochs=1),
+        fed=FedConfig(client_num_in_total=2, comm_round=3),
+        log_dir=str(tmp_path), tag="fold3")
+    tr = LocalTrainer(OLMoE3D(**SMALL), cfg.optim, 1)
+    fed, _ = federate_cohort(cohort, partition_method="site", mesh=None)
+    eng = create_engine("fedavg", cfg, fed, tr, mesh=None,
+                        logger=ExperimentLogger(
+                            str(tmp_path), "synthetic", cfg.identity(),
+                            console=False))
+    eng._fold_budget_bytes = 1
+    obs_trace.arm()
+    try:
+        eng.train()
+        logs = [e for e in obs_trace.TRACER.events()
+                if e["ph"] == "X"
+                and e["name"] == obs_names.SPAN_ROUND_LOG]
+    finally:
+        obs_trace.disarm()
+    assert eng.program.placement == "folded"
+    assert eng.program.dispatches == 3 and eng.program.built == 1
+    assert [e["args"]["round"] for e in logs] == [0, 1, 2]
+    real_steps = int(np.ceil(np.asarray(eng.data.n_train) / 4).sum())
+    for e in logs:
+        assert e["args"]["tokens_routed"] == real_steps * 2 * (4 * 8)
+        assert e["args"]["expert_load_max_over_mean"] >= 1.0 \
+            >= e["args"]["expert_load_min_over_mean"]

@@ -51,7 +51,7 @@ def _bitwise(a, b):
 
 
 def _engine(tmp_path, cohort, algorithm="fedavg", precision="fp32",
-            fused=False, loss_scale=1.0, K=1, comm_round=2,
+            fused=False, loss_scale=1.0, comm_round=2,
             freq=10 ** 9, tag="p", checkpoint_dir="", checkpoint_every=0,
             **fed_kw):
     optim = OptimConfig(lr=1e-3, batch_size=8, epochs=1,
@@ -62,8 +62,7 @@ def _engine(tmp_path, cohort, algorithm="fedavg", precision="fp32",
         data=DataConfig(dataset="synthetic", partition_method="site"),
         optim=optim,
         fed=FedConfig(client_num_in_total=4, comm_round=comm_round,
-                      frequency_of_the_test=freq, rounds_per_dispatch=K,
-                      **fed_kw),
+                      frequency_of_the_test=freq, **fed_kw),
         checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
         log_dir=str(tmp_path), tag=tag)
     trainer = LocalTrainer(
@@ -259,37 +258,46 @@ def test_loss_scale_pin_power_of_two_is_exact(tmp_path, synthetic_cohort):
 
 
 # ---------------------------------------------------------------------------
-# composition: fused windows + checkpoint resume under bf16_mixed
+# composition: the round driver + checkpoint resume under bf16_mixed
 # ---------------------------------------------------------------------------
 
-def test_bf16_fused_window_bitwise_equal_sequential(tmp_path,
-                                                    synthetic_cohort):
-    """bf16_mixed under the K-fused driver equals the sequential loop
-    bitwise — same pin as test_dispatch's, at the new precision (frac<1
-    keeps per-round sampling load-bearing)."""
-    base = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
-                   K=1, comm_round=4, freq=4, frac=0.5, tag="bk1").train()
-    fused = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
-                    K=4, comm_round=4, freq=4, frac=0.5, tag="bk4").train()
-    _bitwise(base["params"], fused["params"])
-    _bitwise(base["batch_stats"], fused["batch_stats"])
-    assert base["history"] == fused["history"]
+def test_bf16_driver_equals_rounds_by_hand(tmp_path, synthetic_cohort):
+    """bf16_mixed under the driver: four rounds of ``train()`` equal the
+    same four rounds dispatched by hand bitwise — same pin as
+    test_dispatch's, at the new precision (frac<1 keeps per-round
+    sampling load-bearing) — and are one compiled program."""
+    drv = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
+                  comm_round=4, freq=4, frac=0.5, tag="bdrv")
+    res = drv.train()
+    assert drv.program.dispatches == 4 and drv.program.built == 1
+    seq = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
+                  comm_round=4, freq=4, frac=0.5, tag="bseq")
+    gs = seq.init_global_state()
+    p, b, losses = gs.params, gs.batch_stats, []
+    for r in range(4):
+        sampled = seq.client_sampling(r)
+        p, b, loss, _ = seq._round_jit(
+            p, b, seq.data, jnp.asarray(sampled),
+            seq.per_client_rngs(r, sampled), seq.round_lr(r))
+        losses.append(float(loss))
+    _bitwise(res["params"], p)
+    _bitwise(res["batch_stats"], b)
+    assert [h["train_loss"] for h in res["history"]] == \
+        [losses[0], losses[3]]
 
 
-def test_bf16_checkpoint_resume_mid_window_bitwise(tmp_path,
-                                                   synthetic_cohort):
-    """Checkpoint round-trip under bf16_mixed (ISSUE 10 satellite,
-    extending test_dispatch's resume-mid-window pin): the saved state IS
-    the f32 master weights (restored bitwise, dtype float32), and a
-    K=4 resume landing mid-window reproduces the unbroken K=1 run
-    bitwise."""
+def test_bf16_checkpoint_resume_bitwise(tmp_path, synthetic_cohort):
+    """Checkpoint round-trip under bf16_mixed (ISSUE 10 satellite): the
+    saved state IS the f32 master weights (restored bitwise, dtype
+    float32), and a resume from the round-1 checkpoint reproduces the
+    unbroken run bitwise."""
     from neuroimagedisttraining_tpu.utils import checkpoint as ckpt
 
     full = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
-                   K=1, comm_round=4, tag="cfull").train()
+                   comm_round=4, tag="cfull").train()
     ck = str(tmp_path / "ck_bf16")
     part = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
-                   K=4, comm_round=2, checkpoint_dir=ck,
+                   comm_round=2, checkpoint_dir=ck,
                    checkpoint_every=2, tag="cpart").train()
     # the checkpoint carries f32 master weights bitwise
     r, state = ckpt.load_checkpoint(ck)
@@ -298,7 +306,7 @@ def test_bf16_checkpoint_resume_mid_window_bitwise(tmp_path,
         assert np.asarray(leaf).dtype == np.float32
     _bitwise(state["params"], part["params"])
     resumed = _engine(tmp_path, synthetic_cohort, precision="bf16_mixed",
-                      K=4, comm_round=4, checkpoint_dir=ck,
+                      comm_round=4, checkpoint_dir=ck,
                       checkpoint_every=2, tag="cres").train()
     _bitwise(full["params"], resumed["params"])
     _bitwise(full["batch_stats"], resumed["batch_stats"])

@@ -2,21 +2,18 @@
 
 Contracts:
 
-(a) Newly-declared engines (ditto / dpsgd / subavg) gain fused
-    ``--rounds_per_dispatch`` windows: a K=4 window dispatched through
-    ``program.run_window`` equals four K=1 single dispatches BITWISE
-    (params, batch_stats, persistent per-client state, per-round
-    losses), with ONE compiled program per window (the ``built`` /
-    ``dispatches`` counters pin it). fedavg/fedprox/salientgrads keep
-    their pre-builder pins in tests/test_dispatch.py — unchanged, the
-    regression oracle of the port.
+(a) The declared engines (ditto / dpsgd / subavg / local) dispatch one
+    round at a time through ONE compiled program: four rounds by hand
+    are four ``dispatches`` and one ``built``, and the driver's
+    ``train()`` lands on the same carried state BITWISE.
+    fedavg/fedprox/salientgrads keep the same pin in
+    tests/test_dispatch.py.
 (b) The same engines gain ``--client_mesh`` cohort sharding: the
     sharded round from identical state matches the sequential C-loop
     (losses bitwise, state to the ~1-ulp compile-context residue —
     parallel/cohort.py contract, same bounds as tests/test_cohort.py).
 (c) Fallback reporting is unified: every reason is a key of
-    ``program.REASONS``, engines that declared stages stopped reporting
-    the old no-fused-body reason, and each announcement increments the
+    ``program.REASONS`` and each announcement increments the
     structured ``nidt_fallback_total{plane, engine, reason}`` counter
     (value-pinned).
 """
@@ -43,7 +40,7 @@ ULP_RTOL = 1e-6
 ULP_ATOL = 1e-6
 
 
-def _engine(tmp_path, cohort, algorithm="ditto", K=1, comm_round=4,
+def _engine(tmp_path, cohort, algorithm="ditto", comm_round=4,
             freq=4, tag="p", epochs=1, client_mesh=0, seq=False,
             donate=True, val_fraction=0.0, **fed_kw):
     cfg = ExperimentConfig(
@@ -52,7 +49,7 @@ def _engine(tmp_path, cohort, algorithm="ditto", K=1, comm_round=4,
                         val_fraction=val_fraction),
         optim=OptimConfig(lr=1e-3, batch_size=8, epochs=epochs),
         fed=FedConfig(client_num_in_total=4, comm_round=comm_round,
-                      frequency_of_the_test=freq, rounds_per_dispatch=K,
+                      frequency_of_the_test=freq,
                       client_mesh=client_mesh, **fed_kw),
         log_dir=str(tmp_path), tag=tag)
     mesh = make_mesh()
@@ -111,7 +108,7 @@ def _init_carry(eng):
 
 
 def _one_round(eng, carry, r):
-    """One K=1 dispatch through the engine's legacy round adapter;
+    """One dispatch through the engine's legacy round adapter;
     returns (new_carry, loss)."""
     lr = eng.round_lr(r)
     if eng.name == "dpsgd":
@@ -135,102 +132,64 @@ def _one_round(eng, carry, r):
 
 
 # ---------------------------------------------------------------------------
-# (a) fused K=4 == 4 x K=1, bitwise, one compiled program per window
+# (a) four rounds, four dispatches, one compiled program; train() == by hand
 # ---------------------------------------------------------------------------
 
 # tier-1 window budget (PR 2/7/9 precedent): the heavy bitwise pins ride
 # the full suite; tier-1 keeps the cheap fallback/counter/reason pins
-# below plus the builder coverage every per-round engine test already
-# exercises (all K=1 dispatches now route through engines/program.py)
-@pytest.mark.parametrize("algorithm,fed_kw", [
-    pytest.param("ditto", {"frac": 0.5}, marks=pytest.mark.slow),
-    pytest.param("subavg", {"frac": 0.5}, marks=pytest.mark.slow),
+# below, tests/test_dispatch.py's fedavg case of this pin and
+# tests/test_checkpoint.py's resume pins of the same engines
+@pytest.mark.parametrize("algorithm,fed_kw,keys", [
+    pytest.param("ditto", {"frac": 0.5},
+                 {"params": 0, "personal_params": 2},
+                 marks=pytest.mark.slow),
+    pytest.param("subavg", {"frac": 0.5}, {"params": 0, "mask_pers": 2},
+                 marks=pytest.mark.slow),
     pytest.param("dpsgd", {"cs": "ring", "frac": 0.5},
-                 marks=pytest.mark.slow),
+                 {"personal_params": 0}, marks=pytest.mark.slow),
     pytest.param("dpsgd", {"cs": "random", "frac": 0.5},
-                 marks=pytest.mark.slow),
+                 {"personal_params": 0}, marks=pytest.mark.slow),
     # ROADMAP 1(a): the local engine's trivial carry on the builder
-    pytest.param("local", {}, marks=pytest.mark.slow),
-    # ROADMAP 1(b): the secure-quant codec-family stage composes with
-    # fused windows — the field fold rides the scan bitwise
+    pytest.param("local", {}, {"personal_params": 0},
+                 marks=pytest.mark.slow),
+    # ROADMAP 1(b): the secure-quant codec-family stage in the round
     pytest.param("fedavg", {"frac": 0.5, "secure_quant": True,
                             "secure_quant_field_bits": 32},
-                 marks=pytest.mark.slow),
-])
-def test_fused_window_bitwise_equals_sequential(tmp_path,
-                                                synthetic_cohort,
-                                                algorithm, fed_kw):
-    """The newly-declared engines' K-round scan: a K=4 window equals
-    four single dispatches bitwise in the full carried state and the
-    per-round losses — and the window is ONE compiled program, dispatched
-    once (program.built / program.dispatches pins)."""
-    seq = _engine(tmp_path, synthetic_cohort, algorithm, K=1,
+                 {"params": 0}, marks=pytest.mark.slow),
+], ids=["ditto", "subavg", "dpsgd-ring", "dpsgd-random", "local",
+        "fedavg-secure_quant"])
+def test_sequential_rounds_one_program(tmp_path, synthetic_cohort,
+                                       algorithm, fed_kw, keys):
+    """Four rounds dispatched by hand are four invocations of ONE
+    compiled program (``program.built`` / ``program.dispatches``, and
+    the scrapeable ``nidt_compiles_total`` moving in the same
+    increment), and the driver's ``train()`` reaches the same carried
+    state bitwise, with the same per-round losses where it logs them."""
+    seq = _engine(tmp_path, synthetic_cohort, algorithm,
                   tag=f"sq-{algorithm}-{len(fed_kw)}", **fed_kw)
     carry = _init_carry(seq)
+    built0 = seq.program.built
+    ctr0 = obs_compute.compiles_total(engine=algorithm)
     losses = []
     for r in range(4):
         carry, loss = _one_round(seq, carry, r)
         losses.append(float(loss))
-    # the dispatch counter is the bench's evidence: 4 sequential rounds
-    # = 4 invocations of 1 compiled program
     assert seq.program.dispatches == 4
-    assert seq.program.built == 1
+    # dpsgd's random topology keys its program by the round's plan spec
+    n_built = seq.program.built - built0
+    assert n_built == 1 or fed_kw.get("cs") == "random"
+    assert obs_compute.compiles_total(engine=algorithm) - ctr0 == n_built
 
-    fz = _engine(tmp_path, synthetic_cohort, algorithm, K=4,
-                 tag=f"fz-{algorithm}-{len(fed_kw)}", **fed_kw)
-    assert fz.fused_fallback_reason() is None
-    fcarry = _init_carry(fz)
-    built0 = fz.program.built
-    # the compiled-programs-per-window pin re-asserted through the
-    # scrapeable counter (ISSUE 14): nidt_compiles_total moves in the
-    # SAME increment as program.built — one measurement, not a second
-    # bookkeeping path
-    ctr0 = obs_compute.compiles_total(engine=algorithm)
-    fcarry, _, outs, wi = fz.program.run_window(fcarry, 0, 4)
-    assert wi.k == 4
-    assert [float(x) for x in np.asarray(outs["loss"])] == losses
-    _assert_trees_bitwise(carry, fcarry)
-    # one compiled program, one dispatch, for the whole window
-    assert fz.program.built - built0 == 1
-    assert obs_compute.compiles_total(engine=algorithm) - ctr0 == 1.0
-    assert fz.program.dispatches == 1
-    assert len(fz.__dict__["_fused_round_jit_cache"]) == 1
-
-
-@pytest.mark.slow
-def test_fused_driver_end_to_end_bitwise_ditto(tmp_path,
-                                               synthetic_cohort):
-    """The full ditto driver: a K=4 train() — windows planned around the
-    eval cadence, personal stacks carried, hooks at boundaries — equals
-    the K=1 run bitwise in global AND personal state, metrics history
-    included."""
-    r1 = _engine(tmp_path, synthetic_cohort, "ditto", K=1, frac=0.5,
-                 tag="dk1").train()
-    e4 = _engine(tmp_path, synthetic_cohort, "ditto", K=4, frac=0.5,
-                 tag="dk4")
-    r4 = e4.train()
-    _assert_trees_bitwise(r1["params"], r4["params"])
-    _assert_trees_bitwise(r1["personal_params"], r4["personal_params"])
-    assert r1["history"] == r4["history"]
-    # windows reused ONE fused program per distinct plan
-    assert len(e4.__dict__["_fused_round_jit_cache"]) == 1
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("algorithm,key", [
-    ("subavg", "params"),
-    ("dpsgd", "personal_params"),
-])
-def test_fused_driver_end_to_end_bitwise(tmp_path, synthetic_cohort,
-                                         algorithm, key):
-    kw = {"cs": "ring", "frac": 0.5} if algorithm == "dpsgd" \
-        else {"frac": 0.5}
-    r1 = _engine(tmp_path, synthetic_cohort, algorithm, K=1,
-                 tag=f"ek1{algorithm}", **kw).train()
-    r4 = _engine(tmp_path, synthetic_cohort, algorithm, K=4,
-                 tag=f"ek4{algorithm}", **kw).train()
-    _assert_trees_bitwise(r1[key], r4[key])
-    assert r1["history"] == r4["history"]
+    drv = _engine(tmp_path, synthetic_cohort, algorithm,
+                  tag=f"dr-{algorithm}-{len(fed_kw)}", **fed_kw)
+    res = drv.train()
+    assert drv.program.dispatches == 4
+    assert drv.program.built == n_built
+    for key, i in keys.items():
+        _assert_trees_bitwise(res[key], carry[i])
+    assert [h["round"] for h in res["history"]] == [0, 3]
+    assert [h["train_loss"] for h in res["history"]] == \
+        [losses[0], losses[3]]
 
 
 # ---------------------------------------------------------------------------
@@ -298,76 +257,46 @@ def test_sharded_round_vs_sequential_loop(tmp_path, synthetic_cohort,
 
 def test_reason_table_has_no_orphans(tmp_path, synthetic_cohort):
     """Single source of truth: every engine's fallback keys resolve in
-    REASONS, declared engines stopped reporting the old no-fused-body
-    reason, and no key in the table is unreachable by construction (the
-    lint rule round-program-reason rejects ad-hoc strings)."""
-    declared = {"fedavg", "fedprox", "salientgrads", "ditto", "dpsgd",
-                "subavg", "local"}
+    REASONS, and the table has no plane a retired driver left behind
+    (the lint rule round-program-reason rejects ad-hoc strings)."""
     seen = set()
     for name, cls in ENGINES.items():
         if name in ("sailentgrads", "sub-fedavg"):  # registry aliases
             continue
         kw = {"val_fraction": 0.25} if name == "fedfomo" else {}
-        eng = _engine(tmp_path, synthetic_cohort, name, K=4,
-                      tag=f"rt-{name}", **kw)
-        key = eng.fused_fallback_key()
+        eng = _engine(tmp_path, synthetic_cohort, name, tag=f"rt-{name}",
+                      **kw)
         ckey = eng.program.cohort_fallback_key()
-        for k in (key, ckey):
-            if k is not None:
-                assert k in round_program.REASONS, (name, k)
-                seen.add(k)
-        if name in declared:
-            assert key is None, (name, key)
-            assert eng.fused_fallback_reason() is None
-        else:
-            assert key is not None
-            assert eng.fused_fallback_reason() == \
-                round_program.reason(key)
-    # every key the engine matrix announced is a table key, and every
-    # message renders from the table (no orphaned ad-hoc strings — the
-    # round-program-reason lint rule enforces the source side)
-    for k in seen:
-        assert round_program.REASONS[k][0] in ("fused", "sharding",
-                                               "streaming")
+        if ckey is not None:
+            assert ckey in round_program.REASONS, (name, ckey)
+            assert round_program.REASONS[ckey][0] == "sharding"
+            seen.add(ckey)
+    assert seen  # this mesh-free matrix announces at least one
+    assert {plane for plane, _ in round_program.REASONS.values()} == \
+        {"sharding", "fold", "recipe"}
+    assert len(round_program.REASONS) == 16
 
 
 def test_fallback_counter_value_pinned(tmp_path, synthetic_cohort):
     """Every announced fallback increments
     nidt_fallback_total{plane, engine, reason} — scrapeable, not
-    grep-able. Constructing a K=4 fedfomo engine announces exactly one
-    fused fallback with the table key."""
+    grep-able. Constructing a --client_mesh fedfomo engine announces
+    exactly one sharding fallback with the table key."""
     c = obs_metrics.counter(
         "nidt_fallback_total", labelnames=("plane", "engine", "reason"))
-    labels = dict(plane="fused", engine="fedfomo",
-                  reason="no-fused-body")
-    before = c.get(**labels)
-    _engine(tmp_path, synthetic_cohort, "fedfomo", K=4,
-            val_fraction=0.25, tag="ctr")
-    assert c.get(**labels) == before + 1.0
-    # and a sharding fallback announcement rides the same counter —
-    # local now DECLARES its round (ROADMAP 1(a)) and ARMS sharding on
-    # the mesh-padded cohort, so the undeclared fedfomo carries this pin
+    # local DECLARES its round (ROADMAP 1(a)) and ARMS sharding on the
+    # mesh-padded cohort, so the undeclared fedfomo carries this pin
     sh_labels = dict(plane="sharding", engine="fedfomo",
                      reason="no-sharded-body")
     before_sh = c.get(**sh_labels)
-    eng = _engine(tmp_path, synthetic_cohort, "fedfomo", K=1,
+    eng = _engine(tmp_path, synthetic_cohort, "fedfomo",
                   client_mesh=8, val_fraction=0.25, tag="ctr2")
     assert not eng._cohort_on
     assert c.get(**sh_labels) == before_sh + 1.0
-    # the newly-declared local engine arms instead of announcing
-    eng_l = _engine(tmp_path, synthetic_cohort, "local", K=1,
+    # the declared local engine arms instead of announcing
+    eng_l = _engine(tmp_path, synthetic_cohort, "local",
                     client_mesh=8, tag="ctr3")
     assert eng_l._cohort_on
-
-
-def test_wire_codec_still_collapses_with_counted_reason(
-        tmp_path, synthetic_cohort):
-    """Declared engines still fall back per MODE: fedavg + --wire_codec
-    reports the wire-codec-host-bytes key (counted), not the stale
-    no-fused-body reason."""
-    eng = _engine(tmp_path, synthetic_cohort, "fedavg", K=4,
-                  wire_codec="delta+quant", tag="wck")
-    assert eng.fused_fallback_key() == "wire-codec-host-bytes"
 
 
 # ---------------------------------------------------------------------------
@@ -458,11 +387,11 @@ def test_secure_quant_engine_round_near_plain(tmp_path,
     """Wiring sanity: a fedavg round with --secure_quant armed agrees
     with the plain round to quantization error (the per-leaf scale's
     2^-frac_bits lattice), not more — the stage replaced the tail, it
-    did not corrupt it. The fused-window bitwise pin rides the slow
+    did not corrupt it. The four-round driver pin rides the slow
     matrix above."""
-    pl = _engine(tmp_path, synthetic_cohort, "fedavg", K=1, frac=0.5,
+    pl = _engine(tmp_path, synthetic_cohort, "fedavg", frac=0.5,
                  tag="sqp")
-    sq = _engine(tmp_path, synthetic_cohort, "fedavg", K=1, frac=0.5,
+    sq = _engine(tmp_path, synthetic_cohort, "fedavg", frac=0.5,
                  secure_quant=True, secure_quant_field_bits=32,
                  tag="sqs")
     assert sq.sq_spec is not None and sq.sq_weight_shift >= 1

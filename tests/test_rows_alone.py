@@ -280,7 +280,7 @@ def test_dealt_counts_on_the_dispatch_span(tmp_path, skewed_cohort):
     obs_trace.arm()
     try:
         sampled = eng.client_sampling(0)
-        eng._note_round_counts([sampled], 8)
+        eng._note_round_counts(sampled, 8)
         got = dict(eng._dispatch_counts)
     finally:
         obs_trace.disarm()

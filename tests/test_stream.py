@@ -563,34 +563,46 @@ def test_streaming_salientgrads_checkpoint_resume(h5_cohort, tmp_path):
     assert resumed["mask_density"] == full["mask_density"]
 
 
-def test_stream_window_feed_matches_per_round(h5_cohort):
-    """The window-granular feed (ISSUE 10): ``get_window``'s [K, S, ...]
-    stacks equal the per-round ``get_train`` buffers round for round,
-    a matching ``prefetch_window`` is served (fetches accounted one per
-    round), and a mismatched prefetch is fetched fresh, never stale."""
+def test_streaming_fedavg_prefetch_one_train_fetch_a_round(h5_cohort,
+                                                           tmp_path):
+    """The one round loop's feed: over the streamed run every round's
+    train shards are fetched exactly ONCE, on the reader thread (the
+    prefetch queued behind the previous round was the one served: a
+    mismatched key would fetch again, on the driver's thread), and the
+    streamed run's final model is the resident run's, bitwise."""
+    import threading
+
     path, data = h5_cohort
+    res = _run_fedavg(data, streaming=False, tmp_path=tmp_path, tag="pres")
     lazy = load_abcd_hdf5(path, lazy=True)
     train_map, test_map, _ = P.site_partition(lazy["site"], seed=42)
     stream = StreamingFederation(lazy["X"], lazy["y"], train_map, test_map)
+    calls = []
+    inner = stream._fetch_put
+
+    def spy(client_ids, split, n_real=None):
+        calls.append((split, len(client_ids), threading.current_thread()
+                      is threading.main_thread()))
+        return inner(client_ids, split, n_real)
+
+    stream._fetch_put = spy
     try:
-        ids = [np.array([0, 2]), np.array([1, 3]), np.array([0, 1])]
-        stream.prefetch_window(ids)
-        f0 = stream.transfer_stats["fetches"]
-        Xw, yw, nw = stream.get_window(ids)
-        assert stream.transfer_stats["fetches"] - f0 == len(ids)
-        assert Xw.shape[0] == len(ids)
-        for k, round_ids in enumerate(ids):
-            Xr, yr, nr = stream.get_train(round_ids)
-            np.testing.assert_array_equal(np.asarray(Xw)[k], np.asarray(Xr))
-            np.testing.assert_array_equal(np.asarray(yw)[k], np.asarray(yr))
-            np.testing.assert_array_equal(np.asarray(nw)[k], np.asarray(nr))
-        # mismatched window prefetch is ignored, not served stale
-        stream.prefetch_window([np.array([0, 1])])
-        X1, _, n1 = stream.get_window([np.array([2, 3])])
-        assert int(np.asarray(n1)[0, 0]) == len(train_map[2])
+        st = _run_fedavg(stream, streaming=True, tmp_path=tmp_path,
+                         tag="pst")
+        stream.sync()
+        assert stream.transfer_stats["fetches"] == len(calls)
     finally:
         stream.close()
         lazy["file"].close()
+    # a round samples 2 of the 4 clients (frac 0.5); the final pass
+    # streams the cohort in chunks of its own size
+    rounds = len(res["history"])
+    per_round = [on_main for split, n, on_main in calls
+                 if split == "train" and n == 2]
+    assert per_round == [False] * rounds == [False] * len(st["history"])
+    for a, b in zip(jax.tree.leaves(res["params"]),
+                    jax.tree.leaves(st["params"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_streaming_double_buffer_prefetch(h5_cohort):
