@@ -143,11 +143,13 @@ class LocalTrainer:
         #: program it traced before the declaration existed.
         self.has_aux = bool(getattr(model, "returns_aux", False))
         #: set by :meth:`rows_alone` while a placement traces rows
-        #: unbatched; ``local_train`` reads it at trace time
+        #: unbatched (and, second, inside a ``shard_map`` partition);
+        #: ``local_train`` reads both at trace time
         self._rows_alone = False
+        self._partitioned = False
 
     @contextlib.contextmanager
-    def rows_alone(self):
+    def rows_alone(self, partitioned: bool = False):
         """For the duration of a trace, ``local_train`` is applied to one
         client row at a time (``lax.map`` / ``lax.scan`` over clients, a
         ``shard_map`` block's loop, a single folded client) and never
@@ -157,12 +159,19 @@ class LocalTrainer:
         ``FederatedEngine._cohort_map`` / ``_per_client``), never by a
         ``local_train`` call site. Under ``vmap`` the loop it selects
         would still be right (JAX batches a ``while`` into selects), only
-        slower than the batched form."""
-        was, self._rows_alone = self._rows_alone, True
+        slower than the batched form.
+
+        ``partitioned``: the rows' loop is a ``shard_map`` block's
+        (``FederatedEngine._cohort_map``). A random sort drawn inside a
+        partition miscompiles (parallel/cohort.py), so a ``local_train``
+        traced there must be handed its hoisted ``perms`` and refuses to
+        draw its own batches."""
+        was = self._rows_alone, self._partitioned
+        self._rows_alone, self._partitioned = True, partitioned
         try:
             yield
         finally:
-            self._rows_alone = was
+            self._rows_alone, self._partitioned = was
 
     # ---------- init ----------
 
@@ -293,6 +302,14 @@ class LocalTrainer:
         bitwise pins). The rng stream is identical either way: the split
         that would have fed the permutation is still consumed.
         """
+        if self._partitioned and perms is None:
+            raise ValueError(
+                "local_train traced inside a shard_map partition without "
+                "hoisted permutations: its batch draws (an argsort-"
+                "lowered permutation, or per-step randint) miscompile "
+                "there (parallel/cohort.py) — hoist them "
+                "(engines/program.py hoisted_epoch_perms) and pass "
+                "perms=")
         total = scan_steps(epochs, batch_size, max_samples)
         steps_per_epoch = total // epochs
         my_steps = jnp.ceil(n_valid / batch_size).astype(jnp.int32)

@@ -563,7 +563,8 @@ class FederatedEngine:
         # eval program, and the blocking read of its result, which waits
         # for everything dispatched before it (the round itself)
         with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                            program="eval_global", split=split):
+                            program="eval_global", split=split,
+                            **self._eval_span_args(n.shape[0])):
             out = self._eval_global_jit(params, bstats, X, y, n)
         with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                             program="eval_global"):
@@ -582,7 +583,8 @@ class FederatedEngine:
             params = pt.tree_stack_index(params, slice(0, 1))
             bstats = pt.tree_stack_index(bstats, slice(0, 1))
         with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                            program="eval_personalized", split=split):
+                            program="eval_personalized", split=split,
+                            **self._eval_span_args(n.shape[0])):
             out = self._eval_personal_jit(params, bstats, X, y, n)
         with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                             program="eval_personalized"):
@@ -784,13 +786,49 @@ class FederatedEngine:
         over clients too, and never hold a state per client."""
         return self.program.placement == round_program.FOLDED
 
+    def _rows_placement(self, rows: int) -> tuple[str, int]:
+        """``(placement, rows_a_chip)`` of ``rows`` client rows handed to
+        :meth:`_per_client`: the round program's placement, except that
+        rows which do not tile the client mesh (the ``--ci`` single row)
+        stay stacked. ``rows_a_chip`` is what one chip's loop walks when
+        the rows are sharded, every row otherwise."""
+        placement = self.program.placement
+        if placement != round_program.SHARDED:
+            return placement, rows
+        chips = int(self.mesh.devices.size)
+        if rows % chips:
+            return round_program.STACKED, rows
+        return placement, rows // chips
+
+    def _eval_span_args(self, rows: int) -> dict:
+        """Where an evaluation program about to be enqueued places its
+        ``rows`` client rows, as arguments of its ``eval_dispatch`` span
+        (obs/names.py ``ARGS_BY_SPAN``): host integers, no device read; a
+        no-op while the tracer is disarmed."""
+        if not obs_trace.TRACER.armed:
+            return {}
+        placement, rows_a_chip = self._rows_placement(rows)
+        return {"placement": placement, "rows": rows,
+                "rows_a_chip": rows_a_chip}
+
     def _per_client(self, fn, *stacked):
-        """``fn`` over the client axis outside the round program:
-        ``vmap``, or one client after another when the round folds (each
-        then runs alone: ``LocalTrainer.rows_alone``)."""
-        if self.folded:
+        """``fn`` over the client axis outside the round program, placed
+        as the round program places its clients (``_rows_placement``):
+        ``vmap`` when stacked; one client after another when the round
+        folds; and where the cohort is sharded over the client mesh,
+        each chip's loop over the rows it holds (``_cohort_map``:
+        ``fn``'s stacked operands are cut by row, what it closes over is
+        replicated, and only its per-client outputs are all-gathered, so
+        evaluation moves four scalars a client and no activation).
+        Folded or sharded a row runs alone
+        (``LocalTrainer.rows_alone``)."""
+        rows = jax.tree.leaves(stacked[0])[0].shape[0]
+        placement, _ = self._rows_placement(rows)
+        if placement == round_program.FOLDED:
             with self.trainer.rows_alone():
                 return cohort.sequential_map(fn, *stacked)
+        if placement == round_program.SHARDED:
+            return self._cohort_map(fn, *stacked)
         return jax.vmap(fn)(*stacked)
 
     def _cohort_map(self, fn, *stacked):
@@ -802,8 +840,11 @@ class FederatedEngine:
         losses — the full contract in parallel/cohort.py). Either way a
         row runs alone, and the trainer is told so while this traces
         (``LocalTrainer.rows_alone``): both take the same unbatched
-        step, which stops at the row's own last one."""
-        with self.trainer.rows_alone():
+        step, which stops at the row's own last one, and both hold
+        ``fn`` to the partition's rule that a ``local_train`` in it is
+        handed hoisted permutations (``_per_client`` sends evaluation
+        here, which draws nothing)."""
+        with self.trainer.rows_alone(partitioned=True):
             if self._cohort_sequential:
                 return cohort.sequential_map(fn, *stacked)
             return cohort.cohort_map(self.mesh, fn, *stacked)
@@ -1603,7 +1644,8 @@ class FederatedEngine:
         ns: list[np.ndarray] = []
         for ch in self.stream.eval_chunks(self._eval_chunk_size(), split):
             with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                                program="eval_global", split=split):
+                                program="eval_global", split=split,
+                                **self._eval_span_args(ch.n.shape[0])):
                 out = self._eval_global_jit(params, bstats, ch.X, ch.y,
                                             ch.n)
             with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
@@ -1660,7 +1702,8 @@ class FederatedEngine:
             p = pt.tree_stack_index(per_params, ch.padded_ids)
             b = pt.tree_stack_index(per_bstats, ch.padded_ids)
             with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                                program="eval_personalized", split=split):
+                                program="eval_personalized", split=split,
+                                **self._eval_span_args(ch.n.shape[0])):
                 out = self._eval_personal_jit(p, b, ch.X, ch.y, ch.n)
             with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                                 program="eval_personalized"):

@@ -243,8 +243,14 @@ class FedAvgEngine(FederatedEngine):
         aggregated model and evaluated on its own test rows, under
         ``_per_client``, so in the folded placement a client's state is
         discarded before the next starts (stacked, they would be one
-        model state per client). Returns the four per-client metric
-        arrays ``_eval_personal_jit`` returns."""
+        model state per client). Reached only streamed or folded, and
+        cohort sharding arms under neither (``RoundProgram.placement``,
+        ``cohort_fallback_key``), so ``_per_client`` never takes its
+        sharded arm here: ``local_train`` draws its own permutations
+        below, and inside a ``shard_map`` partition it refuses to
+        (``LocalTrainer.rows_alone``; tests/test_cohort.py holds the
+        refusal). Returns the four per-client metric arrays
+        ``_eval_personal_jit`` returns."""
         trainer = self.trainer
         o = self.cfg.optim
         max_samples = self._max_samples()
@@ -448,7 +454,8 @@ class FedAvgEngine(FederatedEngine):
             d = self.data
             per_states = None
             with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                                program="finetune_eval", split="test"):
+                                program="finetune_eval", split="test",
+                                **self._eval_span_args(self.num_clients)):
                 out = self._finetune_eval_jit(
                     params, bstats, d.X_train, d.y_train, d.n_train,
                     d.X_test, d.y_test, d.n_test, rngs, self.round_lr(-1))

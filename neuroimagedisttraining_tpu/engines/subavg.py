@@ -49,6 +49,7 @@ from neuroimagedisttraining_tpu.core.trainer import ClientState
 from neuroimagedisttraining_tpu.engines import program as round_program
 from neuroimagedisttraining_tpu.engines.base import FederatedEngine
 from neuroimagedisttraining_tpu.obs import health as obs_health
+from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.ops import flops as flops_ops
 from neuroimagedisttraining_tpu.ops import prune as P
 from neuroimagedisttraining_tpu.ops.masks import ones_mask
@@ -258,7 +259,8 @@ class SubFedAvgEngine(FederatedEngine):
                 auc = binary_auc(mt["scores"], yc, valid)
                 return mt["test_correct"], mt["test_loss"], mt["test_total"], auc
 
-            return jax.vmap(per_client)(mask_pers, X, y, n)
+            with jax.named_scope(obs_names.SCOPE_EVAL):
+                return self._per_client(per_client, mask_pers, X, y, n)
 
         return jax.jit(eval_all)
 

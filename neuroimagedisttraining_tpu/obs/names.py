@@ -150,6 +150,26 @@ ROUND_CHILD_SPANS: tuple[str, ...] = (
     SPAN_EVAL_SYNC, SPAN_ROUND_FLUSH, SPAN_ROUND_LOG,
     SPAN_ROUND_CHECKPOINT)
 
+#: the counters a span carries as arguments, beside the ``round`` id it
+#: takes from the iteration's span: host integers and names the driver
+#: already holds where the work is enqueued (no device read), written
+#: only while the tracer is armed. ``dispatch_program``: what the round
+#: program trains and where (``FederatedEngine._note_round_counts``).
+#: ``eval_dispatch``: which evaluation program, and where
+#: ``FederatedEngine._per_client`` places its client rows: ``placement``
+#: ``stacked`` (one ``vmap``) / ``sharded`` (each chip loops over the rows
+#: it holds) / ``folded`` (one row after another), ``rows`` handed to the
+#: program, and ``rows_a_chip``, what one chip's loop walks when sharded
+#: (every row otherwise).
+ARGS_BY_SPAN: dict[str, tuple[str, ...]] = {
+    SPAN_DISPATCH_PROGRAM: (
+        "program", "engine", "rounds", "samples_real", "steps_real",
+        "steps_run", "steps_skipped", "chip_steps_max", "chip_steps_mean",
+        "placement"),
+    SPAN_EVAL_DISPATCH: (
+        "program", "split", "placement", "rows", "rows_a_chip"),
+}
+
 # ---------------------------------------------------------------------------
 # device scope names (jax.named_scope): compile-time metadata on every
 # op traced inside, read back from a profiler trace's op metadata

@@ -12,7 +12,13 @@ SysML-2018 compile-once/dispatch-once premise in PAPERS.md):
 - :func:`cohort_map` wraps the per-client training block in ``shard_map``
   over the mesh's client axis with EXPLICIT in/out specs: each device
   trains its ``C/D`` client shard, then the trained stacks are
-  all-gathered back to replicated full stacks.
+  all-gathered back to replicated full stacks. Evaluation takes the same
+  road (PR 28, ``FederatedEngine._per_client``): each device scores the
+  client rows it holds, one unbatched row at a time, the global model
+  closed over (replicated) or the personalized stack cut by row, and
+  what is all-gathered is four scalars a client. The ``vmap`` it
+  replaced left the partitioning to GSPMD, which rebuilt the whole
+  stem activation of every row on every chip.
 - :func:`pad_cohort` pads a sampled set that does not tile the mesh
   (21 sites on 8 devices -> 24 rows) with zero-weight pad rows, and
   :func:`pad_row_weights` is THE one place pad-row weights are zeroed
@@ -85,6 +91,12 @@ a hard CORRECTNESS requirement, not a preference:
   the non-hoistable ``batch_order=replacement`` (i.i.d. per-step
   randint draws — same in-partition lowering family, same measured
   wrongness) falls back to the unsharded round with a logged reason.
+  The rule is held where the partition is entered:
+  ``FederatedEngine._cohort_map`` tells the trainer its rows sit in a
+  partition (``LocalTrainer.rows_alone(partitioned=True)``), and a
+  ``local_train`` traced there without ``perms`` raises. Evaluation
+  draws nothing random and sorts nothing (``binary_auc`` is the
+  pairwise form), so it needs no hoist.
 
 The compute-dominant stage (per-client Conv3D local training, ~99% of
 round FLOPs) therefore runs ``ceil(C/D)`` sequential clients per device
@@ -114,9 +126,10 @@ stacks back in the sampler's order and drops the pad rows, so the
 attack / codec / defense / aggregate / update tail sees the rows it
 always saw in the order it saw them and the weighted sum is the same sum:
 a dealt round equals the undealt one bitwise (tests/test_rows_alone.py).
-Phase 1 of SalientGrads and the final fine-tune pass
-(``cohort_local_stage``) go through :func:`cohort_map` in the data's own
-order; the streamed sharded feed keeps the sampler's order too (cohort
+Phase 1 of SalientGrads, the final fine-tune pass
+(``cohort_local_stage``) and evaluation go through :func:`cohort_map` in
+the data's own order (every evaluated row walks the same number of
+eval batches, so there is nothing to deal); the streamed sharded feed keeps the sampler's order too (cohort
 sharding does not arm under streaming: its rows are batched, and batched
 rows all walk the longest row's steps wherever they sit).
 """

@@ -25,6 +25,8 @@ measurements force):
     at startup.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +35,7 @@ import pytest
 from neuroimagedisttraining_tpu.config import (
     DataConfig, ExperimentConfig, FedConfig, OptimConfig,
 )
+from neuroimagedisttraining_tpu.core.losses import binary_auc
 from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
 from neuroimagedisttraining_tpu.data import partition as P
 from neuroimagedisttraining_tpu.data.federate import federate_cohort
@@ -40,6 +43,8 @@ from neuroimagedisttraining_tpu.data.stream import StreamingFederation
 from neuroimagedisttraining_tpu.data.synthetic import generate_synthetic_abcd
 from neuroimagedisttraining_tpu.engines import create_engine
 from neuroimagedisttraining_tpu.models import create_model
+from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.parallel import cohort
 from neuroimagedisttraining_tpu.parallel.mesh import make_mesh
 from neuroimagedisttraining_tpu.utils.logging import ExperimentLogger
@@ -84,6 +89,8 @@ def _engine(tmp_path, cohort_data, algorithm="fedavg", client_mesh=8,
         log_dir=str(tmp_path), tag=tag)
     if mesh is None:
         mesh = make_mesh(num_devices=n_dev)
+    elif mesh is False:  # no mesh at all: the one-chip engines
+        mesh = None
     trainer = LocalTrainer(create_model(cfg.model, num_classes=1),
                            cfg.optim, num_classes=1)
     log = ExperimentLogger(str(tmp_path), "synthetic", cfg.identity(),
@@ -368,6 +375,208 @@ def test_sharded_rounds_one_program_across_deals(tmp_path, cohort21):
     np.testing.assert_allclose(losses[0], ref_losses[0],
                                rtol=LOSS_ULP_RTOL)
     np.testing.assert_allclose(losses, ref_losses, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# (e) evaluation: each client row on the chip that holds it (PR 28)
+# ---------------------------------------------------------------------------
+
+#: the tolerance the evaluation tests of the other placements use
+#: (tests/test_round_fold.py: stacked against folded metrics): a client
+#: batched with others under ``vmap`` is another program than the client
+#: alone, float32 summation order and nothing else
+EVAL_RTOL, EVAL_ATOL = 1e-4, 1e-6
+
+_COLLECTIVE = re.compile(
+    r"= (.*?) (all-reduce|all-gather|all-to-all|collective-permute|"
+    r"collective-broadcast|reduce-scatter)(-start)?\(")
+
+
+def _eval_operands(eng, which):
+    """``(jit, operands)`` of one evaluation program over the whole
+    resident cohort: the global model, or a stack of personalized ones
+    that differ by row (a row read from the wrong chip would score
+    another model's numbers)."""
+    gs = eng.init_global_state()
+    d = eng.data
+    if which == "global":
+        return eng._eval_global_jit, (gs.params, gs.batch_stats,
+                                      d.X_test, d.y_test, d.n_test)
+    per = eng.broadcast_states(gs, eng.num_clients)
+    tilt = 1.0 + 0.05 * jnp.arange(eng.num_clients, dtype=jnp.float32)
+    params = jax.tree.map(
+        lambda x: x * tilt.reshape((-1,) + (1,) * (x.ndim - 1)),
+        per.params)
+    return eng._eval_personal_jit, (params, per.batch_stats,
+                                    d.X_test, d.y_test, d.n_test)
+
+
+def _eval_engines(tmp_path, request, rows):
+    """Sharded over 4 chips, the sequential reference, and the stacked
+    ``vmap`` on the same mesh, over 8 sites (2 rows a chip) or the 21
+    sites the data layer pads to 24 rows (6 a chip, 3 of them pads)."""
+    data, C = {8: ("synthetic_cohort8", 8), 24: ("cohort21", 21)}[rows]
+    data = request.getfixturevalue(data)
+    kw = dict(C=C, n_dev=4)
+    return (_engine(tmp_path, data, client_mesh=4, tag="esh", **kw),
+            _engine(tmp_path, data, client_mesh=4, seq=True, tag="esq",
+                    **kw),
+            _engine(tmp_path, data, client_mesh=0, tag="evm", **kw))
+
+
+@pytest.mark.parametrize("rows", [8, 24])
+@pytest.mark.parametrize("which", ["global", "personalized"])
+def test_sharded_evaluation_on_the_rows_chips(tmp_path, request, which,
+                                              rows):
+    """Where the cohort is sharded, evaluation runs through the cohort's
+    ``shard_map``: per client ``(correct, loss, total, auc)`` BITWISE the
+    sequential loop's (measured on this seed, 8 rows and 24, both models:
+    evaluation is one forward per batch and no training feeds a residue
+    back) and the stacked ``vmap``'s within the tolerance the other
+    placements' evaluation tests use; ``_summarize``'s numbers follow;
+    and the compiled program moves nothing between chips but the
+    per-client scalars: no all-reduce at all (the stacked form's rebuilt
+    stem activation was one), no collective over more than ``rows``
+    elements."""
+    sh, sq, vm = _eval_engines(tmp_path, request, rows)
+    assert sh.num_clients == rows
+    assert sh._rows_placement(rows) == ("sharded", rows // 4)
+    assert vm._rows_placement(rows) == ("stacked", rows)
+    progs = {name: _eval_operands(eng, which)
+             for name, eng in (("sh", sh), ("sq", sq), ("vm", vm))}
+    outs = {name: [np.asarray(o) for o in jit(*ops)]
+            for name, (jit, ops) in progs.items()}
+    n = np.asarray(sh.data.n_test)
+    assert (n[sh.real_clients:] == 0).all()
+    for a, b in zip(outs["sh"], outs["sq"]):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(outs["sh"], outs["vm"]):
+        np.testing.assert_allclose(a, b, rtol=EVAL_RTOL, atol=EVAL_ATOL)
+    # pad rows score nothing, and _summarize drops them
+    assert (outs["sh"][2][sh.real_clients:] == 0).all()
+    if which == "personalized":
+        # the rows are told apart: a model per row, a score per model
+        assert len(set(outs["sh"][1][:sh.real_clients].tolist())) > 1
+    summ = {k: e._summarize(*outs[k], n=n)
+            for k, e in (("sh", sh), ("sq", sq), ("vm", vm))}
+    assert summ["sh"] == summ["sq"]
+    for k in ("acc", "loss", "auc", "acc_pooled"):
+        np.testing.assert_allclose(summ["sh"][k], summ["vm"][k],
+                                   rtol=EVAL_RTOL, atol=EVAL_ATOL)
+    jit, ops = progs["sh"]
+    text = jit.lower(*ops).compile().as_text()
+    assert "all-reduce" not in text
+    moved = [(m.group(2), m.group(1)) for m in _COLLECTIVE.finditer(text)]
+    assert moved and {op for op, _ in moved} == {"all-gather"}
+    for _, result in moved:
+        for dims in re.findall(r"\w+\[([\d,]*)\]", result):
+            assert int(np.prod([int(x) for x in dims.split(",") if x]
+                               or [1])) <= rows, result
+    # the stacked form on the same mesh is what this arm replaced
+    jit, ops = progs["vm"]
+    assert "shard_map" not in jit.lower(*ops).as_text()
+
+
+def test_ci_single_row_evaluates_stacked(tmp_path, synthetic_cohort8):
+    """``--ci`` hands evaluation client 0's row alone: one row does not
+    tile the mesh, so it stays the ``vmap`` it was (no ``shard_map`` in
+    the lowered program), scores what row 0 of the whole cohort scores,
+    and the ``eval_dispatch`` span says so."""
+    ci = _engine(tmp_path, synthetic_cohort8, C=8, n_dev=4, client_mesh=4,
+                 tag="ci", ci=True)
+    full = _engine(tmp_path, synthetic_cohort8, C=8, n_dev=4,
+                   client_mesh=4, tag="cif")
+    assert ci.program.placement == "sharded"
+    assert ci._rows_placement(1) == ("stacked", 1)
+    gs = ci.init_global_state()
+    obs_trace.arm()
+    try:
+        m_ci = ci.eval_global(gs.params, gs.batch_stats)
+        m_full = full.eval_global(gs.params, gs.batch_stats)
+        spans = [e["args"] for e in obs_trace.TRACER.events()
+                 if e.get("name") == obs_names.SPAN_EVAL_DISPATCH]
+    finally:
+        obs_trace.disarm()
+    assert [(a["placement"], a["rows"], a["rows_a_chip"])
+            for a in spans[-2:]] == [("stacked", 1, 1), ("sharded", 8, 2)]
+    d = ci.data
+    one = (d.X_test[:1], d.y_test[:1], d.n_test[:1])
+    assert "shard_map" not in ci._eval_global_jit.lower(
+        gs.params, gs.batch_stats, *one).as_text()
+    row0 = [np.asarray(o)[:1] for o in full._eval_global_jit(
+        gs.params, gs.batch_stats, d.X_test, d.y_test, d.n_test)]
+    want = full._summarize(*row0, n=np.asarray(d.n_test)[:1])
+    for k in ("acc", "loss", "auc", "acc_pooled"):
+        np.testing.assert_allclose(m_ci[k], want[k], rtol=EVAL_RTOL,
+                                   atol=EVAL_ATOL)
+    assert m_full["loss"] != m_ci["loss"]
+
+
+def _eval_all_as_it_was(eng, which, folded):
+    """The evaluation program of the two placements this PR leaves
+    alone, written out as the parent had it: ``vmap`` over the rows, or
+    ``sequential_map`` with the rows alone when the round folds."""
+    trainer = eng.trainer
+
+    def eval_all(params, bstats, X, y, n):
+        def score(p, b, Xc, yc, nc):
+            valid = jnp.arange(Xc.shape[0]) < nc
+            m = trainer.evaluate(p, b, Xc, yc, valid)
+            auc = binary_auc(m["scores"], yc, valid)
+            return m["test_correct"], m["test_loss"], m["test_total"], auc
+
+        if which == "global":
+            fn = lambda Xc, yc, nc: score(params, bstats, Xc, yc, nc)
+            stacked = (X, y, n)
+        else:
+            fn, stacked = score, (params, bstats, X, y, n)
+        if folded:
+            with trainer.rows_alone():
+                return cohort.sequential_map(fn, *stacked)
+        return jax.vmap(fn)(*stacked)
+
+    return jax.jit(eval_all)
+
+
+@pytest.mark.parametrize("which", ["global", "personalized"])
+@pytest.mark.parametrize("placement", ["stacked", "folded"])
+def test_unsharded_evaluation_programs_unchanged(tmp_path,
+                                                 synthetic_cohort,
+                                                 placement, which):
+    """With no mesh, and with the fold forced, ``_per_client`` lowers the
+    program it lowered before it had a sharded arm: the text of the
+    engine's jit against the parent's form written out by hand (lowered
+    text carries no source locations, so equal programs are equal
+    strings)."""
+    eng = _engine(tmp_path, synthetic_cohort, C=4, client_mesh=0,
+                  mesh=False, tag=f"un-{placement}")
+    if placement == "folded":
+        eng._fold_budget_bytes = 1
+    assert eng.program.placement == placement
+    jit, ops = _eval_operands(eng, which)
+    was = _eval_all_as_it_was(eng, which, placement == "folded")
+    assert jit.lower(*ops).as_text() == was.lower(*ops).as_text()
+    assert "shard_map" not in jit.lower(*ops).as_text()
+
+
+def test_local_train_in_the_sharded_arm_needs_hoisted_perms(
+        tmp_path, synthetic_cohort8):
+    """``fedavg._finetune_eval_jit`` trains inside ``_per_client`` and
+    draws its own permutations; it is reached only streamed or folded,
+    where cohort sharding never arms. Were it ever traced in the sharded
+    arm, the in-partition random sort (the measured miscompile,
+    parallel/cohort.py) must not run: ``local_train`` refuses."""
+    eng = _engine(tmp_path, synthetic_cohort8, C=8, n_dev=4,
+                  client_mesh=4, tag="ft")
+    gs = eng.init_global_state()
+    d = eng.data
+    rngs = eng.per_client_rngs(0, np.arange(eng.num_clients))
+    with pytest.raises(ValueError, match="without hoisted permutations"):
+        eng._finetune_eval_jit.lower(
+            gs.params, gs.batch_stats, d.X_train, d.y_train, d.n_train,
+            d.X_test, d.y_test, d.n_test, rngs, eng.round_lr(0))
+    # the trainer is left as it was found
+    assert not eng.trainer._rows_alone and not eng.trainer._partitioned
 
 
 # ---------------------------------------------------------------------------

@@ -248,6 +248,18 @@ def test_one_armed_run_spans_every_iteration(tmp_path, synthetic_cohort,
                           and e["args"]["round"] == 1)
     assert first_sync["args"]["program"] == "eval_global"
     assert first_dispatch["dur"] >= 0
+    # every span carries what obs/names.py's table says it carries;
+    # evaluation says where _per_client placed its rows (no client
+    # mesh armed and no fold here: the four sites' rows, padded to the
+    # eight devices by the data layer, stacked under one vmap)
+    for name in (names.SPAN_DISPATCH_PROGRAM, names.SPAN_EVAL_DISPATCH):
+        for e in events:
+            if e["name"] == name:
+                assert set(names.ARGS_BY_SPAN[name]) <= set(e["args"]), e
+    a = first_dispatch["args"]
+    assert (a["program"], a["split"]) == ("eval_global", "test")
+    assert (a["placement"], a["rows"], a["rows_a_chip"]) == (
+        "stacked", 8, 8)
 
 
 @pytest.mark.parametrize("case", ["fedavg", "salientgrads"])
@@ -301,6 +313,7 @@ def test_names_table_is_complete():
     assert len(spans) == len(set(spans))
     for s in names.ROUND_CHILD_SPANS:
         assert s.startswith(("round", "dispatch", "eval_"))
+    assert set(names.ARGS_BY_SPAN) <= set(spans)
 
 
 def test_model_scopes_table():
