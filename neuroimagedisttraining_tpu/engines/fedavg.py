@@ -37,16 +37,26 @@ from neuroimagedisttraining_tpu.utils import pytree as pt
 EXPERT_TOKENS = "expert_tokens"
 
 
-def expert_load(expert_tokens) -> dict:
+def expert_load(expert_tokens, held: tuple[int, int] | None = None) -> dict:
     """The round's expert-load counters from the round program's
     ``expert_tokens`` output, as host numbers for the ``round_log``
     span: slots routed, and the busiest and the idlest expert's load
-    over the mean (1.0 = perfectly balanced)."""
+    over the mean (1.0 = perfectly balanced). For a model that holds a
+    share of its experts (``held_experts``: the first and how many;
+    models/nemotronh3d.py) also ``rows_held``, the assignments that
+    landed on the held experts (the rows its grouped matmuls multiply),
+    and the busiest held expert over the held mean."""
     tokens = np.asarray(expert_tokens, np.float64)
     mean = max(float(tokens.mean()), 1e-12)
-    return {"tokens_routed": int(tokens.sum()),
-            "expert_load_max_over_mean": float(tokens.max()) / mean,
-            "expert_load_min_over_mean": float(tokens.min()) / mean}
+    out = {"tokens_routed": int(tokens.sum()),
+           "expert_load_max_over_mean": float(tokens.max()) / mean,
+           "expert_load_min_over_mean": float(tokens.min()) / mean}
+    if held is not None:
+        here = tokens[held[0]:held[0] + held[1]]
+        out["rows_held"] = int(here.sum())
+        out["held_load_max_over_mean"] = float(here.max()) / max(
+            float(here.mean()), 1e-12)
+    return out
 
 
 class FedAvgEngine(FederatedEngine):
@@ -394,7 +404,9 @@ class FedAvgEngine(FederatedEngine):
                 if counters and obs_trace.TRACER.armed:
                     # read where the round's loss is read: the round has
                     # finished (eval_sync waited for it), so no new sync
-                    log_span.args.update(expert_load(counters[0]))
+                    log_span.args.update(expert_load(
+                        counters[0], getattr(self.trainer.model,
+                                             "held_experts", None)))
                 history.append({"round": round_idx,
                                 "train_loss": float(loss), **m})
         with obs_trace.span(obs_names.SPAN_ROUND_CHECKPOINT):

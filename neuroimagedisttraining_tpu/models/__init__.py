@@ -39,6 +39,9 @@ from neuroimagedisttraining_tpu.models.neuro3d import (  # noqa: F401
     ResNet3D_l3,
     Tiny3DCNN,
 )
+from neuroimagedisttraining_tpu.models.nemotronh3d import (  # noqa: F401
+    NemotronH3D,
+)
 from neuroimagedisttraining_tpu.models.olmoe3d import OLMoE3D  # noqa: F401
 from neuroimagedisttraining_tpu.models.resnet2d import (  # noqa: F401
     ResNet18,
@@ -103,6 +106,13 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         # sparse-expert block at its published widths over 3D patch tokens
         return OLMoE3D(num_classes=num_classes, dtype=dtype,
                        remat=remat is True)
+    if name == "nemotronh3d":
+        # added here, not ported (models/nemotronh3d.py): the first nine
+        # layers of Nemotron-H's hybrid pattern at the published widths,
+        # 8 of each expert layer's 128 experts held. Its layers are
+        # rematerialised by the model's own declaration (remat_layers):
+        # --remat is the 3D CNN family's policy and does not reach it
+        return NemotronH3D(num_classes=num_classes, dtype=dtype)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":

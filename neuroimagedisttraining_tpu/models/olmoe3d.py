@@ -9,7 +9,8 @@ before RoPE (theta 10000, rotate-half), 64 SiLU-gated experts of width
 1024, 8 per token, no shared expert, router weights NOT renormalised, no
 bias anywhere, load-balancing auxiliary loss with coefficient 0.01.
 
-What is this system's own is how the trunk meets a volume (ROADMAP R4):
+What is this system's own is how the trunk meets a volume (ROADMAP R4;
+models/tokens3d.py, shared with models/nemotronh3d.py):
 
     x uint8 [B,121,145,121] -> (x - mean) / std of the volume, zero-pad
                                to [B,128,160,128]
@@ -46,28 +47,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from neuroimagedisttraining_tpu.models import tokens3d
+from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
 from neuroimagedisttraining_tpu.obs import names as obs_names
 
 Dtype = Any
 _scope = jax.named_scope
 _init = nn.initializers.normal(stddev=0.02)  # OLMoE's, every matrix
-
-
-class RMSNorm(nn.Module):
-    """``weight * x / sqrt(mean(x^2) + eps)``, the statistics in float32
-    as the public code computes them."""
-
-    eps: float = 1e-5
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param("weight", nn.initializers.ones,
-                            (x.shape[-1],), jnp.float32)
-        x32 = x.astype(jnp.float32)  # nidt: allow[precision-upcast] -- norm statistics in float32 (OlmoeRMSNorm)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = (x32 * jax.lax.rsqrt(var + self.eps)).astype(self.dtype)
-        return weight.astype(self.dtype) * y
 
 
 def rope_tables(positions: int, head_dim: int, theta: float):
@@ -209,31 +195,12 @@ class OLMoE3D(nn.Module):
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", "expert_tokens"})
 
-    def patches(self, x):
-        """``[B, D, H, W, 1]`` raw intensities -> ``[B, tokens, patch^3]``:
-        each volume standardised over its own voxels (zero mean, unit
-        variance, in float32), zero-padded (the mean) up to a multiple of
-        the patch, raster order D, H, W (and d, h, w inside a patch)."""
-        P = self.patch
-        x = x[..., 0].astype(jnp.float32)  # nidt: allow[precision-upcast] -- the volume's statistics in float32, like a norm's
-        x = x - jnp.mean(x, axis=(1, 2, 3), keepdims=True)
-        var = jnp.mean(jnp.square(x), axis=(1, 2, 3), keepdims=True)
-        x = (x * jax.lax.rsqrt(var + self.rms_eps)).astype(self.dtype)
-        pads = [(0, 0)] + [(0, (-n) % P) for n in x.shape[1:]]
-        x = jnp.pad(x, pads)
-        B, D, H, W = x.shape
-        x = x.reshape(B, D // P, P, H // P, P, W // P, P)
-        x = x.transpose(0, 1, 3, 5, 2, 4, 6)
-        return x.reshape(B, (D // P) * (H // P) * (W // P), P ** 3)
-
     @nn.compact
     def __call__(self, x, train: bool = False):
         from neuroimagedisttraining_tpu.ops import moe  # ops imports models
 
-        with _scope(obs_names.SCOPE_STEM):
-            h = nn.Dense(self.hidden_size, dtype=self.dtype,
-                         kernel_init=_init, name="patch_embed")(
-                             self.patches(x))
+        h = tokens3d.patch_embed(x, self.hidden_size, self.patch,
+                                 self.rms_eps, self.dtype, _init)
         block = nn.remat(Block) if self.remat else Block
         probs, experts = [], []
         for i in range(self.depth):
@@ -243,16 +210,8 @@ class OLMoE3D(nn.Module):
                 self.dtype, name=f"layers_{i}")(h)
             probs.append(p)
             experts.append(e)
-        with _scope(obs_names.SCOPE_HEAD):
-            # the read-out is float32 whatever the compute dtype: 2048 x
-            # classes, no cost; a bf16 logit of order 1 is 0.4% coarse
-            pooled = jnp.mean(
-                RMSNorm(self.rms_eps, jnp.float32, name="final_norm")(h),
-                axis=1)
-            logits = nn.Dense(self.num_classes, use_bias=False,
-                              dtype=jnp.float32, kernel_init=_init,
-                              precision=jax.lax.Precision.HIGHEST,
-                              name="head")(pooled)
+        logits = tokens3d.pooled_logits(h, self.num_classes, self.rms_eps,
+                                        _init)
         # over every layer's rows at once, as the public
         # load_balancing_loss_func concatenates them
         probs = jnp.concatenate(probs)

@@ -1,0 +1,84 @@
+"""What the token trunks share (models/olmoe3d.py, models/nemotronh3d.py):
+how a decoder trunk meets a volume, and how its one logit is read.
+
+    x uint8 [B,121,145,121] -> (x - mean) / std of the volume, zero-pad
+                               to a multiple of the patch
+    tokens = patches(P^3, raster order D,H,W) @ W_pe + b_pe    (the ``stem``)
+    ... the trunk's layers ...
+    logit = mean_t(RMSNorm(h)) @ W_head, in float32            (the ``head``)
+
+as vision-language models feed a decoder (``inputs_embeds``) and as
+embedding models read one (the mean of the final hidden states). Why the
+volume is standardised and the read-out pooled is in
+benchmark/configs/olmoe-abcd.json (``assumed``). The helpers create their
+flax modules in the calling ``@nn.compact`` method, under the names the
+trunks' parameter trees have always had (``patch_embed``, ``final_norm``,
+``head``).
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
+Dtype = Any
+_scope = jax.named_scope
+
+
+class RMSNorm(nn.Module):
+    """``weight * x / sqrt(mean(x^2) + eps)``, the statistics in float32
+    as the public code computes them."""
+
+    eps: float = 1e-5
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        weight = self.param("weight", nn.initializers.ones,
+                            (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)  # nidt: allow[precision-upcast] -- norm statistics in float32 (OlmoeRMSNorm)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        y = (x32 * jax.lax.rsqrt(var + self.eps)).astype(self.dtype)
+        return weight.astype(self.dtype) * y
+
+
+def patches(x, patch: int, eps: float, dtype):
+    """``[B, D, H, W, 1]`` raw intensities -> ``[B, tokens, patch^3]``:
+    each volume standardised over its own voxels (zero mean, unit
+    variance, in float32), zero-padded (the mean) up to a multiple of
+    the patch, raster order D, H, W (and d, h, w inside a patch)."""
+    P = patch
+    x = x[..., 0].astype(jnp.float32)  # nidt: allow[precision-upcast] -- the volume's statistics in float32, like a norm's
+    x = x - jnp.mean(x, axis=(1, 2, 3), keepdims=True)
+    var = jnp.mean(jnp.square(x), axis=(1, 2, 3), keepdims=True)
+    x = (x * jax.lax.rsqrt(var + eps)).astype(dtype)
+    pads = [(0, 0)] + [(0, (-n) % P) for n in x.shape[1:]]
+    x = jnp.pad(x, pads)
+    B, D, H, W = x.shape
+    x = x.reshape(B, D // P, P, H // P, P, W // P, P)
+    x = x.transpose(0, 1, 3, 5, 2, 4, 6)
+    return x.reshape(B, (D // P) * (H // P) * (W // P), P ** 3)
+
+
+def patch_embed(x, hidden_size: int, patch: int, eps: float, dtype, init):
+    """The ``stem``: one linear patch embedding with a bias."""
+    with _scope(obs_names.SCOPE_STEM):
+        return nn.Dense(hidden_size, dtype=dtype, kernel_init=init,
+                        name="patch_embed")(patches(x, patch, eps, dtype))
+
+
+def pooled_logits(h, num_classes: int, eps: float, init):
+    """The ``head``: float32 whatever the compute dtype (hidden x
+    classes, no cost; a bf16 logit of order 1 is 0.4% coarse)."""
+    with _scope(obs_names.SCOPE_HEAD):
+        pooled = jnp.mean(RMSNorm(eps, jnp.float32, name="final_norm")(h),
+                          axis=1)
+        return nn.Dense(num_classes, use_bias=False, dtype=jnp.float32,
+                        kernel_init=init,
+                        precision=jax.lax.Precision.HIGHEST,
+                        name="head")(pooled)

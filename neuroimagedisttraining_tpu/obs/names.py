@@ -222,10 +222,22 @@ SCOPE_DISPATCH = "dispatch"  # sort of the k*T slots by expert + gather
 SCOPE_EXPERTS = "experts"    # the three grouped matmuls and the SiLU gate
 SCOPE_COMBINE = "combine"    # un-sort and the weighted sum over k
 
-#: the model scopes of the table above (disjoint from DEVICE_SCOPES)
+# the hybrid trunk's further stages (models/nemotronh3d.py, PR 29;
+# benchmark/metrics/nemotronh_scopes.json): the Mamba-2 mixer in five,
+# and the expert layer's shared expert. Its GQA layer reuses SCOPE_ATTN,
+# its held experts the four expert scopes above.
+SCOPE_SSM_IN_PROJ = "ssm_in_proj"      # u W_in and the split into z, xBC, dt
+SCOPE_SSM_CONV = "ssm_conv"            # causal depthwise conv, SiLU, split
+SCOPE_SSD = "ssd"                      # softplus(dt), the chunked scan (ops/ssd.py)
+SCOPE_SSM_GATE_NORM = "ssm_gate_norm"  # y * silu(z), grouped RMSNorm
+SCOPE_SSM_OUT_PROJ = "ssm_out_proj"    # y W_out
+SCOPE_SHARED_EXPERT = "shared_expert"  # the relu^2 MLP every token takes
+
+#: the model scopes of the two tables above (disjoint from DEVICE_SCOPES)
 MODEL_SCOPES: frozenset[str] = frozenset(
     (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
-     SCOPE_COMBINE))
+     SCOPE_COMBINE, SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSD,
+     SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT))
 
 #: every declared metric name — the set obs/rules.py validates rule
 #: manifests against at startup (unknown names fail with this list)

@@ -403,3 +403,52 @@ def test_folded_train_logs_expert_load_every_round(tmp_path):
         assert e["args"]["tokens_routed"] == real_steps * 2 * (4 * 8)
         assert e["args"]["expert_load_max_over_mean"] >= 1.0 \
             >= e["args"]["expert_load_min_over_mean"]
+
+
+def test_olmoe3d_lowers_to_the_parents_text(monkeypatch):
+    """PR 29 put two routers behind ``route``, a ``held`` window into
+    ``grouped_matmul`` and the tiles behind ``gmm_tiling``, and moved
+    ``RMSNorm``, ``patches`` and the read-out to models/tokens3d.py. The
+    OLMoE block must not notice: its training step compiles to the program
+    it compiles to with the parent's ``route`` and ``grouped_matmul``
+    (copied here verbatim) in their place, metadata apart; and on a TPU
+    the kernel is called as the parent called it (all 64 groups, tiles
+    (512, 1024, 1024), no ``group_offset``)."""
+    from neuroimagedisttraining_tpu.ops import moe
+
+    def parent_route(logits, k):
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        return probs, weights, experts
+
+    def parent_grouped_matmul(xs, w, group_sizes):
+        return jax.lax.ragged_dot(xs, w, group_sizes)
+
+    tr = _trainer()
+    cs, (x, y) = _state(tr), _batch(0)
+
+    def text():
+        return _instructions(
+            jax.jit(tr.loss_and_grad).lower(cs, x, y).compile().as_text())
+
+    ours = text()
+    assert len(ours) > 100
+    with monkeypatch.context() as m:
+        m.setattr(moe, "route", parent_route)
+        m.setattr(moe, "grouped_matmul", parent_grouped_matmul)
+        assert text() == ours
+
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    calls = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        megablox, "gmm", lambda *a: calls.append(a) or jnp.zeros(
+            (a[0].shape[0], a[1].shape[2]), a[3]))
+    xs = jnp.zeros((81920, 2048), jnp.bfloat16)
+    w = jnp.zeros((64, 2048, 1024), jnp.bfloat16)
+    sizes = jnp.zeros((64,), jnp.int32)
+    moe.grouped_matmul(xs, w, sizes)
+    ((a_xs, a_w, a_sizes, dtype, tiling),) = calls  # five operands, no sixth
+    assert a_xs is xs and a_w is w and a_sizes is sizes
+    assert dtype == jnp.bfloat16 and tiling == (512, 1024, 1024)

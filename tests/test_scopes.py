@@ -317,17 +317,19 @@ def test_names_table_is_complete():
 
 
 def test_model_scopes_table():
-    """MODEL_SCOPES (the sparse-expert block's stages, PR 25) is a second
-    table, disjoint from DEVICE_SCOPES (which benchmark/scopes.json pins);
-    every constant is used at least once in models/, none is spelled as a
-    literal there, and the benchmark's own rules file names each."""
+    """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, and of
+    the hybrid trunk, PR 29) is a second table, disjoint from DEVICE_SCOPES
+    (which benchmark/scopes.json pins); every constant is used at least
+    once in models/, none is spelled as a literal there, and the
+    benchmark's two rules files name each between them: the OLMoE block's
+    five in olmoe_scopes.json, all eleven in nemotronh_scopes.json."""
     import glob
     import json
     import os
 
     consts = {k: v for k, v in vars(names).items()
               if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
-    assert len(consts) == len(names.MODEL_SCOPES) == 5
+    assert len(consts) == len(names.MODEL_SCOPES) == 11
     assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sources = {p: open(p).read() for p in glob.glob(os.path.join(
@@ -336,10 +338,16 @@ def test_model_scopes_table():
         assert any(f"obs_names.{const}" in s for s in sources.values()), const
         for path, s in sources.items():
             assert not re.search(rf"""["']{value}["']""", s), (path, value)
-    rules = json.load(open(os.path.join(
-        root, "benchmark", "metrics", "olmoe_scopes.json")))
-    named = {s for k, v in rules["scope_names"].items() if k != "what"
-             for s in v}
-    assert named == set(names.MODEL_SCOPES)
-    classes = {r["class"] for r in rules["block"]}
-    assert set(rules["scope_names"]) - {"what"} <= classes
+    named = {}
+    for file, partition in (("olmoe_scopes.json", "block"),
+                            ("nemotronh_scopes.json", "trunk")):
+        rules = json.load(open(os.path.join(
+            root, "benchmark", "metrics", file)))
+        named[file] = {s for k, v in rules["scope_names"].items()
+                       if k != "what" for s in v}
+        classes = {r["class"] for r in rules[partition]}
+        assert set(rules["scope_names"]) - {"what"} <= classes
+    assert named["olmoe_scopes.json"] == {
+        names.SCOPE_ATTN, names.SCOPE_ROUTER, names.SCOPE_DISPATCH,
+        names.SCOPE_EXPERTS, names.SCOPE_COMBINE}
+    assert named["nemotronh_scopes.json"] == set(names.MODEL_SCOPES)

@@ -133,3 +133,40 @@ def test_stem_dw_compiles_at_full_volume(chip, clients):
         # a client's patch rows alone would be 126 MiB. (XLA's own form,
         # unbatched, takes 1,157 MiB for its padded copy of x.)
         assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
+
+
+#: (rows, held experts' first, label): Nemotron-H's training batch (120 row
+#: tiles of 512), its one-volume initialisation (15 tiles of 256) and a
+#: share that starts in the middle of the layer's 128 experts
+GMM_CASES = [(61440, 0, "batch16"), (3840, 0, "one_volume"),
+             (61440, 56, "mid_window")]
+
+
+@pytest.mark.parametrize("rows,first,label", GMM_CASES,
+                         ids=[c[2] for c in GMM_CASES])
+def test_held_grouped_matmul_compiles_at_the_published_widths(
+        chip, monkeypatch, rows, first, label):
+    """``ops/moe.py`` ``grouped_matmul`` over a share of the experts, at
+    Nemotron-H's widths (2688 x 1856: no multiple of 1024, 1856 no
+    multiple of 128), forward and both gradients: ``megablox.gmm`` with a
+    ``group_offset``, the tiles :func:`gmm_tiling` chose (384: a ragged
+    last tile of 1856 that the kernel masks): the first matrix's forward
+    kernel and ``gmm`` / ``tgmm`` for both matrices' gradients (the second
+    matrix's forward output is not needed for the gradient of a sum)."""
+    from neuroimagedisttraining_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    xs = _on(chip, (rows, 2688), jnp.bfloat16)
+    up = _on(chip, (8, 2688, 1856), jnp.bfloat16)
+    down = _on(chip, (8, 1856, 2688), jnp.bfloat16)
+    sizes = _on(chip, (128,), jnp.int32)
+
+    def loss(xs, up, down, sizes):
+        u = moe.grouped_matmul(xs, up, sizes, first)
+        y = moe.grouped_matmul(jnp.square(jax.nn.relu(u)), down, sizes,
+                               first)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        xs, up, down, sizes).compile().as_text()
+    assert text.count(KERNEL_MARK) == 5
