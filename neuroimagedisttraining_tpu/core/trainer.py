@@ -142,6 +142,11 @@ class LocalTrainer:
         #: function. A model without the declaration traces exactly the
         #: program it traced before the declaration existed.
         self.has_aux = bool(getattr(model, "returns_aux", False))
+        #: the integer entries of that dict (the model's
+        #: ``aux_counters``), each summed over a client's real steps
+        #: and returned after the loss, in this order
+        self.aux_counters = tuple(model.aux_counters) if self.has_aux \
+            else ()
         #: set by :meth:`rows_alone` while a placement traces rows
         #: unbatched (and, second, inside a ``shard_map`` partition);
         #: ``local_train`` reads both at trace time
@@ -284,9 +289,10 @@ class LocalTrainer:
 
         A model that declares an auxiliary output (``has_aux``) trains on
         task loss + ``aux["loss"]``; the returned mean loss stays the task
-        loss, and such a model's call returns a third value, its
-        ``expert_tokens`` summed over the client's REAL steps (masked
-        padded steps add nothing).
+        loss, and such a model's call returns its ``aux_counters`` after
+        it (``expert_tokens``; Nemotron-H's ``held_overflow_calls``
+        too), each summed over the client's REAL steps (masked padded
+        steps add nothing).
 
         ``prox_lamda``/``prox_ref``: Ditto's personalized proximal pull,
         applied after each optimizer step: ``w -= lr * lamda * (w - ref)``
@@ -326,8 +332,8 @@ class LocalTrainer:
         def advance(state, t, brng, drng):
             """Iteration ``t`` computed: its batch, forward, backward and
             the optimizer tail. ``(params, batch_stats, opt_state, out)``
-            with ``out`` the step's loss, or ``(loss, expert_tokens)``
-            of a model with an auxiliary output."""
+            with ``out`` the step's loss, or ``(loss, *counters)`` of a
+            model with an auxiliary output."""
             with jax.named_scope(obs_names.SCOPE_BATCH_PREP):
                 if shuffle:
                     idx, wb = shuffle_batch_indices(
@@ -374,7 +380,8 @@ class LocalTrainer:
                     params = jax.tree.map(
                         lambda w, ref: w - lr * prox_lamda * (w - ref),
                         params, prox_ref)
-            out = (loss, aux["expert_tokens"]) if self.has_aux else loss
+            out = (loss, *(aux[name] for name in self.aux_counters)) \
+                if self.has_aux else loss
             return params, bstats, opt_state, out
 
         def step(carry, t):
@@ -397,10 +404,11 @@ class LocalTrainer:
                     rng=rng)
                 if not self.has_aux:
                     return new_state, jnp.where(active, out, 0.0)
-                loss, tokens = out
-                return new_state, (jnp.where(active, loss, 0.0),
-                                   jnp.where(active, tokens,
-                                             jnp.zeros_like(tokens)))
+                loss, *counters = out
+                return new_state, (
+                    jnp.where(active, loss, 0.0),
+                    *(jnp.where(active, c, jnp.zeros_like(c))
+                      for c in counters))
 
         def real_steps(cs):
             """The row runs alone: its ``epochs * my_steps`` real
@@ -448,8 +456,9 @@ class LocalTrainer:
         denom = jnp.maximum(epochs * my_steps, 1)
         if not self.has_aux:
             return cs, jnp.sum(outs) / denom
-        losses, tokens = outs
-        return cs, jnp.sum(losses) / denom, jnp.sum(tokens, axis=0)
+        losses, *counters = outs
+        return (cs, jnp.sum(losses) / denom,
+                *(jnp.sum(c, axis=0) for c in counters))
 
     def lower_train_step(self, input_shape: tuple[int, ...],
                          batch_size: int):

@@ -31,13 +31,17 @@ from neuroimagedisttraining_tpu.obs import trace as obs_trace
 from neuroimagedisttraining_tpu.utils import pytree as pt
 
 
-#: the round program's extra output for a model that declares an
-#: auxiliary output (core/trainer.py ``has_aux``): int32 [experts], the
-#: slots routed to each expert over the round's real steps
+#: the round program's first extra output for a model that declares an
+#: auxiliary output (core/trainer.py ``aux_counters``): int32 [experts],
+#: the slots routed to each expert over the round's real steps
 EXPERT_TOKENS = "expert_tokens"
+#: Nemotron-H's second: the expert-layer calls of those steps whose held
+#: rows passed the buffer (ops/moe.py ``held_expert_rows``)
+HELD_OVERFLOW_CALLS = "held_overflow_calls"
 
 
-def expert_load(expert_tokens, held: tuple[int, int] | None = None) -> dict:
+def expert_load(expert_tokens, held: tuple[int, int] | None = None,
+                overflow_calls=None, capacity: int | None = None) -> dict:
     """The round's expert-load counters from the round program's
     ``expert_tokens`` output, as host numbers for the ``round_log``
     span: slots routed, and the busiest and the idlest expert's load
@@ -45,7 +49,11 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None) -> dict:
     share of its experts (``held_experts``: the first and how many;
     models/nemotronh3d.py) also ``rows_held``, the assignments that
     landed on the held experts (the rows its grouped matmuls multiply),
-    and the busiest held expert over the held mean."""
+    and the busiest held expert over the held mean; and, from its second
+    output, ``held_overflow_calls``, the calls whose held rows passed
+    the buffer and took more than one window of it, beside
+    ``held_capacity_rows`` (``capacity``: the rows of a training step's
+    buffer; 0 where a step has none)."""
     tokens = np.asarray(expert_tokens, np.float64)
     mean = max(float(tokens.mean()), 1e-12)
     out = {"tokens_routed": int(tokens.sum()),
@@ -56,6 +64,9 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None) -> dict:
         out["rows_held"] = int(here.sum())
         out["held_load_max_over_mean"] = float(here.max()) / max(
             float(here.mean()), 1e-12)
+    if overflow_calls is not None:
+        out[HELD_OVERFLOW_CALLS] = int(overflow_calls)
+        out["held_capacity_rows"] = capacity or 0
     return out
 
 
@@ -88,10 +99,8 @@ class FedAvgEngine(FederatedEngine):
         hand-written paths (tests/test_dispatch.py, test_cohort.py).
         The stage works on a one-client stack and routes the default
         tail, so it declares the folded placement; a model with an
-        auxiliary output adds its expert-load counter to the outputs."""
-        outputs = ("loss", "n_bad")
-        if self.trainer.has_aux:
-            outputs += (EXPERT_TOKENS,)
+        auxiliary output adds its counters to the outputs."""
+        outputs = ("loss", "n_bad") + self.trainer.aux_counters
         return round_program.RoundStages(
             carry=("params", "batch_stats"),
             train=self._train_stage,
@@ -133,13 +142,30 @@ class FedAvgEngine(FederatedEngine):
                 batch_size=o.batch_size, max_samples=max_samples,
                 perms=perms_c, **prox)
 
-        cs, losses, *tokens = ctx.client_map(
+        cs, losses, *counters = ctx.client_map(
             local, cs, Xs, ys, ns,
             hoisted=(lambda: ctx.local_perms(ctx.rngs, ns, o.epochs),))
         return round_program.TrainOut(
             losses=losses,
             upload={"params": cs.params, "batch_stats": cs.batch_stats},
-            state=cs, extra=dict(zip((EXPERT_TOKENS,), tokens)))
+            state=cs, extra=dict(zip(trainer.aux_counters, counters)))
+
+    @functools.cached_property
+    def _held_capacity_rows(self) -> int | None:
+        """The rows of the buffer a training batch's held runs are
+        gathered into, for a model that has one."""
+        capacity = getattr(self.trainer.model, "held_capacity_rows", None)
+        return capacity and capacity((self.cfg.optim.batch_size,
+                                      *self.sample_input().shape[1:]))
+
+    def _expert_load(self, counters) -> dict:
+        """:func:`expert_load` of one round's auxiliary outputs, with
+        what the model says of its held share."""
+        named = dict(zip(self.trainer.aux_counters, counters))
+        return expert_load(
+            named[EXPERT_TOKENS],
+            getattr(self.trainer.model, "held_experts", None),
+            named.get(HELD_OVERFLOW_CALLS), self._held_capacity_rows)
 
     # ---------- legacy-signature program adapters ----------
     # The builder's compiled programs take structured (carry, data,
@@ -404,9 +430,7 @@ class FedAvgEngine(FederatedEngine):
                 if counters and obs_trace.TRACER.armed:
                     # read where the round's loss is read: the round has
                     # finished (eval_sync waited for it), so no new sync
-                    log_span.args.update(expert_load(
-                        counters[0], getattr(self.trainer.model,
-                                             "held_experts", None)))
+                    log_span.args.update(self._expert_load(counters))
                 history.append({"round": round_idx,
                                 "train_loss": float(loss), **m})
         with obs_trace.span(obs_names.SPAN_ROUND_CHECKPOINT):

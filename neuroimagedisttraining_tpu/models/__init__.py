@@ -162,9 +162,10 @@ def primary_logits(out):
 
 def aux_outputs(out) -> dict | None:
     """The auxiliary dict of a model that declares one (``returns_aux``:
-    ``(logits, {"loss": weighted scalar, "expert_tokens": int32 [E]})``,
-    models/olmoe3d.py), or None: the reference models' second output is
-    a feature tensor, never a dict."""
+    ``(logits, {"loss": weighted scalar, "expert_tokens": int32 [E],
+    ...})``, the integer entries named by the model's ``aux_counters``;
+    models/olmoe3d.py, models/nemotronh3d.py), or None: the reference
+    models' second output is a feature tensor, never a dict."""
     if isinstance(out, (tuple, list)) and len(out) == 2 \
             and isinstance(out[1], dict):
         return out[1]

@@ -28,12 +28,14 @@ of three kinds (d = 2688, eps 1e-5, no bias but the conv's):
        (position comes through the ``M`` layers).
 
 **The expert layer holds a share of its experts** (``held``: the first
-expert and how many; ops/moe.py): it routes over all 128, sorts every
-(token, slot) pair, and computes the part of the result that its own
-experts give for the rows routed to them, dropping none. What the absent
-experts would add is left out, as on one chip of a deployment that
-divides each layer over 16 by expert parallelism. The router, the shared
-expert, the mixers and every norm are whole.
+expert and how many; ops/moe.py ``held_expert_rows``): it routes over
+all 128 and computes the part of the result that its own experts give
+for the rows routed to them, dropping none: it gathers, multiplies and
+adds back those rows alone, a buffer of twice the uniform share at a
+time (once, unless the routing sends more here than the buffer holds).
+What the absent experts would add is left out, as on one chip of a
+deployment that divides each layer over 16 by expert parallelism. The
+router, the shared expert, the mixers and every norm are whole.
 
 What is NOT built: the row's second, denoiser tower (adaLN,
 bidirectional in-block attention, cross-tower conditioning) and
@@ -45,7 +47,8 @@ models/olmoe3d.py. The model returns ``(logits, aux)`` like it:
 ``aux["loss"]`` is 0 (the published balancing is the bias update, a
 training recipe the config does not give), ``aux["expert_tokens"]``
 counts the slots routed to each of the 128 experts, summed over the
-``E`` layers.
+``E`` layers, and ``aux["held_overflow_calls"]`` the ``E`` layers whose
+held rows passed the buffer in this call.
 
 Nine layers of three kinds at 10,240 tokens a step do not keep their
 activations beside a 590 M-parameter training state: every layer is
@@ -180,9 +183,11 @@ def relu2(x):
 
 class HeldExperts(nn.Module):
     """The routed part of the ``E`` layer for the experts this chip
-    holds: ``(y [B, T, d], experts [B*T, k])``. Routes over all
+    holds: ``(y [B, T, d], experts [B*T, k], passed)``. Routes over all
     ``num_experts``; the weights hold ``held[1]`` of them, from expert
-    ``held[0]``."""
+    ``held[0]``; ``passed`` is 1 where this call's held rows passed the
+    buffer and took more than one window of it (ops/moe.py
+    ``held_expert_rows``: the dropless answer either way)."""
 
     num_experts: int
     held: tuple[int, int]
@@ -214,17 +219,11 @@ class HeldExperts(nn.Module):
             _, weights, experts = moe.route(
                 logits, self.experts_per_token, scoring="sigmoid",
                 scale=self.scaling)
-        with _scope(obs_names.SCOPE_DISPATCH):
-            plan = moe.dispatch_plan(experts, E)
-            xs = moe.gather_slots(x, plan)
-        with _scope(obs_names.SCOPE_EXPERTS):
-            u = moe.grouped_matmul(xs, up.astype(self.dtype),
-                                   plan.group_sizes, first)
-            ys = moe.grouped_matmul(relu2(u), down.astype(self.dtype),
-                                    plan.group_sizes, first)
-        with _scope(obs_names.SCOPE_COMBINE):
-            y = moe.combine_slots(ys, weights, plan).astype(self.dtype)
-        return y.reshape(B, T, d), experts
+        # the trainer initialises eagerly (NemotronH3D.__call__)
+        y, passed = moe.held_expert_rows(
+            x, weights, experts, up, down, E, first, relu2,
+            buffer=not self.is_initializing())
+        return y.reshape(B, T, d), experts, passed
 
 
 class SharedExpert(nn.Module):
@@ -308,9 +307,10 @@ class Widths:
 
 class Layer(nn.Module):
     """One pre-norm layer of kind ``kind``: ``(h + mixer(norm(h)),
-    experts)``, ``experts`` the ``E`` layer's choices ``[B*T, k]`` (an
-    empty ``[0, k]`` for the other kinds, so that every layer returns
-    the same structure under ``nn.remat``)."""
+    experts, passed)``, ``experts`` the ``E`` layer's choices
+    ``[B*T, k]`` and ``passed`` its buffer's verdict (an empty ``[0, k]``
+    and 0 for the other kinds, so that every layer returns the same
+    structure under ``nn.remat``)."""
 
     kind: str
     w: Widths
@@ -324,13 +324,14 @@ class Layer(nn.Module):
         out_std = 0.02 / math.sqrt(len(c.pattern))
         a = RMSNorm(c.rms_eps, dtype, name="norm")(h)
         experts = jnp.zeros((0, c.experts_per_token), jnp.int32)
+        passed = jnp.zeros((), jnp.int32)
         if self.kind == "M":
             y = Mamba2Mixer(
                 c.mamba_num_heads, c.mamba_head_dim, c.n_groups,
                 c.ssm_state_size, c.conv_kernel, c.chunk_size, c.rms_eps,
                 out_std, c.time_step, dtype, name="mixer")(a)
         elif self.kind == "E":
-            y, experts = HeldExperts(
+            y, experts, passed = HeldExperts(
                 c.num_experts, c.held, c.experts_per_token, c.expert_width,
                 c.routed_scaling_factor, out_std, dtype, name="mixer")(a)
             y = y + SharedExpert(c.shared_expert_width, out_std, dtype,
@@ -341,7 +342,7 @@ class Layer(nn.Module):
         else:
             raise ValueError(f"unknown layer kind {self.kind!r} in the "
                              f"pattern {c.pattern!r}; have {sorted(KINDS)}")
-        return h + y, experts
+        return h + y, experts, passed
 
 
 class NemotronH3D(nn.Module):
@@ -353,13 +354,28 @@ class NemotronH3D(nn.Module):
     remat_layers: bool = True
 
     input_rank = 5  # [B, D, H, W, C]
-    returns_aux = True  # (logits, {"loss", "expert_tokens"})
+    returns_aux = True  # (logits, {"loss", *aux_counters})
+    #: the integer entries of the auxiliary dict, summed over a round's
+    #: real steps into round outputs of these names (core/trainer.py)
+    aux_counters = ("expert_tokens", "held_overflow_calls")
 
     @property
     def held_experts(self) -> tuple[int, int]:
         """``(first, count)`` of the experts whose rows are computed
         here: the round driver counts ``rows_held`` over them."""
         return self.widths.held
+
+    def held_capacity_rows(self, batch_shape) -> int | None:
+        """The rows of the held runs' buffer for a batch ``[B, D, H, W,
+        ...]`` of volumes (ops/moe.py ``held_capacity``), ``None`` where
+        such a batch is computed by the full sort alone."""
+        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
+
+        c = self.widths
+        tokens = batch_shape[0] * math.prod(
+            -(-n // c.patch) for n in batch_shape[1:4])
+        return moe.held_capacity(c.experts_per_token * tokens, c.held[1],
+                                 c.num_experts)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
@@ -372,10 +388,12 @@ class NemotronH3D(nn.Module):
         # window, my chip run, PR 29); the parameter tree is the same
         remat = self.remat_layers and not self.is_initializing()
         layer = nn.remat(Layer) if remat else Layer
-        chosen = []
+        chosen, passed = [], []
         for i, kind in enumerate(c.pattern):
-            h, experts = layer(kind, c, self.dtype, name=f"layers_{i}")(h)
+            h, experts, over = layer(kind, c, self.dtype,
+                                     name=f"layers_{i}")(h)
             chosen.append(experts)
+            passed.append(over)
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps,
                                         _normal(0.02))
         with _scope(obs_names.SCOPE_ROUTER):
@@ -384,5 +402,6 @@ class NemotronH3D(nn.Module):
                 "expert_tokens": jnp.bincount(
                     jnp.concatenate(chosen).reshape(-1),
                     length=c.num_experts).astype(jnp.int32),
+                "held_overflow_calls": sum(passed),
             }
         return logits, aux

@@ -194,6 +194,7 @@ class OLMoE3D(nn.Module):
 
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", "expert_tokens"})
+    aux_counters = ("expert_tokens",)  # summed over a round's real steps
 
     @nn.compact
     def __call__(self, x, train: bool = False):

@@ -13,27 +13,48 @@ are un-sorted and summed back per token under the router's weights.
     ys = grouped_matmul(xs, w, plan.group_sizes)       # [k*T, N]
     y = combine_slots(ys, weights, plan)               # [T, N]
 
+or, for a layer of two matrices an expert that holds some of the ``E``,
+
+    y = held_expert_rows(x, weights, experts, up, down, E, first, act)
+
 Two routers stand behind :func:`route`'s one signature: OLMoE's softmax
 top-k, and the sigmoid scores with a selection bias, renormalised top-k
 weights and a scaling factor of the DeepSeek-V3 line (Nemotron-H).
 
 A layer may hold a SHARE of its experts (expert parallelism: this chip's
 ``count`` of the layer's ``E``, starting at expert ``first``). It still
-routes over all ``E`` and sorts every slot; ``grouped_matmul`` is then
+routes over all ``E``; what it computes is the part of the result that
+its own experts give for the rows routed to them, the partial result one
+chip of the deployment contributes (:func:`held_expert_rows`). In the
+sort those rows are one contiguous run, about ``count / E`` of the
+``k * T``, and the layer moves that run alone, as a chip of the
+deployment receives only its own experts' rows: the run's window of the
+sort is taken into a buffer of a static row count ``C``
+(:func:`held_capacity`: twice the uniform share), the ``C`` token rows
+are gathered, multiplied under the held experts' own ``group_sizes
+[count]`` and added back into their tokens' rows in float32. The buffer
+is the unit of work and not a capacity factor: a routing that sends here
+more rows than it holds is computed in as many windows of ``C`` rows as
+the run needs, by a loop whose bound the program reads from the routing
+it already has (one window where the run fits, which is every training
+step measured; the forward pass and, behind a ``custom_vjp`` whose
+residuals are the layer's inputs, the backward pass each have the loop).
+No row routed to a held expert is dropped, on any input, and no row of
+an absent expert is ever moved. The trainer's evaluation, whose filler
+volumes are zero and send their 10,240 identical tokens to the same six
+experts, is where more than one window runs (PR 29 built the buffer
+without the loop, and withdrew it there: real rows were dropped in a
+program that counts nothing); in training such a call is counted
+(``held_overflow_calls`` on the round driver's ``round_log`` span).
+
+Where nothing is to be gained (every expert held, or a buffer as long as
+the sort) and where a call runs eagerly (a model's initialisation) the
+held part is computed by the sort of every slot: ``grouped_matmul`` is
 handed the held experts' weights ``[count, K, N]`` beside the full
 ``group_sizes [E]`` and multiplies only the runs of the held experts,
-wherever they start in the sort. No row routed to a held expert can be
-dropped, because there is no buffer to overflow; the rows of the absent
-experts come out zero, as the partial result one chip of the deployment
-contributes.
-
-The alternative, gathering the held runs alone into a buffer of a static
-row bound, was built and measured too (PERF.md, PR 29): 47% more samples
-a second in Nemotron-H's cell, and not shipped, because a bound can be
-passed where nothing counts it: the trainer's evaluation pads a batch
-with zero volumes, whose 10,240 identical tokens all take the same six
-experts, and where one of those is held here the real rows behind them
-were dropped in silence.
+wherever they start in the sort; the rows of the absent experts come out
+zero. That was the whole layer until PR 30, at 38% of Nemotron-H's step
+for 1% of its useful FLOPs (PERF.md, sections 5 and 6).
 
 The group sizes are data, so the grouped matmul is a ragged
 contraction. Two candidates were measured on the v5e inside the real
@@ -47,10 +68,15 @@ one client at a time (engines/program.py, the folded placement).
 
 from __future__ import annotations
 
-from typing import NamedTuple
+import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
+_scope = jax.named_scope
 
 
 class DispatchPlan(NamedTuple):
@@ -238,6 +264,234 @@ def combine_slots(ys: jax.Array, weights: jax.Array,
         T, plan.k, -1)
     return jnp.einsum("tkn,tk->tn", per_slot, weights.astype(ys.dtype),
                       preferred_element_type=jnp.float32)
+
+
+#: the held runs' buffer over the uniform share ``k * T * count / E``.
+#: Twice: at the initial weights Nemotron-H's 8 held of 128 receive 5.8 to
+#: 6.4% of the rows where uniform is 6.25% (PERF.md, PR 29), so training
+#: never comes near it, and the buffer still moves an eighth of the rows
+#: the full sort does. What passes it takes a second window, so the
+#: factor trades time only, never a row.
+HELD_BUFFER_OVER_UNIFORM = 2
+
+
+def held_capacity(rows: int, count: int, num_experts: int) -> int | None:
+    """The static row count ``C`` of the buffer a layer that holds
+    ``count`` of ``num_experts`` experts gathers its runs into, of the
+    ``rows = k * T`` it routes: :data:`HELD_BUFFER_OVER_UNIFORM` times
+    the uniform share, rounded up to the kernel's widest row tile where
+    the kernel runs (7,680 of the training step's 61,440 rows at 8 of
+    128; 15,360 of evaluation's 122,880). ``None`` where there is no
+    share to move alone: every expert is held (OLMoE), or the buffer
+    would be no smaller than the sort. From shapes alone, so which
+    paths a program holds is decided when it is traced."""
+    if count == num_experts:
+        return None
+    tile = GMM_ROW_TILES[0] if jax.default_backend() == "tpu" else 1
+    share = HELD_BUFFER_OVER_UNIFORM * rows * count
+    capacity = -(-share // (num_experts * tile)) * tile
+    return capacity if capacity < rows else None
+
+
+def rows_held(experts: jax.Array, first: int, count: int) -> jax.Array:
+    """``int32 [count]``: the (token, slot) pairs of ``experts [T, k]``
+    routed to each of the experts ``first .. first + count - 1``."""
+    held = first + jnp.arange(count, dtype=experts.dtype)
+    return jnp.sum(experts.reshape(-1, 1) == held, axis=0, dtype=jnp.int32)
+
+
+def _full_sort_rows(x, weights, experts, up, down, num_experts, first, act):
+    """The held experts' part by the sort of every slot: no buffer, so
+    any routing fits; the rows of the absent experts are gathered,
+    zeroed and un-sorted with the rest."""
+    with _scope(obs_names.SCOPE_DISPATCH):
+        plan = dispatch_plan(experts, num_experts)
+        xs = gather_slots(x, plan)
+    with _scope(obs_names.SCOPE_EXPERTS):
+        u = grouped_matmul(xs, up, plan.group_sizes, first)
+        ys = grouped_matmul(act(u), down, plan.group_sizes, first)
+    with _scope(obs_names.SCOPE_COMBINE):
+        return combine_slots(ys, weights, plan).astype(x.dtype)
+
+
+class _HeldRuns(NamedTuple):
+    """Where the held experts' rows are in the sort of ``k * T`` slots:
+    ``order`` as :class:`DispatchPlan` has it, the sorted row ``begin``
+    at which the first held expert's run starts, and the runs' lengths
+    ``sizes [count]`` (one after another from ``begin``)."""
+
+    order: jax.Array
+    begin: jax.Array
+    sizes: jax.Array
+
+
+def _held_runs(experts, first, count):
+    flat = experts.reshape(-1)
+    return _HeldRuns(jnp.argsort(flat, stable=True),
+                     jnp.sum(flat < first, dtype=jnp.int32),
+                     rows_held(experts, first, count))
+
+
+def _window(x, weights, runs: _HeldRuns, capacity: int, i):
+    """Window ``i`` of the held runs, ``capacity`` rows of them:
+    ``(slot, token, valid, sizes, xs, w)``: the flat slot and the token
+    of each buffer row, which rows of the buffer the runs reach, how many
+    of the window's rows belong to each held expert, and the rows' tokens
+    ``x[token]`` and weights (rounded to the compute dtype as
+    ``combine_slots`` rounds them, in float32).
+
+    The window is read by index, not sliced: a slice that would pass the
+    end of the sort is moved back by XLA, into another expert's rows."""
+    ends = jnp.cumsum(runs.sizes)
+    w0 = i * capacity
+    sizes = jnp.clip(jnp.minimum(ends, w0 + capacity)
+                     - jnp.maximum(ends - runs.sizes, w0), 0, None)
+    j = w0 + jnp.arange(capacity, dtype=jnp.int32)
+    valid = j < ends[-1]
+    last = runs.order.shape[0] - 1
+    slot = jnp.where(
+        valid, jnp.take(runs.order, jnp.minimum(runs.begin + j, last)), 0)
+    token = slot // weights.shape[1]
+    w = jnp.take(weights.reshape(-1), slot).astype(x.dtype)
+    return (slot, token, valid, sizes.astype(jnp.int32),
+            jnp.take(x, token, axis=0), w.astype(jnp.float32))  # nidt: allow[precision-upcast] -- the combine multiplies and accumulates in float32, as combine_slots does
+
+
+def _window_experts(xs, up, down, sizes, valid, act):
+    """The buffer's rows through the held experts. A row past the runs
+    is masked on both sides of the matmuls: the kernel neither reads nor
+    writes it, so it comes out as whatever the memory held, forward and
+    in ``dx``."""
+    xs = jnp.where(valid[:, None], xs, 0)
+    ys = grouped_matmul(act(grouped_matmul(xs, up, sizes)), down, sizes)
+    return jnp.where(valid[:, None], ys, 0)
+
+
+def _windows(runs: _HeldRuns, capacity: int):
+    """How many windows of ``capacity`` rows the held runs take: one,
+    unless the routing sends here more than the buffer holds, or
+    nothing."""
+    return -(-jnp.sum(runs.sizes) // capacity)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_run_rows(x, weights, experts, up, down, first, capacity, act):
+    """The held experts' part from the held runs alone: window by window
+    of ``capacity`` rows, the window's token rows gathered, multiplied
+    under the held experts' own group sizes and added into their tokens'
+    rows in float32: a loop to a traced bound, one iteration where the
+    runs fit the buffer, so every routing has the dropless answer and
+    none moves the absent experts' rows."""
+    with _scope(obs_names.SCOPE_DISPATCH):
+        runs = _held_runs(experts, first, up.shape[0])
+
+    def add_window(i, y):
+        with _scope(obs_names.SCOPE_DISPATCH):
+            _, token, valid, sizes, xs, w = _window(x, weights, runs,
+                                                    capacity, i)
+        with _scope(obs_names.SCOPE_EXPERTS):
+            ys = _window_experts(xs, up, down, sizes, valid, act)
+        with _scope(obs_names.SCOPE_COMBINE):
+            return y.at[token].add(ys.astype(jnp.float32) * w[:, None])  # nidt: allow[precision-upcast] -- see _window
+
+    # the first window is an iteration like the rest, not a copy of the
+    # body before the loop: a step's program is 164 MiB of code on the
+    # chip, a quarter of it these kernels, and every program that trains
+    # or evaluates holds them (PERF.md, PR 30: the copies did not fit)
+    y = jax.lax.fori_loop(
+        0, _windows(runs, capacity), add_window,
+        jnp.zeros((x.shape[0], down.shape[2]), jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _held_run_rows_fwd(x, weights, experts, up, down, *static):
+    return (_held_run_rows(x, weights, experts, up, down, *static),
+            (x, weights, experts, up, down))
+
+
+def _held_run_rows_bwd(first, capacity, act, inputs, g):
+    """Window by window again: each window's rows recomputed from the
+    layer's inputs (the only residuals: the layer is rematerialised
+    anyway, models/nemotronh3d.py) and transposed, the tokens' and the
+    weights' cotangents added in float32 where the rows came from.
+    Autodiff cannot transpose a loop to a traced bound, and would keep a
+    window's intermediates for each of a static one."""
+    x, weights, experts, up, down = inputs
+    with _scope(obs_names.SCOPE_DISPATCH):
+        runs = _held_runs(experts, first, up.shape[0])
+
+    def add_window(i, sums):
+        dx, dw, dup, ddown = sums
+        with _scope(obs_names.SCOPE_DISPATCH):
+            slot, token, valid, sizes, xs, w = _window(x, weights, runs,
+                                                       capacity, i)
+        # the scopes around the inner transposition, none inside it: a
+        # scope entered under ``jax.vjp`` is named ``transpose(jvp(..))``
+        # and the benchmark's rules would not know it
+        with _scope(obs_names.SCOPE_EXPERTS):
+            ys, transpose = jax.vjp(
+                lambda *a: _window_experts(*a, sizes, valid, act),
+                xs, up, down)
+        with _scope(obs_names.SCOPE_COMBINE):
+            g_rows = jnp.take(g, token, axis=0).astype(jnp.float32)  # nidt: allow[precision-upcast] -- the cotangent of a float32 sum
+            dws = jnp.sum(g_rows * ys.astype(jnp.float32), axis=1)  # nidt: allow[precision-upcast] -- the same
+            dys = (g_rows * w[:, None]).astype(ys.dtype)
+        with _scope(obs_names.SCOPE_EXPERTS):
+            dxs, dup_i, ddown_i = transpose(dys)
+            dup, ddown = dup + dup_i, ddown + ddown_i
+        with _scope(obs_names.SCOPE_DISPATCH):
+            dx = dx.at[token].add(dxs.astype(jnp.float32))  # nidt: allow[precision-upcast] -- a sum over a token's rows: float32 like the combine's
+            dw = dw.at[slot].add(dws)
+        return dx, dw, dup, ddown
+
+    # the matrices' cotangents are summed and leave in the compute dtype,
+    # as the kernel's own do: the cast to the master weights' float32 is
+    # the caller's, and XLA is free to leave it to the step's end
+    dx, dw, dup, ddown = jax.lax.fori_loop(
+        0, _windows(runs, capacity), add_window,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(weights.size, jnp.float32), jnp.zeros_like(up),
+         jnp.zeros_like(down)))
+    return (dx.astype(x.dtype),
+            dw.reshape(weights.shape).astype(weights.dtype), None, dup,
+            ddown)
+
+
+_held_run_rows.defvjp(_held_run_rows_fwd, _held_run_rows_bwd)
+
+
+def held_expert_rows(x: jax.Array, weights: jax.Array, experts: jax.Array,
+                     up: jax.Array, down: jax.Array, num_experts: int,
+                     first: int, act: Callable,
+                     buffer: bool = True) -> tuple[jax.Array, jax.Array]:
+    """What the experts ``first .. first + count - 1`` of ``num_experts``
+    add to each token, ``sum_slots weight * act(x @ up[e]) @ down[e]``
+    over the token's slots routed to one of them: ``x [T, H]``, the
+    router's ``weights`` and ``experts [T, k]``, ``up [count, H, W]``,
+    ``down [count, W, N]`` (cast to ``x``'s dtype here) -> ``(y [T, N],
+    passed int32)``, ``y`` summed in float32 and returned in ``x``'s
+    dtype.
+
+    Where a share is held (:func:`held_capacity` gives a buffer) the held
+    runs alone are moved and multiplied, a buffer of rows at a time:
+    once where they fit it, ``passed`` 0; in as many windows as they
+    need where they do not, ``passed`` 1. Where every expert is held, or
+    the buffer would not be smaller than the sort, the full sort of
+    every slot is what is traced; so it is with ``buffer=False``, for a
+    call that runs eagerly (a model's initialisation: an eager loop
+    compiles anew on every call). Under a client-axis ``vmap`` the loop
+    runs to the longest row's bound (never on the chip: such a model is
+    folded)."""
+    capacity = held_capacity(experts.size, up.shape[0], num_experts) \
+        if buffer else None
+    with _scope(obs_names.SCOPE_EXPERTS):  # where the casts always were
+        up, down = up.astype(x.dtype), down.astype(x.dtype)
+    if capacity is None:
+        return (_full_sort_rows(x, weights, experts, up, down, num_experts,
+                                first, act), jnp.zeros((), jnp.int32))
+    y = _held_run_rows(x, weights, experts, up, down, first, capacity, act)
+    held = jnp.sum(rows_held(experts, first, up.shape[0]))
+    return y, (held > capacity).astype(jnp.int32)
 
 
 def load_balancing_loss(probs: jax.Array, experts: jax.Array,

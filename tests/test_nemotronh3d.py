@@ -24,7 +24,7 @@ from neuroimagedisttraining_tpu.config import OptimConfig
 from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
 from neuroimagedisttraining_tpu.models import create_model, primary_logits
 from neuroimagedisttraining_tpu.models.nemotronh3d import (
-    PATTERN, HeldExperts, NemotronH3D, Widths,
+    PATTERN, HeldExperts, NemotronH3D, Widths, relu2,
 )
 from neuroimagedisttraining_tpu.ops import moe
 
@@ -209,12 +209,17 @@ def _expert_layer(ref, seed, k=3):
                        "down": {"kernel": f(48, 64) * 0.2}}}
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref, seed):
+#: (seed, shares whose rows pass their buffer of 6): at seed 2 the 48
+#: assignments spread so that every share's rows fit (5 at most); at seeds
+#: 0 and 1 one share receives 7 and takes a second window of its buffer
+@pytest.mark.parametrize("seed,passes", [(0, 1), (1, 1), (2, 0)])
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref, seed, passes):
     """The routed parts that all 16 ``held`` windows of 2 experts give,
     plus the shared expert counted once, equal the uncut reference layer
     (all 32 experts held). Each share is the PROGRAM's expert layer, told
-    which experts it holds and given their weights alone."""
+    which experts it holds and given their weights alone; it says which
+    of its two paths computed it, and that follows the rows it received
+    against its buffer's and nothing else."""
     t = _expert_layer(ref, seed)
     cfg = {**CFG, "held": (0, E)}
     with jax.default_matmul_precision("highest"):
@@ -223,14 +228,18 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref, seed):
             t["shared"], cfg, ref.ops.exact, None, "layer")
         shared = ref.ops.relu2_mlp(t["m"], t["shared"]["up"]["kernel"],
                                    t["shared"]["down"]["kernel"])
-    total, rows = shared, 0
+    capacity = moe.held_capacity(2 * 8 * 3, COUNT, E)
+    assert capacity == 6  # twice the uniform share of 48 x 2 / 32
+    total, rows, passed_shares = shared, 0, 0
     for first in range(0, E, COUNT):
         layer = HeldExperts(E, (first, COUNT), 3, 24, 2.5, 0.02)
-        part, chosen = layer.apply({"params": {
+        part, chosen, passed = layer.apply({"params": {
             "router": t["router"], "up": t["up"][first:first + COUNT],
             "down": t["down"][first:first + COUNT]}}, t["m"])
         held = (chosen >= first) & (chosen < first + COUNT)
         rows += int(held.sum())
+        assert int(passed) == int(int(held.sum()) > capacity)
+        passed_shares += int(passed)
         # a share whose experts nobody chose adds exactly nothing
         assert bool(held.any()) or float(jnp.max(jnp.abs(part))) == 0.0
         total = total + part
@@ -245,6 +254,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(ref, seed):
         np.testing.assert_allclose(part, part_ref - shared, rtol=1e-4,
                                    atol=1e-5)
     assert rows == 2 * 8 * 3  # every assignment landed on exactly one share
+    assert passed_shares == passes
     np.testing.assert_allclose(total, whole, rtol=1e-4, atol=1e-5)
 
 
@@ -272,17 +282,26 @@ def test_sigmoid_routing_by_hand():
         moe.route(logits, 2, scoring="tanh")
 
 
-def test_no_row_is_lost_when_every_token_comes_here(ref):
+@pytest.mark.parametrize("planted,passed", [
+    ({6: 0.5, 7: 0.4, 9: 0.3}, 4), ({9: 0.5, 10: 0.4}, 0)],
+    ids=["here", "elsewhere"])
+def test_no_row_is_lost_when_every_token_comes_here(ref, planted, passed):
     """A planted router whose three largest columns are the held experts 6
     and 7 and the unheld 9 sends about half of all tokens here, five
-    times the uniform share of 3 x 2 / 32 of the assignments; nothing is
-    dropped (there is no buffer to overflow) and the output is still the
-    reference's."""
+    times the uniform share of 3 x 2 / 32 of the assignments: they pass
+    the buffer (twice the uniform share) in each of the four expert
+    layers, which says so and takes as many windows as they need; nothing
+    is dropped and the output is still the reference's. With two unheld
+    columns planted instead, a token's third choice falls where a small
+    drawn term puts it, a few land here, the held runs are moved alone,
+    and the output is the reference's as well."""
     tr = _trainer()
     cs, (x, y) = _state(tr), _batch(7)
     params = jax.tree.map(lambda a: a, cs.params)
-    router = jnp.full((64, E), -1.0).at[:, 6].set(0.5).at[:, 7].set(
-        0.4).at[:, 9].set(0.3)
+    router = -1.0 + 0.05 * jnp.asarray(
+        np.random.RandomState(5).randn(64, E), jnp.float32)
+    for column, value in planted.items():
+        router = router.at[:, column].set(value)
     for i, kind in enumerate(PATTERN):
         if kind == "E":
             params[f"layers_{i}"]["mixer"]["router"] = router
@@ -297,8 +316,111 @@ def test_no_row_is_lost_when_every_token_comes_here(ref):
         tokens, np.bincount(np.asarray(e_ref).ravel(), minlength=E))
     assert tokens.sum() == 4 * 3 * T
     uniform = 4 * 3 * T * COUNT / E
-    assert tokens[6:8].sum() >= 4 * uniform
+    assert int(out[1]["held_overflow_calls"]) == passed
+    if passed:
+        assert tokens[6:8].sum() >= 4 * uniform
+    else:
+        assert 0 < tokens[6:8].sum() <= 2 * uniform
     np.testing.assert_allclose(out[0], want, rtol=F32_RTOL, atol=F32_ATOL)
+
+
+def _held_part_by_hand(x, weights, experts, up, down, first):
+    """What the held experts add to each token, every token through
+    every held expert and a mask: no sort, no buffer."""
+    y = jnp.zeros_like(x)
+    for i in range(up.shape[0]):
+        w = jnp.sum(jnp.where(experts == first + i, weights, 0.0), axis=1)
+        y = y + w[:, None] * (relu2(x @ up[i]) @ down[i])
+    return y
+
+
+#: 64 tokens x 3 slots = 192 rows, 2 of 32 experts held: a buffer of 24.
+#: (label, first held expert, rows planted on the held experts, passed).
+#: The other rows go to experts 0-2 (32 tokens) and 8-10 (32 tokens), so
+#: the held runs start in the middle of the sort unless they end it
+PLANTED = [("none_here", 6, 0, 0), ("a_few", 6, 5, 0),
+           ("buffer_full", 6, 24, 0), ("one_row_over", 6, 25, 1),
+           ("every_token", 6, 64, 1), ("run_ends_the_sort", 30, 5, 0),
+           ("run_ends_the_sort_full", 30, 24, 0),
+           ("run_ends_the_sort_over", 30, 25, 1)]
+
+
+@pytest.mark.parametrize("label,first,n,passed", PLANTED,
+                         ids=[c[0] for c in PLANTED])
+def test_held_runs_window_by_window_by_hand(label, first, n, passed):
+    """``held_expert_rows`` on planted routings, forward and every
+    gradient, against the masked loop: a buffer exactly full is still one
+    window, one row more is two, every token here is three, and each is
+    the dropless answer; a held window that ends the layer (``first + count
+    == E``) has its run at the end of the sort, where the buffer's window
+    passes the last row and must not be moved back into another expert's
+    rows."""
+    T, k, H, W = 64, 3, 16, 24
+    r = np.random.RandomState(n)
+    f = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    x, up, down = f(T, H), f(COUNT, H, W) * 0.3, f(COUNT, W, H) * 0.3
+    weights = jnp.asarray(r.uniform(0.1, 1.0, (T, k)), jnp.float32)
+    experts = np.where(np.arange(T)[:, None] < 32, [0, 1, 2], [8, 9, 10])
+    experts[:n, 0] = first + np.arange(n) % COUNT
+    experts = jnp.asarray(experts, jnp.int32)
+    assert moe.held_capacity(T * k, COUNT, E) == 24
+    assert int(moe.rows_held(experts, first, COUNT).sum()) == n
+
+    def program(x, weights, up, down):
+        return moe.held_expert_rows(x, weights, experts, up, down, E, first,
+                                    relu2)
+
+    def by_hand(x, weights, up, down):
+        return _held_part_by_hand(x, weights, experts, up, down, first)
+
+    with jax.default_matmul_precision("highest"):
+        got, over = jax.jit(program)(x, weights, up, down)
+        want = by_hand(x, weights, up, down)
+        tangent = f(T, H)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(program(*a)[0] * tangent),
+            argnums=(0, 1, 2, 3)))(x, weights, up, down)
+        grads_want = jax.grad(lambda *a: jnp.sum(by_hand(*a) * tangent),
+                              argnums=(0, 1, 2, 3))(x, weights, up, down)
+    assert int(over) == passed
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for g, w, name in zip(grads, grads_want, ("x", "weights", "up", "down")):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_layer_by_either_path_has_the_same_gradients(monkeypatch, seed):
+    """The expert layer at 512 tokens, where the drawn routing's held rows
+    (about 96 of 1,536) fit the buffer of 192: its output and the
+    gradient of every leaf (the tokens, the router through the weights,
+    both matrices) equal those of the same layer traced with the full
+    sort alone."""
+    r = np.random.RandomState(seed)
+    f = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    m, tangent = f(4, 128, 64), f(4, 128, 64)
+    params = {"router": f(64, E) * 0.5, "up": f(COUNT, 64, 24) * 0.2,
+              "down": f(COUNT, 24, 64) * 0.2}
+    layer = HeldExperts(E, (6, COUNT), 3, 24, 2.5, 0.02)
+
+    def run():
+        def loss(params, m):
+            y, _, passed = layer.apply({"params": params}, m)
+            return jnp.sum(y * tangent), (y, passed)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                              has_aux=True))(params, m)
+
+    (_, (y, passed)), grads = run()
+    assert moe.held_capacity(512 * 3, COUNT, E) == 192 and int(passed) == 0
+    monkeypatch.setattr(moe, "held_capacity", lambda *a: None)
+    (_, (y_full, _)), grads_full = run()
+    np.testing.assert_allclose(y, y_full, rtol=1e-5, atol=1e-5)
+    assert float(jnp.max(jnp.abs(grads[0]["router"]))) > 0
+    # the same float32 products summed in another order: 1e-6 of a leaf's
+    # largest entry
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=1e-4, atol=1e-6 * float(jnp.max(jnp.abs(b)))),
+        grads, grads_full)
 
 
 def test_held_window_of_the_grouped_matmul():
@@ -420,62 +542,3 @@ def test_a_second_eager_initialisation_compiles_nothing():
     before = len(compiles)
     init()
     assert len(compiles) == before
-
-
-def test_folded_train_logs_the_held_rows_every_round(tmp_path):
-    """Two rounds of the folded ``train()`` through FedAvg's declared
-    round, tracer armed: every round's ``round_log`` span carries
-    ``tokens_routed`` over all 32 experts and the four expert layers,
-    ``rows_held`` over the two held, and both load ratios."""
-    from neuroimagedisttraining_tpu.config import (
-        DataConfig, ExperimentConfig, FedConfig,
-    )
-    from neuroimagedisttraining_tpu.data.federate import federate_cohort
-    from neuroimagedisttraining_tpu.data.synthetic import (
-        generate_synthetic_abcd,
-    )
-    from neuroimagedisttraining_tpu.engines import create_engine
-    from neuroimagedisttraining_tpu.engines.fedavg import expert_load
-    from neuroimagedisttraining_tpu.obs import names as obs_names
-    from neuroimagedisttraining_tpu.obs import trace as obs_trace
-    from neuroimagedisttraining_tpu.utils.logging import ExperimentLogger
-
-    cohort = generate_synthetic_abcd(num_subjects=24, shape=SHAPE,
-                                     num_sites=2, seed=0)
-    cohort["site"] = np.repeat(np.arange(2), (16, 8)).astype(
-        cohort["site"].dtype)
-    cfg = ExperimentConfig(
-        model="nemotronh3d", num_classes=1, algorithm="fedavg",
-        data=DataConfig(dataset="synthetic", partition_method="site"),
-        optim=OptimConfig(lr=1e-2, batch_size=4, epochs=1),
-        fed=FedConfig(client_num_in_total=2, comm_round=2),
-        log_dir=str(tmp_path), tag="held")
-    tr = LocalTrainer(NemotronH3D(widths=SMALL), cfg.optim, 1)
-    fed, _ = federate_cohort(cohort, partition_method="site", mesh=None)
-    eng = create_engine("fedavg", cfg, fed, tr, mesh=None,
-                        logger=ExperimentLogger(
-                            str(tmp_path), "synthetic", cfg.identity(),
-                            console=False))
-    eng._fold_budget_bytes = 1
-    obs_trace.arm()
-    try:
-        eng.train()
-        logs = [e["args"] for e in obs_trace.TRACER.events()
-                if e["ph"] == "X"
-                and e["name"] == obs_names.SPAN_ROUND_LOG]
-    finally:
-        obs_trace.disarm()
-    assert eng.program.placement == "folded"
-    assert [a["round"] for a in logs] == [0, 1]
-    real_steps = int(np.ceil(np.asarray(eng.data.n_train) / 4).sum())
-    for a in logs:
-        assert a["tokens_routed"] == real_steps * 4 * 3 * (4 * 8)
-        assert 0 < a["rows_held"] < a["tokens_routed"]
-        assert a["held_load_max_over_mean"] >= 1.0
-        assert a["expert_load_max_over_mean"] >= 1.0
-    # by hand: 4 experts, the middle two held
-    load = expert_load(np.asarray([10, 30, 10, 50]), (1, 2))
-    assert load["tokens_routed"] == 100 and load["rows_held"] == 40
-    assert load["held_load_max_over_mean"] == 1.5
-    assert load["expert_load_max_over_mean"] == 2.0
-    assert "rows_held" not in expert_load(np.asarray([1, 2]))

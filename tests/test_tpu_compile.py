@@ -170,3 +170,84 @@ def test_held_grouped_matmul_compiles_at_the_published_widths(
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         xs, up, down, sizes).compile().as_text()
     assert text.count(KERNEL_MARK) == 5
+
+
+def test_held_runs_buffer_compiles_at_the_published_widths(chip,
+                                                           monkeypatch):
+    """``ops/moe.py`` ``held_expert_rows`` for Nemotron-H's training step
+    (10,240 tokens x 6 slots, 8 of 128 experts held, 2688 x 1856, bf16
+    beside float32 master weights), value and every gradient: a buffer of
+    7,680 rows, which ``gmm_tiling`` gives the widest row tile; one loop
+    over windows forward and one backward, ``megablox.gmm`` with no
+    ``group_offset`` over ``[7680, .]`` in their bodies and nowhere else
+    (as many kernels as the rematerialised full sort: a step's program is
+    164 MiB of code on the chip, and a second copy of these did not fit);
+    no array
+    of all 61,440 rows anywhere, and a third of the full sort's
+    temporaries."""
+    from neuroimagedisttraining_tpu.models.nemotronh3d import relu2
+    from neuroimagedisttraining_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    T, k, E, count = 10240, 6, 128, 8
+    capacity = moe.held_capacity(T * k, count, E)
+    assert capacity == 7680
+    assert moe.gmm_tiling(capacity, 2688, 1856) == (512, 384, 384)
+    operands = (_on(chip, (T, 2688), jnp.bfloat16), _on(chip, (T, k)),
+                _on(chip, (count, 2688, 1856)),
+                _on(chip, (count, 1856, 2688)))
+    experts = _on(chip, (T, k), jnp.int32)
+
+    def compiled(rows):
+        def loss(x, weights, up, down, experts):
+            y = rows(x, weights, experts, up, down, E, 0, relu2)
+            return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))) \
+            .lower(*operands, experts).compile()
+
+    windows = compiled(lambda *a: moe.held_expert_rows(*a)[0])
+    full = compiled(moe._full_sort_rows)
+    text = windows.as_text()
+    assert " conditional(" not in text
+    # a window forward: 2 kernels; backward: the 2 again and gmm / tgmm
+    # for both matrices
+    assert text.count(KERNEL_MARK) == 2 + 6
+    assert f"bf16[{capacity},2688]" in text
+    assert f"[{T * k},2688]" not in text
+    assert f"[{T * k},2688]" in full.as_text()
+    assert 3 * windows.memory_analysis().temp_size_in_bytes \
+        <= full.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("rows,count,experts,buffered", [
+    (61440, 8, 128, True), (122880, 8, 128, True),  # training, evaluation
+    (81920, 64, 64, False),   # OLMoE: every expert held
+    (3840, 64, 128, False),   # a buffer as long as the sort
+    (256, 8, 128, False)], ids=["train", "eval", "all_held", "half_held",
+                                "tiny"])
+def test_which_paths_a_held_layer_traces_follows_shapes(monkeypatch, rows,
+                                                        count, experts,
+                                                        buffered):
+    """Where every expert is held, or the buffer would be no smaller than
+    the sort, the layer's program holds no loop over windows: the full
+    sort is what is traced, and ``olmoe3d``'s step stays what it was."""
+    from neuroimagedisttraining_tpu.models.nemotronh3d import relu2
+    from neuroimagedisttraining_tpu.ops import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    capacity = moe.held_capacity(rows, count, experts)
+    assert (capacity is not None) == buffered
+    if buffered:
+        assert capacity == 2 * rows * count // experts
+        assert capacity % moe.GMM_ROW_TILES[0] == 0
+    monkeypatch.undo()  # trace the layer with the CPU's grouped matmul
+    T, k = rows // 8, 8
+    s = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(
+        lambda x, w, e, up, down: moe.held_expert_rows(
+            x, w, e, up, down, experts, 0, relu2))(
+        s((T, 16), jnp.float32), s((T, k), jnp.float32),
+        s((T, k), jnp.int32), s((count, 16, 8), jnp.float32),
+        s((count, 8, 16), jnp.float32))
+    assert (" while[" in str(jaxpr)) == (
+        moe.held_capacity(rows, count, experts) is not None)
