@@ -41,7 +41,8 @@ HELD_OVERFLOW_CALLS = "held_overflow_calls"
 
 
 def expert_load(expert_tokens, held: tuple[int, int] | None = None,
-                overflow_calls=None, capacity: int | None = None) -> dict:
+                overflow_calls=None, capacity: int | None = None,
+                skip: int | None = None) -> dict:
     """The round's expert-load counters from the round program's
     ``expert_tokens`` output, as host numbers for the ``round_log``
     span: slots routed, and the busiest and the idlest expert's load
@@ -53,12 +54,18 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None,
     output, ``held_overflow_calls``, the calls whose held rows passed
     the buffer and took more than one window of it, beside
     ``held_capacity_rows`` (``capacity``: the rows of a training step's
-    buffer; 0 where a step has none)."""
+    buffer; 0 where a step has none). For a router one of whose outputs
+    is no expert (``skip``: its index; models/zaya3d.py) ``rows_skipped``,
+    the tokens sent there, which count as routed and are left out of the
+    experts' load."""
     tokens = np.asarray(expert_tokens, np.float64)
-    mean = max(float(tokens.mean()), 1e-12)
-    out = {"tokens_routed": int(tokens.sum()),
-           "expert_load_max_over_mean": float(tokens.max()) / mean,
-           "expert_load_min_over_mean": float(tokens.min()) / mean}
+    out = {"tokens_routed": int(tokens.sum())}
+    if skip is not None:
+        out["rows_skipped"] = int(tokens[skip])
+    experts = tokens if skip is None else np.delete(tokens, skip)
+    mean = max(float(experts.mean()), 1e-12)
+    out.update(expert_load_max_over_mean=float(experts.max()) / mean,
+               expert_load_min_over_mean=float(experts.min()) / mean)
     if held is not None:
         here = tokens[held[0]:held[0] + held[1]]
         out["rows_held"] = int(here.sum())
@@ -165,7 +172,8 @@ class FedAvgEngine(FederatedEngine):
         return expert_load(
             named[EXPERT_TOKENS],
             getattr(self.trainer.model, "held_experts", None),
-            named.get(HELD_OVERFLOW_CALLS), self._held_capacity_rows)
+            named.get(HELD_OVERFLOW_CALLS), self._held_capacity_rows,
+            getattr(self.trainer.model, "skip_output", None))
 
     # ---------- legacy-signature program adapters ----------
     # The builder's compiled programs take structured (carry, data,

@@ -43,6 +43,7 @@ from neuroimagedisttraining_tpu.models.nemotronh3d import (  # noqa: F401
     NemotronH3D,
 )
 from neuroimagedisttraining_tpu.models.olmoe3d import OLMoE3D  # noqa: F401
+from neuroimagedisttraining_tpu.models.zaya3d import Zaya3D  # noqa: F401
 from neuroimagedisttraining_tpu.models.resnet2d import (  # noqa: F401
     ResNet18,
     customized_resnet18,
@@ -113,6 +114,11 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         # rematerialised by the model's own declaration (remat_layers):
         # --remat is the 3D CNN family's policy and does not reach it
         return NemotronH3D(num_classes=num_classes, dtype=dtype)
+    if name == "zaya3d":
+        # added here, not ported (models/zaya3d.py): five of ZAYA1-8B's
+        # layers at the published widths, experts 0-7 of each layer's 16
+        # held; rematerialised by its own declaration, like nemotronh3d
+        return Zaya3D(num_classes=num_classes, dtype=dtype)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":
@@ -164,8 +170,9 @@ def aux_outputs(out) -> dict | None:
     """The auxiliary dict of a model that declares one (``returns_aux``:
     ``(logits, {"loss": weighted scalar, "expert_tokens": int32 [E],
     ...})``, the integer entries named by the model's ``aux_counters``;
-    models/olmoe3d.py, models/nemotronh3d.py), or None: the reference
-    models' second output is a feature tensor, never a dict."""
+    models/olmoe3d.py, models/nemotronh3d.py, models/zaya3d.py), or None:
+    the reference models' second output is a feature tensor, never a
+    dict."""
     if isinstance(out, (tuple, list)) and len(out) == 2 \
             and isinstance(out[1], dict):
         return out[1]

@@ -143,12 +143,8 @@ class Mamba2Mixer(nn.Module):
                                 (self.conv_kernel, conv_dim), f32)
             bias = self.param("conv_bias", nn.initializers.zeros,
                               (conv_dim,), f32)
-            # causal, depthwise: tap j reads the token K-1-j positions back
-            K = self.conv_kernel
-            padded = jnp.pad(xBC, ((0, 0), (K - 1, 0), (0, 0)))
-            conv = sum(padded[:, j:j + T] * kernel[j].astype(self.dtype)
-                       for j in range(K))
-            xBC = nn.silu(conv + bias.astype(self.dtype))
+            xBC = nn.silu(tokens3d.causal_depthwise_conv(xBC, kernel)
+                          + bias.astype(self.dtype))
             x, Bm, Cm = jnp.split(xBC, [inner, inner + bc], axis=-1)
         with _scope(obs_names.SCOPE_SSD):
             dt_bias = self.param("dt_bias", _dt_bias_init(*self.dt_limits),
@@ -264,15 +260,8 @@ class GQAttention(nn.Module):
             q = dense(Hq * hd, "q_proj")(a).reshape(B, T, Hkv, Hq // Hkv, hd)
             k = dense(Hkv * hd, "k_proj")(a).reshape(B, T, Hkv, hd)
             v = dense(Hkv * hd, "v_proj")(a).reshape(B, T, Hkv, hd)
-            scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                                preferred_element_type=jnp.float32)
-            scores = scores / jnp.sqrt(jnp.float32(hd))
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            scores = jnp.where(causal, scores, -jnp.inf)
-            p = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
-            out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
             return dense(d, "o_proj", self.out_std)(
-                out.reshape(B, T, Hq * hd))
+                tokens3d.causal_gq_attention(q, k, v, self.dtype))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -372,10 +361,9 @@ class NemotronH3D(nn.Module):
         from neuroimagedisttraining_tpu.ops import moe  # ops imports models
 
         c = self.widths
-        tokens = batch_shape[0] * math.prod(
-            -(-n // c.patch) for n in batch_shape[1:4])
-        return moe.held_capacity(c.experts_per_token * tokens, c.held[1],
-                                 c.num_experts)
+        return moe.held_capacity(
+            c.experts_per_token * tokens3d.token_count(batch_shape, c.patch),
+            c.held[1], c.num_experts)
 
     @nn.compact
     def __call__(self, x, train: bool = False):

@@ -48,31 +48,14 @@ import jax
 import jax.numpy as jnp
 
 from neuroimagedisttraining_tpu.models import tokens3d
-from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
+from neuroimagedisttraining_tpu.models.tokens3d import (
+    RMSNorm, apply_rope, rope_tables,
+)
 from neuroimagedisttraining_tpu.obs import names as obs_names
 
 Dtype = Any
 _scope = jax.named_scope
 _init = nn.initializers.normal(stddev=0.02)  # OLMoE's, every matrix
-
-
-def rope_tables(positions: int, head_dim: int, theta: float):
-    """``(cos, sin)`` ``[positions, head_dim]`` in float32: frequencies
-    ``theta^(-2i/d)`` repeated over both halves (rotate-half form)."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                           / head_dim))
-    ang = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv[None]
-    ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rope(x, cos, sin):
-    """``x [B, T, heads, d]``: ``x * cos + rotate_half(x) * sin``."""
-    half = x.shape[-1] // 2
-    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    c = cos[None, :, None, :].astype(x.dtype)
-    s = sin[None, :, None, :].astype(x.dtype)
-    return x * c + rot * s
 
 
 class Attention(nn.Module):

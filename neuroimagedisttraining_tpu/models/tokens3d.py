@@ -1,5 +1,6 @@
-"""What the token trunks share (models/olmoe3d.py, models/nemotronh3d.py):
-how a decoder trunk meets a volume, and how its one logit is read.
+"""What the token trunks share (models/olmoe3d.py, models/nemotronh3d.py,
+models/zaya3d.py): how a decoder trunk meets a volume, and how its one
+logit is read.
 
     x uint8 [B,121,145,121] -> (x - mean) / std of the volume, zero-pad
                                to a multiple of the patch
@@ -13,11 +14,14 @@ volume is standardised and the read-out pooled is in
 benchmark/configs/olmoe-abcd.json (``assumed``). The helpers create their
 flax modules in the calling ``@nn.compact`` method, under the names the
 trunks' parameter trees have always had (``patch_embed``, ``final_norm``,
-``head``).
+``head``). Beside them, what more than one trunk computes the same way:
+the rotary tables (for a whole head, or for its first part) and the
+causal depthwise convolution over the token axis.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import flax.linen as nn
@@ -45,6 +49,67 @@ class RMSNorm(nn.Module):
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = (x32 * jax.lax.rsqrt(var + self.eps)).astype(self.dtype)
         return weight.astype(self.dtype) * y
+
+
+def rope_tables(positions: int, rotary_dim: int, theta: float):
+    """``(cos, sin)`` ``[positions, rotary_dim]`` in float32: frequencies
+    ``theta^(-2i/d)`` repeated over both halves (rotate-half form)."""
+    inv = 1.0 / (theta ** (jnp.arange(0, rotary_dim, 2, dtype=jnp.float32)
+                           / rotary_dim))
+    ang = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv[None]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rope(x, cos, sin):
+    """``x [B, T, heads, d]``: ``x * cos + rotate_half(x) * sin`` over the
+    first ``cos.shape[-1]`` of each head's ``d`` dimensions, the rest as
+    they are (``partial_rotary_factor``; a table as wide as the head
+    rotates all of it)."""
+    rotary = cos.shape[-1]
+    if rotary < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary], cos, sin), x[..., rotary:]],
+            axis=-1)
+    half = x.shape[-1] // 2
+    rot = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    c = cos[None, :, None, :].astype(x.dtype)
+    s = sin[None, :, None, :].astype(x.dtype)
+    return x * c + rot * s
+
+
+def causal_depthwise_conv(x, kernel):
+    """``y[t] = sum_j kernel[j] * x[t - (K-1) + j]`` a channel, zeros
+    before the sequence: ``x [B, T, C]``, ``kernel [K, C]`` (tap ``K-1``
+    reads this token, tap 0 the one ``K-1`` positions back), computed in
+    ``x``'s dtype. No bias: the caller adds its own."""
+    K, T = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + T] * kernel[j].astype(x.dtype)
+               for j in range(K))
+
+
+def causal_gq_attention(q, k, v, dtype):
+    """Causal softmax attention over grouped heads: ``q [B, T, Hkv, G,
+    d]`` (query head ``g * G + r`` reads key/value head ``g``), ``k, v
+    [B, T, Hkv, d]`` -> ``[B, T, Hkv * G * d]``; scores and softmax in
+    float32, scaled by ``d^-1/2``."""
+    B, T, Hkv, G, d = q.shape
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(d))
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    scores = jnp.where(causal, scores, -jnp.inf)
+    p = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
+    return out.reshape(B, T, Hkv * G * d)
+
+
+def token_count(batch_shape, patch: int) -> int:
+    """Tokens in a batch ``[B, D, H, W, ...]`` of volumes cut into
+    ``patch``-cubes (each axis padded up to a multiple)."""
+    return batch_shape[0] * math.prod(-(-n // patch)
+                                      for n in batch_shape[1:4])
 
 
 def patches(x, patch: int, eps: float, dtype):

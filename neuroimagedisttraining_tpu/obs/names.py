@@ -233,11 +233,20 @@ SCOPE_SSM_GATE_NORM = "ssm_gate_norm"  # y * silu(z), grouped RMSNorm
 SCOPE_SSM_OUT_PROJ = "ssm_out_proj"    # y W_out
 SCOPE_SHARED_EXPERT = "shared_expert"  # the relu^2 MLP every token takes
 
-#: the model scopes of the two tables above (disjoint from DEVICE_SCOPES)
+# the compressed convolutional attention's stages before its softmax
+# (models/zaya3d.py, PR 31; benchmark/metrics/zaya_scopes.json). Scores,
+# softmax, values and W_o reuse SCOPE_ATTN, the router MLP SCOPE_ROUTER,
+# the held experts the three expert scopes above.
+SCOPE_CCA_PROJ = "cca_proj"  # a W_q, a W_k, a W_v1, a W_v2 into the latents
+SCOPE_CCA_CONV = "cca_conv"  # the depthwise and the per-head grouped conv
+SCOPE_CCA_MIX = "cca_mix"    # q-k mean, value shift, L2 norm + temperature, rotary
+
+#: the model scopes of the three tables above (disjoint from DEVICE_SCOPES)
 MODEL_SCOPES: frozenset[str] = frozenset(
     (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
      SCOPE_COMBINE, SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSD,
-     SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT))
+     SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT,
+     SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX))
 
 #: every declared metric name — the set obs/rules.py validates rule
 #: manifests against at startup (unknown names fail with this list)

@@ -1,11 +1,11 @@
 """Dropless sparse-expert dispatch: route, sort, grouped matmul, combine.
 
-The expert layer of a top-k mixture (models/olmoe3d.py,
-models/nemotronh3d.py) computes, for each of ``T`` tokens, ``k`` of ``E``
-experts and nothing else. There is no capacity factor and no dropped
-token: the ``k * T`` (token, slot) pairs are sorted by expert, every
-expert multiplies the contiguous run of rows routed to it, and the rows
-are un-sorted and summed back per token under the router's weights.
+The expert layer of a top-k mixture (models/olmoe3d.py, nemotronh3d.py,
+zaya3d.py) computes, for each of ``T`` tokens, ``k`` of ``E`` experts and
+nothing else. There is no capacity factor and no dropped token: the
+``k * T`` (token, slot) pairs are sorted by expert, every expert
+multiplies the contiguous run of rows routed to it, and the rows are
+un-sorted and summed back per token under the router's weights.
 
     probs, weights, experts = route(logits, k)        # float32, always
     plan = dispatch_plan(experts, E)                   # the sort
@@ -17,9 +17,12 @@ or, for a layer of two matrices an expert that holds some of the ``E``,
 
     y = held_expert_rows(x, weights, experts, up, down, E, first, act)
 
-Two routers stand behind :func:`route`'s one signature: OLMoE's softmax
-top-k, and the sigmoid scores with a selection bias, renormalised top-k
-weights and a scaling factor of the DeepSeek-V3 line (Nemotron-H).
+Two scorings stand behind :func:`route`'s one signature: the softmax
+top-k (OLMoE; ZAYA's router MLP hands it its 17 logits, the last of which
+is no expert: a token sent there skips the layer, and since no chip holds
+it the held layer below adds nothing for it), and the sigmoid scores with
+renormalised top-k weights and a scaling factor of the DeepSeek-V3 line
+(Nemotron-H); either takes a selection bias.
 
 A layer may hold a SHARE of its experts (expert parallelism: this chip's
 ``count`` of the layer's ``E``, starting at expert ``first``). It still
@@ -98,7 +101,10 @@ def route(logits: jax.Array, k: int, *, scoring: str = "softmax",
 
     ``scoring="softmax"`` (OLMoE): softmax over the experts, then the top
     ``k``; the weights are the chosen probabilities as they are, NOT
-    renormalised over the k (``norm_topk_prob`` false).
+    renormalised over the k (``norm_topk_prob`` false). With a ``bias``
+    (ZAYA's balancing buffer) the choice is the top ``k`` of
+    ``stop_gradient(probs) + bias`` and the weights are still the chosen
+    probabilities: the bias moves the CHOICE and never the weight.
 
     ``scoring="sigmoid"`` (Nemotron-H, after DeepSeek-V3): scores
     ``sigmoid(logits)``; the top ``k`` of ``scores + bias`` (the
@@ -108,8 +114,11 @@ def route(logits: jax.Array, k: int, *, scoring: str = "softmax",
     logits = logits.astype(jnp.float32)  # nidt: allow[precision-upcast] -- the router is float32 by the architecture's definition (a bf16 softmax flips near-tied experts)
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
-        return probs, weights, experts
+        if bias is None:
+            weights, experts = jax.lax.top_k(probs, k)
+            return probs, weights, experts
+        _, experts = jax.lax.top_k(jax.lax.stop_gradient(probs) + bias, k)
+        return probs, jnp.take_along_axis(probs, experts, axis=-1), experts
     if scoring != "sigmoid":
         raise ValueError(f"route: unknown scoring {scoring!r}")
     scores = jax.nn.sigmoid(logits)

@@ -317,19 +317,21 @@ def test_names_table_is_complete():
 
 
 def test_model_scopes_table():
-    """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, and of
-    the hybrid trunk, PR 29) is a second table, disjoint from DEVICE_SCOPES
-    (which benchmark/scopes.json pins); every constant is used at least
-    once in models/, none is spelled as a literal there, and the
-    benchmark's two rules files name each between them: the OLMoE block's
-    five in olmoe_scopes.json, all eleven in nemotronh_scopes.json."""
+    """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, of the
+    hybrid trunk, PR 29, and of compressed convolutional attention, PR 31)
+    is a second table, disjoint from DEVICE_SCOPES (which
+    benchmark/scopes.json pins); every constant is used at least once in
+    models/, none is spelled as a literal there, and the benchmark's three
+    rules files name each between them: the OLMoE block's five in
+    olmoe_scopes.json, the hybrid trunk's eleven in nemotronh_scopes.json,
+    ZAYA1's layer's eight (three of them new) in zaya_scopes.json."""
     import glob
     import json
     import os
 
     consts = {k: v for k, v in vars(names).items()
               if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
-    assert len(consts) == len(names.MODEL_SCOPES) == 11
+    assert len(consts) == len(names.MODEL_SCOPES) == 14
     assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sources = {p: open(p).read() for p in glob.glob(os.path.join(
@@ -340,14 +342,19 @@ def test_model_scopes_table():
             assert not re.search(rf"""["']{value}["']""", s), (path, value)
     named = {}
     for file, partition in (("olmoe_scopes.json", "block"),
-                            ("nemotronh_scopes.json", "trunk")):
+                            ("nemotronh_scopes.json", "trunk"),
+                            ("zaya_scopes.json", "layer")):
         rules = json.load(open(os.path.join(
             root, "benchmark", "metrics", file)))
         named[file] = {s for k, v in rules["scope_names"].items()
                        if k != "what" for s in v}
         classes = {r["class"] for r in rules[partition]}
         assert set(rules["scope_names"]) - {"what"} <= classes
-    assert named["olmoe_scopes.json"] == {
-        names.SCOPE_ATTN, names.SCOPE_ROUTER, names.SCOPE_DISPATCH,
-        names.SCOPE_EXPERTS, names.SCOPE_COMBINE}
-    assert named["nemotronh_scopes.json"] == set(names.MODEL_SCOPES)
+    expert_layer = {names.SCOPE_ROUTER, names.SCOPE_DISPATCH,
+                    names.SCOPE_EXPERTS, names.SCOPE_COMBINE}
+    assert named["olmoe_scopes.json"] == expert_layer | {names.SCOPE_ATTN}
+    cca = {names.SCOPE_CCA_PROJ, names.SCOPE_CCA_CONV, names.SCOPE_CCA_MIX}
+    assert named["zaya_scopes.json"] == \
+        expert_layer | cca | {names.SCOPE_ATTN}
+    assert named["nemotronh_scopes.json"] == set(names.MODEL_SCOPES) - cca
+    assert set().union(*named.values()) == set(names.MODEL_SCOPES)

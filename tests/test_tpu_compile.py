@@ -251,3 +251,82 @@ def test_which_paths_a_held_layer_traces_follows_shapes(monkeypatch, rows,
         s((count, 8, 16), jnp.float32))
     assert (" while[" in str(jaxpr)) == (
         moe.held_capacity(rows, count, experts) is not None)
+
+
+# ---------- whole training steps of the token trunks ----------
+
+def _compiled_step(chip, monkeypatch, name):
+    """One training step (``LocalTrainer.loss_and_grad``: batch 16 of the
+    full volume, ``bf16_mixed``, the cells' optimizer) of ``--model name``
+    at its published widths, compiled for the described chip from shapes
+    alone."""
+    from neuroimagedisttraining_tpu.config import OptimConfig
+    from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
+
+    trainer = LocalTrainer(
+        create_model(name, 1, dtype=jnp.bfloat16),
+        OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
+                    grad_clip=10.0, batch_size=16), 1)
+    state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
+                           jnp.zeros((1,) + SHAPE, jnp.float32))
+    state = jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), state)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jax.jit(trainer.loss_and_grad).lower(
+        state, _on(chip, (16,) + SHAPE, jnp.uint8),
+        _on(chip, (16,), jnp.int32)).compile()
+
+
+def _program_of(compiled) -> tuple[int, int, str]:
+    """``(instructions, Mosaic kernels, sha256)`` of a compiled program's
+    instructions in order, without what a checkout's path or a line
+    number changes: the ``metadata`` and, in a kernel's call, the
+    serialized body (``backend_config``), which carries both."""
+    import hashlib
+    import re
+
+    text = compiled.as_text()
+    lines = [re.sub(r"backend_config=.*$", "backend_config=MASKED",
+                    re.sub(r", metadata=\{[^}]*\}", "", line))
+             for line in text.splitlines()
+             if re.match(r"\s*(ROOT )?%?[\w.\-]+ = ", line)]
+    return (len(lines), text.count(KERNEL_MARK),
+            hashlib.sha256("\n".join(lines).encode()).hexdigest())
+
+
+#: what the PARENT of PR 31 (ab41654) compiles these steps to (a scratch
+#: script that imports the parent's checkout, the same shapes, the same
+#: masking): PR 31 moved the rotary tables and the causal depthwise
+#: convolution to models/tokens3d.py, gave ``route`` a bias under the
+#: softmax and ``expert_load`` a skip output, and neither trunk's program
+#: may notice. A PR that means to change one of them brings its new row.
+PARENT_STEPS = {
+    "olmoe3d": (
+        3759, 9,
+        "9d00652a34bff7c0712b1289620349954ed3c0258c148a39889f404aeabffcb5"),
+    "nemotronh3d": (
+        24151, 32,
+        "71c3a658c5dd612a30b7c6842c3f93e5497abbbc469268a2e25e40f6f8237123"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_STEPS))
+def test_training_step_compiles_to_the_parents_program(chip, monkeypatch,
+                                                       name):
+    assert _program_of(_compiled_step(chip, monkeypatch, name)) == \
+        PARENT_STEPS[name]
+
+
+def test_zaya3d_training_step_fits_at_the_published_widths(chip,
+                                                           monkeypatch):
+    """``--model zaya3d``'s step at 543 M parameters: the held runs'
+    buffer of 9,728 rows in every layer (forward, rematerialised forward
+    and backward: 10 kernels a layer), no array of all 10,240 sorted rows
+    of a layer's width, and code + temporaries that leave room for the
+    folded round's 10.1 GiB of state (PERF.md, PR 31: 283.6 MiB and 2.87
+    GiB; the chip then held ZAYA_PEAK GiB at its peak)."""
+    compiled = _compiled_step(chip, monkeypatch, "zaya3d")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count(KERNEL_MARK) == 5 * 10
+    assert "bf16[9728,2048]" in text and "bf16[9728,4096]" in text
+    assert mem.temp_size_in_bytes < 3.0 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 300 * 2 ** 20
