@@ -253,7 +253,41 @@ def test_which_paths_a_held_layer_traces_follows_shapes(monkeypatch, rows,
         moe.held_capacity(rows, count, experts) is not None)
 
 
+@pytest.mark.parametrize("batch,train", [(16, True), (32, False)],
+                         ids=["step_b16", "evaluation_b32"])
+def test_ssd_kernels_compile_at_the_published_widths(chip, monkeypatch,
+                                                     batch, train):
+    """``ops/ssd.py`` ``ssd_chunked`` on a TPU at Nemotron-H's mixer (640
+    tokens in chunks of 128, 8 groups of 8 heads of 64, state 128, bf16
+    beside float32 ``dt``): a training step's batch with every gradient
+    (the forward kernel that also writes the chunks' start states, and the
+    backward kernel), evaluation's batch forward. No ``[b, 5, 128, 128,
+    ...]`` array: the decay tiles never leave the kernels."""
+    from neuroimagedisttraining_tpu.ops import ssd
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    T, H, P, G, N = 640, 64, 64, 8, 128
+    assert ssd.kernel_tiles(128, H // G, P, N)
+    operands = (_on(chip, (batch, T, H, P), jnp.bfloat16),
+                _on(chip, (batch, T, H)), _on(chip, (H,)),
+                _on(chip, (batch, T, G, N), jnp.bfloat16),
+                _on(chip, (batch, T, G, N), jnp.bfloat16), _on(chip, (H,)))
+    scan = lambda *a: ssd.ssd_chunked(*a, 128)
+    if train:
+        scan = jax.grad(lambda *a: jnp.sum(jnp.sin(ssd.ssd_chunked(
+            *a, 128).astype(jnp.float32))), argnums=tuple(range(6)))
+    compiled = jax.jit(scan).lower(*operands).compile()
+    text = compiled.as_text()
+    assert text.count(KERNEL_MARK) == (2 if train else 1)
+    assert f"[{batch},5,128,128," not in text
+    # the plain form's temporaries at b16 are 2.3 GiB forward alone
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7 * 2 ** 30
+
+
 # ---------- whole training steps of the token trunks ----------
+
+_STEPS: dict = {}  # a step compiles in a minute: once a name
+
 
 def _compiled_step(chip, monkeypatch, name):
     """One training step (``LocalTrainer.loss_and_grad``: batch 16 of the
@@ -263,6 +297,8 @@ def _compiled_step(chip, monkeypatch, name):
     from neuroimagedisttraining_tpu.config import OptimConfig
     from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
 
+    if name in _STEPS:
+        return _STEPS[name]
     trainer = LocalTrainer(
         create_model(name, 1, dtype=jnp.bfloat16),
         OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
@@ -271,9 +307,10 @@ def _compiled_step(chip, monkeypatch, name):
                            jnp.zeros((1,) + SHAPE, jnp.float32))
     state = jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), state)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return jax.jit(trainer.loss_and_grad).lower(
+    _STEPS[name] = jax.jit(trainer.loss_and_grad).lower(
         state, _on(chip, (16,) + SHAPE, jnp.uint8),
         _on(chip, (16,), jnp.int32)).compile()
+    return _STEPS[name]
 
 
 def _program_of(compiled) -> tuple[int, int, str]:
@@ -293,7 +330,8 @@ def _program_of(compiled) -> tuple[int, int, str]:
             hashlib.sha256("\n".join(lines).encode()).hexdigest())
 
 
-#: what the PARENT of PR 31 (ab41654) compiles these steps to (a scratch
+#: what the PARENT of PR 31 (ab41654) compiles ``olmoe3d``'s step to, and
+#: what PR 32 compiles ``nemotronh3d``'s to (a scratch
 #: script that imports the parent's checkout, the same shapes, the same
 #: masking): PR 31 moved the rotary tables and the causal depthwise
 #: convolution to models/tokens3d.py, gave ``route`` a bias under the
@@ -303,9 +341,11 @@ PARENT_STEPS = {
     "olmoe3d": (
         3759, 9,
         "9d00652a34bff7c0712b1289620349954ed3c0258c148a39889f404aeabffcb5"),
+    # PR 32's own row: the scan's kernels in the four M layers (the parent
+    # of PR 32 read 24151 instructions, 32 kernels, 71c3a658...)
     "nemotronh3d": (
-        24151, 32,
-        "71c3a658c5dd612a30b7c6842c3f93e5497abbbc469268a2e25e40f6f8237123"),
+        20542, 44,
+        "d07dfedba13a53cb752af3189894fb8c6fec08fbe96fda615febbd4179073996"),
 }
 
 
@@ -314,6 +354,21 @@ def test_training_step_compiles_to_the_parents_program(chip, monkeypatch,
                                                        name):
     assert _program_of(_compiled_step(chip, monkeypatch, name)) == \
         PARENT_STEPS[name]
+
+
+def test_nemotronh3d_step_holds_no_decay_matrix_and_fits(chip, monkeypatch):
+    """PR 32: the scan's kernels in the four ``M`` layers (forward,
+    rematerialised forward, backward: 12 beside the expert layers' 32), no
+    ``[b, chunks, 128, 128, ...]`` decay or mix array in any dtype, and
+    code and temporaries under the parent's 168.4 MiB + 2.481 GiB (the
+    cell fits the chip by tens of MB: PERF.md section 7, item 13d)."""
+    compiled = _compiled_step(chip, monkeypatch, "nemotronh3d")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count(KERNEL_MARK) == 32 + 4 * 3
+    assert "f32[16,5,128,128," not in text
+    assert "bf16[16,5,128,128," not in text
+    assert mem.temp_size_in_bytes < 2.481 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 180 * 2 ** 20
 
 
 def test_zaya3d_training_step_fits_at_the_published_widths(chip,
