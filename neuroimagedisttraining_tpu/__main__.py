@@ -962,6 +962,8 @@ def main(argv: list[str] | None = None) -> int:
                 print(f"[obs] host-span trace written to {out} "
                       "(load in Perfetto / chrome://tracing)",
                       flush=True)
+            # the jax.monitoring bridge lives from arm() to disarm()
+            obs_trace.disarm()
         if msrv is not None:
             msrv.close()
 

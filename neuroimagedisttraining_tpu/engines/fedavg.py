@@ -465,57 +465,66 @@ class FedAvgEngine(FederatedEngine):
         if self.stream is not None:
             return self._train_streaming()
         cfg = self.cfg
-        self._register_reflexes()
-        start, restored = self.restore_checkpoint()
-        if restored is not None:
-            params, bstats = restored["params"], restored["batch_stats"]
-            history = restored["history"]
-        else:
-            gs = self.init_global_state()
-            params, bstats = gs.params, gs.batch_stats
-            history = []
-        if self.wire_spec is not None and self.wire_spec.needs_ef:
-            # per-client error-feedback accumulators over the FULL upload
-            # payload (params + batch_stats — what the wire encodes),
-            # threaded across rounds: rows for the sampled set ride into
-            # the jitted round and the updated rows scatter back (pads
-            # dropped)
-            self._wire_ef = jax.tree.map(
-                lambda x: jnp.zeros((self.num_clients,) + x.shape,
-                                    jnp.float32),
-                {"params": params, "batch_stats": bstats})
+        # train_init / final_pass: what this call does outside its rounds
+        # (obs/names.py); disarmed, the shared no-op
+        with obs_trace.span(obs_names.SPAN_TRAIN_INIT):
+            self._register_reflexes()
+            start, restored = self.restore_checkpoint()
+            if restored is not None:
+                params, bstats = (restored["params"],
+                                  restored["batch_stats"])
+                history = restored["history"]
+            else:
+                gs = self.init_global_state()
+                params, bstats = gs.params, gs.batch_stats
+                history = []
+            if self.wire_spec is not None and self.wire_spec.needs_ef:
+                # per-client error-feedback accumulators over the FULL
+                # upload payload (params + batch_stats — what the wire
+                # encodes), threaded across rounds: rows for the sampled
+                # set ride into the jitted round and the updated rows
+                # scatter back (pads dropped)
+                self._wire_ef = jax.tree.map(
+                    lambda x: jnp.zeros((self.num_clients,) + x.shape,
+                                        jnp.float32),
+                    {"params": params, "batch_stats": bstats})
         params, bstats, history = self._round_loop(start, params, bstats,
                                                    history)
-        # final fine-tune pass -> personalized models + final eval at "-1"
-        rngs = self.per_client_rngs(cfg.fed.comm_round,
-                                    np.arange(self.num_clients))
-        # reference passes round=-1 for this pass (fedavg_api.py:85), so the
-        # fine-tune lr is lr * decay^-1, not the decayed end-of-training lr
-        if self.folded:
-            # fine-tuned, evaluated and discarded a client at a time, as
-            # the streamed pass does per chunk: no stack of personalized
-            # states ("personal" is None there too)
-            d = self.data
-            per_states = None
-            with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
-                                program="finetune_eval", split="test",
-                                **self._eval_span_args(self.num_clients)):
-                out = self._finetune_eval_jit(
-                    params, bstats, d.X_train, d.y_train, d.n_train,
-                    d.X_test, d.y_test, d.n_test, rngs, self.round_lr(-1))
-            m_global = self.eval_global(params, bstats)
-            with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
-                                program="finetune_eval"):
-                ci = slice(0, 1) if cfg.fed.ci else slice(None)
-                m_person = self._summarize(*(o[ci] for o in out),
-                                           n=d.n_test[ci])
-        else:
-            per_states = self._finetune_jit(params, bstats, self.data,
-                                            rngs, self.round_lr(-1))
-            m_global = self.eval_global(params, bstats)
-            m_person = self.eval_personalized(per_states)
-        self.stat_info["person_test_acc"].append(m_person["acc"])
-        self.log.metrics(-1, global_=m_global, personal=m_person)
+        with obs_trace.span(obs_names.SPAN_FINAL_PASS):
+            # final fine-tune pass -> personalized models + final eval at
+            # "-1"
+            rngs = self.per_client_rngs(cfg.fed.comm_round,
+                                        np.arange(self.num_clients))
+            # reference passes round=-1 for this pass (fedavg_api.py:85),
+            # so the fine-tune lr is lr * decay^-1, not the decayed
+            # end-of-training lr
+            if self.folded:
+                # fine-tuned, evaluated and discarded a client at a time,
+                # as the streamed pass does per chunk: no stack of
+                # personalized states ("personal" is None there too)
+                d = self.data
+                per_states = None
+                with obs_trace.span(
+                        obs_names.SPAN_EVAL_DISPATCH,
+                        program="finetune_eval", split="test",
+                        **self._eval_span_args(self.num_clients)):
+                    out = self._finetune_eval_jit(
+                        params, bstats, d.X_train, d.y_train, d.n_train,
+                        d.X_test, d.y_test, d.n_test, rngs,
+                        self.round_lr(-1))
+                m_global = self.eval_global(params, bstats)
+                with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
+                                    program="finetune_eval"):
+                    ci = slice(0, 1) if cfg.fed.ci else slice(None)
+                    m_person = self._summarize(*(o[ci] for o in out),
+                                               n=d.n_test[ci])
+            else:
+                per_states = self._finetune_jit(params, bstats, self.data,
+                                                rngs, self.round_lr(-1))
+                m_global = self.eval_global(params, bstats)
+                m_person = self.eval_personalized(per_states)
+            self.stat_info["person_test_acc"].append(m_person["acc"])
+            self.log.metrics(-1, global_=m_global, personal=m_person)
         return {"params": params, "batch_stats": bstats,
                 "personal": per_states, "history": history,
                 "final_global": m_global, "final_personal": m_person}
@@ -527,43 +536,51 @@ class FedAvgEngine(FederatedEngine):
         device each round (double-buffered host reads), and evaluation +
         the final fine-tune pass stream the cohort in client chunks."""
         cfg = self.cfg
-        self._register_reflexes()
-        start, restored = self.restore_checkpoint()
-        if restored is not None:
-            params, bstats = restored["params"], restored["batch_stats"]
-            history = restored["history"]
-        else:
-            gs = self.init_global_state()
-            params, bstats = gs.params, gs.batch_stats
-            history = []
-        self._stream_prefetch_for(start)
+        with obs_trace.span(obs_names.SPAN_TRAIN_INIT):
+            self._register_reflexes()
+            start, restored = self.restore_checkpoint()
+            if restored is not None:
+                params, bstats = (restored["params"],
+                                  restored["batch_stats"])
+                history = restored["history"]
+            else:
+                gs = self.init_global_state()
+                params, bstats = gs.params, gs.batch_stats
+                history = []
+            self._stream_prefetch_for(start)
         params, bstats, history = self._round_loop(start, params, bstats,
                                                    history)
-        # final fine-tune: chunked over client blocks; personalized models
-        # are evaluated per block then discarded (they'd exceed HBM)
-        chunk = self._eval_chunk_size()
-        ft_lr = self.round_lr(-1)
-        per_parts, per_ns = [], []
-        test_iter = self.stream.eval_chunks(chunk, "test")
-        for ch in self.stream.eval_chunks(chunk, "train"):
-            if self.cfg.fed.ci and per_parts:
-                break  # CI escape hatch: first chunk only
-            rngs = self.per_client_rngs(cfg.fed.comm_round, ch.padded_ids)
-            che = next(test_iter)
-            assert np.array_equal(ch.ids, che.ids)
-            out = self._finetune_eval_jit(params, bstats, ch.X, ch.y, ch.n,
-                                          che.X, che.y, che.n, rngs, ft_lr)
-            per_parts.append(tuple(np.asarray(o)[: len(ch.ids)]
-                                   for o in out))
-            per_ns.append(np.asarray(jax.device_get(che.n))[: len(ch.ids)])
-        cat = [np.concatenate([p[i] for p in per_parts]) for i in range(4)]
-        n_cat = np.concatenate(per_ns)
-        if self.cfg.fed.ci:  # client 0 only, matching the resident CI path
-            cat, n_cat = [c[:1] for c in cat], n_cat[:1]
-        m_person = self._summarize(*cat, n=n_cat)
-        m_global = self.eval_global_stream(params, bstats)
-        self.stat_info["person_test_acc"].append(m_person["acc"])
-        self.log.metrics(-1, global_=m_global, personal=m_person)
+        with obs_trace.span(obs_names.SPAN_FINAL_PASS):
+            # final fine-tune: chunked over client blocks; personalized
+            # models are evaluated per block then discarded (they'd
+            # exceed HBM)
+            chunk = self._eval_chunk_size()
+            ft_lr = self.round_lr(-1)
+            per_parts, per_ns = [], []
+            test_iter = self.stream.eval_chunks(chunk, "test")
+            for ch in self.stream.eval_chunks(chunk, "train"):
+                if self.cfg.fed.ci and per_parts:
+                    break  # CI escape hatch: first chunk only
+                rngs = self.per_client_rngs(cfg.fed.comm_round,
+                                            ch.padded_ids)
+                che = next(test_iter)
+                assert np.array_equal(ch.ids, che.ids)
+                out = self._finetune_eval_jit(
+                    params, bstats, ch.X, ch.y, ch.n, che.X, che.y, che.n,
+                    rngs, ft_lr)
+                per_parts.append(tuple(np.asarray(o)[: len(ch.ids)]
+                                       for o in out))
+                per_ns.append(
+                    np.asarray(jax.device_get(che.n))[: len(ch.ids)])
+            cat = [np.concatenate([p[i] for p in per_parts])
+                   for i in range(4)]
+            n_cat = np.concatenate(per_ns)
+            if self.cfg.fed.ci:  # client 0 only, as the resident CI path
+                cat, n_cat = [c[:1] for c in cat], n_cat[:1]
+            m_person = self._summarize(*cat, n=n_cat)
+            m_global = self.eval_global_stream(params, bstats)
+            self.stat_info["person_test_acc"].append(m_person["acc"])
+            self.log.metrics(-1, global_=m_global, personal=m_person)
         return {"params": params, "batch_stats": bstats,
                 "personal": None, "history": history,
                 "final_global": m_global, "final_personal": m_person}

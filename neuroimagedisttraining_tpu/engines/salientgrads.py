@@ -392,51 +392,59 @@ class SalientGradsEngine(FederatedEngine):
 
     def train(self):
         cfg = self.cfg
-        gs = self.init_global_state()
-        params, bstats = gs.params, gs.batch_stats
+        # train_init / mask_phase / final_pass: what this call does
+        # outside its rounds (obs/names.py); disarmed, the shared no-op
+        with obs_trace.span(obs_names.SPAN_TRAIN_INIT):
+            gs = self.init_global_state()
+            params, bstats = gs.params, gs.batch_stats
 
-        start, restored = self.restore_checkpoint()
-        if restored is not None:
-            masks = restored["masks"]  # phase 1 not recomputed on resume
-        else:
-            masks, thr = self.generate_global_mask(params, bstats)
-        density = float(mask_density(masks))
-        # mask handoff: the wire codec (and any cross-silo deployment of
-        # this engine) packs uploads against this mask — both endpoints
-        # own it, phase 1 computed it server-side and broadcast it
-        self._wire_masks = masks
-        self.log.info("global SNIP mask density = %.4f (target %.4f)",
-                      density, cfg.sparsity.dense_ratio)
-        self.stat_info["mask_density"] = density
-        if cfg.sparsity.save_masks:
-            self.stat_info["final_masks"] = jax.tree.map(np.asarray, masks)
+            start, restored = self.restore_checkpoint()
+            if restored is not None:
+                masks = restored["masks"]  # phase 1 not recomputed on resume
+            else:
+                with obs_trace.span(obs_names.SPAN_MASK_PHASE):
+                    masks, thr = self.generate_global_mask(params, bstats)
+            density = float(mask_density(masks))
+            # mask handoff: the wire codec (and any cross-silo deployment
+            # of this engine) packs uploads against this mask — both
+            # endpoints own it, phase 1 computed it server-side and
+            # broadcast it
+            self._wire_masks = masks
+            self.log.info("global SNIP mask density = %.4f (target %.4f)",
+                          density, cfg.sparsity.dense_ratio)
+            self.stat_info["mask_density"] = density
+            if cfg.sparsity.save_masks:
+                self.stat_info["final_masks"] = jax.tree.map(np.asarray,
+                                                             masks)
 
-        # flops/comm accounting (reference stat_info parity)
-        dens_map = flops_ops.densities_from_masks(masks)
-        flops_per_sample = flops_ops.count_training_flops_per_sample(
-            self.trainer.model, params, self.trainer._prep(self.sample_input()),
-            mask_density=dens_map, batch_stats=bstats)
-        # communicated parameters per client per round = nonzero mask entries
-        # (masks are ones on non-maskable leaves), matching the reference's
-        # nonzero-parameter comm metric (model_trainer.py:49-53)
-        comm_params_per_client = float(sum(
-            float(jnp.sum(m)) for m in jax.tree.leaves(masks)))
+            # flops/comm accounting (reference stat_info parity)
+            dens_map = flops_ops.densities_from_masks(masks)
+            flops_per_sample = flops_ops.count_training_flops_per_sample(
+                self.trainer.model, params,
+                self.trainer._prep(self.sample_input()),
+                mask_density=dens_map, batch_stats=bstats)
+            # communicated parameters per client per round = nonzero mask
+            # entries (masks are ones on non-maskable leaves), matching
+            # the reference's nonzero-parameter comm metric
+            # (model_trainer.py:49-53)
+            comm_params_per_client = float(sum(
+                float(jnp.sum(m)) for m in jax.tree.leaves(masks)))
 
-        per = self.broadcast_states(
-            ClientState(params=params, batch_stats=bstats,
-                        opt_state=self.trainer.opt.init(params),
-                        rng=gs.rng), self.num_clients)
-        per_params, per_bstats = per.params, per.batch_stats
+            per = self.broadcast_states(
+                ClientState(params=params, batch_stats=bstats,
+                            opt_state=self.trainer.opt.init(params),
+                            rng=gs.rng), self.num_clients)
+            per_params, per_bstats = per.params, per.batch_stats
 
-        history = []
-        if restored is not None:
-            params, bstats = restored["params"], restored["batch_stats"]
-            per_params, per_bstats = (restored["per_params"],
-                                      restored["per_bstats"])
-            history = restored["history"]
-        if self.stream is not None:
-            self.stream.prefetch_train(*self.stream_sampling(start))
-        state = (params, bstats, per_params, per_bstats)
+            history = []
+            if restored is not None:
+                params, bstats = restored["params"], restored["batch_stats"]
+                per_params, per_bstats = (restored["per_params"],
+                                          restored["per_bstats"])
+                history = restored["history"]
+            if self.stream is not None:
+                self.stream.prefetch_train(*self.stream_sampling(start))
+            state = (params, bstats, per_params, per_bstats)
         for round_idx in range(start, cfg.fed.comm_round):
             # one span for the whole iteration, sampling to checkpoint;
             # its children carry the same round id (obs/names.py)
@@ -444,11 +452,12 @@ class SalientGradsEngine(FederatedEngine):
                 state = self._round_iteration(
                     round_idx, state, masks, history,
                     (flops_per_sample, comm_params_per_client))
-        params, bstats, per_params, per_bstats = state
-        self._flush_nonfinite(cfg.fed.comm_round - 1)
-        m_global = self._eval_g(params, bstats)
-        m_person = self._eval_p(per_params, per_bstats)
-        self.log.metrics(-1, global_=m_global, personal=m_person)
+        with obs_trace.span(obs_names.SPAN_FINAL_PASS):
+            params, bstats, per_params, per_bstats = state
+            self._flush_nonfinite(cfg.fed.comm_round - 1)
+            m_global = self._eval_g(params, bstats)
+            m_person = self._eval_p(per_params, per_bstats)
+            self.log.metrics(-1, global_=m_global, personal=m_person)
         return {"params": params, "batch_stats": bstats, "masks": masks,
                 "mask_density": density, "history": history,
                 "final_global": m_global, "final_personal": m_person}

@@ -143,6 +143,24 @@ SPAN_ROUND_CHECKPOINT = "round_checkpoint"
 SPAN_FEED_WAIT = "feed_wait"              # driver waits on the reader
 SPAN_FEED_GATHER = "feed_gather"          # reader thread: host gather
 SPAN_FEED_PUT = "feed_put"                # reader thread: device_put
+# what a train() does outside its rounds (ISSUE 35): restore /
+# init_global_state / accumulators up to the first round, SalientGrads'
+# phase 1 inside it, and fine-tune + final evaluations + the -1 log row
+SPAN_TRAIN_INIT = "train_init"
+SPAN_MASK_PHASE = "mask_phase"
+SPAN_FINAL_PASS = "final_pass"
+# JAX's own build events, bridged from jax.monitoring while the tracer
+# is armed (obs/trace.py _JaxBridge): one span a trace / lowering /
+# backend compile / persistent-cache fetch, on the compiling thread
+SPAN_JAX_TRACE = "jax_trace"
+SPAN_JAX_LOWER = "jax_lower"
+SPAN_JAX_COMPILE = "jax_compile"
+SPAN_JAX_CACHE_FETCH = "jax_cache_fetch"
+
+#: the bridged build spans: they may lie anywhere a program is first
+#: called, inside a stage of a round too
+JAX_BUILD_SPANS: tuple[str, ...] = (
+    SPAN_JAX_TRACE, SPAN_JAX_LOWER, SPAN_JAX_COMPILE, SPAN_JAX_CACHE_FETCH)
 
 #: the children a resident round's iteration is tiled by, in loop order
 ROUND_CHILD_SPANS: tuple[str, ...] = (
@@ -160,7 +178,9 @@ ROUND_CHILD_SPANS: tuple[str, ...] = (
 #: ``stacked`` (one ``vmap``) / ``sharded`` (each chip loops over the rows
 #: it holds) / ``folded`` (one row after another), ``rows`` handed to the
 #: program, and ``rows_a_chip``, what one chip's loop walks when sharded
-#: (every row otherwise).
+#: (every row otherwise). The ``jax_*`` spans: ``program`` is JAX's
+#: ``fun_name`` (the traced function; ``jit(<name>)`` from lowering on),
+#: ``cache`` the persistent cache's answer where it gave one.
 ARGS_BY_SPAN: dict[str, tuple[str, ...]] = {
     SPAN_DISPATCH_PROGRAM: (
         "program", "engine", "rounds", "samples_real", "steps_real",
@@ -168,6 +188,10 @@ ARGS_BY_SPAN: dict[str, tuple[str, ...]] = {
         "placement"),
     SPAN_EVAL_DISPATCH: (
         "program", "split", "placement", "rows", "rows_a_chip"),
+    SPAN_JAX_TRACE: ("program",),
+    SPAN_JAX_LOWER: ("program",),
+    SPAN_JAX_COMPILE: ("program", "cache"),
+    SPAN_JAX_CACHE_FETCH: ("program",),
 }
 
 # ---------------------------------------------------------------------------

@@ -36,15 +36,34 @@ holds the arm instant on both clocks (``perf_counter_ns`` and
 ``--profile_dir`` trace of one run, whose "Task Environment" plane
 carries ``profile_start_time`` in unix nanoseconds, lie on one axis
 through it.
+
+JAX's own build events ride on the same axis (ISSUE 35): between
+``arm()`` and ``disarm()``, and only then, ``jax.monitoring`` listeners
+turn every trace, lowering, backend compile and persistent-cache fetch
+into one "X" event (``jax_trace`` / ``jax_lower`` / ``jax_compile`` /
+``jax_cache_fetch``, ``program=<fun_name>``, ``cache="hit"|"miss"`` on a
+compile whose cache event preceded it, ``round`` from the compiling
+thread's open spans). JAX stamps them on ``time.time()``; the clock
+anchor above puts them on the tracer's. The round driver's ``train_init``
+/ ``mask_phase`` / ``final_pass`` spans (engines/fedavg.py,
+salientgrads.py) cover what a ``train()`` does outside its rounds, so an
+operator's ``--trace_out`` file says where set-up went. Disarmed there is
+no listener, no wrapper and no hook: the bridge is installed by ``arm()``
+when ``jax`` is already imported, and this module never imports it at
+module level.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 from typing import Any
+
+from neuroimagedisttraining_tpu.obs import names as obs_names
 
 __all__ = ["SpanTracer", "TRACER", "span", "instant", "flow", "arm",
            "disarm", "dump", "make_trace_ctx", "flow_id_of"]
@@ -110,13 +129,7 @@ class _Span:
 
     def __enter__(self):
         t = self._tracer
-        stack = t._open_spans()
-        if stack:
-            outer = stack[-1].args
-            for key in t.INHERITED:
-                if key in outer and key not in self.args:
-                    self.args[key] = outer[key]
-        stack.append(self)
+        t._inherit(self.args).append(self)
         if t._annotate:
             try:
                 import jax
@@ -139,6 +152,85 @@ class _Span:
                 pass
         self._tracer._record(self.name, self._t0, t1, self.args)
         return False
+
+
+#: jax.monitoring time-span events (``start_time``, ``end_time`` on
+#: ``time.time()``, ``fun_name``) -> the span each becomes
+JAX_SPAN_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": obs_names.SPAN_JAX_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        obs_names.SPAN_JAX_LOWER,
+    "/jax/core/compile/backend_compile_duration":
+        obs_names.SPAN_JAX_COMPILE,
+}
+#: the persistent cache's events, recorded by JAX from inside its
+#: backend-compile event, without the program's name: held on the
+#: compiling thread until that event closes and names them
+JAX_CACHE_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+JAX_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                    "/jax/compilation_cache/cache_misses": "miss"}
+
+
+class _JaxBridge:
+    """The ``jax.monitoring`` listeners of one armed tracer. JAX calls
+    a listener on the thread that traces / lowers / compiles, after the
+    work, with clock reads it already took: each becomes one complete
+    event through ``SpanTracer._record``, nothing is timed twice. The
+    registry holds the bridge and the bridge only a weak reference to
+    its tracer: a tracer dropped while armed takes its listeners along."""
+
+    def __init__(self, tracer: "SpanTracer", monitoring):
+        self._tracer = weakref.ref(tracer)
+        self._monitoring = monitoring
+        self._local = threading.local()  # the compile in flight's cache
+        monitoring.register_event_time_span_listener(self.time_span)
+        monitoring.register_event_duration_secs_listener(self.duration)
+        monitoring.register_event_listener(self.event)
+        self._finalizer = weakref.finalize(tracer, self.remove)
+        self._finalizer.atexit = False
+
+    def remove(self) -> None:
+        m = self._monitoring
+        self._finalizer.detach()
+        for unregister, listener in (
+                (m.unregister_event_time_span_listener, self.time_span),
+                (m.unregister_event_duration_listener, self.duration),
+                (m.unregister_event_listener, self.event)):
+            try:
+                unregister(listener)
+            except (AssertionError, ValueError):
+                # somebody's clear_event_listeners() was here first
+                pass
+
+    def event(self, event: str, **_: Any) -> None:
+        outcome = JAX_CACHE_EVENTS.get(event)
+        if outcome is not None:
+            self._local.cache = outcome
+
+    def duration(self, event: str, duration_secs: float, **_: Any) -> None:
+        if event == JAX_CACHE_FETCH_EVENT:
+            # a duration without a span: it ends now
+            t1 = time.perf_counter_ns()
+            self._local.fetch = (t1 - int(duration_secs * 1e9), t1)
+
+    def time_span(self, event: str, start_time: float, end_time: float,
+                  **kwargs: Any) -> None:
+        name = JAX_SPAN_EVENTS.get(event)
+        t = self._tracer()
+        if name is None or t is None:
+            return
+        args = {"program": str(kwargs.get("fun_name", ""))}
+        t._inherit(args)
+        if name == obs_names.SPAN_JAX_COMPILE:
+            pending = vars(self._local)  # this thread's
+            fetch = pending.pop("fetch", None)
+            if fetch is not None:
+                t._record(obs_names.SPAN_JAX_CACHE_FETCH, *fetch,
+                          dict(args))
+            if "cache" in pending:
+                args["cache"] = pending.pop("cache")
+        t._record(name, t.from_unix_s(start_time), t.from_unix_s(end_time),
+                  args)
 
 
 class SpanTracer:
@@ -169,6 +261,7 @@ class SpanTracer:
         self._epoch_unix_ns = time.time_ns()
         self._max_events = self.DEFAULT_MAX_EVENTS
         self._dropped = 0
+        self._jax_bridge: _JaxBridge | None = None
 
     # ---- lifecycle ----
 
@@ -183,6 +276,12 @@ class SpanTracer:
         (``obs/fanin.py``) aligns worker timelines with."""
         return self._epoch_ns
 
+    def from_unix_s(self, unix_s: float) -> int:
+        """A ``time.time()`` reading on the tracer's clock
+        (``perf_counter_ns``), through the pair read back to back at
+        ``arm()``: the ``nidtClockAnchor`` of a dump."""
+        return self._epoch_ns + int(unix_s * 1e9) - self._epoch_unix_ns
+
     def arm(self, path: str | None = None, *, annotate: bool = False,
             tags: dict | None = None,
             max_events: int | None = None) -> None:
@@ -191,7 +290,10 @@ class SpanTracer:
         ``--profile_dir`` so host spans appear on the XLA timeline);
         ``tags`` ride in every event's args; ``max_events`` caps the
         buffer (default ``DEFAULT_MAX_EVENTS``; excess events are
-        dropped and counted in the dump's ``nidtDroppedEvents``)."""
+        dropped and counted in the dump's ``nidtDroppedEvents``). Where
+        ``jax`` is already imported, JAX's build events are bridged into
+        the buffer until ``disarm()`` (``_JaxBridge``)."""
+        jax = sys.modules.get("jax")
         with self._lock:
             self._path = path
             self._annotate = bool(annotate)
@@ -204,11 +306,16 @@ class SpanTracer:
                                 else int(max_events))
             self._dropped = 0
             self._armed = True
+            if self._jax_bridge is None and jax is not None:
+                self._jax_bridge = _JaxBridge(self, jax.monitoring)
 
     def disarm(self) -> None:
         with self._lock:
             self._armed = False
             self._annotate = False
+            bridge, self._jax_bridge = self._jax_bridge, None
+        if bridge is not None:
+            bridge.remove()
 
     # ---- recording ----
 
@@ -219,6 +326,17 @@ class SpanTracer:
         except AttributeError:
             stack = self._local.stack = []
             return stack
+
+    def _inherit(self, args: dict) -> list:
+        """Hand ``args`` the ``INHERITED`` arguments of the span open on
+        this thread, where it does not name its own; returns the stack."""
+        stack = self._open_spans()
+        if stack:
+            outer = stack[-1].args
+            for key in self.INHERITED:
+                if key in outer and key not in args:
+                    args[key] = outer[key]
+        return stack
 
     def span(self, name: str, **args: Any):
         """Context manager for one host span. Disarmed: a shared no-op

@@ -131,6 +131,47 @@ def test_chrome_trace_event_schema(tmp_path):
             assert isinstance(e["dur"], float) and e["dur"] >= 0
 
 
+@pytest.mark.parametrize("name", ["jax_trace", "jax_lower", "jax_compile"])
+def test_jax_build_events_land_in_the_dump(tmp_path, name):
+    """ISSUE 35: armed, JAX's own build events are "X" events of the same
+    schema and tags as the spans around them; disarmed again, a build
+    records nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    t = SpanTracer()
+    t.arm(str(tmp_path / "t.json"), tags={"role": "server"})
+    with t.span("round", round=4):
+        jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+    t.disarm()
+    n = len(t.events())
+    jax.jit(lambda x: x * 3 - 1)(jnp.ones(3)).block_until_ready()
+    assert len(t.events()) == n
+    doc = json.load(open(t.dump()))
+    mine = [e for e in doc["traceEvents"] if e["name"] == name]
+    assert mine
+    for e in mine:
+        assert e["ph"] == "X" and e["args"]["role"] == "server"
+        assert e["args"]["round"] == 4
+        assert isinstance(e["args"]["program"], str) and e["args"]["program"]
+        assert isinstance(e["ts"], float) and isinstance(e["dur"], float)
+        assert e["dur"] >= 0
+
+
+def test_jax_build_events_respect_the_buffer_cap():
+    import jax
+    import jax.numpy as jnp
+
+    t = SpanTracer()
+    t.arm(max_events=2)
+    try:
+        jax.jit(lambda x: x * 5 + 2)(jnp.ones(3)).block_until_ready()
+    finally:
+        t.disarm()
+    assert len(t.events()) == 2  # trace, lower; the compile was dropped
+    assert t._dropped >= 1
+
+
 # ------------------------------------------------ metrics registry
 
 
