@@ -64,16 +64,44 @@ class _StemConv(nn.Module):
     features: int
     dtype: Dtype = jnp.float32
 
-    @nn.compact
+    def setup(self):
+        self.kernel = self.param("kernel", nn.initializers.lecun_normal(),
+                                 (5, 5, 5, 1, self.features), jnp.float32)
+        self.bias = self.param("bias", nn.initializers.zeros,
+                               (self.features,), jnp.float32)
+
     def __call__(self, x):
         from neuroimagedisttraining_tpu.ops.stemconv import stem_conv3d
 
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (5, 5, 5, 1, self.features), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (self.features,),
-                          jnp.float32)
-        y = stem_conv3d(x.astype(self.dtype), kernel.astype(self.dtype))
-        return y + bias.astype(self.dtype)
+        y = stem_conv3d(x.astype(self.dtype), self.kernel.astype(self.dtype))
+        return y + self.bias.astype(self.dtype)
+
+
+class _StemNorm(nn.Module):
+    """``nn.BatchNorm``'s "bn" tree for a block that ``ops.stemconv.
+    stem_block`` computes: ``scale`` and ``bias`` parameters,
+    ``batch_stats`` ``mean`` and ``var``, the same initialisers and the
+    same running update."""
+
+    features: int
+    momentum: float = 0.9
+
+    def setup(self):
+        shape = (self.features,)
+        self.mean = self.variable("batch_stats", "mean", jnp.zeros, shape,
+                                  jnp.float32)
+        self.var = self.variable("batch_stats", "var", jnp.ones, shape,
+                                 jnp.float32)
+        self.scale = self.param("scale", nn.initializers.ones, shape,
+                                jnp.float32)
+        self.bias = self.param("bias", nn.initializers.zeros, shape,
+                               jnp.float32)
+
+    def update(self, mean, var):
+        if not self.is_initializing():
+            for running, batch in ((self.mean, mean), (self.var, var)):
+                running.value = (self.momentum * running.value
+                                 + (1 - self.momentum) * batch)
 
 
 class ConvBNReLU3D(nn.Module):
@@ -89,10 +117,33 @@ class ConvBNReLU3D(nn.Module):
     dtype: Dtype = jnp.float32
     norm: str = "batch"  # "batch" | "group" (3D GroupNorm option — parity
     # with the functional GroupNorm3d, group_normalization.py:7-118)
+    pool: int = 0  # a max pool of this window and stride closes the block
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        if (self.kernel, self.stride, self.pad, x.shape[-1]) == (5, 2, 0, 1):
+        stem = (self.kernel, self.stride, self.pad, x.shape[-1]) == (5, 2, 0, 1)
+        if stem and self.norm == "batch" and self.pool:
+            # the whole first stage as one function, which keeps the
+            # clients' channels side by side under a client-axis vmap
+            # (ops/stemconv.py); same "conv" and "bn" trees
+            from neuroimagedisttraining_tpu.ops.stemconv import stem_block
+
+            conv = _StemConv(self.features, dtype=self.dtype, name="conv")
+            bn = _StemNorm(self.features, name="bn")
+            if self.is_mutable_collection("intermediates"):
+                # ops/flops.py counts a convolution from its module's
+                # captured output, which this route never materialises
+                conv.sow("intermediates", "__call__", jnp.zeros(
+                    (x.shape[0], *((e - 5) // 2 + 1 for e in x.shape[1:4]),
+                     self.features), self.dtype))
+            x, mean, var = stem_block(
+                x.astype(self.dtype), conv.kernel, conv.bias, bn.scale,
+                bn.bias, bn.mean.value, bn.var.value, train=train,
+                pool=self.pool)
+            if train:
+                bn.update(mean, var)
+            return x
+        if stem:
             # the C_in = 1 stride-2 stem: XLA's own lowering leaves the MXU
             # nearly empty (ops/stemconv.py); same "conv" parameters
             x = _StemConv(self.features, dtype=self.dtype, name="conv")(x)
@@ -107,7 +158,11 @@ class ConvBNReLU3D(nn.Module):
         else:
             x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
                              epsilon=1e-5, dtype=self.dtype, name="bn")(x)
-        return nn.relu(x)
+        x = nn.relu(x)
+        if self.pool:
+            with _scope(obs_names.SCOPE_POOL0):
+                x = _pool(x, "max", self.pool, self.pool)
+        return x
 
 
 # Rematerialized block: the backward pass recomputes conv/bn activations
@@ -143,9 +198,7 @@ class AlexNet3D_Dropout(nn.Module):
             x = x.astype(self.dtype)
         with _scope(obs_names.SCOPE_STEM):
             x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype,
-                             norm=self.norm, name="f0")(x, train)
-            with _scope(obs_names.SCOPE_POOL0):
-                x = _pool(x, "max", 3, 3)
+                             norm=self.norm, pool=3, name="f0")(x, train)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype,
                          norm=self.norm, name="f1")(x, train)
         with _scope(obs_names.SCOPE_POOL1):
@@ -185,9 +238,8 @@ class AlexNet3D_Deeper_Dropout(nn.Module):
         with _scope(obs_names.SCOPE_BATCH_PREP):
             x = x.astype(self.dtype)
         with _scope(obs_names.SCOPE_STEM):
-            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
-            with _scope(obs_names.SCOPE_POOL0):
-                x = _pool(x, "max", 3, 3)
+            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype,
+                             pool=3, name="f0")(x, train)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype, name="f1")(x, train)
         with _scope(obs_names.SCOPE_POOL1):
             x = _pool(x, "max", 3, 3)
@@ -225,9 +277,8 @@ class AlexNet3D_Dropout_Regression(nn.Module):
         with _scope(obs_names.SCOPE_BATCH_PREP):
             x = x.astype(self.dtype)
         with _scope(obs_names.SCOPE_STEM):
-            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype, name="f0")(x, train)
-            with _scope(obs_names.SCOPE_POOL0):
-                x = _pool(x, "max", 3, 3)
+            x = self._blk(0)(64, kernel=5, stride=2, pad=0, dtype=self.dtype,
+                             pool=3, name="f0")(x, train)
         x = self._blk(1)(128, kernel=3, stride=1, pad=0, dtype=self.dtype, name="f1")(x, train)
         with _scope(obs_names.SCOPE_POOL1):
             x = _pool(x, "max", 3, 3)

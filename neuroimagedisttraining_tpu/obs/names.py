@@ -272,6 +272,13 @@ MODEL_SCOPES: frozenset[str] = frozenset(
      SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT,
      SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX))
 
+# A layout marker, not a stage: ops/stemconv.py's stem block names the ops
+# of its batched rule (the client-merged lanes a client-axis ``vmap``
+# selects, PR 37) with it, inside SCOPE_STEM. It belongs to neither table:
+# no class reads it, the ``[scopes]`` table of a traced run shows it as
+# ``.../stem/f0/merged/...`` rows where that form was traced.
+SCOPE_STEM_MERGED = "merged"
+
 #: every declared metric name — the set obs/rules.py validates rule
 #: manifests against at startup (unknown names fail with this list)
 DECLARED: frozenset[str] = frozenset(

@@ -115,6 +115,123 @@ def test_registry_models_forward(name, shape, nc):
     assert primary_logits(out).shape == (shape[0], nc)
 
 
+#: the flagship family's trees at 69^3 under ``key(0)``, as the tree stood
+#: before the stem became one function (PR 37; parent 9b24476): leaves and
+#: a crc32 over every leaf's path, shape, dtype and bytes in path order
+PARENT_TREES = {
+    "3DCNN": {"params": (24, 1306946193), "batch_stats": (10, 3328936775)},
+    "3dcnn_deeper": {"params": (28, 1810821921),
+                     "batch_stats": (12, 557897556)},
+    "3dcnn_regression": {"params": (24, 1306946193),
+                         "batch_stats": (10, 3328936775)},
+}
+#: the stem block's own leaves, spelled out
+PARENT_F0 = {
+    "params/f0/conv/kernel": ((5, 5, 5, 1, 64), "float32"),
+    "params/f0/conv/bias": ((64,), "float32"),
+    "params/f0/bn/scale": ((64,), "float32"),
+    "params/f0/bn/bias": ((64,), "float32"),
+    "batch_stats/f0/bn/mean": ((64,), "float32"),
+    "batch_stats/f0/bn/var": ((64,), "float32"),
+}
+
+
+def _flagship_variables(name):
+    model = create_model(name, num_classes=1)
+    return model, model.init(
+        {"params": jax.random.key(0), "dropout": jax.random.key(1)},
+        jnp.zeros((1, 69, 69, 69, 1)), train=False)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_TREES))
+def test_flagship_trees_are_the_parents(name):
+    """``stem_block`` computes f0 and its pool; the model's parameter and
+    ``batch_stats`` trees keep the parent's names, shapes, dtypes and, for
+    a fixed key, values: checkpoints, SalientGrads' masks and the
+    benchmark's float32 reference see the tree they saw."""
+    import zlib
+
+    from flax.traverse_util import flatten_dict
+
+    _, variables = _flagship_variables(name)
+    assert set(variables) == {"params", "batch_stats"}
+    flat = flatten_dict(variables, sep="/")
+    for path, (shape, dtype) in PARENT_F0.items():
+        assert (flat[path].shape, str(flat[path].dtype)) == (shape, dtype)
+    assert {p for p in flat if "/f0/" in p} == set(PARENT_F0)
+    for col, (leaves, want) in PARENT_TREES[name].items():
+        crc, table = 0, []
+        for path, leaf in sorted(flatten_dict(variables[col],
+                                              sep="/").items()):
+            crc = zlib.crc32(f"{path}{leaf.shape}{leaf.dtype}".encode()
+                             + np.asarray(leaf).tobytes(), crc)
+            table.append((path, leaf.shape, str(leaf.dtype)))
+        assert (len(table), crc) == (leaves, want), (col, table)
+
+
+def test_parents_checkpoint_restores_into_the_flagship(tmp_path):
+    """A checkpoint holding the parent's tree (built here from the pinned
+    names, not from the model) is read back, takes the model's variables'
+    structure leaf for leaf, and the model runs on it, evaluating and
+    training; the running statistics move by the parent's rule (momentum
+    0.9)."""
+    from flax import serialization
+    from flax.traverse_util import flatten_dict, unflatten_dict
+
+    from neuroimagedisttraining_tpu.utils.checkpoint import (
+        load_checkpoint,
+        save_checkpoint,
+    )
+
+    model, variables = _flagship_variables("3DCNN")
+    flat = flatten_dict(jax.tree.map(np.asarray, dict(variables)), sep="/")
+    assert set(PARENT_F0) <= set(flat)
+    rng = np.random.default_rng(0)
+    written = {p: (v + 0.01 * rng.standard_normal(v.shape)).astype(v.dtype)
+               for p, v in flat.items()}
+    save_checkpoint(str(tmp_path), 3, {"variables": unflatten_dict(
+        written, sep="/")})
+    rnd, state = load_checkpoint(str(tmp_path))
+    assert rnd == 3
+    restored = serialization.from_state_dict(variables, state["variables"])
+    for p, v in flatten_dict(restored, sep="/").items():
+        np.testing.assert_array_equal(np.asarray(v), written[p])
+    x = jnp.asarray(rng.standard_normal((2, 69, 69, 69, 1)), jnp.float32)
+    assert model.apply(restored, x, train=False).shape == (2, 1)
+    _, new = model.apply(restored, x, train=True, mutable=["batch_stats"],
+                         rngs={"dropout": jax.random.key(2)})
+    # stem_block's batch statistics, through the parent's running update
+    from neuroimagedisttraining_tpu.ops.stemconv import _conv_bias
+    f0 = restored["params"]["f0"]["conv"]
+    y = _conv_bias(x, f0["kernel"], f0["bias"])
+    want = (0.9 * restored["batch_stats"]["f0"]["bn"]["mean"]
+            + 0.1 * jnp.mean(y, (0, 1, 2, 3)))
+    np.testing.assert_allclose(
+        np.asarray(new["batch_stats"]["f0"]["bn"]["mean"]),
+        np.asarray(want), rtol=1e-5, atol=1e-6)
+
+
+def test_flagship_flops_count_sees_the_stem_convolution():
+    """``ops/flops.py`` counts a convolution from its module's captured
+    output; ``stem_block`` never materialises f0's, so the block declares
+    its shape where the counter looks: the count is the parent's."""
+    from neuroimagedisttraining_tpu.ops import flops
+
+    model = create_model("3DCNN", num_classes=1)
+    x = jnp.zeros((1, 121, 145, 121, 1))
+    variables = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.key(0), "dropout": jax.random.key(1)}, x,
+        train=False))
+    assert flops.count_inference_flops(
+        model, variables["params"], x,
+        batch_stats=variables["batch_stats"]) == 7_452_031_488.0
+    # and the forward pass proper sows nothing
+    assert set(jax.eval_shape(
+        lambda v: model.apply(v, x, train=True, mutable=["batch_stats"],
+                              rngs={"dropout": jax.random.key(2)})[1],
+        variables)) == {"batch_stats"}
+
+
 def test_norm_variants_have_no_running_stats():
     """GN-3D and resnet_ip variants must carry NO batch_stats collection —
     GroupNorm is stat-free and IP-norm never tracks (resnet_ip semantics,

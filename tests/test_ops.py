@@ -1,6 +1,7 @@
 """Sparsity ops tests: top-k selection vs numpy, ERK sparsities, mask init
 exact counts, fire/regrow semantics, SNIP identity, FLOPs counter."""
 
+import functools
 import re
 
 import jax
@@ -416,6 +417,256 @@ def test_stem_routing_is_decided_by_shape(k, s, pad, c_in):
     text = str(jax.make_jaxpr(lambda v: blk.apply(v, x, False))(variables))
     assert ("custom_vjp_call" in text) == ((k, s, pad, c_in) == (5, 2, 0, 1))
     assert variables["params"]["conv"]["kernel"].shape == (k, k, k, c_in, 4)
+
+
+class _PlainStem:
+    """The stage as the model spelled it before ``stem_block``: flax's own
+    ``nn.Conv`` + ``nn.BatchNorm`` + relu + ``nn.max_pool``."""
+
+    def __init__(self, features, pool, dtype):
+        import flax.linen as nn
+
+        class Plain(nn.Module):
+            @nn.compact
+            def __call__(self, x, train):
+                x = nn.Conv(features, (5, 5, 5), strides=(2, 2, 2),
+                            padding="VALID", dtype=dtype, name="conv")(x)
+                x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                                 epsilon=1e-5, dtype=dtype, name="bn")(x)
+                return nn.max_pool(nn.relu(x), (pool,) * 3,
+                                   strides=(pool,) * 3, padding="VALID")
+
+        self.module = Plain()
+
+    def __call__(self, x, kernel, bias, scale, offset, mean, var, *, train):
+        variables = {"params": {"conv": {"kernel": kernel, "bias": bias},
+                                "bn": {"scale": scale, "bias": offset}},
+                     "batch_stats": {"bn": {"mean": mean, "var": var}}}
+        if not train:
+            return self.module.apply(variables, x, False), mean, var
+        out, new = self.module.apply(variables, x, True,
+                                     mutable=["batch_stats"])
+        # the batch statistics, out of the running update
+        bn = new["batch_stats"]["bn"]
+        return (out, (bn["mean"] - 0.9 * mean) / 0.1,
+                (bn["var"] - 0.9 * var) / 0.1)
+
+
+def _stem_block_case(clients, features, shape=(2, 17, 19, 21),
+                     dtype=jnp.float32, seed=11):
+    """Operands of ``clients`` stem blocks, a leading client axis on each:
+    17 x 19 x 21 voxels leave 7 x 8 x 9 after the convolution, so two of
+    the pool's three extents have tail voxels outside the last window."""
+    ks = jax.random.split(jax.random.key(seed), 7)
+    lead = (clients,)
+    x = jax.random.normal(ks[0], lead + shape + (1,), jnp.float32)
+    return (x.astype(dtype),
+            0.2 * jax.random.normal(ks[1], lead + (5, 5, 5, 1, features)),
+            0.1 * jax.random.normal(ks[2], lead + (features,)),
+            1.0 + 0.1 * jax.random.normal(ks[3], lead + (features,)),
+            0.1 * jax.random.normal(ks[4], lead + (features,)),
+            0.1 * jax.random.normal(ks[5], lead + (features,)),
+            1.0 + 0.1 * jax.random.uniform(ks[6], lead + (features,)))
+
+
+def _stem_block_grads(block, train):
+    """``(pooled, mean, var), (dkernel, dbias, dscale, doffset)`` of
+    ``sum(sin(pooled)) + sum(cos(3 mean)) + sum(var^2)``: a cotangent
+    that differs at every element, on each of the three results."""
+    def loss(x, *params):
+        out = block(x, *params, train=train)
+        return (jnp.sum(jnp.sin(out[0].astype(jnp.float32)))
+                + jnp.sum(jnp.cos(3.0 * out[1])) + jnp.sum(out[2] ** 2)), out
+
+    def run(*args):
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(1, 2, 3, 4), has_aux=True)(*args)
+        return out, grads
+    return run
+
+
+def _tree_rel(got, want):
+    """Largest error of a tuple of arrays over its largest entry: one
+    scale for the parameters' gradients, because the conv bias's is zero
+    in exact arithmetic (the norm subtracts the mean) and noise in both."""
+    scale = max(float(np.max(np.abs(np.asarray(w, np.float32))))
+                for w in want)
+    return max(float(np.max(np.abs(np.asarray(g, np.float32)
+                                   - np.asarray(w, np.float32))))
+               for g, w in zip(got, want)) / scale
+
+
+_STEM_BLOCK_CASES = [
+    (f"c{c}-f{f}-{'train' if t else 'eval'}", c, f, t)
+    for c in (1, 2, 3, 4) for f in (8, 64) for t in (True, False)]
+
+
+@pytest.mark.parametrize("case,clients,features,train", _STEM_BLOCK_CASES + [
+    ("even_extent", 2, 8, True), ("one_window", 3, 8, True),
+    ("shared_parameters", 3, 8, False), ("shared_input", 2, 8, True),
+    ("remat", 2, 8, True), ("lax_map", 2, 8, True),
+    ("unbatched", 1, 8, True), ("bf16", 4, 64, True),
+    ("bf16_unbatched", 1, 64, True)],
+    ids=lambda v: v if isinstance(v, str) else None)
+def test_stem_block_matches_plain_composition(case, clients, features, train):
+    """``ops.stemconv.stem_block`` against flax's ``nn.Conv`` +
+    ``nn.BatchNorm`` + relu + ``nn.max_pool``, a client at a time: the
+    pooled output, the batch statistics and the gradients of kernel, bias,
+    scale and offset, under a client-axis ``vmap`` (the merged layout) of
+    1 to 4 clients and 8 or 64 channels (64: two clients share a 128-lane
+    window of ``g``; 3 x 64: the last window is clamped), training and
+    evaluating; with an even extent, a single pool window, parameters the
+    ``vmap`` does not batch (evaluation of one model on every client's
+    volumes), a shared input, ``jax.checkpoint``, and unbatched (directly
+    and in ``lax.map``: the plain composition). float32 to 1e-4 of the
+    largest entry: the sums run in another order. In bfloat16 the plain
+    composition's own kernel gradient is a fifth off the float32 one at
+    these shapes (a rounded activation flips a pool window's arg-max, and
+    the norm's backward cancels), so the bound there is the plain
+    composition's own distance from float32, not a constant."""
+    from neuroimagedisttraining_tpu.ops import stemconv as SC
+
+    shape = {"even_extent": (2, 18, 20, 16),
+             "one_window": (3, 9, 9, 11)}.get(case, (2, 17, 19, 21))
+    pool = 3
+    half = case.startswith("bf16")
+    args = _stem_block_case(clients, features, shape,
+                            jnp.bfloat16 if half else jnp.float32)
+
+    def mine(*a, train):
+        return SC.stem_block(*a, train=train, pool=pool)
+
+    def plain(dtype):
+        return _PlainStem(features, pool, dtype)
+
+    got_fn = _stem_block_grads(mine, train)
+    want_fn = _stem_block_grads(plain(args[0].dtype), train)
+    axes = 0
+    if case == "shared_parameters":
+        axes = (0,) + (None,) * 6
+        args = args[:1] + tuple(a[0] for a in args[1:])
+    elif case == "shared_input":
+        axes = (None,) + (0,) * 6
+        args = (args[0][0],) + args[1:]
+    if case in ("unbatched", "bf16_unbatched"):
+        args = tuple(a[0] for a in args)
+        got, want = jax.jit(got_fn)(*args), want_fn(*args)
+    elif case == "lax_map":
+        got = jax.jit(lambda *a: jax.lax.map(lambda t: got_fn(*t), a))(*args)
+        want = jax.vmap(want_fn)(*args)
+    elif case == "remat":
+        got = jax.jit(jax.vmap(_stem_block_grads(
+            lambda *a, train: jax.checkpoint(
+                functools.partial(mine, train=train))(*a), train)))(*args)
+        want = jax.vmap(want_fn)(*args)
+    else:
+        got = jax.jit(jax.vmap(got_fn, in_axes=axes))(*args)
+        want = jax.vmap(want_fn, in_axes=axes)(*args)
+    for name, g, w in zip(("pooled", "mean", "var"), got[0], want[0]):
+        assert g.shape == w.shape and g.dtype == w.dtype, (case, name)
+        assert _rel(g, w) < (2e-2 if half else 1e-4), (case, name, _rel(g, w))
+    for g, w in zip(got[1], want[1]):
+        assert g.shape == w.shape and g.dtype == w.dtype == jnp.float32
+    if not half:
+        assert _tree_rel(got[1], want[1]) < 1e-4, (
+            case, _tree_rel(got[1], want[1]))
+        return
+    exact_args = (args[0].astype(jnp.float32),) + args[1:]
+    exact_fn = _stem_block_grads(plain(jnp.float32), train)
+    exact = (exact_fn if case == "bf16_unbatched"
+             else jax.vmap(exact_fn))(*exact_args)[1]
+    assert _tree_rel(got[1], exact) < max(
+        2e-2, 1.25 * _tree_rel(want[1], exact)), (
+        case, _tree_rel(got[1], exact), _tree_rel(want[1], exact))
+
+
+def test_stem_block_form_follows_the_client_axis():
+    """Which layout the stage computes in is decided by what
+    ``custom_vmap`` sees. Unbatched (directly and in ``lax.map``: the mesh
+    cell's rows) the compiled program holds the plain composition's
+    convolutions, reductions, pool and pool backward, one for one, and no
+    op carries the ``merged`` scope; under ``vmap`` the ops carry it, the forward is one
+    ``feature_group_count = C`` convolution, and no tensor of the compiled
+    program, forward or backward, has the pre-pool extent with the clients
+    and the channels as axes of their own (``[..., C, F]`` or ``[C, ...,
+    F]``): the split comes after the pool."""
+    import collections
+
+    from neuroimagedisttraining_tpu.obs import names
+    from neuroimagedisttraining_tpu.ops import stemconv as SC
+
+    clients, features, pool = 2, 8, 3
+    stack = _stem_block_case(clients, features)
+    one = tuple(a[0] for a in stack)
+
+    def grads_of(block):
+        def scoped(*a, train):  # as the model calls it: inside "stem"
+            with jax.named_scope(names.SCOPE_STEM):
+                return block(*a, train=train, pool=pool)
+        return _stem_block_grads(scoped, True)
+
+    def operations(fn, *args):
+        """(opcode, result type) of the compiled program's convolutions,
+        reductions, pool and pool backward: what costs. (The statistics'
+        chain rule is spelled by hand in ``_block_vjp``, so a few
+        per-channel vector ops differ from autodiff's spelling.)"""
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        found = (re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", ln)
+                 for ln in text.splitlines())
+        return collections.Counter(
+            (m.group(2), m.group(1)) for m in found
+            if m and m.group(2) in ("convolution", "reduce", "reduce-window",
+                                    "select-and-scatter"))
+
+    def plain_block(*a, **kw):
+        return SC._block(*a, **kw)[0]
+
+    mine, plain = grads_of(SC.stem_block), grads_of(plain_block)
+    assert operations(mine, *one) == operations(plain, *one)
+    assert len(operations(mine, *one)) >= 4
+
+    def pool_backward_paths(fn, *args):
+        """Paths of the pool's backward ops: the benchmark's rules spell a
+        scope ``/pool0/``, and one entered inside an inner ``jax.vjp``
+        would read ``transpose(jvp(pool0))``."""
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        return [m.group(1) for m in re.finditer(
+            r'op_name="([^"]*/select_and_scatter[^"]*)"', text)]
+
+    for paths in (pool_backward_paths(mine, *one),
+                  pool_backward_paths(jax.vmap(mine), *stack)):
+        assert paths and all(f"/{names.SCOPE_POOL0}/" in p for p in paths)
+
+    def lowered(fn, *args):
+        return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+    def looped(*a):
+        return jax.lax.map(lambda t: mine(*t), a)
+
+    scope = f"/{names.SCOPE_STEM_MERGED}/"
+    assert scope not in lowered(mine, *one)
+    assert scope not in lowered(looped, *stack)
+    batched = lowered(jax.vmap(mine), *stack)
+    assert scope in batched
+
+    def forward(*a):
+        return SC.stem_block(*a, train=True, pool=pool)
+
+    convs = [ln for ln in lowered(jax.vmap(forward), *stack).splitlines()
+             if "stablehlo.convolution" in ln]
+    assert len(convs) == 1 and f"feature_group_count = {clients}" in convs[0]
+    # The two activations the backward reads cross from the forward in the
+    # clients-first shape a batched value must have, and the backward
+    # undoes it at once: the COMPILED program holds neither split shape.
+    n, (od, oh, ow) = stack[0].shape[1], (7, 8, 9)
+    split = (f"[{n},{od},{oh},{ow},{clients},{features}]",
+             f"[{clients},{n},{od},{oh},{ow},{features}]")
+    compiled = jax.jit(jax.vmap(mine)).lower(*stack).compile().as_text()
+    assert f"[{n},{od},{oh},{ow},{clients * features}]" in compiled
+    assert not any(t in compiled for t in split)
+    # ... which the plain composition under the same vmap does have
+    compiled = jax.jit(jax.vmap(plain)).lower(*stack).compile().as_text()
+    assert any(t in compiled for t in split)
 
 
 def test_fast_maxpool_tie_gradient_is_conserved():

@@ -50,12 +50,13 @@ def cohort3():
 
 
 def _engine(tmp_path, cohort, *, budget, tag, algorithm="fedavg",
-            comm_round=2, **fed_kw):
+            comm_round=2, model="3dcnn_tiny", batch_size=8, clients=3,
+            epochs=2, **fed_kw):
     cfg = ExperimentConfig(
-        model="3dcnn_tiny", num_classes=1, algorithm=algorithm,
+        model=model, num_classes=1, algorithm=algorithm,
         data=DataConfig(dataset="synthetic", partition_method="site"),
-        optim=OptimConfig(lr=1e-2, batch_size=8, epochs=2),
-        fed=FedConfig(client_num_in_total=3, comm_round=comm_round,
+        optim=OptimConfig(lr=1e-2, batch_size=batch_size, epochs=epochs),
+        fed=FedConfig(client_num_in_total=clients, comm_round=comm_round,
                       frequency_of_the_test=1, **fed_kw),
         log_dir=str(tmp_path), tag=tag)
     trainer = LocalTrainer(create_model(cfg.model, num_classes=1),
@@ -132,6 +133,58 @@ def test_folded_round_equals_stacked_round(tmp_path, cohort3):
     moved = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
         jax.tree.leaves(gs.params), jax.tree.leaves(fo[0])))
     assert moved > 1e-4
+
+
+def test_flagship_stem_stacked_equals_folded(tmp_path):
+    """The flagship ``3DCNN`` at the smallest volume it takes (69^3), two
+    clients of unequal size: stacked, the stem block computes in the
+    client-merged layout (ops/stemconv.py: a grouped convolution, norm,
+    relu and pool on 2 x 64 channels, each client's weight gradient a
+    re-expressed contraction on its window of the merged ``g``); folded,
+    a row runs alone and takes the plain composition. One round of each
+    agrees to float32 summation order: at this size a gradient is a sum
+    of 72 thousand terms a sample through the norm's cancelling backward,
+    and the parent's two placements already differed by 2.3e-6 in f1's
+    kernel, so the band is five times the tiny model's (a wrong client's
+    channels or window would be off by the values themselves); f0's
+    convolution bias, whose gradient is zero in exact arithmetic (the
+    norm subtracts the mean), is noise in both. The stacked program
+    names its merged ops inside ``stem/f0``, the folded one names
+    none."""
+    from neuroimagedisttraining_tpu.obs import names as obs_names
+
+    sites = (4, 2)  # two steps and one: the stacked row pads a step
+    cohort = generate_synthetic_abcd(num_subjects=sum(sites),
+                                     shape=(69, 69, 69), num_sites=2, seed=5)
+    cohort["site"] = np.repeat(np.arange(2), sites).astype(
+        cohort["site"].dtype)
+    kw = dict(model="3DCNN", batch_size=2, clients=2, epochs=1)
+    stacked = _engine(tmp_path, cohort, budget=1 << 40, tag="s", **kw)
+    folded = _engine(tmp_path, cohort, budget=1, tag="f", **kw)
+    st, fo = _one_round(stacked), _one_round(folded)
+    params = [jax.tree.map(lambda x: x, t[0]) for t in (st, fo)]
+    np.testing.assert_allclose(
+        *(p["f0"]["conv"].pop("bias") for p in params), atol=1e-5)
+    for a, b in zip(jax.tree.leaves((params[0], st[1])),
+                    jax.tree.leaves((params[1], fo[1]))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5 * RTOL, atol=5 * ATOL)
+    np.testing.assert_allclose(float(st[2]), float(fo[2]), rtol=RTOL)
+    assert int(st[3]) == int(fo[3]) == 0
+
+    def op_names(eng):
+        gs = eng.init_global_state()
+        sampled = eng.client_sampling(0)
+        text = eng._round_jit.lower(
+            gs.params, gs.batch_stats, eng.data, jnp.asarray(sampled),
+            eng.per_client_rngs(0, sampled), eng.round_lr(0)
+        ).as_text(debug_info=True)
+        return text
+
+    merged = (f"/{obs_names.SCOPE_STEM}/f0/"
+              f"{obs_names.SCOPE_STEM_MERGED}/")
+    assert merged in op_names(stacked)
+    assert f"/{obs_names.SCOPE_STEM_MERGED}/" not in op_names(folded)
 
 
 def test_nonfinite_client_dropped_in_both(tmp_path, cohort3):

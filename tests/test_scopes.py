@@ -311,6 +311,9 @@ def test_names_table_is_complete():
     """Every scope and span constant is unique, and the children of a
     round are named so that benchmark/harness.py GAP_SPANS admits them."""
     assert len(names.DEVICE_SCOPES) == 21
+    # a layout marker inside SCOPE_STEM, in neither table: no class reads it
+    assert names.SCOPE_STEM_MERGED not in (names.DEVICE_SCOPES
+                                           | names.MODEL_SCOPES)
     spans = [v for k, v in vars(names).items() if k.startswith("SPAN_")]
     assert len(spans) == len(set(spans))
     for s in names.ROUND_CHILD_SPANS:
