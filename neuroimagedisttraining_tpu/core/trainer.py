@@ -42,6 +42,11 @@ from neuroimagedisttraining_tpu.models import aux_outputs, primary_logits
 from neuroimagedisttraining_tpu.obs import names as obs_names
 
 PyTree = Any
+#: an evaluation batch is the largest number of rows whose tokens fit the
+#: budget (32 rows of 640 tokens, the batch every trunk before PR 38
+#: evaluated at), and never more than the 32 rows a CNN evaluates at
+EVAL_TOKEN_BUDGET = 20480
+EVAL_BATCH_MAX = 32
 
 
 def epoch_permutations(rng: jax.Array, epochs: int, max_samples: int,
@@ -500,10 +505,26 @@ class LocalTrainer:
 
     # ---------- evaluation ----------
 
+    def eval_batch_rows(self, row_shape) -> int:
+        """Rows of an evaluation batch for rows of ``row_shape`` (``[D,
+        H, W, ...]``): as many as fit the token budget the trunks
+        evaluate at, never more than ``EVAL_BATCH_MAX``. A model whose
+        rows cost more than the budget's 32nd part says what a row costs
+        (``row_tokens``: models/evabyte3d.py); any other (a CNN, a
+        640-token trunk) gets ``EVAL_BATCH_MAX``."""
+        row_tokens = getattr(self.model, "row_tokens", None)
+        tokens = row_tokens(row_shape) if row_tokens is not None else 1
+        return min(EVAL_BATCH_MAX, max(1, EVAL_TOKEN_BUDGET // tokens))
+
     @jax.named_scope(obs_names.SCOPE_EVAL)
-    def evaluate(self, params, batch_stats, X, y, valid, batch_size: int = 32):
-        """Chunked full-set eval. Returns dict with ``test_correct``,
-        ``test_loss`` (sum), ``test_total`` and raw ``scores`` for AUC."""
+    def evaluate(self, params, batch_stats, X, y, valid,
+                 batch_size: int | None = None):
+        """Chunked full-set eval in batches of ``batch_size`` rows
+        (:meth:`eval_batch_rows` where none is given). Returns dict with
+        ``test_correct``, ``test_loss`` (sum), ``test_total`` and raw
+        ``scores`` for AUC."""
+        if batch_size is None:
+            batch_size = self.eval_batch_rows(X.shape[1:])
         n = X.shape[0]
         nb = max(1, math.ceil(n / batch_size))
         pad = nb * batch_size - n

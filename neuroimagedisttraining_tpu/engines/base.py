@@ -133,12 +133,18 @@ class FederatedEngine:
                 f"just its state); streaming currently supports: {ok}")
         if fed_data is not None:
             self.num_clients = int(fed_data.num_clients)  # incl. mesh padding
-            self._n_train_host = np.asarray(fed_data.n_train)
+            source = fed_data
         elif stream is not None:
             self.num_clients = int(stream.num_clients)
-            self._n_train_host = np.asarray(stream.n_train)
+            source = stream
         else:
             raise ValueError("need fed_data or stream")
+        # every split's real rows a client, read to the host once
+        self._n_host = {
+            split: np.asarray(getattr(source, f"n_{split}"))
+            for split in ("train", "test", "val")
+            if getattr(source, f"n_{split}", None) is not None}
+        self._n_train_host = self._n_host["train"]
         self.real_clients = int(np.sum(self._n_train_host > 0))
         # deterministic fault injection (faults/): the SAME seeded
         # schedule that drives the multiprocess federation filters the
@@ -564,7 +570,7 @@ class FederatedEngine:
         # for everything dispatched before it (the round itself)
         with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
                             program="eval_global", split=split,
-                            **self._eval_span_args(n.shape[0])):
+                            **self._eval_span_args(X, split)):
             out = self._eval_global_jit(params, bstats, X, y, n)
         with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                             program="eval_global"):
@@ -584,7 +590,7 @@ class FederatedEngine:
             bstats = pt.tree_stack_index(bstats, slice(0, 1))
         with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
                             program="eval_personalized", split=split,
-                            **self._eval_span_args(n.shape[0])):
+                            **self._eval_span_args(X, split)):
             out = self._eval_personal_jit(params, bstats, X, y, n)
         with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                             program="eval_personalized"):
@@ -800,16 +806,27 @@ class FederatedEngine:
             return round_program.STACKED, rows
         return placement, rows // chips
 
-    def _eval_span_args(self, rows: int) -> dict:
-        """Where an evaluation program about to be enqueued places its
-        ``rows`` client rows, as arguments of its ``eval_dispatch`` span
-        (obs/names.py ``ARGS_BY_SPAN``): host integers, no device read; a
-        no-op while the tracer is disarmed."""
+    def _eval_span_args(self, X, split: str, ids=None) -> dict:
+        """Where an evaluation program about to be enqueued places the
+        client rows of ``X [clients, rows, ...]`` (clients ``ids`` of
+        ``split``; by default the first ``X.shape[0]``), as arguments of
+        its ``eval_dispatch`` span (obs/names.py ``ARGS_BY_SPAN``), and
+        how many sample rows its loops compute (``rows_run``: every
+        client's rows padded up to whole batches of
+        ``LocalTrainer.eval_batch_rows``) for the ``rows_real`` there
+        are. Host integers, no device read; a no-op while the tracer is
+        disarmed."""
         if not obs_trace.TRACER.armed:
             return {}
+        rows = X.shape[0]
         placement, rows_a_chip = self._rows_placement(rows)
+        batch = self.trainer.eval_batch_rows(X.shape[2:])
+        n = self._n_host[split]
         return {"placement": placement, "rows": rows,
-                "rows_a_chip": rows_a_chip}
+                "rows_a_chip": rows_a_chip,
+                "rows_run": rows * max(1, -(-X.shape[1] // batch)) * batch,
+                "rows_real": int(n[:rows].sum() if ids is None
+                                 else n[ids].sum())}
 
     def _per_client(self, fn, *stacked):
         """``fn`` over the client axis outside the round program, placed
@@ -1645,7 +1662,7 @@ class FederatedEngine:
         for ch in self.stream.eval_chunks(self._eval_chunk_size(), split):
             with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
                                 program="eval_global", split=split,
-                                **self._eval_span_args(ch.n.shape[0])):
+                                **self._eval_span_args(ch.X, split, ch.ids)):
                 out = self._eval_global_jit(params, bstats, ch.X, ch.y,
                                             ch.n)
             with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
@@ -1703,7 +1720,7 @@ class FederatedEngine:
             b = pt.tree_stack_index(per_bstats, ch.padded_ids)
             with obs_trace.span(obs_names.SPAN_EVAL_DISPATCH,
                                 program="eval_personalized", split=split,
-                                **self._eval_span_args(ch.n.shape[0])):
+                                **self._eval_span_args(ch.X, split, ch.ids)):
                 out = self._eval_personal_jit(p, b, ch.X, ch.y, ch.n)
             with obs_trace.span(obs_names.SPAN_EVAL_SYNC,
                                 program="eval_personalized"):

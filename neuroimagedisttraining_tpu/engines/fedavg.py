@@ -507,7 +507,7 @@ class FedAvgEngine(FederatedEngine):
                 with obs_trace.span(
                         obs_names.SPAN_EVAL_DISPATCH,
                         program="finetune_eval", split="test",
-                        **self._eval_span_args(self.num_clients)):
+                        **self._eval_span_args(d.X_test, "test")):
                     out = self._finetune_eval_jit(
                         params, bstats, d.X_train, d.y_train, d.n_train,
                         d.X_test, d.y_test, d.n_test, rngs,

@@ -119,6 +119,13 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         # layers at the published widths, experts 0-7 of each layer's 16
         # held; rematerialised by its own declaration, like nemotronh3d
         return Zaya3D(num_classes=num_classes, dtype=dtype)
+    if name == "evabyte3d":
+        # added here, not ported (models/evabyte3d.py): four of EvaByte's
+        # layers at the published widths, 8 of each layer's 32 heads held;
+        # rematerialised by its own declaration, like nemotronh3d
+        from neuroimagedisttraining_tpu.models.evabyte3d import EvaByte3D
+
+        return EvaByte3D(num_classes=num_classes, dtype=dtype)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":

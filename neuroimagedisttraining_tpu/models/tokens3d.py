@@ -1,6 +1,6 @@
 """What the token trunks share (models/olmoe3d.py, models/nemotronh3d.py,
-models/zaya3d.py): how a decoder trunk meets a volume, and how its one
-logit is read.
+models/zaya3d.py, models/evabyte3d.py): how a decoder trunk meets a volume,
+and how its one logit is read.
 
     x uint8 [B,121,145,121] -> (x - mean) / std of the volume, zero-pad
                                to a multiple of the patch
@@ -36,15 +36,21 @@ _scope = jax.named_scope
 
 class RMSNorm(nn.Module):
     """``weight * x / sqrt(mean(x^2) + eps)``, the statistics in float32
-    as the public code computes them."""
+    as the public code computes them. With ``unit_offset`` the gain is ``1
+    + weight`` and ``weight`` starts at zero (``norm_add_unit_offset``)."""
 
     eps: float = 1e-5
     dtype: Dtype = jnp.float32
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        weight = self.param("weight", nn.initializers.ones,
-                            (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            weight = 1.0 + self.param("weight", nn.initializers.zeros,
+                                      (x.shape[-1],), jnp.float32)
+        else:
+            weight = self.param("weight", nn.initializers.ones,
+                                (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)  # nidt: allow[precision-upcast] -- norm statistics in float32 (OlmoeRMSNorm)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = (x32 * jax.lax.rsqrt(var + self.eps)).astype(self.dtype)
@@ -137,12 +143,13 @@ def patch_embed(x, hidden_size: int, patch: int, eps: float, dtype, init):
                         name="patch_embed")(patches(x, patch, eps, dtype))
 
 
-def pooled_logits(h, num_classes: int, eps: float, init):
+def pooled_logits(h, num_classes: int, eps: float, init,
+                  unit_offset: bool = False):
     """The ``head``: float32 whatever the compute dtype (hidden x
     classes, no cost; a bf16 logit of order 1 is 0.4% coarse)."""
     with _scope(obs_names.SCOPE_HEAD):
-        pooled = jnp.mean(RMSNorm(eps, jnp.float32, name="final_norm")(h),
-                          axis=1)
+        pooled = jnp.mean(RMSNorm(eps, jnp.float32, unit_offset,
+                                  name="final_norm")(h), axis=1)
         return nn.Dense(num_classes, use_bias=False, dtype=jnp.float32,
                         kernel_init=init,
                         precision=jax.lax.Precision.HIGHEST,

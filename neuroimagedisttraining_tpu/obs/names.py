@@ -178,7 +178,10 @@ ROUND_CHILD_SPANS: tuple[str, ...] = (
 #: ``stacked`` (one ``vmap``) / ``sharded`` (each chip loops over the rows
 #: it holds) / ``folded`` (one row after another), ``rows`` handed to the
 #: program, and ``rows_a_chip``, what one chip's loop walks when sharded
-#: (every row otherwise). The ``jax_*`` spans: ``program`` is JAX's
+#: (every row otherwise); ``rows_run``, the SAMPLE rows its loops compute
+#: (each client's rows padded to whole evaluation batches,
+#: core/trainer.py ``eval_batch_rows``) for the ``rows_real`` samples there
+#: are. The ``jax_*`` spans: ``program`` is JAX's
 #: ``fun_name`` (the traced function; ``jit(<name>)`` from lowering on),
 #: ``cache`` the persistent cache's answer where it gave one.
 ARGS_BY_SPAN: dict[str, tuple[str, ...]] = {
@@ -187,7 +190,8 @@ ARGS_BY_SPAN: dict[str, tuple[str, ...]] = {
         "steps_run", "steps_skipped", "chip_steps_max", "chip_steps_mean",
         "placement"),
     SPAN_EVAL_DISPATCH: (
-        "program", "split", "placement", "rows", "rows_a_chip"),
+        "program", "split", "placement", "rows", "rows_a_chip", "rows_run",
+        "rows_real"),
     SPAN_JAX_TRACE: ("program",),
     SPAN_JAX_LOWER: ("program",),
     SPAN_JAX_COMPILE: ("program", "cache"),
@@ -265,12 +269,22 @@ SCOPE_CCA_PROJ = "cca_proj"  # a W_q, a W_k, a W_v1, a W_v2 into the latents
 SCOPE_CCA_CONV = "cca_conv"  # the depthwise and the per-head grouped conv
 SCOPE_CCA_MIX = "cca_mix"    # q-k mean, value shift, L2 norm + temperature, rotary
 
-#: the model scopes of the three tables above (disjoint from DEVICE_SCOPES)
+# EVA attention's three stages and the dense gated feed-forward
+# (models/evabyte3d.py, PR 38; benchmark/metrics/evabyte_scopes.json). The
+# three lie inside SCOPE_ATTN, which keeps the projections, the rotary
+# embedding and W_o.
+SCOPE_EVA_POOL = "eva_pool"      # the chunks' summary keys and values
+SCOPE_EVA_LOCAL = "eva_local"    # scores, exp and values inside a window; 1 / Z
+SCOPE_EVA_REMOTE = "eva_remote"  # the same against earlier windows' summaries
+SCOPE_MLP = "mlp"                # gate, up, SiLU, down
+
+#: the model scopes of the four tables above (disjoint from DEVICE_SCOPES)
 MODEL_SCOPES: frozenset[str] = frozenset(
     (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
      SCOPE_COMBINE, SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSD,
      SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT,
-     SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX))
+     SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX,
+     SCOPE_EVA_POOL, SCOPE_EVA_LOCAL, SCOPE_EVA_REMOTE, SCOPE_MLP))
 
 # A layout marker, not a stage: ops/stemconv.py's stem block names the ops
 # of its batched rule (the client-merged lanes a client-axis ``vmap``

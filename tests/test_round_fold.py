@@ -118,18 +118,35 @@ def test_placement_follows_the_budget(tmp_path, cohort3):
     assert under.program.placement == round_program.FOLDED
 
 
-def test_folded_round_equals_stacked_round(tmp_path, cohort3):
+def _small_evabyte3d(name, num_classes=1):
+    """EvaByte's layer at a small size (models/evabyte3d.py): 12 x 14 x 12
+    volumes in patches of 4 are 36 tokens, two windows of 16 and one of
+    4, so a folded client computes summaries read across both edges."""
+    from neuroimagedisttraining_tpu.models.evabyte3d import EvaByte3D, Widths
+
+    return EvaByte3D(num_classes=num_classes, widths=Widths(
+        layers=2, hidden_size=32, heads=2, head_dim=16,
+        intermediate_size=48, window_size=16, chunk_size=4, patch=4))
+
+
+@pytest.mark.parametrize("model", ["3dcnn_tiny", "evabyte3d_small"])
+def test_folded_round_equals_stacked_round(tmp_path, cohort3, monkeypatch,
+                                           model):
     """New global parameters and batch statistics equal to float32
     summation order; the round's loss and n_bad equal."""
-    st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s"))
-    fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f"))
+    if model == "evabyte3d_small":
+        monkeypatch.setitem(globals(), "create_model", _small_evabyte3d)
+    st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s",
+                            model=model))
+    fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f",
+                            model=model))
     _close(st[0], fo[0])
     _close(st[1], fo[1])
     np.testing.assert_allclose(float(st[2]), float(fo[2]), rtol=RTOL)
     assert int(st[3]) == int(fo[3]) == 0
     # the round moved the model (the comparison is not of two no-ops)
-    gs = _engine(tmp_path, cohort3, budget=None,
-                 tag="g").init_global_state()
+    gs = _engine(tmp_path, cohort3, budget=None, tag="g",
+                 model=model).init_global_state()
     moved = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
         jax.tree.leaves(gs.params), jax.tree.leaves(fo[0])))
     assert moved > 1e-4

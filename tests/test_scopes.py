@@ -323,20 +323,22 @@ def test_names_table_is_complete():
 
 def test_model_scopes_table():
     """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, of the
-    hybrid trunk, PR 29, and of compressed convolutional attention, PR 31)
+    hybrid trunk, PR 29, of compressed convolutional attention, PR 31, and
+    of EVA attention with its dense feed-forward, PR 38)
     is a second table, disjoint from DEVICE_SCOPES (which
     benchmark/scopes.json pins); every constant is used at least once in
-    models/, none is spelled as a literal there, and the benchmark's three
+    models/, none is spelled as a literal there, and the benchmark's four
     rules files name each between them: the OLMoE block's five in
     olmoe_scopes.json, the hybrid trunk's eleven in nemotronh_scopes.json,
-    ZAYA1's layer's eight (three of them new) in zaya_scopes.json."""
+    ZAYA1's layer's eight (three of them new) in zaya_scopes.json,
+    EvaByte's layer's five (four of them new) in evabyte_scopes.json."""
     import glob
     import json
     import os
 
     consts = {k: v for k, v in vars(names).items()
               if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
-    assert len(consts) == len(names.MODEL_SCOPES) == 14
+    assert len(consts) == len(names.MODEL_SCOPES) == 18
     assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sources = {p: open(p).read() for p in glob.glob(os.path.join(
@@ -348,7 +350,8 @@ def test_model_scopes_table():
     named = {}
     for file, partition in (("olmoe_scopes.json", "block"),
                             ("nemotronh_scopes.json", "trunk"),
-                            ("zaya_scopes.json", "layer")):
+                            ("zaya_scopes.json", "layer"),
+                            ("evabyte_scopes.json", "layer")):
         rules = json.load(open(os.path.join(
             root, "benchmark", "metrics", file)))
         named[file] = {s for k, v in rules["scope_names"].items()
@@ -361,5 +364,9 @@ def test_model_scopes_table():
     cca = {names.SCOPE_CCA_PROJ, names.SCOPE_CCA_CONV, names.SCOPE_CCA_MIX}
     assert named["zaya_scopes.json"] == \
         expert_layer | cca | {names.SCOPE_ATTN}
-    assert named["nemotronh_scopes.json"] == set(names.MODEL_SCOPES) - cca
+    eva = {names.SCOPE_EVA_POOL, names.SCOPE_EVA_LOCAL,
+           names.SCOPE_EVA_REMOTE, names.SCOPE_MLP}
+    assert named["evabyte_scopes.json"] == eva | {names.SCOPE_ATTN}
+    assert named["nemotronh_scopes.json"] == \
+        set(names.MODEL_SCOPES) - cca - eva
     assert set().union(*named.values()) == set(names.MODEL_SCOPES)

@@ -328,8 +328,8 @@ def test_ssd_kernels_compile_at_the_published_widths(chip, monkeypatch,
 _STEPS: dict = {}  # a step compiles in a minute: once a name
 
 
-def _compiled_step(chip, monkeypatch, name):
-    """One training step (``LocalTrainer.loss_and_grad``: batch 16 of the
+def _compiled_step(chip, monkeypatch, name, batch=16):
+    """One training step (``LocalTrainer.loss_and_grad``: ``batch`` of the
     full volume, ``bf16_mixed``, the cells' optimizer) of ``--model name``
     at its published widths, compiled for the described chip from shapes
     alone."""
@@ -341,14 +341,14 @@ def _compiled_step(chip, monkeypatch, name):
     trainer = LocalTrainer(
         create_model(name, 1, dtype=jnp.bfloat16),
         OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
-                    grad_clip=10.0, batch_size=16), 1)
+                    grad_clip=10.0, batch_size=batch), 1)
     state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
                            jnp.zeros((1,) + SHAPE, jnp.float32))
     state = jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), state)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     _STEPS[name] = jax.jit(trainer.loss_and_grad).lower(
-        state, _on(chip, (16,) + SHAPE, jnp.uint8),
-        _on(chip, (16,), jnp.int32)).compile()
+        state, _on(chip, (batch,) + SHAPE, jnp.uint8),
+        _on(chip, (batch,), jnp.int32)).compile()
     return _STEPS[name]
 
 
@@ -424,3 +424,45 @@ def test_zaya3d_training_step_fits_at_the_published_widths(chip,
     assert "bf16[9728,2048]" in text and "bf16[9728,4096]" in text
     assert mem.temp_size_in_bytes < 3.0 * 2 ** 30
     assert mem.generated_code_size_in_bytes < 300 * 2 ** 20
+
+
+def test_evabyte3d_training_step_fits_at_the_published_widths(chip,
+                                                              monkeypatch):
+    """``--model evabyte3d``'s step at 610 M parameters and the cell's
+    batch of 2 x 4,864 tokens: plain XLA (no kernel), the scores a window
+    at a time (a ``[2048, 2048]`` block a head, never ``[4864, 4864]``),
+    the summaries' scores beside them (``[768, 256]`` in the last window),
+    and code + temporaries that leave room for the folded round's 11.4 GiB
+    of state (PERF.md, PR 38: 106.8 MiB and 1.918 GiB; the chip then held
+    14.92 GiB at its peak)."""
+    compiled = _compiled_step(chip, monkeypatch, "evabyte3d", batch=2)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count(KERNEL_MARK) == 0
+    assert "f32[2,8,2048,2048]" in text and "f32[2,8,768,256]" in text
+    assert "[2,8,4864,4864]" not in text
+    assert mem.temp_size_in_bytes < 2.0 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 120 * 2 ** 20
+
+
+def test_evabyte3d_step_keeps_scores_and_softmax_float32(chip, monkeypatch):
+    """The configuration states float32 scores and softmax under
+    ``bf16_mixed``, and no limit of the benchmark's ``correct`` can tell
+    bfloat16 scores from them at the initial weights (PERF.md section 7,
+    item 16): the program the cell times says it itself. Every exponential
+    over an attention block (``[2, 8, L, ...]``: the windows' scores and
+    the summaries') is float32, forward, rematerialised and backward, and
+    so is every such block a product writes; bfloat16 appears there only
+    as the probabilities cast to meet the values. A kernel that keeps the
+    scores on the chip (ROADMAP S11) has no such instruction to show: it
+    answers to ``benchmark/evabyte_check.py``'s ``probe``."""
+    import re
+
+    text = _compiled_step(chip, monkeypatch, "evabyte3d",
+                          batch=2).as_text()
+    block = re.compile(r" = (\w+)\[2,8,(2048|768),(2048|768|128|256)\]\S* "
+                       r"(exponential|convolution|dot)\(")
+    found = [m.groups() for m in map(block.search, text.splitlines()) if m]
+    exps = [f for f in found if f[3] == "exponential"]
+    assert len(exps) >= 3 and {f[0] for f in exps} == {"f32"}
+    products = [f for f in found if f[3] != "exponential"]
+    assert products and {f[0] for f in products} == {"f32"}
