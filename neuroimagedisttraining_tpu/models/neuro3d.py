@@ -36,12 +36,9 @@ Dtype = Any
 _scope = jax.named_scope
 
 
-def _pool(x, kind: str, k: int, s: int, pad: int = 0):
-    dims = (1, k, k, k, 1)
-    strides = (1, s, s, s, 1)
-    padding = [(0, 0)] + [(pad, pad)] * 3 + [(0, 0)]
+def _pool(x, kind: str, k: int, s: int):
     if kind == "max":
-        if s == k and pad == 0 and os.environ.get("NIDT_FAST_POOL") == "1":
+        if s == k and os.environ.get("NIDT_FAST_POOL") == "1":
             # opt-in scatter-free backward for the reference's
             # non-overlapping pools: ~4% faster step but carries extra
             # residual memory — see ops/pooling.py for the measured
@@ -51,24 +48,30 @@ def _pool(x, kind: str, k: int, s: int, pad: int = 0):
             )
 
             return max_pool_3d_nonoverlap(x, k)
-        return nn.max_pool(x, dims[1:4], strides=strides[1:4], padding=padding[1:4])
-    return nn.avg_pool(x, dims[1:4], strides=strides[1:4], padding=padding[1:4])
+        return nn.max_pool(x, (k,) * 3, strides=(s,) * 3)
+    return nn.avg_pool(x, (k,) * 3, strides=(s,) * 3)
 
 
 class _StemConv(nn.Module):
-    """The stem's ``nn.Conv(features, (5, 5, 5), strides 2, VALID)`` on a
-    single-channel input, computed by ``ops.stemconv.stem_conv3d``: same
-    "conv" parameter tree (kernel ``[5, 5, 5, 1, features]`` + bias), same
-    initializers, same result."""
+    """``nn.Conv(features, (window,) * 3, use_bias=use_bias)``'s parameter
+    tree on a single-channel input (kernel ``[window, window, window, 1,
+    features]``, a bias or none; same initializers), for a convolution
+    that ``ops.stemconv`` computes: called, the k5 / stride-2 / VALID one
+    through ``stem_conv3d``; ``_stem_stage`` reads the parameters and
+    hands them to ``stem_block`` with the geometry."""
 
     features: int
+    window: int = 5
+    use_bias: bool = True
     dtype: Dtype = jnp.float32
 
     def setup(self):
         self.kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                                 (5, 5, 5, 1, self.features), jnp.float32)
+                                 (self.window,) * 3 + (1, self.features),
+                                 jnp.float32)
         self.bias = self.param("bias", nn.initializers.zeros,
-                               (self.features,), jnp.float32)
+                               (self.features,),
+                               jnp.float32) if self.use_bias else None
 
     def __call__(self, x):
         from neuroimagedisttraining_tpu.ops.stemconv import stem_conv3d
@@ -104,6 +107,34 @@ class _StemNorm(nn.Module):
                                  + (1 - self.momentum) * batch)
 
 
+def _stem_stage(x, conv: _StemConv, bn: _StemNorm, train: bool, *,
+               stride: int, pad: int, pool, norm_dtype: Dtype):
+    """A first stage (convolution of the one-channel volume, batch norm,
+    relu, the max pool that closes it) as one function, which keeps the
+    clients' channels side by side under a client-axis ``vmap``
+    (``ops/stemconv.py`` ``stem_block``). The geometry is the calling
+    model's own fields, handed on: ``conv``'s window and bias, ``stride``,
+    ``pad``, ``pool`` (``stem_block``'s), the norm's output dtype. ``conv``
+    and ``bn`` hold the trees ``nn.Conv`` and ``nn.BatchNorm`` would
+    declare under their names."""
+    from neuroimagedisttraining_tpu.ops.stemconv import stem_block
+
+    if conv.is_mutable_collection("intermediates"):
+        # ops/flops.py counts a convolution from its module's captured
+        # output, which this route never materialises
+        conv.sow("intermediates", "__call__", jnp.zeros(
+            (x.shape[0],
+             *((e + 2 * pad - conv.window) // stride + 1
+               for e in x.shape[1:4]), conv.features), conv.dtype))
+    x, mean, var = stem_block(
+        x.astype(conv.dtype), conv.kernel, conv.bias, bn.scale, bn.bias,
+        bn.mean.value, bn.var.value, train=train, stride=stride, pad=pad,
+        pool=pool, norm_dtype=norm_dtype)
+    if train:
+        bn.update(mean, var)
+    return x
+
+
 class ConvBNReLU3D(nn.Module):
     """Conv3d + BatchNorm3d + ReLU block (salient_models.py:147-149 pattern).
 
@@ -121,29 +152,17 @@ class ConvBNReLU3D(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        stem = (self.kernel, self.stride, self.pad, x.shape[-1]) == (5, 2, 0, 1)
-        if stem and self.norm == "batch" and self.pool:
-            # the whole first stage as one function, which keeps the
-            # clients' channels side by side under a client-axis vmap
-            # (ops/stemconv.py); same "conv" and "bn" trees
-            from neuroimagedisttraining_tpu.ops.stemconv import stem_block
-
-            conv = _StemConv(self.features, dtype=self.dtype, name="conv")
-            bn = _StemNorm(self.features, name="bn")
-            if self.is_mutable_collection("intermediates"):
-                # ops/flops.py counts a convolution from its module's
-                # captured output, which this route never materialises
-                conv.sow("intermediates", "__call__", jnp.zeros(
-                    (x.shape[0], *((e - 5) // 2 + 1 for e in x.shape[1:4]),
-                     self.features), self.dtype))
-            x, mean, var = stem_block(
-                x.astype(self.dtype), conv.kernel, conv.bias, bn.scale,
-                bn.bias, bn.mean.value, bn.var.value, train=train,
-                pool=self.pool)
-            if train:
-                bn.update(mean, var)
-            return x
-        if stem:
+        if x.shape[-1] == 1 and self.norm == "batch" and self.pool:
+            # what the block can see of a first stage: one input channel,
+            # batch norm, a pool that closes it. Whatever its geometry,
+            # it goes through _stem_stage; same "conv" and "bn" trees
+            return _stem_stage(
+                x, _StemConv(self.features, self.kernel, dtype=self.dtype,
+                             name="conv"),
+                _StemNorm(self.features, name="bn"), train,
+                stride=self.stride, pad=self.pad, pool=self.pool,
+                norm_dtype=self.dtype)
+        if (self.kernel, self.stride, self.pad, x.shape[-1]) == (5, 2, 0, 1):
             # the C_in = 1 stride-2 stem: XLA's own lowering leaves the MXU
             # nearly empty (ops/stemconv.py); same "conv" parameters
             x = _StemConv(self.features, dtype=self.dtype, name="conv")(x)
@@ -405,13 +424,13 @@ class ResNet3D_l3(nn.Module):
         blk = BasicBlock3D if self.block == "basic" else Bottleneck3D
         expansion = 1 if self.block == "basic" else 4
         with _scope(obs_names.SCOPE_STEM):
-            x = nn.Conv(64, (3,) * 3, strides=(2,) * 3, padding=[(3, 3)] * 3,
-                        use_bias=False, dtype=self.dtype, name="conv1")(x)
-            x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                             dtype=jnp.float32, name="bn1")(x)
-            x = nn.relu(x)
-            with _scope(obs_names.SCOPE_POOL0):
-                x = _pool(x, "max", 3, 2, pad=1)
+            # nn.Conv(64, k3, stride 2, pad 3, no bias) "conv1", a float32
+            # nn.BatchNorm "bn1", relu, max pool k3 s2 pad 1 (pool0)
+            x = _stem_stage(
+                x, _StemConv(64, 3, use_bias=False, dtype=self.dtype,
+                             name="conv1"),
+                _StemNorm(64, name="bn1"), train, stride=2, pad=3,
+                pool=(3, 2, 1), norm_dtype=jnp.float32)
         inplanes = 64
         for stage, (planes, blocks) in enumerate(zip((64, 128, 256), self.layers)):
             stride = 1 if stage == 0 else 2

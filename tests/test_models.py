@@ -117,13 +117,16 @@ def test_registry_models_forward(name, shape, nc):
 
 #: the flagship family's trees at 69^3 under ``key(0)``, as the tree stood
 #: before the stem became one function (PR 37; parent 9b24476): leaves and
-#: a crc32 over every leaf's path, shape, dtype and bytes in path order
+#: a crc32 over every leaf's path, shape, dtype and bytes in path order;
+#: ResNet3D's as it stood before its first stage did (PR 39; parent d4e0186)
 PARENT_TREES = {
     "3DCNN": {"params": (24, 1306946193), "batch_stats": (10, 3328936775)},
     "3dcnn_deeper": {"params": (28, 1810821921),
                      "batch_stats": (12, 557897556)},
     "3dcnn_regression": {"params": (24, 1306946193),
                          "batch_stats": (10, 3328936775)},
+    "resnet3d": {"params": (31, 1424005596),
+                 "batch_stats": (18, 4201344682)},
 }
 #: the stem block's own leaves, spelled out
 PARENT_F0 = {
@@ -133,6 +136,14 @@ PARENT_F0 = {
     "params/f0/bn/bias": ((64,), "float32"),
     "batch_stats/f0/bn/mean": ((64,), "float32"),
     "batch_stats/f0/bn/var": ((64,), "float32"),
+}
+#: ResNet3D's first stage: ``nn.Conv(use_bias=False)`` "conv1", "bn1"
+PARENT_CONV1 = {
+    "params/conv1/kernel": ((3, 3, 3, 1, 64), "float32"),
+    "params/bn1/scale": ((64,), "float32"),
+    "params/bn1/bias": ((64,), "float32"),
+    "batch_stats/bn1/mean": ((64,), "float32"),
+    "batch_stats/bn1/var": ((64,), "float32"),
 }
 
 
@@ -156,9 +167,11 @@ def test_flagship_trees_are_the_parents(name):
     _, variables = _flagship_variables(name)
     assert set(variables) == {"params", "batch_stats"}
     flat = flatten_dict(variables, sep="/")
-    for path, (shape, dtype) in PARENT_F0.items():
+    stem = PARENT_CONV1 if name == "resnet3d" else PARENT_F0
+    for path, (shape, dtype) in stem.items():
         assert (flat[path].shape, str(flat[path].dtype)) == (shape, dtype)
-    assert {p for p in flat if "/f0/" in p} == set(PARENT_F0)
+    modules = {p.split("/")[1] for p in stem}   # f0; conv1 and bn1
+    assert {p for p in flat if p.split("/")[1] in modules} == set(stem)
     for col, (leaves, want) in PARENT_TREES[name].items():
         crc, table = 0, []
         for path, leaf in sorted(flatten_dict(variables[col],
@@ -169,12 +182,13 @@ def test_flagship_trees_are_the_parents(name):
         assert (len(table), crc) == (leaves, want), (col, table)
 
 
-def test_parents_checkpoint_restores_into_the_flagship(tmp_path):
+@pytest.mark.parametrize("name", ["3DCNN", "resnet3d"])
+def test_parents_checkpoint_restores_into_the_flagship(tmp_path, name):
     """A checkpoint holding the parent's tree (built here from the pinned
     names, not from the model) is read back, takes the model's variables'
     structure leaf for leaf, and the model runs on it, evaluating and
     training; the running statistics move by the parent's rule (momentum
-    0.9)."""
+    0.9). ResNet3D's first stage (PR 39) as the flagship's (PR 37)."""
     from flax import serialization
     from flax.traverse_util import flatten_dict, unflatten_dict
 
@@ -183,9 +197,9 @@ def test_parents_checkpoint_restores_into_the_flagship(tmp_path):
         save_checkpoint,
     )
 
-    model, variables = _flagship_variables("3DCNN")
+    model, variables = _flagship_variables(name)
     flat = flatten_dict(jax.tree.map(np.asarray, dict(variables)), sep="/")
-    assert set(PARENT_F0) <= set(flat)
+    assert set(PARENT_CONV1 if name == "resnet3d" else PARENT_F0) <= set(flat)
     rng = np.random.default_rng(0)
     written = {p: (v + 0.01 * rng.standard_normal(v.shape)).astype(v.dtype)
                for p, v in flat.items()}
@@ -197,34 +211,46 @@ def test_parents_checkpoint_restores_into_the_flagship(tmp_path):
     for p, v in flatten_dict(restored, sep="/").items():
         np.testing.assert_array_equal(np.asarray(v), written[p])
     x = jnp.asarray(rng.standard_normal((2, 69, 69, 69, 1)), jnp.float32)
-    assert model.apply(restored, x, train=False).shape == (2, 1)
+    assert primary_logits(
+        model.apply(restored, x, train=False)).shape == (2, 1)
     _, new = model.apply(restored, x, train=True, mutable=["batch_stats"],
                          rngs={"dropout": jax.random.key(2)})
     # stem_block's batch statistics, through the parent's running update
-    from neuroimagedisttraining_tpu.ops.stemconv import _conv_bias
-    f0 = restored["params"]["f0"]["conv"]
-    y = _conv_bias(x, f0["kernel"], f0["bias"])
-    want = (0.9 * restored["batch_stats"]["f0"]["bn"]["mean"]
-            + 0.1 * jnp.mean(y, (0, 1, 2, 3)))
-    np.testing.assert_allclose(
-        np.asarray(new["batch_stats"]["f0"]["bn"]["mean"]),
-        np.asarray(want), rtol=1e-5, atol=1e-6)
+    from neuroimagedisttraining_tpu.ops import stemconv
+    if name == "resnet3d":
+        y = stemconv._conv(x, restored["params"]["conv1"]["kernel"],
+                           stemconv._Window(3, 2, 3))
+        stats = lambda tree: tree["batch_stats"]["bn1"]
+    else:
+        f0 = restored["params"]["f0"]["conv"]
+        y = stemconv._conv_bias(x, f0["kernel"], f0["bias"])
+        stats = lambda tree: tree["batch_stats"]["f0"]["bn"]
+    mean = jnp.mean(y, (0, 1, 2, 3))
+    for which, batch in (("mean", mean),
+                         ("var", jnp.mean(y * y, (0, 1, 2, 3)) - mean ** 2)):
+        np.testing.assert_allclose(
+            np.asarray(stats(new)[which]),
+            np.asarray(0.9 * stats(restored)[which] + 0.1 * batch),
+            rtol=1e-5, atol=1e-6)
 
 
-def test_flagship_flops_count_sees_the_stem_convolution():
+@pytest.mark.parametrize("name,parents", [
+    ("3DCNN", 7_452_031_488.0), ("resnet3d", 28_219_390_080.0)])
+def test_flagship_flops_count_sees_the_stem_convolution(name, parents):
     """``ops/flops.py`` counts a convolution from its module's captured
-    output; ``stem_block`` never materialises f0's, so the block declares
-    its shape where the counter looks: the count is the parent's."""
+    output; ``stem_block`` never materialises f0's (nor ResNet3D's
+    ``conv1``'s), so the stage declares its shape where the counter looks:
+    the count is the parent's."""
     from neuroimagedisttraining_tpu.ops import flops
 
-    model = create_model("3DCNN", num_classes=1)
+    model = create_model(name, num_classes=1)
     x = jnp.zeros((1, 121, 145, 121, 1))
     variables = jax.eval_shape(lambda: model.init(
         {"params": jax.random.key(0), "dropout": jax.random.key(1)}, x,
         train=False))
     assert flops.count_inference_flops(
         model, variables["params"], x,
-        batch_stats=variables["batch_stats"]) == 7_452_031_488.0
+        batch_stats=variables["batch_stats"]) == parents
     # and the forward pass proper sows nothing
     assert set(jax.eval_shape(
         lambda v: model.apply(v, x, train=True, mutable=["batch_stats"],

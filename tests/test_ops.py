@@ -3,6 +3,7 @@ exact counts, fire/regrow semantics, SNIP identity, FLOPs counter."""
 
 import functools
 import re
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -419,27 +420,64 @@ def test_stem_routing_is_decided_by_shape(k, s, pad, c_in):
     assert variables["params"]["conv"]["kernel"].shape == (k, k, k, c_in, 4)
 
 
-class _PlainStem:
-    """The stage as the model spelled it before ``stem_block``: flax's own
-    ``nn.Conv`` + ``nn.BatchNorm`` + relu + ``nn.max_pool``."""
+class _StemGeometry(NamedTuple):
+    """A first stage's static fields, as a model spells them."""
 
-    def __init__(self, features, pool, dtype):
+    kernel: int
+    stride: int
+    pad: int
+    use_bias: bool
+    norm_dtype: Any          # None: the compute dtype
+    pool: tuple              # (window, stride, pad)
+
+    def block(self, x, kernel, bias, *rest, train):
+        from neuroimagedisttraining_tpu.ops import stemconv as SC
+
+        return SC.stem_block(
+            x, kernel, bias if self.use_bias else None, *rest, train=train,
+            stride=self.stride, pad=self.pad, pool=self.pool,
+            norm_dtype=self.norm_dtype)
+
+
+#: AlexNet3D's f0 + pool0 and ResNet3D's conv1 / bn1 / pool0
+_STEMS = {
+    "alexnet": _StemGeometry(5, 2, 0, True, None, (3, 3, 0)),
+    "resnet": _StemGeometry(3, 2, 3, False, jnp.float32, (3, 2, 1)),
+}
+
+
+class _PlainStem:
+    """The stage as the models spelled it before ``stem_block``: flax's
+    own ``nn.Conv`` + ``nn.BatchNorm`` + relu + ``nn.max_pool``. A stage
+    without a bias ignores the one it is handed."""
+
+    def __init__(self, features, geo, dtype):
         import flax.linen as nn
+
+        window, stride, pad = geo.pool
 
         class Plain(nn.Module):
             @nn.compact
             def __call__(self, x, train):
-                x = nn.Conv(features, (5, 5, 5), strides=(2, 2, 2),
-                            padding="VALID", dtype=dtype, name="conv")(x)
+                x = nn.Conv(features, (geo.kernel,) * 3,
+                            strides=(geo.stride,) * 3,
+                            padding=[(geo.pad, geo.pad)] * 3,
+                            use_bias=geo.use_bias, dtype=dtype,
+                            name="conv")(x)
                 x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                                 epsilon=1e-5, dtype=dtype, name="bn")(x)
-                return nn.max_pool(nn.relu(x), (pool,) * 3,
-                                   strides=(pool,) * 3, padding="VALID")
+                                 epsilon=1e-5, dtype=geo.norm_dtype or dtype,
+                                 name="bn")(x)
+                return nn.max_pool(nn.relu(x), (window,) * 3,
+                                   strides=(stride,) * 3,
+                                   padding=[(pad, pad)] * 3)
 
         self.module = Plain()
+        self.use_bias = geo.use_bias
 
     def __call__(self, x, kernel, bias, scale, offset, mean, var, *, train):
-        variables = {"params": {"conv": {"kernel": kernel, "bias": bias},
+        conv = {"kernel": kernel, "bias": bias} if self.use_bias \
+            else {"kernel": kernel}
+        variables = {"params": {"conv": conv,
                                 "bn": {"scale": scale, "bias": offset}},
                      "batch_stats": {"bn": {"mean": mean, "var": var}}}
         if not train:
@@ -453,15 +491,17 @@ class _PlainStem:
 
 
 def _stem_block_case(clients, features, shape=(2, 17, 19, 21),
-                     dtype=jnp.float32, seed=11):
+                     dtype=jnp.float32, seed=11, kernel=5):
     """Operands of ``clients`` stem blocks, a leading client axis on each:
-    17 x 19 x 21 voxels leave 7 x 8 x 9 after the convolution, so two of
-    the pool's three extents have tail voxels outside the last window."""
+    17 x 19 x 21 voxels leave 7 x 8 x 9 after the k5 convolution, so two
+    of the k3 s3 pool's three extents have tail voxels outside the last
+    window (11 x 12 x 13 after k3 s2 pad 3, 6 x 6 x 7 after its pool)."""
     ks = jax.random.split(jax.random.key(seed), 7)
     lead = (clients,)
     x = jax.random.normal(ks[0], lead + shape + (1,), jnp.float32)
     return (x.astype(dtype),
-            0.2 * jax.random.normal(ks[1], lead + (5, 5, 5, 1, features)),
+            0.2 * jax.random.normal(
+                ks[1], lead + (kernel,) * 3 + (1, features)),
             0.1 * jax.random.normal(ks[2], lead + (features,)),
             1.0 + 0.1 * jax.random.normal(ks[3], lead + (features,)),
             0.1 * jax.random.normal(ks[4], lead + (features,)),
@@ -499,6 +539,19 @@ def _tree_rel(got, want):
 _STEM_BLOCK_CASES = [
     (f"c{c}-f{f}-{'train' if t else 'eval'}", c, f, t)
     for c in (1, 2, 3, 4) for f in (8, 64) for t in (True, False)]
+#: the second geometry (ResNet3D's stage): 2 clients x 64 channels are
+#: one 128-lane window of ``g``, 3 x 64 clamp the last
+_RESNET_BLOCK_CASES = [
+    (f"resnet-c{c}-f{f}-{'train' if t else 'eval'}", c, f, t)
+    for c in (1, 2, 3) for f in (8, 64) for t in (True, False)] + [
+    ("resnet-even_extent", 2, 8, True), ("resnet-even_extent", 2, 8, False),
+    ("resnet-shared_parameters", 2, 8, False),
+    ("resnet-shared_input", 2, 8, True), ("resnet-remat", 2, 8, True),
+    ("resnet-lax_map", 2, 8, True), ("resnet-unbatched", 1, 8, True),
+    ("resnet-unbatched", 1, 8, False), ("resnet-bf16", 2, 64, True),
+    ("resnet-bf16", 3, 64, False), ("resnet-bf16_even_extent", 2, 64, True),
+    ("resnet-bf16_unbatched", 1, 64, True),
+    ("resnet-bf16_unbatched", 1, 64, False)]
 
 
 @pytest.mark.parametrize("case,clients,features,train", _STEM_BLOCK_CASES + [
@@ -506,11 +559,14 @@ _STEM_BLOCK_CASES = [
     ("shared_parameters", 3, 8, False), ("shared_input", 2, 8, True),
     ("remat", 2, 8, True), ("lax_map", 2, 8, True),
     ("unbatched", 1, 8, True), ("bf16", 4, 64, True),
-    ("bf16_unbatched", 1, 64, True)],
+    ("bf16_unbatched", 1, 64, True)] + _RESNET_BLOCK_CASES,
     ids=lambda v: v if isinstance(v, str) else None)
 def test_stem_block_matches_plain_composition(case, clients, features, train):
     """``ops.stemconv.stem_block`` against flax's ``nn.Conv`` +
-    ``nn.BatchNorm`` + relu + ``nn.max_pool``, a client at a time: the
+    ``nn.BatchNorm`` + relu + ``nn.max_pool``, a client at a time, in the
+    two geometries the models have (``_STEMS``; a ``resnet-`` case is k3
+    stride 2 pad 3 without a bias, a float32 norm whatever the input's
+    dtype, an overlapping k3 s2 pad 1 pool): the
     pooled output, the batch statistics and the gradients of kernel, bias,
     scale and offset, under a client-axis ``vmap`` (the merged layout) of
     1 to 4 clients and 8 or 64 channels (64: two clients share a 128-lane
@@ -524,20 +580,18 @@ def test_stem_block_matches_plain_composition(case, clients, features, train):
     these shapes (a rounded activation flips a pool window's arg-max, and
     the norm's backward cancels), so the bound there is the plain
     composition's own distance from float32, not a constant."""
-    from neuroimagedisttraining_tpu.ops import stemconv as SC
-
-    shape = {"even_extent": (2, 18, 20, 16),
-             "one_window": (3, 9, 9, 11)}.get(case, (2, 17, 19, 21))
-    pool = 3
+    geo = _STEMS["resnet" if case.startswith("resnet-") else "alexnet"]
+    case = case.removeprefix("resnet-")
     half = case.startswith("bf16")
+    shape = {"even_extent": (2, 18, 20, 16), "bf16_even_extent": (2, 18, 20, 16),
+             "one_window": (3, 9, 9, 11)}.get(case, (2, 17, 19, 21))
     args = _stem_block_case(clients, features, shape,
-                            jnp.bfloat16 if half else jnp.float32)
-
-    def mine(*a, train):
-        return SC.stem_block(*a, train=train, pool=pool)
+                            jnp.bfloat16 if half else jnp.float32,
+                            kernel=geo.kernel)
+    mine = geo.block
 
     def plain(dtype):
-        return _PlainStem(features, pool, dtype)
+        return _PlainStem(features, geo, dtype)
 
     got_fn = _stem_block_grads(mine, train)
     want_fn = _stem_block_grads(plain(args[0].dtype), train)
@@ -548,7 +602,7 @@ def test_stem_block_matches_plain_composition(case, clients, features, train):
     elif case == "shared_input":
         axes = (None,) + (0,) * 6
         args = (args[0][0],) + args[1:]
-    if case in ("unbatched", "bf16_unbatched"):
+    if case.endswith("unbatched"):
         args = tuple(a[0] for a in args)
         got, want = jax.jit(got_fn)(*args), want_fn(*args)
     elif case == "lax_map":
@@ -580,9 +634,10 @@ def test_stem_block_matches_plain_composition(case, clients, features, train):
         case, _tree_rel(got[1], exact), _tree_rel(want[1], exact))
 
 
-def test_stem_block_form_follows_the_client_axis():
+@pytest.mark.parametrize("stem", sorted(_STEMS))
+def test_stem_block_form_follows_the_client_axis(stem):
     """Which layout the stage computes in is decided by what
-    ``custom_vmap`` sees. Unbatched (directly and in ``lax.map``: the mesh
+    ``custom_vmap`` sees, in either geometry. Unbatched (directly and in ``lax.map``: the mesh
     cell's rows) the compiled program holds the plain composition's
     convolutions, reductions, pool and pool backward, one for one, and no
     op carries the ``merged`` scope; under ``vmap`` the ops carry it, the forward is one
@@ -595,14 +650,15 @@ def test_stem_block_form_follows_the_client_axis():
     from neuroimagedisttraining_tpu.obs import names
     from neuroimagedisttraining_tpu.ops import stemconv as SC
 
-    clients, features, pool = 2, 8, 3
-    stack = _stem_block_case(clients, features)
+    clients, features, geo = 2, 8, _STEMS[stem]
+    conv = SC._Window(geo.kernel, geo.stride, geo.pad)
+    stack = _stem_block_case(clients, features, kernel=geo.kernel)
     one = tuple(a[0] for a in stack)
 
     def grads_of(block):
         def scoped(*a, train):  # as the model calls it: inside "stem"
             with jax.named_scope(names.SCOPE_STEM):
-                return block(*a, train=train, pool=pool)
+                return block(*a, train=train)
         return _stem_block_grads(scoped, True)
 
     def operations(fn, *args):
@@ -618,10 +674,14 @@ def test_stem_block_form_follows_the_client_axis():
             if m and m.group(2) in ("convolution", "reduce", "reduce-window",
                                     "select-and-scatter"))
 
-    def plain_block(*a, **kw):
-        return SC._block(*a, **kw)[0]
+    def plain_block(x, kernel, bias, *rest, train):
+        stage = SC._Stage(train, conv, SC._Window(*geo.pool),
+                          jnp.dtype(geo.norm_dtype or x.dtype))
+        if not geo.use_bias:
+            bias = jnp.zeros_like(bias)
+        return SC._block(x, kernel, bias, *rest, stage=stage)[0]
 
-    mine, plain = grads_of(SC.stem_block), grads_of(plain_block)
+    mine, plain = grads_of(geo.block), grads_of(plain_block)
     assert operations(mine, *one) == operations(plain, *one)
     assert len(operations(mine, *one)) >= 4
 
@@ -649,8 +709,7 @@ def test_stem_block_form_follows_the_client_axis():
     batched = lowered(jax.vmap(mine), *stack)
     assert scope in batched
 
-    def forward(*a):
-        return SC.stem_block(*a, train=True, pool=pool)
+    forward = functools.partial(geo.block, train=True)
 
     convs = [ln for ln in lowered(jax.vmap(forward), *stack).splitlines()
              if "stablehlo.convolution" in ln]
@@ -658,7 +717,7 @@ def test_stem_block_form_follows_the_client_axis():
     # The two activations the backward reads cross from the forward in the
     # clients-first shape a batched value must have, and the backward
     # undoes it at once: the COMPILED program holds neither split shape.
-    n, (od, oh, ow) = stack[0].shape[1], (7, 8, 9)
+    n, (od, oh, ow) = stack[0].shape[1], map(conv.out, stack[0].shape[2:5])
     split = (f"[{n},{od},{oh},{ow},{clients},{features}]",
              f"[{clients},{n},{od},{oh},{ow},{features}]")
     compiled = jax.jit(jax.vmap(mine)).lower(*stack).compile().as_text()

@@ -135,8 +135,15 @@ def test_stem_dw_compiles_at_full_volume(chip, clients):
         assert compiled.memory_analysis().temp_size_in_bytes < 200 * 2 ** 20
 
 
-@pytest.mark.parametrize("clients", [0, 4], ids=["unbatched", "vmap4"])
-def test_stem_block_compiles_merged_at_full_volume(chip, clients):
+#: (clients under ``vmap``, stage): the flagship's f0 + pool0 at 4 clients
+#: and ResNet3D's conv1 / bn1 / pool0 at 2, each beside its unbatched form
+STEM_BLOCK_CASES = [(0, "alexnet"), (4, "alexnet"), (0, "resnet"),
+                    (2, "resnet")]
+
+
+@pytest.mark.parametrize("clients,stage", STEM_BLOCK_CASES, ids=[
+    "unbatched", "vmap4", "resnet-unbatched", "resnet-vmap2"])
+def test_stem_block_compiles_merged_at_full_volume(chip, clients, stage):
     """The whole first stage (``ops/stemconv.py`` ``stem_block``: f0's
     convolution, norm, relu, pool0), forward and backward, at the
     flagship cell's shape: 4 clients of ``bf16[16, 121, 145, 121, 1]``
@@ -146,15 +153,31 @@ def test_stem_block_compiles_merged_at_full_volume(chip, clients):
     lanes to 128: 4.05 GB for 2.02), and the temporaries come to under 7
     GiB (5.87 measured; the plain composition under the same ``vmap``
     takes 11.44). Unbatched (a mesh row) it is the plain composition's
-    program: 3.84 GiB, as before the function existed."""
-    d, h, w = ((s - 5) // 2 + 1 for s in SHAPE)
+    program: 3.84 GiB, as before the function existed.
+
+    ResNet3D's stage (k3 stride 2 pad 3, no bias, a float32 norm, pool k3
+    s2 pad 1; PR 39) at its cell's shape, 2 clients: 2 x 64 channels are
+    one 128-lane tile, the activations after the norm are float32 as the
+    configuration states (2.44 GB each, where the split form wrote 4.88),
+    ``g`` is read as ``bf16[63, 75, 1008, 128]``: 5.98 GiB of temporaries
+    measured, the plain composition under the same ``vmap`` 14.22.
+    Unbatched it is the plain composition's program, 6.99 GiB in both."""
+    resnet = stage == "resnet"
+    k, pad, features = (3, 3, 64) if resnet else (5, 0, 64)
+    d, h, w = ((s + 2 * pad - k) // 2 + 1 for s in SHAPE)
     lead = (clients,) if clients else ()
     args = (_on(chip, lead + (16,) + SHAPE + (1,), jnp.bfloat16),
-            _on(chip, lead + (5, 5, 5, 1, 64))) + tuple(
-                _on(chip, lead + (64,)) for _ in range(5))
+            _on(chip, lead + (k, k, k, 1, features))) + tuple(
+                _on(chip, lead + (features,)) for _ in range(5))
 
-    def loss(x, *params):
-        out, mean, var = stem_block(x, *params, train=True, pool=3)
+    def loss(x, kernel, bias, *rest):
+        if resnet:
+            out, mean, var = stem_block(
+                x, kernel, None, *rest, train=True, stride=2, pad=3,
+                pool=(3, 2, 1), norm_dtype=jnp.float32)
+        else:
+            out, mean, var = stem_block(x, kernel, bias, *rest, train=True,
+                                        stride=2, pad=0, pool=3)
         return jnp.sum(jnp.sin(out.astype(jnp.float32))), (mean, var)
 
     step = jax.value_and_grad(loss, argnums=(1, 2, 3, 4), has_aux=True)
@@ -163,6 +186,18 @@ def test_stem_block_compiles_merged_at_full_volume(chip, clients):
     text = compiled.as_text()
     assert KERNEL_MARK not in text
     temp = compiled.memory_analysis().temp_size_in_bytes / 2 ** 30
+    if resnet and not clients:
+        assert 6.8 < temp < 7.2, temp
+        assert f"f32[16,{d},{h},{w},64]" in text
+        return
+    if resnet:
+        assert f"f32[16,{d},{h},{w},128]" in text   # float32 after the norm
+        assert f"bf16[{d},{h},{w * 16},128]" in text
+        for split in (f"[16,{d},{h},{w},2,64]", f"[2,16,{d},{h},{w},64]"):
+            assert split not in text, split
+        assert ".remat" not in text
+        assert temp < 6.5, temp
+        return
     if not clients:
         assert 3.7 < temp < 4.0, temp
         assert f"[16,{d},{h},{w},64]" in text
@@ -328,28 +363,31 @@ def test_ssd_kernels_compile_at_the_published_widths(chip, monkeypatch,
 _STEPS: dict = {}  # a step compiles in a minute: once a name
 
 
-def _compiled_step(chip, monkeypatch, name, batch=16):
+def _compiled_step(chip, monkeypatch, name, batch=16, clients=0):
     """One training step (``LocalTrainer.loss_and_grad``: ``batch`` of the
     full volume, ``bf16_mixed``, the cells' optimizer) of ``--model name``
     at its published widths, compiled for the described chip from shapes
-    alone."""
+    alone; ``clients``: under the stacked placement's client-axis
+    ``vmap``."""
     from neuroimagedisttraining_tpu.config import OptimConfig
     from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
 
-    if name in _STEPS:
-        return _STEPS[name]
+    if (name, clients) in _STEPS:
+        return _STEPS[name, clients]
     trainer = LocalTrainer(
         create_model(name, 1, dtype=jnp.bfloat16),
         OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
                     grad_clip=10.0, batch_size=batch), 1)
+    lead = (clients,) if clients else ()
     state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
                            jnp.zeros((1,) + SHAPE, jnp.float32))
-    state = jax.tree.map(lambda a: _on(chip, a.shape, a.dtype), state)
+    state = jax.tree.map(lambda a: _on(chip, lead + a.shape, a.dtype), state)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    _STEPS[name] = jax.jit(trainer.loss_and_grad).lower(
-        state, _on(chip, (batch,) + SHAPE, jnp.uint8),
-        _on(chip, (batch,), jnp.int32)).compile()
-    return _STEPS[name]
+    step = trainer.loss_and_grad
+    _STEPS[name, clients] = jax.jit(jax.vmap(step) if clients else step) \
+        .lower(state, _on(chip, lead + (batch,) + SHAPE, jnp.uint8),
+               _on(chip, lead + (batch,), jnp.int32)).compile()
+    return _STEPS[name, clients]
 
 
 def _program_of(compiled) -> tuple[int, int, str]:
@@ -466,3 +504,24 @@ def test_evabyte3d_step_keeps_scores_and_softmax_float32(chip, monkeypatch):
     assert len(exps) >= 3 and {f[0] for f in exps} == {"f32"}
     products = [f for f in found if f[3] != "exponential"]
     assert products and {f[0] for f in products} == {"f32"}
+
+
+def test_resnet3d_vmapped_step_stays_client_merged(chip, monkeypatch):
+    """``resnet3d.fedavg_resident``'s training step: 2 clients of batch 16
+    under ``vmap``. The first stage stays in the grouped convolution's
+    128-lane layout (PR 39): no instruction has a pre-pool activation
+    split into ``[..., 2, 64]`` or ``[2, ...]`` (64 lanes padded to 128:
+    4.88 GB written for 2.44), none is a ``.remat`` copy, and the step's
+    temporaries are 6.12 GiB where the parent's were 14.36 of the chip's
+    15.75. Unbatched (a mesh row) the step takes what the parent's took,
+    6.99 GiB: the plain composition."""
+    compiled = _compiled_step(chip, monkeypatch, "resnet3d", clients=2)
+    text = compiled.as_text()
+    assert "f32[16,63,75,63,128]" in text
+    for split in ("[16,63,75,63,2,64]", "[2,16,63,75,63,64]"):
+        assert split not in text, split
+    assert ".remat" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.6 * 2 ** 30
+    alone = _compiled_step(chip, monkeypatch, "resnet3d")
+    assert "f32[16,63,75,63,64]" in alone.as_text()
+    assert 6.8 < alone.memory_analysis().temp_size_in_bytes / 2 ** 30 < 7.2
