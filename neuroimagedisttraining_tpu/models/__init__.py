@@ -126,6 +126,14 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         from neuroimagedisttraining_tpu.models.evabyte3d import EvaByte3D
 
         return EvaByte3D(num_classes=num_classes, dtype=dtype)
+    if name == "moonlight3d":
+        # added here, not ported (models/moonlight3d.py): Moonlight-16B-
+        # A3B's leading dense layer and five of its expert layers at the
+        # published widths, experts 0-7 of each layer's 64 held;
+        # rematerialised by its own declaration, like nemotronh3d
+        from neuroimagedisttraining_tpu.models.moonlight3d import Moonlight3D
+
+        return Moonlight3D(num_classes=num_classes, dtype=dtype)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":

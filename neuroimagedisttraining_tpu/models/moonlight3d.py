@@ -1,0 +1,329 @@
+"""Moonlight's layer over 3D patch tokens (``--model moonlight3d``).
+
+Added here, not ported: the reference repository has no such model. The
+layer is ``Moonlight-16B-A3B``'s (moonshotai; the public ``config.json``,
+``model_type`` ``deepseek_v3``; Moonlight arXiv:2502.16982, its attention
+DeepSeek-V2's multi-head latent attention arXiv:2405.04434, its expert
+layer DeepSeek-V3's arXiv:2412.19437), every width as published: hidden
+``H`` = 2048, 16 heads ``a`` with ``dn`` = 128 score dimensions without
+position, ``dr`` = 64 rotary ones and ``dv`` = 128 value dimensions, a
+latent of ``r`` = 512, a leading dense feed-forward of width 11264, then
+64 experts of width 1408, 6 a token, beside two shared experts. The
+leading dense layer and five of the 26 expert layers; ``N`` is RMSNorm
+with a plain weight, eps 1e-5, its statistics in float32; no bias:
+
+    x          = N_1(h)
+    q_a        = x Wq_a                      Wq [H, 16 x 192] (no low-rank query)
+    qn_a, qr_a = q_a[:128], rope(q_a[128:])  rotary over the 64, theta 50000
+    [c, kr]    = x Wdkv                      Wdkv [H, 512 + 64]
+    c          = N_kv(c)                     the latent; kr' = rope(kr): ONE
+                                             rotary key a token, read by every head
+    [kn_a,v_a] = c Wukv_a                    Wukv [512, 16 x (128 + 128)]
+    s_a,i,t    = 192^-1/2 (qn_a,i . kn_a,t + qr_a,i . kr'_t)     t <= i, float32
+    o_a,i      = sum_t softmax_t(s_a,i,.) v_a,t
+    h          = h + concat_a(o_a) Wo        Wo [16 x 128, H]
+
+    layer 0    h = h + (silu(u Wg) * (u Wu)) Wd                  u = N_2(h), width 11264
+    layers 1-5 s   = sigmoid(u Wr) in R^64, float32              u = N_2(h)
+               C   = top-6 of (s + b)        b the e_score_correction_bias: zeros
+               g_e = 2.446 s_e / (sum over C of s + 1e-20)
+               h   = h + sum over e in C and HELD of g_e E_e(u) + S(u)
+               E_e = the gated form above at width 1408; S the same at 2 x 1408
+    aux        for each sequence f_e = 64 / (6 T) #{t : e in C_t},
+               P_e = mean_t (s_e,t / sum_j s_j,t), L = alpha sum_e f_e P_e;
+               the mean over the batch's sequences, summed over the five layers
+
+Keys and values are REBUILT from the latent by one up-projection (not
+kept in it, as models/zaya3d.py's are), the rotary key bypasses the
+latent and is shared by all heads, and a score is 192 wide where its
+value is 128.
+
+**How the attention is computed** (``tokens3d.blocked_causal_attention``):
+``kn`` and ``v`` are rebuilt once a layer, the shared rotary key is
+repeated beside each head's ``kn`` (20 MB), and the scores are taken a
+block of ``block`` queries at a time against the keys up to the block's
+end: exact and causal over the whole sequence, no ``[T, T]`` scores of
+all heads, no pair above the diagonal's blocks. A block's probabilities
+are not kept: it is rematerialised in the backward pass.
+
+**The expert layer holds experts 0-7 of the 64** (``held``; ops/moe.py
+``held_expert_rows``): eight chips share each layer by expert
+parallelism. The router keeps its 64 outputs and 6 a token; a slot routed
+to another chip's expert adds nothing here, and nothing stands in for the
+other chips or their exchange. Gate and up are one ``[count, 2048, 2816]``
+matrix, side by side. Attention, the shared experts (one gated MLP of
+width 2816, as the public code computes ``n_shared_experts x
+moe_intermediate_size``), the norms and the leading layer are whole.
+
+What is NOT built: the token embedding and LM head (replaced as in the
+other trunks, models/tokens3d.py), generation and the cache of latents,
+the absorbed form of the up-projections that serving uses, the update of
+``b`` (a training recipe ``config.json`` does not give: none is handed to
+the router). What ``config.json`` does not give is listed, with where
+each was taken from, in benchmark/configs/moonlight-abcd.json
+(``assumed``).
+
+The model returns ``(logits, aux)``: ``aux["loss"]`` is the weighted
+``L`` (filler rows of a padded batch count as sequences, as
+models/olmoe3d.py's term counts their tokens), ``aux["expert_tokens"]``
+the slots routed to each of the 64 experts, summed over the expert
+layers, ``aux["held_overflow_calls"]`` the layers whose held rows passed
+the buffer in this call. Every layer is rematerialised
+(``remat_layers``, the model's own declaration).
+
+Device scopes (obs/names.py MODEL_SCOPES): ``attn`` (W_q, its rotary,
+W_o) with ``mla_latent`` and ``mla_core`` inside it; ``mlp`` (layer 0);
+``router``, ``dispatch``, ``experts``, ``combine``, ``shared_expert``;
+``stem``, ``head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from neuroimagedisttraining_tpu.models import evabyte3d, tokens3d
+from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
+from neuroimagedisttraining_tpu.models.zaya3d import swiglu
+from neuroimagedisttraining_tpu.obs import names as obs_names
+
+Dtype = Any
+_scope = jax.named_scope
+INIT_STD = 0.02
+
+
+@dataclasses.dataclass(frozen=True)
+class Widths:
+    """The trunk's sizes; the defaults are the published widths and this
+    chip's share (the CPU tests pass a small size)."""
+
+    dense_layers: int = 1   # first_k_dense_replace
+    expert_layers: int = 5  # of the published 26
+    hidden_size: int = 2048
+    heads: int = 16
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    intermediate_size: int = 11264
+    num_experts: int = 64
+    held: tuple[int, int] = (0, 8)  # one of 8 chips' experts of a layer
+    experts_per_token: int = 6
+    expert_width: int = 1408
+    shared_experts: int = 2
+    routed_scaling_factor: float = 2.446
+    aux_alpha: float = 0.001
+    rope_theta: float = 5e4
+    block: int = 512  # queries a block of scores (the program's, no width)
+    patch: int = 8
+    rms_eps: float = 1e-5
+
+
+def _dense(n, name, dtype):
+    return nn.Dense(n, use_bias=False, dtype=dtype, name=name,
+                    kernel_init=nn.initializers.normal(stddev=INIT_STD))
+
+
+def gated_mlp(hidden: int, width: int, dtype, name: str):
+    """``(silu(x W_gate) * (x W_up)) W_down`` at ``width``: EvaByte's
+    module (models/evabyte3d.py ``GatedMLP``), which reads its three sizes
+    from that model's ``Widths``."""
+    return evabyte3d.GatedMLP(
+        evabyte3d.Widths(hidden_size=hidden, intermediate_size=width,
+                         init_std=INIT_STD), dtype, name=name)
+
+
+def mla_core(qn, qr, kn, kr, v, block: int, dtype):
+    """Scores, softmax and values: ``qn, kn [B, T, A, dn]``, ``qr [B, T,
+    A, dr]`` and the ONE rotary key a token ``kr [B, T, 1, dr]`` (both
+    after the rotary embedding), ``v [B, T, A, dv]`` -> ``[B, T, A * dv]``.
+    A head's score is ``(dn + dr)^-1/2 (qn . kn + qr . kr)``: the shared
+    key is repeated beside each head's own and the two products are one
+    contraction over ``dn + dr``."""
+    with _scope(obs_names.SCOPE_MLA_CORE):
+        q = jnp.concatenate([qn, qr], axis=-1)
+        k = jnp.concatenate(
+            [kn, jnp.broadcast_to(kr, kn.shape[:-1] + kr.shape[-1:])],
+            axis=-1)
+        return tokens3d.blocked_causal_attention(q, k, v, block, dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention: ``x [B, T, H]`` -> ``[B, T, H]`` (the
+    equations are in the module's docstring)."""
+
+    w: Widths
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.w
+        B, T, H = x.shape
+        A, dn, dr, dv = (c.heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
+                         c.v_head_dim)
+        cos, sin = tokens3d.rope_tables(T, dr, c.rope_theta)
+        q = _dense(A * (dn + dr), "q_proj", self.dtype)(x).reshape(
+            B, T, A, dn + dr)
+        qn, qr = q[..., :dn], tokens3d.apply_rope(q[..., dn:], cos, sin)
+        with _scope(obs_names.SCOPE_MLA_LATENT):
+            down = _dense(c.kv_lora_rank + dr, "kv_a_proj", self.dtype)(x)
+            latent = RMSNorm(c.rms_eps, self.dtype, name="kv_norm")(
+                down[..., :c.kv_lora_rank])
+            kr = tokens3d.apply_rope(
+                down[..., c.kv_lora_rank:].reshape(B, T, 1, dr), cos, sin)
+            up = _dense(A * (dn + dv), "kv_b_proj", self.dtype)(
+                latent).reshape(B, T, A, dn + dv)
+            kn, v = up[..., :dn], up[..., dn:]
+        out = mla_core(qn, qr, kn, kr, v, c.block, self.dtype)
+        return _dense(H, "o_proj", self.dtype)(out)
+
+
+class HeldGatedExperts(nn.Module):
+    """The routed part of an expert layer for the experts this chip
+    holds: ``u [B, T, H]`` -> ``(y [B, T, H], experts [B*T, k], passed,
+    balance)``. Routes over all ``num_experts`` by sigmoid scores;
+    ``passed`` is 1 where this call's held rows passed the buffer and took
+    more than one window of it (ops/moe.py ``held_expert_rows``: the
+    dropless answer either way); ``balance`` is the sequence-wise balance
+    loss, the mean over the batch's sequences, unweighted."""
+
+    w: Widths
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
+
+        c = self.w
+        B, T, H = u.shape
+        E, W, k = c.num_experts, c.expert_width, c.experts_per_token
+        first, count = c.held
+        init = nn.initializers.normal(stddev=INIT_STD)
+        x = u.reshape(B * T, H)
+        # the router's weight is named as its stage is
+        w_router = self.param(obs_names.SCOPE_ROUTER, init, (H, E),
+                              jnp.float32)
+        up = self.param("up", init, (count, H, 2 * W), jnp.float32)
+        down = self.param("down", init, (count, W, H), jnp.float32)
+        with _scope(obs_names.SCOPE_ROUTER):
+            # float32 whatever the compute dtype, as the architecture has it
+            logits = jnp.dot(x.astype(jnp.float32), w_router,  # nidt: allow[precision-upcast] -- the router is float32 by the architecture's definition
+                             precision=jax.lax.Precision.HIGHEST)
+            # e_score_correction_bias: a buffer the published recipe
+            # moves outside the gradient; zeros, so no bias is handed on
+            scores, weights, experts = moe.route(
+                logits, k, scoring="sigmoid", scale=c.routed_scaling_factor)
+            balance = jnp.mean(moe.sequence_balance_loss(
+                scores.reshape(B, T, E), experts.reshape(B, T, k), E))
+        # the trainer initialises eagerly (Moonlight3D.__call__)
+        y, passed = moe.held_expert_rows(
+            x, weights, experts, up, down, E, first, swiglu(W),
+            buffer=not self.is_initializing())
+        return y.reshape(B, T, H), experts, passed, balance
+
+
+class Layer(nn.Module):
+    """One layer, attention then feed-forward: ``h -> (h, experts,
+    passed, balance)``. ``dense``: the leading layer's whole feed-forward
+    (an empty ``[0, k]`` of choices, 0 and 0.0 beside it, so that every
+    layer returns the same structure under ``nn.remat``); else the held
+    experts beside the shared ones."""
+
+    dense: bool
+    w: Widths
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        c, dtype = self.w, self.dtype
+        norm = lambda name: RMSNorm(c.rms_eps, dtype, name=name)
+        x = norm("attn_norm")(h)
+        with _scope(obs_names.SCOPE_ATTN):
+            y = LatentAttention(c, dtype, name="mla")(x)
+        h = h + y
+        u = norm("mlp_norm")(h)
+        if self.dense:
+            with _scope(obs_names.SCOPE_MLP):
+                y = gated_mlp(c.hidden_size, c.intermediate_size, dtype,
+                              "ffn")(u)
+            return (h + y, jnp.zeros((0, c.experts_per_token), jnp.int32),
+                    jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32))
+        y, experts, passed, balance = HeldGatedExperts(c, dtype,
+                                                       name="moe")(u)
+        with _scope(obs_names.SCOPE_SHARED_EXPERT):
+            y = y + gated_mlp(c.hidden_size,
+                              c.shared_experts * c.expert_width, dtype,
+                              "shared")(u)
+        return h + y, experts, passed, balance
+
+
+class Moonlight3D(nn.Module):
+    """The trunk over 3D patch tokens: ``widths.dense_layers`` dense
+    layers, then ``widths.expert_layers`` expert layers."""
+
+    num_classes: int = 1
+    dtype: Dtype = jnp.float32
+    widths: Widths = Widths()
+    remat_layers: bool = True
+
+    input_rank = 5  # [B, D, H, W, C]
+    returns_aux = True  # (logits, {"loss", *aux_counters})
+    #: the integer entries of the auxiliary dict, summed over a round's
+    #: real steps into round outputs of these names (core/trainer.py)
+    aux_counters = ("expert_tokens", "held_overflow_calls")
+
+    @property
+    def held_experts(self) -> tuple[int, int]:
+        """``(first, count)`` of the experts whose rows are computed
+        here: the round driver counts ``rows_held`` over them."""
+        return self.widths.held
+
+    def row_tokens(self, row_shape) -> int:
+        """Tokens of one volume ``[D, H, W, ...]``: what a row of an
+        evaluation batch costs (core/trainer.py ``eval_batch_rows``)."""
+        return tokens3d.token_count((1, *row_shape), self.widths.patch)
+
+    def held_capacity_rows(self, batch_shape) -> int | None:
+        """The rows of the held runs' buffer for a batch ``[B, D, H, W,
+        ...]`` of volumes (ops/moe.py ``held_capacity``), ``None`` where
+        such a batch is computed by the full sort alone."""
+        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
+
+        c = self.widths
+        return moe.held_capacity(
+            c.experts_per_token * tokens3d.token_count(batch_shape, c.patch),
+            c.held[1], c.num_experts)
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        c = self.widths
+        init = nn.initializers.normal(stddev=INIT_STD)
+        h = tokens3d.patch_embed(x, c.hidden_size, c.patch, c.rms_eps,
+                                 self.dtype, init)
+        # not while initialising: the trainer initialises eagerly, and a
+        # rematerialised layer run eagerly compiles its body anew on
+        # every call (models/nemotronh3d.py); the parameter tree is the
+        # same
+        remat = self.remat_layers and not self.is_initializing()
+        layer = nn.remat(Layer) if remat else Layer
+        chosen, passed, balance = [], [], []
+        for i in range(c.dense_layers + c.expert_layers):
+            h, experts, over, aux = layer(i < c.dense_layers, c, self.dtype,
+                                          name=f"layers_{i}")(h)
+            chosen.append(experts)
+            passed.append(over)
+            balance.append(aux)
+        logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps, init)
+        with _scope(obs_names.SCOPE_ROUTER):
+            aux = {
+                "loss": c.aux_alpha * sum(balance),
+                "expert_tokens": jnp.bincount(
+                    jnp.concatenate(chosen).reshape(-1),
+                    length=c.num_experts).astype(jnp.int32),
+                "held_overflow_calls": sum(passed),
+            }
+        return logits, aux

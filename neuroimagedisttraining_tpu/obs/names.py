@@ -278,13 +278,22 @@ SCOPE_EVA_LOCAL = "eva_local"    # scores, exp and values inside a window; 1 / Z
 SCOPE_EVA_REMOTE = "eva_remote"  # the same against earlier windows' summaries
 SCOPE_MLP = "mlp"                # gate, up, SiLU, down
 
-#: the model scopes of the four tables above (disjoint from DEVICE_SCOPES)
+# latent attention of the reconstructing kind (models/moonlight3d.py, PR
+# 40; benchmark/metrics/moonlight_scopes.json). Both lie inside SCOPE_ATTN,
+# which keeps W_q, its rotary embedding and W_o. The leading dense layer's
+# feed-forward reuses SCOPE_MLP, the two shared experts
+# SCOPE_SHARED_EXPERT, the held experts the four expert scopes above.
+SCOPE_MLA_LATENT = "mla_latent"  # W_dkv, the latent's norm, the shared key's rotary, W_ukv
+SCOPE_MLA_CORE = "mla_core"      # scores, softmax and values of every query block
+
+#: the model scopes of the five tables above (disjoint from DEVICE_SCOPES)
 MODEL_SCOPES: frozenset[str] = frozenset(
     (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
      SCOPE_COMBINE, SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSD,
      SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT,
      SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX,
-     SCOPE_EVA_POOL, SCOPE_EVA_LOCAL, SCOPE_EVA_REMOTE, SCOPE_MLP))
+     SCOPE_EVA_POOL, SCOPE_EVA_LOCAL, SCOPE_EVA_REMOTE, SCOPE_MLP,
+     SCOPE_MLA_LATENT, SCOPE_MLA_CORE))
 
 # A layout marker, not a stage: ops/stemconv.py's stem block names the ops
 # of its batched rule (the client-merged lanes a client-axis ``vmap``

@@ -514,3 +514,24 @@ def load_balancing_loss(probs: jax.Array, experts: jax.Array,
     counts = jnp.bincount(experts.reshape(-1), length=num_experts)
     f = counts.astype(jnp.float32) / T  # nidt: allow[precision-upcast] -- an auxiliary loss term: float32 like every loss
     return num_experts * jnp.sum(f * jnp.mean(probs, axis=0))
+
+
+def sequence_balance_loss(scores: jax.Array, experts: jax.Array,
+                          num_experts: int) -> jax.Array:
+    """The sequence-wise balance loss of the DeepSeek-V3 line
+    (``seq_aux``): ``scores [B, T, E]`` (the router's sigmoid scores,
+    float32), ``experts [B, T, k]`` -> ``[B]``, for each sequence
+
+        f_e = E / (k T) #{t : e in C_t}      P_e = mean_t (s_e,t / sum_j s_j,t)
+        L   = sum_e f_e P_e
+
+    (1 at uniform routing). Beside :func:`load_balancing_loss`, which is
+    one term over every row of the batch and over softmax probabilities.
+    ``f`` is a count: the gradient reaches the router through ``P`` alone,
+    never through the choice or a selection bias. Unweighted: the model
+    multiplies by its coefficient and takes the mean over sequences."""
+    T, k = experts.shape[1], experts.shape[2]
+    chosen = jax.nn.one_hot(experts, num_experts, dtype=jnp.float32)
+    f = jnp.sum(chosen, axis=(1, 2)) * (num_experts / (k * T))
+    norm = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    return jnp.sum(f * jnp.mean(norm, axis=1), axis=-1)

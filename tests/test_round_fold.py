@@ -129,13 +129,33 @@ def _small_evabyte3d(name, num_classes=1):
         intermediate_size=48, window_size=16, chunk_size=4, patch=4))
 
 
-@pytest.mark.parametrize("model", ["3dcnn_tiny", "evabyte3d_small"])
+def _small_moonlight3d(name, num_classes=1):
+    """Moonlight's layer at a small size (models/moonlight3d.py): 12 x 14
+    x 12 volumes in patches of 4 are 36 tokens, two query blocks of 16 and
+    one of 4; a dense layer, then two expert layers that hold 4 of 16
+    experts (the stacked round runs their held rows under ``vmap``)."""
+    from neuroimagedisttraining_tpu.models.moonlight3d import (
+        Moonlight3D, Widths,
+    )
+
+    return Moonlight3D(num_classes=num_classes, widths=Widths(
+        dense_layers=1, expert_layers=2, hidden_size=32, heads=2,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        kv_lora_rank=16, intermediate_size=48, num_experts=16, held=(0, 4),
+        experts_per_token=4, expert_width=16, block=16, patch=4))
+
+
+SMALL_MODELS = {"evabyte3d_small": _small_evabyte3d,
+                "moonlight3d_small": _small_moonlight3d}
+
+
+@pytest.mark.parametrize("model", ["3dcnn_tiny", *sorted(SMALL_MODELS)])
 def test_folded_round_equals_stacked_round(tmp_path, cohort3, monkeypatch,
                                            model):
     """New global parameters and batch statistics equal to float32
     summation order; the round's loss and n_bad equal."""
-    if model == "evabyte3d_small":
-        monkeypatch.setitem(globals(), "create_model", _small_evabyte3d)
+    if model in SMALL_MODELS:
+        monkeypatch.setitem(globals(), "create_model", SMALL_MODELS[model])
     st = _one_round(_engine(tmp_path, cohort3, budget=1 << 40, tag="s",
                             model=model))
     fo = _one_round(_engine(tmp_path, cohort3, budget=1, tag="f",

@@ -323,22 +323,24 @@ def test_names_table_is_complete():
 
 def test_model_scopes_table():
     """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, of the
-    hybrid trunk, PR 29, of compressed convolutional attention, PR 31, and
-    of EVA attention with its dense feed-forward, PR 38)
+    hybrid trunk, PR 29, of compressed convolutional attention, PR 31, of
+    EVA attention with its dense feed-forward, PR 38, and of latent
+    attention of the reconstructing kind, PR 40)
     is a second table, disjoint from DEVICE_SCOPES (which
     benchmark/scopes.json pins); every constant is used at least once in
-    models/, none is spelled as a literal there, and the benchmark's four
+    models/, none is spelled as a literal there, and the benchmark's five
     rules files name each between them: the OLMoE block's five in
     olmoe_scopes.json, the hybrid trunk's eleven in nemotronh_scopes.json,
     ZAYA1's layer's eight (three of them new) in zaya_scopes.json,
-    EvaByte's layer's five (four of them new) in evabyte_scopes.json."""
+    EvaByte's layer's five (four of them new) in evabyte_scopes.json,
+    Moonlight's layer's nine (two of them new) in moonlight_scopes.json."""
     import glob
     import json
     import os
 
     consts = {k: v for k, v in vars(names).items()
               if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
-    assert len(consts) == len(names.MODEL_SCOPES) == 18
+    assert len(consts) == len(names.MODEL_SCOPES) == 20
     assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sources = {p: open(p).read() for p in glob.glob(os.path.join(
@@ -351,7 +353,8 @@ def test_model_scopes_table():
     for file, partition in (("olmoe_scopes.json", "block"),
                             ("nemotronh_scopes.json", "trunk"),
                             ("zaya_scopes.json", "layer"),
-                            ("evabyte_scopes.json", "layer")):
+                            ("evabyte_scopes.json", "layer"),
+                            ("moonlight_scopes.json", "layer")):
         rules = json.load(open(os.path.join(
             root, "benchmark", "metrics", file)))
         named[file] = {s for k, v in rules["scope_names"].items()
@@ -367,6 +370,9 @@ def test_model_scopes_table():
     eva = {names.SCOPE_EVA_POOL, names.SCOPE_EVA_LOCAL,
            names.SCOPE_EVA_REMOTE, names.SCOPE_MLP}
     assert named["evabyte_scopes.json"] == eva | {names.SCOPE_ATTN}
+    mla = {names.SCOPE_MLA_LATENT, names.SCOPE_MLA_CORE}
+    assert named["moonlight_scopes.json"] == expert_layer | mla | {
+        names.SCOPE_ATTN, names.SCOPE_MLP, names.SCOPE_SHARED_EXPERT}
     assert named["nemotronh_scopes.json"] == \
-        set(names.MODEL_SCOPES) - cca - eva
+        set(names.MODEL_SCOPES) - cca - eva - mla
     assert set().union(*named.values()) == set(names.MODEL_SCOPES)

@@ -506,6 +506,54 @@ def test_evabyte3d_step_keeps_scores_and_softmax_float32(chip, monkeypatch):
     assert products and {f[0] for f in products} == {"f32"}
 
 
+def test_moonlight3d_training_step_fits_at_the_published_widths(chip,
+                                                                monkeypatch):
+    """``--model moonlight3d``'s step at 586 M parameters and the cell's
+    batch of 2 x 4,864 tokens: the held runs' buffer of 14,848 rows in
+    every expert layer (forward, rematerialised forward and backward: 8
+    kernels a layer), the scores a block of 512 queries at a time against
+    the keys up to the block's end (``[2, 16, 512, 4608]`` the widest whole
+    block, ``[2, 16, 256, 4864]`` the last), never ``[4864, 4864]``, and
+    code + temporaries that leave room for the folded round's 10.9 GiB of
+    state (PERF.md, PR 40: 334.8 MiB and 1.792 GiB)."""
+    compiled = _compiled_step(chip, monkeypatch, "moonlight3d", batch=2)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count(KERNEL_MARK) == 5 * 8
+    assert "bf16[14848,2048]" in text and "bf16[14848,2816]" in text
+    assert "f32[2,16,512,4608]" in text and "f32[2,16,256,4864]" in text
+    assert "[2,16,4864,4864]" not in text
+    assert mem.temp_size_in_bytes < 1.9 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 350 * 2 ** 20
+
+
+def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
+        chip, monkeypatch):
+    """The configuration states float32 scores, softmax and router under
+    ``bf16_mixed``: the program the cell times says so itself. Every
+    exponential over a block of scores (``[2, 16, queries, keys]``) is
+    float32, forward, rematerialised and backward, and every block has a
+    float32 product (bfloat16 appears there as the probabilities cast to
+    meet the values, and as their cotangent); nothing over the router's
+    ``[9728, 64]`` scores is bfloat16, and its product is float32."""
+    import re
+
+    text = _compiled_step(chip, monkeypatch, "moonlight3d",
+                          batch=2).as_text()
+    block = re.compile(r" = (\w+)\[2,16,(512|256),(\d+)\]\S* "
+                       r"(exponential|convolution|dot)\(")
+    found = [m.groups() for m in map(block.search, text.splitlines()) if m]
+    keys = {str(n) for n in (*range(512, 4864, 512), 4864)}
+    exps = [f for f in found if f[3] == "exponential"]
+    assert {f[2] for f in exps} == keys  # every block of the ten
+    assert {f[0] for f in exps} == {"f32"}
+    scores = {f[2] for f in found if f[3] != "exponential"
+              and f[0] == "f32"}
+    assert scores >= keys
+    assert "bf16[9728,64]" not in text
+    assert re.search(r" = f32\[9728,64\]\S* convolution\(", text)
+    assert re.search(r" = f32\[9728,64\]\S* exponential\(", text)
+
+
 def test_resnet3d_vmapped_step_stays_client_merged(chip, monkeypatch):
     """``resnet3d.fedavg_resident``'s training step: 2 clients of batch 16
     under ``vmap``. The first stage stays in the grouped convolution's
