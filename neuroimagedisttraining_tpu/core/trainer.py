@@ -42,8 +42,8 @@ from neuroimagedisttraining_tpu.models import aux_outputs, primary_logits
 from neuroimagedisttraining_tpu.obs import names as obs_names
 
 PyTree = Any
-#: an evaluation batch is the largest number of rows whose tokens fit the
-#: budget (32 rows of 640 tokens, the batch every trunk before PR 38
+#: an evaluation batch holds at most as many rows as have their tokens fit
+#: the budget (32 rows of 640 tokens, the batch every trunk before PR 38
 #: evaluated at), and never more than the 32 rows a CNN evaluates at
 EVAL_TOKEN_BUDGET = 20480
 EVAL_BATCH_MAX = 32
@@ -506,27 +506,42 @@ class LocalTrainer:
     # ---------- evaluation ----------
 
     def eval_batch_rows(self, row_shape) -> int:
-        """Rows of an evaluation batch for rows of ``row_shape`` (``[D,
-        H, W, ...]``): as many as fit the token budget the trunks
-        evaluate at, never more than ``EVAL_BATCH_MAX``. A model whose
-        rows cost more than the budget's 32nd part says what a row costs
-        (``row_tokens``: models/evabyte3d.py); any other (a CNN, a
-        640-token trunk) gets ``EVAL_BATCH_MAX``."""
+        """The most rows an evaluation batch may hold for rows of
+        ``row_shape`` (``[D, H, W, ...]``): as many as fit the token
+        budget the trunks evaluate at, never more than
+        ``EVAL_BATCH_MAX``. A model whose rows cost more than the
+        budget's 32nd part says what a row costs (``row_tokens``:
+        models/evabyte3d.py); any other (a CNN, a 640-token trunk) gets
+        ``EVAL_BATCH_MAX``."""
         row_tokens = getattr(self.model, "row_tokens", None)
         tokens = row_tokens(row_shape) if row_tokens is not None else 1
         return min(EVAL_BATCH_MAX, max(1, EVAL_TOKEN_BUDGET // tokens))
 
+    def eval_batches(self, row_shape, rows: int) -> tuple[int, int]:
+        """``(batches, batch)`` that :meth:`evaluate` walks for ``rows``
+        rows of ``row_shape``: the fewest batches :meth:`eval_batch_rows`
+        allows, at the one width that tiles the rows with the least
+        filler (2 rows under a cap of 4 run as 1 x 2, 44 under 32 as
+        2 x 22: at most ``batches - 1`` filler rows, none where the rows
+        tile the cap). The engines' ``rows_run`` counter is computed from
+        it too (engines/base.py ``_eval_span_args``)."""
+        batches = max(1, -(-rows // self.eval_batch_rows(row_shape)))
+        return batches, max(1, -(-rows // batches))
+
     @jax.named_scope(obs_names.SCOPE_EVAL)
     def evaluate(self, params, batch_stats, X, y, valid,
                  batch_size: int | None = None):
-        """Chunked full-set eval in batches of ``batch_size`` rows
-        (:meth:`eval_batch_rows` where none is given). Returns dict with
+        """Chunked full-set eval: ``batch_size`` rows a batch where one
+        is given, else the balanced width of :meth:`eval_batches` for the
+        static row count of ``X``. Filler rows are zero volumes that
+        ``valid`` masks out of the sums. Returns dict with
         ``test_correct``, ``test_loss`` (sum), ``test_total`` and raw
         ``scores`` for AUC."""
-        if batch_size is None:
-            batch_size = self.eval_batch_rows(X.shape[1:])
         n = X.shape[0]
-        nb = max(1, math.ceil(n / batch_size))
+        if batch_size is None:
+            nb, batch_size = self.eval_batches(X.shape[1:], n)
+        else:
+            nb = max(1, math.ceil(n / batch_size))
         pad = nb * batch_size - n
         Xp = jnp.pad(X, [(0, pad)] + [(0, 0)] * (X.ndim - 1))
         yp = jnp.pad(y, (0, pad))

@@ -812,19 +812,19 @@ class FederatedEngine:
         ``split``; by default the first ``X.shape[0]``), as arguments of
         its ``eval_dispatch`` span (obs/names.py ``ARGS_BY_SPAN``), and
         how many sample rows its loops compute (``rows_run``: every
-        client's rows padded up to whole batches of
-        ``LocalTrainer.eval_batch_rows``) for the ``rows_real`` there
-        are. Host integers, no device read; a no-op while the tracer is
-        disarmed."""
+        client's rows as the batches ``LocalTrainer.eval_batches`` gives
+        ``evaluate`` for them, the one rule both read) for the
+        ``rows_real`` there are. Host integers, no device read; a no-op
+        while the tracer is disarmed."""
         if not obs_trace.TRACER.armed:
             return {}
         rows = X.shape[0]
         placement, rows_a_chip = self._rows_placement(rows)
-        batch = self.trainer.eval_batch_rows(X.shape[2:])
+        batches, batch = self.trainer.eval_batches(X.shape[2:], X.shape[1])
         n = self._n_host[split]
         return {"placement": placement, "rows": rows,
                 "rows_a_chip": rows_a_chip,
-                "rows_run": rows * max(1, -(-X.shape[1] // batch)) * batch,
+                "rows_run": rows * batches * batch,
                 "rows_real": int(n[:rows].sum() if ids is None
                                  else n[ids].sum())}
 

@@ -284,7 +284,8 @@ class Moonlight3D(nn.Module):
 
     def row_tokens(self, row_shape) -> int:
         """Tokens of one volume ``[D, H, W, ...]``: what a row of an
-        evaluation batch costs (core/trainer.py ``eval_batch_rows``)."""
+        evaluation batch costs (core/trainer.py ``eval_batch_rows``, the
+        cap under which ``eval_batches`` balances a client's rows)."""
         return tokens3d.token_count((1, *row_shape), self.widths.patch)
 
     def held_capacity_rows(self, batch_shape) -> int | None:

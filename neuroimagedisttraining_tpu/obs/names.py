@@ -179,8 +179,8 @@ ROUND_CHILD_SPANS: tuple[str, ...] = (
 #: it holds) / ``folded`` (one row after another), ``rows`` handed to the
 #: program, and ``rows_a_chip``, what one chip's loop walks when sharded
 #: (every row otherwise); ``rows_run``, the SAMPLE rows its loops compute
-#: (each client's rows padded to whole evaluation batches,
-#: core/trainer.py ``eval_batch_rows``) for the ``rows_real`` samples there
+#: (each client's rows as the balanced batches under the cap,
+#: core/trainer.py ``eval_batches``) for the ``rows_real`` samples there
 #: are. The ``jax_*`` spans: ``program`` is JAX's
 #: ``fun_name`` (the traced function; ``jit(<name>)`` from lowering on),
 #: ``cache`` the persistent cache's answer where it gave one.

@@ -512,6 +512,71 @@ def test_ci_single_row_evaluates_stacked(tmp_path, synthetic_cohort8):
     assert m_full["loss"] != m_ci["loss"]
 
 
+def _subjaxprs(jaxpr):
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (tuple, list)) else (v,)):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    yield eqn, j
+
+
+def _evaluation_loops(jaxpr, depth=0):
+    """``(depth, length, batch width)`` of every ``scan`` in a traced
+    program that stacks an output a row: the width is the minor extent
+    of its output of the highest rank (``evaluate``'s ``scores``, ``[nb,
+    batch]`` with the client axis between them under ``vmap``)."""
+    found = []
+    for eqn, sub in _subjaxprs(jaxpr):
+        found += _evaluation_loops(sub, depth + 1)
+        if eqn.primitive.name == "scan":
+            widest = max((v.aval for v in eqn.outvars),
+                         key=lambda a: a.ndim)
+            if widest.ndim >= 2:
+                found.append((depth, eqn.params["length"],
+                              widest.shape[-1]))
+    return found
+
+
+@pytest.mark.parametrize("placement", ["stacked", "folded", "sharded"])
+def test_rows_run_counts_the_program_that_is_traced(tmp_path, placement):
+    """``rows_run`` on the ``eval_dispatch`` span and the evaluation
+    program come from one rule (core/trainer.py ``eval_batches``): under
+    each placement the counter is the client rows x the length x the
+    width of the innermost loop that ``eval_global`` traces, with sites
+    of 44, 8, 4 and 2 test rows stacked to 44 (2 batches of 22)."""
+    sizes = (220, 40, 20, 10)
+    data = generate_synthetic_abcd(num_subjects=sum(sizes),
+                                   shape=(12, 14, 12), num_sites=4, seed=3)
+    data["site"] = np.repeat(np.arange(4), sizes).astype(np.int16)
+    if placement == "sharded":
+        eng = _engine(tmp_path, data, C=4, n_dev=4, client_mesh=4, tag="rr")
+    else:
+        eng = _engine(tmp_path, data, C=4, mesh=False, client_mesh=0,
+                      tag="rr")
+        if placement == "folded":
+            eng._fold_budget_bytes = 1
+    assert eng.program.placement == placement
+    d = eng.data
+    assert np.asarray(d.n_test).tolist() == [44, 8, 4, 2]
+    rows, width = d.X_test.shape[:2]
+    assert (rows, width) == (4, 44)
+    obs_trace.arm()
+    try:
+        args = eng._eval_span_args(d.X_test, "test")
+    finally:
+        obs_trace.disarm()
+    assert args["placement"] == placement
+    assert args["rows_real"] == 58
+    gs = eng.init_global_state()
+    jaxpr = jax.make_jaxpr(eng._eval_global_jit)(
+        gs.params, gs.batch_stats, d.X_test, d.y_test, d.n_test)
+    ((_, length, batch),) = _evaluation_loops(jaxpr.jaxpr)
+    assert (length, batch) == eng.trainer.eval_batches(
+        d.X_test.shape[2:], width) == (2, 22)
+    assert args["rows_run"] == rows * length * batch == 176
+
+
 def _eval_all_as_it_was(eng, which, folded):
     """The evaluation program of the two placements this PR leaves
     alone, written out as the parent had it: ``vmap`` over the rows, or

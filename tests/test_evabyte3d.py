@@ -282,14 +282,18 @@ def test_the_four_shares_add_up_to_the_uncut_layer(ref, seed):
 
 # ---------- (e) evaluation's batch follows what a row costs ----------
 
-class _Costly(nn.Module):
-    """A model that declares a row a quarter of the evaluation budget."""
+class _Plain(nn.Module):
+    """A model that says nothing of what a row costs: the cap is 32."""
 
     input_rank = 5
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         return nn.Dense(1)(x.reshape(x.shape[0], -1))
+
+
+class _Costly(_Plain):
+    """A model that declares a row a quarter of the evaluation budget."""
 
     def row_tokens(self, row_shape):
         return 5120
@@ -298,6 +302,14 @@ class _Costly(nn.Module):
 def _scan_lengths(jaxpr):
     return [e.params["length"] for e in jaxpr.eqns
             if e.primitive.name == "scan"]
+
+
+def _scan_batches(jaxpr):
+    """``(length, batch width)`` of each scan: the width is the minor
+    extent of the one output a row, the stacked ``scores``."""
+    return [(e.params["length"], v.aval.shape[-1])
+            for e in jaxpr.eqns if e.primitive.name == "scan"
+            for v in e.outvars if v.aval.ndim == 2]
 
 
 def test_evaluation_batches_follow_the_rows_cost():
@@ -319,6 +331,48 @@ def test_evaluation_batches_follow_the_rows_cost():
     got, want = run(), run(batch_size=32)
     assert got["scores"].shape == want["scores"].shape == (6,)
     assert float(got["test_total"]) == float(want["test_total"]) == 5.0
+    for k in ("test_correct", "test_loss", "scores"):
+        _close(got[k], want[k], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows,cap,batches,batch", [
+    (2, 4, 1, 2),      # evabyte's and moonlight's cells: a site's 2 rows
+    (16, 32, 1, 16),   # nemotronh's and zaya's
+    (24, 32, 1, 24),   # olmoe's and the one-chip CNN cells'
+    (44, 32, 2, 22),   # the mesh cell's largest site
+    (6, 4, 2, 3),
+    (1, 32, 1, 1),
+    (32, 32, 1, 32),   # rows that tile the cap: the program of before
+    (64, 32, 2, 32),
+    (8, 4, 2, 4),
+])
+def test_evaluation_batches_follow_the_rows_there_are(rows, cap, batches,
+                                                      batch):
+    """Where no ``batch_size`` is given ``evaluate`` walks the fewest
+    batches the cap allows at the one width that tiles the static row
+    count with the least filler (``eval_batches``); the sums and scores
+    are those of a run at 32 rows a batch, under a ``valid`` mask that
+    cuts inside the last batch."""
+    tr = LocalTrainer({4: _Costly, 32: _Plain}[cap](), OptimConfig(), 1)
+    r = np.random.RandomState(rows)
+    X = jnp.asarray(r.randint(0, 256, (rows, 4, 4, 4)).astype(np.uint8))
+    y = jnp.asarray(r.randint(0, 2, (rows,)).astype(np.int32))
+    real = max(1, rows - 1)
+    valid = jnp.arange(rows) < real
+    cs = tr.init_client_state(jax.random.key(0),
+                              jnp.zeros((1, 4, 4, 4), jnp.float32))
+    assert tr.eval_batch_rows(X.shape[1:]) == cap
+    assert tr.eval_batches(X.shape[1:], rows) == (batches, batch)
+    run = lambda **kw: tr.evaluate(cs.params, cs.batch_stats, X, y, valid,
+                                   **kw)
+    jaxpr = jax.make_jaxpr(run)()
+    assert _scan_batches(jaxpr.jaxpr) == [(batches, batch)]
+    if batch == cap:
+        assert str(jaxpr) == str(jax.make_jaxpr(
+            lambda: run(batch_size=cap))())
+    got, want = run(), run(batch_size=32)
+    assert got["scores"].shape == want["scores"].shape == (rows,)
+    assert float(got["test_total"]) == float(want["test_total"]) == real
     for k in ("test_correct", "test_loss", "scores"):
         _close(got[k], want[k], rtol=1e-6, atol=1e-6)
 
@@ -391,7 +445,8 @@ def test_a_second_eager_initialisation_compiles_nothing():
 def test_a_folded_job_puts_its_counters_on_the_spans(tmp_path):
     """The small model through FedAvg's folded round, tracer armed:
     ``eval_dispatch`` carries the sample rows its loops compute for the
-    real ones: 3 test rows a site of 36 tokens run as one batch of 32."""
+    real ones: the test rows a site of 36 tokens run as one batch of
+    as many rows as the larger site has."""
     from neuroimagedisttraining_tpu.config import (
         DataConfig, ExperimentConfig, FedConfig,
     )
@@ -439,4 +494,4 @@ def test_a_folded_job_puts_its_counters_on_the_spans(tmp_path):
     for e in events:
         if e["name"] == obs_names.SPAN_EVAL_DISPATCH:
             assert e["args"]["rows_real"] == int(n_test.sum())
-            assert e["args"]["rows_run"] == 2 * 32
+            assert e["args"]["rows_run"] == 2 * rows

@@ -152,7 +152,9 @@ def test_a_round_whose_rows_pass_the_buffer_is_counted_and_exact(
 
 def test_evaluation_filler_that_passes_the_buffer_drops_no_row(
         ref, monkeypatch):
-    """``LocalTrainer.evaluate`` pads a batch to 32 with ZERO volumes,
+    """``LocalTrainer.evaluate`` fills its last batch with ZERO volumes
+    (here 27 of the 32 rows asked for; left to itself it leaves at most
+    one fewer than its batches, core/trainer.py ``eval_batches``),
     whose tokens are all alike and take the same experts; the share held
     here is made to include the one they take first, so the filler alone
     passes the buffer (27 volumes x 8 tokens against 96 rows). The five
@@ -175,7 +177,7 @@ def test_evaluation_filler_that_passes_the_buffer_drops_no_row(
     out = tr.model.apply({"params": cs.params}, tr._prep(padded))
     assert int(out[1]["held_overflow_calls"]) > 0
     evaluate = lambda: jax.jit(lambda p, x, y: tr.evaluate(
-        p, {}, x, y, jnp.ones((5,))))(cs.params, x, y)
+        p, {}, x, y, jnp.ones((5,)), batch_size=32))(cs.params, x, y)
     with jax.default_matmul_precision("highest"):
         got = evaluate()
         want, _ = ref.trunk(cs.params, x, cfg={**CFG, "held": held})

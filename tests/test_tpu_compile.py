@@ -246,11 +246,16 @@ def test_held_grouped_matmul_compiles_at_the_published_widths(
     assert text.count(KERNEL_MARK) == 5
 
 
+@pytest.mark.parametrize("train", [True, False],
+                         ids=["step_b16", "evaluation_b16"])
 def test_held_runs_buffer_compiles_at_the_published_widths(chip,
-                                                           monkeypatch):
+                                                           monkeypatch,
+                                                           train):
     """``ops/moe.py`` ``held_expert_rows`` for Nemotron-H's training step
     (10,240 tokens x 6 slots, 8 of 128 experts held, 2688 x 1856, bf16
-    beside float32 master weights), value and every gradient: a buffer of
+    beside float32 master weights), value and every gradient, and for a
+    site's 16 test rows, which evaluation runs as one batch of those
+    61,440 slots since PR 41, value alone (one loop, 2 kernels): a buffer of
     7,680 rows, which ``gmm_tiling`` gives the widest row tile; one loop
     over windows forward and one backward, ``megablox.gmm`` with no
     ``group_offset`` over ``[7680, .]`` in their bodies and nowhere else
@@ -276,8 +281,9 @@ def test_held_runs_buffer_compiles_at_the_published_widths(chip,
         def loss(x, weights, up, down, experts):
             y = rows(x, weights, experts, up, down, E, 0, relu2)
             return jnp.sum(jnp.sin(y.astype(jnp.float32)))
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))) \
-            .lower(*operands, experts).compile()
+        if train:
+            loss = jax.value_and_grad(loss, argnums=(0, 1, 2, 3))
+        return jax.jit(loss).lower(*operands, experts).compile()
 
     windows = compiled(lambda *a: moe.held_expert_rows(*a)[0])
     full = compiled(moe._full_sort_rows)
@@ -285,7 +291,7 @@ def test_held_runs_buffer_compiles_at_the_published_widths(chip,
     assert " conditional(" not in text
     # a window forward: 2 kernels; backward: the 2 again and gmm / tgmm
     # for both matrices
-    assert text.count(KERNEL_MARK) == 2 + 6
+    assert text.count(KERNEL_MARK) == (2 + 6 if train else 2)
     assert f"bf16[{capacity},2688]" in text
     assert f"[{T * k},2688]" not in text
     assert f"[{T * k},2688]" in full.as_text()
@@ -294,7 +300,8 @@ def test_held_runs_buffer_compiles_at_the_published_widths(chip,
 
 
 @pytest.mark.parametrize("rows,count,experts,buffered", [
-    (61440, 8, 128, True), (122880, 8, 128, True),  # training, evaluation
+    # a training step and a site's 16 test rows; 32 test rows
+    (61440, 8, 128, True), (122880, 8, 128, True),
     (81920, 64, 64, False),   # OLMoE: every expert held
     (3840, 64, 128, False),   # a buffer as long as the sort
     (256, 8, 128, False)], ids=["train", "eval", "all_held", "half_held",
@@ -327,15 +334,18 @@ def test_which_paths_a_held_layer_traces_follows_shapes(monkeypatch, rows,
         moe.held_capacity(rows, count, experts) is not None)
 
 
-@pytest.mark.parametrize("batch,train", [(16, True), (32, False)],
-                         ids=["step_b16", "evaluation_b32"])
+@pytest.mark.parametrize("batch,train",
+                         [(16, True), (32, False), (16, False)],
+                         ids=["step_b16", "evaluation_b32",
+                              "evaluation_b16"])
 def test_ssd_kernels_compile_at_the_published_widths(chip, monkeypatch,
                                                      batch, train):
     """``ops/ssd.py`` ``ssd_chunked`` on a TPU at Nemotron-H's mixer (640
     tokens in chunks of 128, 8 groups of 8 heads of 64, state 128, bf16
     beside float32 ``dt``): a training step's batch with every gradient
     (the forward kernel that also writes the chunks' start states, and the
-    backward kernel), evaluation's batch forward. No ``[b, 5, 128, 128,
+    backward kernel), evaluation's batch forward at the cap's 32 rows and
+    at the cell's 16 test rows a site. No ``[b, 5, 128, 128,
     ...]`` array: the decay tiles never leave the kernels."""
     from neuroimagedisttraining_tpu.ops import ssd
 
