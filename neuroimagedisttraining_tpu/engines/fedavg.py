@@ -38,11 +38,14 @@ EXPERT_TOKENS = "expert_tokens"
 #: Nemotron-H's second: the expert-layer calls of those steps whose held
 #: rows passed the buffer (ops/moe.py ``held_expert_rows``)
 HELD_OVERFLOW_CALLS = "held_overflow_calls"
+#: Moonlight's third: the attention calls of those steps that ran as the
+#: kernel (ops/attention.py ``causal_attention``; 0 on its XLA form)
+ATTN_KERNEL_CALLS = "attn_kernel_calls"
 
 
 def expert_load(expert_tokens, held: tuple[int, int] | None = None,
                 overflow_calls=None, capacity: int | None = None,
-                skip: int | None = None) -> dict:
+                skip: int | None = None, kernel_calls=None) -> dict:
     """The round's expert-load counters from the round program's
     ``expert_tokens`` output, as host numbers for the ``round_log``
     span: slots routed, and the busiest and the idlest expert's load
@@ -57,7 +60,10 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None,
     buffer; 0 where a step has none). For a router one of whose outputs
     is no expert (``skip``: its index; models/zaya3d.py) ``rows_skipped``,
     the tokens sent there, which count as routed and are left out of the
-    experts' load."""
+    experts' load. For a model whose attention is a kernel where the
+    platform and the shapes allow (``kernel_calls``: its third output;
+    models/moonlight3d.py) ``attn_kernel_calls``, the calls that took
+    it."""
     tokens = np.asarray(expert_tokens, np.float64)
     out = {"tokens_routed": int(tokens.sum())}
     if skip is not None:
@@ -74,6 +80,8 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None,
     if overflow_calls is not None:
         out[HELD_OVERFLOW_CALLS] = int(overflow_calls)
         out["held_capacity_rows"] = capacity or 0
+    if kernel_calls is not None:
+        out[ATTN_KERNEL_CALLS] = int(kernel_calls)
     return out
 
 
@@ -173,7 +181,8 @@ class FedAvgEngine(FederatedEngine):
             named[EXPERT_TOKENS],
             getattr(self.trainer.model, "held_experts", None),
             named.get(HELD_OVERFLOW_CALLS), self._held_capacity_rows,
-            getattr(self.trainer.model, "skip_output", None))
+            getattr(self.trainer.model, "skip_output", None),
+            named.get(ATTN_KERNEL_CALLS))
 
     # ---------- legacy-signature program adapters ----------
     # The builder's compiled programs take structured (carry, data,

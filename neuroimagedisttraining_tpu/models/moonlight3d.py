@@ -38,13 +38,19 @@ kept in it, as models/zaya3d.py's are), the rotary key bypasses the
 latent and is shared by all heads, and a score is 192 wide where its
 value is 128.
 
-**How the attention is computed** (``tokens3d.blocked_causal_attention``):
-``kn`` and ``v`` are rebuilt once a layer, the shared rotary key is
-repeated beside each head's ``kn`` (20 MB), and the scores are taken a
-block of ``block`` queries at a time against the keys up to the block's
-end: exact and causal over the whole sequence, no ``[T, T]`` scores of
-all heads, no pair above the diagonal's blocks. A block's probabilities
-are not kept: it is rematerialised in the backward pass.
+**How the attention is computed** (ops/attention.py
+``causal_attention``): ``kn`` and ``v`` are rebuilt once a layer; exact and
+causal over the whole sequence, no ``[T, T]`` scores of all heads, no pair
+above the diagonal's blocks. On a TPU, at widths its blocks tile (the
+published ones), one Pallas kernel a pass: a tile of float32 scores lives
+and dies in vector memory, every head reads the ONE rotary key from its
+``[T, dr]`` array, and the backward pass remakes the probabilities from
+the rows' log-sum-exp. Everywhere else (the CPU tests, the small widths,
+the eager initialisation) ``tokens3d.blocked_causal_attention``: the shared
+key repeated beside each head's ``kn``, the scores a block of ``block``
+queries at a time against the keys up to the block's end, each block
+rematerialised in the backward pass. ``aux["attn_kernel_calls"]`` counts
+the layers whose attention took the kernel.
 
 **The expert layer holds experts 0-7 of the 64** (``held``; ops/moe.py
 ``held_expert_rows``): eight chips share each layer by expert
@@ -68,7 +74,8 @@ The model returns ``(logits, aux)``: ``aux["loss"]`` is the weighted
 models/olmoe3d.py's term counts their tokens), ``aux["expert_tokens"]``
 the slots routed to each of the 64 experts, summed over the expert
 layers, ``aux["held_overflow_calls"]`` the layers whose held rows passed
-the buffer in this call. Every layer is rematerialised
+the buffer in this call, ``aux["attn_kernel_calls"]`` the layers whose
+attention ran as the kernel. Every layer is rematerialised
 (``remat_layers``, the model's own declaration).
 
 Device scopes (obs/names.py MODEL_SCOPES): ``attn`` (W_q, its rotary,
@@ -118,7 +125,7 @@ class Widths:
     routed_scaling_factor: float = 2.446
     aux_alpha: float = 0.001
     rope_theta: float = 5e4
-    block: int = 512  # queries a block of scores (the program's, no width)
+    block: int = 512  # queries a block of the XLA form's scores (no width)
     patch: int = 8
     rms_eps: float = 1e-5
 
@@ -137,24 +144,28 @@ def gated_mlp(hidden: int, width: int, dtype, name: str):
                          init_std=INIT_STD), dtype, name=name)
 
 
-def mla_core(qn, qr, kn, kr, v, block: int, dtype):
+def mla_core(qn, qr, kn, kr, v, block: int, dtype, kernel: bool = True):
     """Scores, softmax and values: ``qn, kn [B, T, A, dn]``, ``qr [B, T,
     A, dr]`` and the ONE rotary key a token ``kr [B, T, 1, dr]`` (both
-    after the rotary embedding), ``v [B, T, A, dv]`` -> ``[B, T, A * dv]``.
-    A head's score is ``(dn + dr)^-1/2 (qn . kn + qr . kr)``: the shared
-    key is repeated beside each head's own and the two products are one
-    contraction over ``dn + dr``."""
+    after the rotary embedding), ``v [B, T, A, dv]`` -> ``([B, T, A * dv],
+    took)``. A head's score is ``(dn + dr)^-1/2 (qn . kn + qr . kr)``.
+    ``took``: whether this call ran as the kernel (ops/attention.py: on a
+    TPU, at widths its blocks tile, unless the caller says
+    ``kernel=False``); else ``block`` queries at a time in plain XLA."""
+    from neuroimagedisttraining_tpu.ops import attention  # ops imports models
+
+    took = attention.takes_kernel(qn.shape[1], qn.shape[-1], qr.shape[-1],
+                                  v.shape[-1], kernel)
     with _scope(obs_names.SCOPE_MLA_CORE):
-        q = jnp.concatenate([qn, qr], axis=-1)
-        k = jnp.concatenate(
-            [kn, jnp.broadcast_to(kr, kn.shape[:-1] + kr.shape[-1:])],
-            axis=-1)
-        return tokens3d.blocked_causal_attention(q, k, v, block, dtype)
+        return attention.causal_attention(
+            qn, kn, v, block, dtype, q_shared=qr, k_shared=kr,
+            kernel=took), took
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention: ``x [B, T, H]`` -> ``[B, T, H]`` (the
-    equations are in the module's docstring)."""
+    """Multi-head latent attention: ``x [B, T, H]`` -> ``([B, T, H],
+    took)`` (the equations are in the module's docstring; ``took`` is
+    :func:`mla_core`'s)."""
 
     w: Widths
     dtype: Dtype = jnp.float32
@@ -178,8 +189,10 @@ class LatentAttention(nn.Module):
             up = _dense(A * (dn + dv), "kv_b_proj", self.dtype)(
                 latent).reshape(B, T, A, dn + dv)
             kn, v = up[..., :dn], up[..., dn:]
-        out = mla_core(qn, qr, kn, kr, v, c.block, self.dtype)
-        return _dense(H, "o_proj", self.dtype)(out)
+        # the trainer initialises eagerly (Moonlight3D.__call__)
+        out, took = mla_core(qn, qr, kn, kr, v, c.block, self.dtype,
+                             kernel=not self.is_initializing())
+        return _dense(H, "o_proj", self.dtype)(out), took
 
 
 class HeldGatedExperts(nn.Module):
@@ -228,10 +241,11 @@ class HeldGatedExperts(nn.Module):
 
 class Layer(nn.Module):
     """One layer, attention then feed-forward: ``h -> (h, experts,
-    passed, balance)``. ``dense``: the leading layer's whole feed-forward
-    (an empty ``[0, k]`` of choices, 0 and 0.0 beside it, so that every
-    layer returns the same structure under ``nn.remat``); else the held
-    experts beside the shared ones."""
+    passed, balance, kernels)``. ``dense``: the leading layer's whole
+    feed-forward (an empty ``[0, k]`` of choices, 0 and 0.0 beside it, so
+    that every layer returns the same structure under ``nn.remat``); else
+    the held experts beside the shared ones. ``kernels``: 1 where the
+    attention ran as the kernel."""
 
     dense: bool
     w: Widths
@@ -243,7 +257,8 @@ class Layer(nn.Module):
         norm = lambda name: RMSNorm(c.rms_eps, dtype, name=name)
         x = norm("attn_norm")(h)
         with _scope(obs_names.SCOPE_ATTN):
-            y = LatentAttention(c, dtype, name="mla")(x)
+            y, took = LatentAttention(c, dtype, name="mla")(x)
+        kernels = jnp.full((), took, jnp.int32)
         h = h + y
         u = norm("mlp_norm")(h)
         if self.dense:
@@ -251,14 +266,15 @@ class Layer(nn.Module):
                 y = gated_mlp(c.hidden_size, c.intermediate_size, dtype,
                               "ffn")(u)
             return (h + y, jnp.zeros((0, c.experts_per_token), jnp.int32),
-                    jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32))
+                    jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
+                    kernels)
         y, experts, passed, balance = HeldGatedExperts(c, dtype,
                                                        name="moe")(u)
         with _scope(obs_names.SCOPE_SHARED_EXPERT):
             y = y + gated_mlp(c.hidden_size,
                               c.shared_experts * c.expert_width, dtype,
                               "shared")(u)
-        return h + y, experts, passed, balance
+        return h + y, experts, passed, balance, kernels
 
 
 class Moonlight3D(nn.Module):
@@ -274,7 +290,8 @@ class Moonlight3D(nn.Module):
     returns_aux = True  # (logits, {"loss", *aux_counters})
     #: the integer entries of the auxiliary dict, summed over a round's
     #: real steps into round outputs of these names (core/trainer.py)
-    aux_counters = ("expert_tokens", "held_overflow_calls")
+    aux_counters = ("expert_tokens", "held_overflow_calls",
+                    "attn_kernel_calls")
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -311,13 +328,14 @@ class Moonlight3D(nn.Module):
         # same
         remat = self.remat_layers and not self.is_initializing()
         layer = nn.remat(Layer) if remat else Layer
-        chosen, passed, balance = [], [], []
+        chosen, passed, balance, kernels = [], [], [], []
         for i in range(c.dense_layers + c.expert_layers):
-            h, experts, over, aux = layer(i < c.dense_layers, c, self.dtype,
-                                          name=f"layers_{i}")(h)
+            h, experts, over, aux, took = layer(
+                i < c.dense_layers, c, self.dtype, name=f"layers_{i}")(h)
             chosen.append(experts)
             passed.append(over)
             balance.append(aux)
+            kernels.append(took)
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps, init)
         with _scope(obs_names.SCOPE_ROUTER):
             aux = {
@@ -326,5 +344,6 @@ class Moonlight3D(nn.Module):
                     jnp.concatenate(chosen).reshape(-1),
                     length=c.num_experts).astype(jnp.int32),
                 "held_overflow_calls": sum(passed),
+                "attn_kernel_calls": sum(kernels),
             }
         return logits, aux

@@ -98,6 +98,7 @@ def test_folded_train_logs_the_held_rows_every_round(tmp_path):
         # a step routes 4 volumes x 8 tokens x 3 slots: twice 96 x 2 / 32
         assert a["held_capacity_rows"] == 12
         assert 0 <= a["held_overflow_calls"] <= real_steps * 4
+        assert "attn_kernel_calls" not in a  # no such output of this model
     # by hand: 4 experts, the middle two held
     load = expert_load(np.asarray([10, 30, 10, 50]), (1, 2))
     assert load["tokens_routed"] == 100 and load["rows_held"] == 40
@@ -109,6 +110,12 @@ def test_folded_train_logs_the_held_rows_every_round(tmp_path):
     assert "rows_held" not in load
     assert load["held_overflow_calls"] == 3
     assert load["held_capacity_rows"] == 512
+    assert "attn_kernel_calls" not in load
+    # Moonlight's third output: the attention calls that ran as the kernel
+    load = expert_load(np.asarray([1, 2]), (0, 1), np.int32(0), 512,
+                       kernel_calls=np.int32(144))
+    assert load["attn_kernel_calls"] == 144
+    assert load["held_overflow_calls"] == 0 and load["rows_held"] == 1
 
 
 def _all_alike(params):

@@ -142,6 +142,7 @@ def test_float32_logits_loss_balance_and_every_gradient(ref, tokens):
         np.bincount(np.asarray(chosen).ravel(), minlength=E))
     assert int(aux["expert_tokens"].sum()) == 2 * B * tokens * K
     assert int(aux["held_overflow_calls"]) == 0
+    assert int(aux["attn_kernel_calls"]) == 0  # off the TPU: the XLA form
     flat = jax.tree_util.tree_flatten_with_path(got_grads)[0]
     flat_ref = jax.tree.leaves(want_grads)
     # attention 5 + two norms a layer; layer 0's feed-forward 3; an expert
@@ -217,6 +218,32 @@ def _all_eqns(jaxpr):
             yield from _all_eqns(sub)
 
 
+def attention_kernel_bodies(traced, layers: int):
+    """The attention's ``pallas_call``s of a traced gradient step (a layer:
+    forward, rematerialised forward, backward) and the equations inside
+    their bodies, held to the stated precision: every exponential,
+    logarithm, maximum, sum and product's result float32, every product's
+    operands the compute dtype's (bfloat16). Also used by
+    tests/test_tpu_compile.py at the published size."""
+    calls = [e for e in _all_eqns(traced.jaxpr)
+             if e.primitive.name == "pallas_call"
+             and (e.params["name"] or "").startswith("attention_")]
+    assert sorted(e.params["name"] for e in calls) == (
+        ["attention_backward"] * layers + ["attention_forward"] * 2 * layers)
+    inside = [e for call in calls for e in _all_eqns(call.params["jaxpr"])]
+    by = lambda *names: [e for e in inside if e.primitive.name in names]
+    # a forward: the running maximum's and the tile's, in its loop and on
+    # its diagonal; a backward: the tile's, twice
+    assert len(by("exp")) >= layers * (2 * 4 + 2)
+    assert len(by("log")) == 2 * layers
+    for e in by("exp", "log", "reduce_max", "reduce_sum", "max",
+                "dot_general"):
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+    assert {v.aval.dtype for e in by("dot_general") for v in e.invars} == {
+        jnp.dtype(jnp.bfloat16)}
+    return calls, inside
+
+
 # ---------- (b) the blocked attention ----------
 
 def _qkv(seed, tokens, rows=2):
@@ -251,7 +278,7 @@ def test_blocked_attention_equals_one_dense_masked_block(tokens):
         *a, BLOCK, jnp.float32)
     with jax.default_matmul_precision("highest"):
         got = jax.jit(blocked)(q, k, v)
-        core = jax.jit(lambda *a: mla_core(*a, BLOCK, jnp.float32))(
+        core = jax.jit(lambda *a: mla_core(*a, BLOCK, jnp.float32)[0])(
             qn, qr, kn, kr, v)
         want = _dense_masked(q, k, v)
         f = lambda fn: jax.jit(jax.grad(
@@ -271,7 +298,7 @@ def test_one_rotary_key_serves_every_head(t):
     and none before."""
     qn, qr, kn, kr, v = _qkv(t, 76)
     core = jax.jit(lambda kr: mla_core(qn, qr, kn, kr, v, BLOCK,
-                                       jnp.float32))
+                                       jnp.float32)[0])
     before, after = core(kr), core(kr.at[:, t].add(1.0))
     np.testing.assert_array_equal(before[:, :t], after[:, :t])
     moved = np.abs(np.asarray(before - after)).reshape(2, 76, HEADS, DV)
@@ -285,7 +312,7 @@ def test_a_change_at_token_t_leaves_the_outputs_before_t_bitwise_alone(t):
     layer = LatentAttention(SMALL)
     x = jax.random.normal(jax.random.key(t), (2, 76, 64))
     params = _jitter(layer.init(jax.random.key(0), x)["params"], t, 0.2)
-    f = jax.jit(lambda x: layer.apply({"params": params}, x))
+    f = jax.jit(lambda x: layer.apply({"params": params}, x)[0])
     before, after = f(x), f(x.at[:, t].add(1.0))
     np.testing.assert_array_equal(before[:, :t], after[:, :t])
     moved = np.abs(np.asarray(before - after)).max(-1)
@@ -327,11 +354,11 @@ def test_the_four_shares_add_up_to_the_uncut_layer(ref, seed):
                     HeldGatedExperts(w).apply({"params": share["moe"]},
                                               u)[0])
 
-        (out, experts, passed, _), routed = program(share)
+        (out, experts, passed, _, kernels), routed = program(share)
         np.testing.assert_array_equal(experts, chosen)
         held = (experts >= first) & (experts < first + 4)
         rows += int(held.sum())
-        assert int(passed) == 0
+        assert int(passed) == 0 and int(kernels) == 0  # the XLA form
         # a token none of whose choices is held here gets nothing
         none = ~held.any(-1)
         assert float(jnp.max(jnp.abs(routed.reshape(-1, 64)[none]),
@@ -349,7 +376,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(ref, seed):
 def _after_attention(w, p, h):
     """``h + attention(N_1(h))`` by the program's own modules."""
     x = tokens3d.RMSNorm().apply({"params": p["attn_norm"]}, h)
-    return h + LatentAttention(w).apply({"params": p["mla"]}, x)
+    return h + LatentAttention(w).apply({"params": p["mla"]}, x)[0]
 
 
 # ---------- (e) the balance loss is per sequence ----------
@@ -437,7 +464,8 @@ def test_the_model_declares_what_the_trainer_reads():
     assert (w.dense_layers, w.expert_layers, w.held, w.patch) == (
         1, 5, (0, 8), 8)
     assert model.returns_aux and model.remat_layers
-    assert model.aux_counters == ("expert_tokens", "held_overflow_calls")
+    assert model.aux_counters == ("expert_tokens", "held_overflow_calls",
+                                  "attn_kernel_calls")
     assert model.held_experts == (0, 8)
     assert model.row_tokens((121, 145, 121)) == 4864
     assert LocalTrainer(model, OptimConfig(), 1).eval_batch_rows(
@@ -455,3 +483,157 @@ def test_the_model_declares_what_the_trainer_reads():
     assert sizes["layers_1"] == 100_405_760
     assert sum(sizes.values()) == 585_001_984 + 512 * 2048 + 2048 * 3
 
+
+
+# ---------- the attention kernel's counter (PR 42) ----------
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_folded_train_logs_the_attention_kernels_calls(tmp_path, monkeypatch,
+                                                       kernel):
+    """Every round's ``round_log`` span carries ``attn_kernel_calls``
+    beside the routing counters: the attention calls of the round's real
+    steps that ran as the kernel. 0 here as it stands (off the TPU the XLA
+    form runs); with the choice answered as a TPU answers it at widths the
+    blocks tile (and the kernel stood in for, the small widths tile no
+    block), three layers a real step, none from the eager initialisation
+    or the evaluation."""
+    from neuroimagedisttraining_tpu.config import (
+        DataConfig, ExperimentConfig, FedConfig, OptimConfig,
+    )
+    from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
+    from neuroimagedisttraining_tpu.data.federate import federate_cohort
+    from neuroimagedisttraining_tpu.data.synthetic import (
+        generate_synthetic_abcd,
+    )
+    from neuroimagedisttraining_tpu.engines import create_engine
+    from neuroimagedisttraining_tpu.obs import names as obs_names
+    from neuroimagedisttraining_tpu.obs import trace as obs_trace
+    from neuroimagedisttraining_tpu.ops import attention
+    from neuroimagedisttraining_tpu.utils.logging import ExperimentLogger
+
+    eager = []
+    if kernel:
+        monkeypatch.setattr(attention, "takes_kernel",
+                            lambda T, dk, ds, dv, kernel: kernel)
+        monkeypatch.setattr(
+            attention, "attention_kernel",
+            lambda q, k, v, qs, ks: eager.append(
+                not isinstance(q, jax.core.Tracer))
+            or tokens3d.blocked_causal_attention(
+                jnp.concatenate([q, qs], -1),
+                jnp.concatenate([k, jnp.broadcast_to(ks, qs.shape)], -1),
+                v, BLOCK, q.dtype))
+    cohort = generate_synthetic_abcd(num_subjects=24, shape=_shape(24),
+                                     num_sites=2, seed=0)
+    cohort["site"] = np.repeat(np.arange(2), (16, 8)).astype(
+        cohort["site"].dtype)
+    cfg = ExperimentConfig(
+        model="moonlight3d", num_classes=1, algorithm="fedavg",
+        data=DataConfig(dataset="synthetic", partition_method="site"),
+        optim=OptimConfig(lr=1e-2, batch_size=4, epochs=1),
+        fed=FedConfig(client_num_in_total=2, comm_round=2),
+        log_dir=str(tmp_path), tag=f"kernel{kernel}")
+    tr = LocalTrainer(Moonlight3D(widths=SMALL), cfg.optim, 1)
+    fed, _ = federate_cohort(cohort, partition_method="site", mesh=None)
+    eng = create_engine("fedavg", cfg, fed, tr, mesh=None,
+                        logger=ExperimentLogger(
+                            str(tmp_path), "synthetic", cfg.identity(),
+                            console=False))
+    eng._fold_budget_bytes = 1
+    obs_trace.arm()
+    try:
+        eng.train()
+        logs = [e["args"] for e in obs_trace.TRACER.events()
+                if e["ph"] == "X" and e["name"] == obs_names.SPAN_ROUND_LOG]
+    finally:
+        obs_trace.disarm()
+    assert [a["round"] for a in logs] == [0, 1]
+    real_steps = int(np.ceil(np.asarray(eng.data.n_train) / 4).sum())
+    for a in logs:
+        assert a["attn_kernel_calls"] == (real_steps * 3 if kernel else 0)
+        assert a["held_overflow_calls"] >= 0 and a["rows_held"] > 0
+        assert a["tokens_routed"] == real_steps * 4 * 24 * K * 2
+    assert not any(eager)  # the eager initialisation says kernel=False
+
+
+def test_on_a_tpu_the_kernels_bodies_keep_scores_and_softmax_float32(
+        monkeypatch):
+    """Where the scores live since PR 42: at widths the kernel's blocks
+    tile, traced as a TPU traces it, every layer's attention is three
+    ``pallas_call``s of the gradient step (forward, rematerialised
+    forward, backward) and no block of scores is left outside them; inside
+    their bodies every exponential, logarithm, maximum and sum is float32
+    and every product accumulates in float32 under ``bf16_mixed``:
+    bfloat16 enters a product as an operand and leaves a body as an
+    output, nowhere else."""
+    # dense layers alone: the experts' grouped matmul asks the platform
+    # too, and no megablox tile fits the small widths
+    tile = dataclasses.replace(SMALL, dense_layers=3, expert_layers=0,
+                               heads=2, qk_nope_head_dim=128,
+                               qk_rope_head_dim=64, v_head_dim=128)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = Moonlight3D(widths=tile, dtype=jnp.bfloat16)
+    x = jnp.zeros((2, 16, 128, 4, 1))  # 128 tokens: one block
+    params = jax.eval_shape(
+        Moonlight3D(widths=tile).init, jax.random.key(0), x[:1])["params"]
+
+    def loss(p):
+        logits, aux = model.apply({"params": p}, x)
+        return jnp.sum(logits) + aux["loss"], aux
+
+    traced = jax.make_jaxpr(jax.grad(loss, has_aux=True))(params)
+    _, inside = attention_kernel_bodies(traced, layers=3)
+    # no block of scores [B, heads, queries, keys] is left in XLA
+    outside = [e for e in _all_eqns(traced.jaxpr)
+               if not any(e is i for i in inside)]
+    assert not [e for e in outside if e.primitive.name in ("exp",
+                                                           "dot_general")
+                and e.outvars[0].aval.shape[:2] == (2, 2)]
+
+
+def test_through_the_interpreted_kernel_the_model_is_the_xla_forms(
+        monkeypatch):
+    """The whole small trunk at widths the kernel's blocks tile (256
+    tokens: two blocks), its attention through the kernels' own bodies in
+    Pallas' interpreter (the choice answered as a TPU answers it), against
+    the same trunk on the XLA form: logits, the balance loss, the counters
+    and every gradient, under ``nn.remat`` as the trainer runs it."""
+    import functools
+
+    from neuroimagedisttraining_tpu.ops import attention
+
+    tile = dataclasses.replace(SMALL, heads=2, qk_nope_head_dim=128,
+                               qk_rope_head_dim=64, v_head_dim=128,
+                               block=128)
+    model = Moonlight3D(widths=tile)
+    x, y = _batch(7, 256, rows=2)
+    params = _jitter(model.init(jax.random.key(7), jnp.zeros(
+        (1,) + _shape(256) + (1,)))["params"], 7)
+
+    def run(p):
+        def loss(p):
+            logits, aux = _apply(model, p, x)
+            return (jnp.sum(logits * (2.0 * y[:, None] - 1)) + aux["loss"],
+                    (logits, aux))
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
+
+    (_, (want, want_aux)), want_grads = run(params)
+    assert int(want_aux["attn_kernel_calls"]) == 0
+    real = attention.attention_kernel
+    monkeypatch.setattr(attention, "takes_kernel",
+                        lambda T, dk, ds, dv, kernel: kernel
+                        and attention.kernel_tiles(T, dk, ds, dv))
+    monkeypatch.setattr(attention, "attention_kernel",
+                        functools.partial(real, interpret=True))
+    (_, (got, aux)), got_grads = run(params)
+    assert int(aux["attn_kernel_calls"]) == 3
+    _close(got, want)
+    _close(aux["loss"], want_aux["loss"])
+    np.testing.assert_array_equal(aux["expert_tokens"],
+                                  want_aux["expert_tokens"])
+    for (path, g), h in zip(
+            jax.tree_util.tree_flatten_with_path(got_grads)[0],
+            jax.tree.leaves(want_grads)):
+        top = float(jnp.max(jnp.abs(h)))
+        _close(g, h, rtol=F32_RTOL * 10, atol=F32_ATOL * max(top, 1e-30)
+               * 20)

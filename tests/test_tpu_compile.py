@@ -521,47 +521,65 @@ def test_moonlight3d_training_step_fits_at_the_published_widths(chip,
     """``--model moonlight3d``'s step at 586 M parameters and the cell's
     batch of 2 x 4,864 tokens: the held runs' buffer of 14,848 rows in
     every expert layer (forward, rematerialised forward and backward: 8
-    kernels a layer), the scores a block of 512 queries at a time against
-    the keys up to the block's end (``[2, 16, 512, 4608]`` the widest whole
-    block, ``[2, 16, 256, 4864]`` the last), never ``[4864, 4864]``, and
-    code + temporaries that leave room for the folded round's 10.9 GiB of
-    state (PERF.md, PR 40: 334.8 MiB and 1.792 GiB)."""
+    kernels a layer), the attention's kernels in all six layers (forward,
+    rematerialised forward, backward: ops/attention.py, PR 42), every one
+    under the scope ``mla_core``; no float32 block of scores ``[2, 16,
+    queries, keys]`` of any extent is left in the program (the XLA form
+    held ``[2, 16, 512, 4608]`` and nine more), never ``[4864, 4864]``;
+    and code + temporaries under what the XLA form took (PERF.md, PR 40:
+    334.8 MiB and 1.792 GiB; 225.9 MiB and 1.648 GiB with the kernels),
+    beside the folded round's 10.9 GiB of state."""
+    import re
+
     compiled = _compiled_step(chip, monkeypatch, "moonlight3d", batch=2)
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert text.count(KERNEL_MARK) == 5 * 8
+    assert text.count(KERNEL_MARK) == 5 * 8 + 6 * 3
+    calls = [line for line in text.splitlines() if KERNEL_MARK in line]
+    attention = [c for c in calls if "/attention_" in c]
+    assert len(attention) == 6 * 3
+    assert all("/mla_core/attention_" in c for c in attention)
     assert "bf16[14848,2048]" in text and "bf16[14848,2816]" in text
-    assert "f32[2,16,512,4608]" in text and "f32[2,16,256,4864]" in text
+    assert not re.search(r"f32\[2,16,\d+,\d+\]", text)
     assert "[2,16,4864,4864]" not in text
-    assert mem.temp_size_in_bytes < 1.9 * 2 ** 30
-    assert mem.generated_code_size_in_bytes < 350 * 2 ** 20
+    assert mem.temp_size_in_bytes < 1.792 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 335 * 2 ** 20
 
 
 def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
         chip, monkeypatch):
     """The configuration states float32 scores, softmax and router under
-    ``bf16_mixed``: the program the cell times says so itself. Every
-    exponential over a block of scores (``[2, 16, queries, keys]``) is
-    float32, forward, rematerialised and backward, and every block has a
-    float32 product (bfloat16 appears there as the probabilities cast to
-    meet the values, and as their cotangent); nothing over the router's
-    ``[9728, 64]`` scores is bfloat16, and its product is float32."""
+    ``bf16_mixed``: the program the cell times says so itself. Nothing over
+    the router's ``[9728, 64]`` scores is bfloat16, and its product is
+    float32. The attention's scores live inside the kernels since PR 42,
+    where the compiled text does not look: in the step as traced for the
+    chip, inside the bodies of its 18 ``pallas_call``s, every exponential,
+    logarithm, maximum and sum is float32 and every product accumulates in
+    float32 (bfloat16 there is an operand of a product or an output); and
+    no exponential over a ``[2, 16, ...]`` block is left outside them."""
     import re
 
     text = _compiled_step(chip, monkeypatch, "moonlight3d",
                           batch=2).as_text()
-    block = re.compile(r" = (\w+)\[2,16,(512|256),(\d+)\]\S* "
-                       r"(exponential|convolution|dot)\(")
-    found = [m.groups() for m in map(block.search, text.splitlines()) if m]
-    keys = {str(n) for n in (*range(512, 4864, 512), 4864)}
-    exps = [f for f in found if f[3] == "exponential"]
-    assert {f[2] for f in exps} == keys  # every block of the ten
-    assert {f[0] for f in exps} == {"f32"}
-    scores = {f[2] for f in found if f[3] != "exponential"
-              and f[0] == "f32"}
-    assert scores >= keys
     assert "bf16[9728,64]" not in text
     assert re.search(r" = f32\[9728,64\]\S* convolution\(", text)
     assert re.search(r" = f32\[9728,64\]\S* exponential\(", text)
+    assert not re.search(r" = \w+\[2,16,\d+,\d+\]\S* exponential\(", text)
+
+    from neuroimagedisttraining_tpu.config import OptimConfig
+    from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
+    from tests.test_moonlight3d import attention_kernel_bodies
+
+    trainer = LocalTrainer(
+        create_model("moonlight3d", 1, dtype=jnp.bfloat16),
+        OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
+                    grad_clip=10.0, batch_size=2), 1)
+    state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
+                           jnp.zeros((1,) + SHAPE, jnp.float32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    traced = jax.make_jaxpr(trainer.loss_and_grad)(
+        state, jax.ShapeDtypeStruct((2,) + SHAPE, jnp.uint8),
+        jax.ShapeDtypeStruct((2,), jnp.int32))
+    attention_kernel_bodies(traced, layers=6)
 
 
 def test_resnet3d_vmapped_step_stays_client_merged(chip, monkeypatch):
