@@ -35,7 +35,7 @@ windows before]`` block of summary scores. The two are merged by their
 common maximum, not concatenated: ``exp(s - m)`` and ``exp(r - m)`` each
 multiply their own values and share one ``Z``. Scores, exponentials and
 ``Z`` are float32; the probabilities meet the values in the compute dtype,
-as in the other trunks (models/tokens3d.py ``causal_gq_attention``). Only
+as in the other trunks (ops/attention.py ``causal_gq_attention``). Only
 the whole windows before the last are pooled: no query reads the last
 window's summaries.
 
@@ -177,7 +177,7 @@ class EvaAttention(nn.Module):
         c = self.w
         B, T, H = x.shape
         A, d = c.heads, c.head_dim
-        init = nn.initializers.normal(stddev=c.init_std)
+        init = tokens3d.normal(c.init_std)
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          kernel_init=init, name=name)
         heads = lambda t: t.reshape(B, T, A, d)
@@ -192,21 +192,11 @@ class EvaAttention(nn.Module):
         return dense(H, "o_proj")(out)
 
 
-class GatedMLP(nn.Module):
-    """``(silu(x W_gate) * (x W_up)) W_down``, whole."""
-
-    w: Widths
-    dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.w
-        dense = lambda n, name: nn.Dense(
-            n, use_bias=False, dtype=self.dtype, name=name,
-            kernel_init=nn.initializers.normal(stddev=c.init_std))
-        gated = nn.silu(dense(c.intermediate_size, "gate_proj")(x)) \
-            * dense(c.intermediate_size, "up_proj")(x)
-        return dense(c.hidden_size, "down_proj")(gated)
+def GatedMLP(w: Widths, dtype: Dtype = jnp.float32, **module):
+    """The feed-forward at this trunk's widths (models/tokens3d.py
+    ``GatedMLP``)."""
+    return tokens3d.GatedMLP(w.hidden_size, w.intermediate_size, w.init_std,
+                             dtype, **module)
 
 
 class Layer(nn.Module):
@@ -244,23 +234,14 @@ class EvaByte3D(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         c = self.widths
-        init = nn.initializers.normal(stddev=c.init_std)
+        init = tokens3d.normal(c.init_std)
         h = tokens3d.patch_embed(x, c.hidden_size, c.patch, c.rms_eps,
                                  self.dtype, init)
         h = h.astype(jnp.float32)  # nidt: allow[precision-upcast] -- the residual stream is float32 (fp32_skip_add)
-        # not while initialising: the trainer initialises eagerly, and a
-        # rematerialised layer run eagerly compiles its body anew on
-        # every call (models/nemotronh3d.py); the parameter tree is the
-        # same
-        remat = self.remat_layers and not self.is_initializing()
-        layer = nn.remat(Layer) if remat else Layer
-        for i in range(c.layers):
-            h = layer(c, self.dtype, name=f"layers_{i}")(h)
+        (h,), _ = tokens3d.layer_stack(self, Layer,
+                                       [(c, self.dtype)] * c.layers, (h,))
         return tokens3d.pooled_logits(h, self.num_classes, c.rms_eps, init,
                                       unit_offset=True)
 
     def row_tokens(self, row_shape) -> int:
-        """Tokens of one volume ``[D, H, W, ...]``: what a row of an
-        evaluation batch costs (core/trainer.py ``eval_batch_rows``, the
-        cap under which ``eval_batches`` balances a client's rows)."""
-        return tokens3d.token_count((1, *row_shape), self.widths.patch)
+        return tokens3d.row_tokens(row_shape, self.widths.patch)

@@ -46,7 +46,7 @@ published ones), one Pallas kernel a pass: a tile of float32 scores lives
 and dies in vector memory, every head reads the ONE rotary key from its
 ``[T, dr]`` array, and the backward pass remakes the probabilities from
 the rows' log-sum-exp. Everywhere else (the CPU tests, the small widths,
-the eager initialisation) ``tokens3d.blocked_causal_attention``: the shared
+the eager initialisation) its ``blocked_causal_attention``: the shared
 key repeated beside each head's ``kn``, the scores a block of ``block``
 queries at a time against the keys up to the block's end, each block
 rematerialised in the backward pass. ``aux["attn_kernel_calls"]`` counts
@@ -93,10 +93,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from neuroimagedisttraining_tpu.models import evabyte3d, tokens3d
-from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
-from neuroimagedisttraining_tpu.models.zaya3d import swiglu
+from neuroimagedisttraining_tpu.models import tokens3d
+from neuroimagedisttraining_tpu.models.tokens3d import GatedMLP, RMSNorm
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops import attention, moe
 
 Dtype = Any
 _scope = jax.named_scope
@@ -132,16 +132,7 @@ class Widths:
 
 def _dense(n, name, dtype):
     return nn.Dense(n, use_bias=False, dtype=dtype, name=name,
-                    kernel_init=nn.initializers.normal(stddev=INIT_STD))
-
-
-def gated_mlp(hidden: int, width: int, dtype, name: str):
-    """``(silu(x W_gate) * (x W_up)) W_down`` at ``width``: EvaByte's
-    module (models/evabyte3d.py ``GatedMLP``), which reads its three sizes
-    from that model's ``Widths``."""
-    return evabyte3d.GatedMLP(
-        evabyte3d.Widths(hidden_size=hidden, intermediate_size=width,
-                         init_std=INIT_STD), dtype, name=name)
+                    kernel_init=tokens3d.normal(INIT_STD))
 
 
 def mla_core(qn, qr, kn, kr, v, block: int, dtype, kernel: bool = True):
@@ -152,8 +143,6 @@ def mla_core(qn, qr, kn, kr, v, block: int, dtype, kernel: bool = True):
     ``took``: whether this call ran as the kernel (ops/attention.py: on a
     TPU, at widths its blocks tile, unless the caller says
     ``kernel=False``); else ``block`` queries at a time in plain XLA."""
-    from neuroimagedisttraining_tpu.ops import attention  # ops imports models
-
     took = attention.takes_kernel(qn.shape[1], qn.shape[-1], qr.shape[-1],
                                   v.shape[-1], kernel)
     with _scope(obs_names.SCOPE_MLA_CORE):
@@ -189,7 +178,7 @@ class LatentAttention(nn.Module):
             up = _dense(A * (dn + dv), "kv_b_proj", self.dtype)(
                 latent).reshape(B, T, A, dn + dv)
             kn, v = up[..., :dn], up[..., dn:]
-        # the trainer initialises eagerly (Moonlight3D.__call__)
+        # the trainer initialises eagerly (tokens3d.layer_stack)
         out, took = mla_core(qn, qr, kn, kr, v, c.block, self.dtype,
                              kernel=not self.is_initializing())
         return _dense(H, "o_proj", self.dtype)(out), took
@@ -209,33 +198,21 @@ class HeldGatedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         c = self.w
         B, T, H = u.shape
-        E, W, k = c.num_experts, c.expert_width, c.experts_per_token
-        first, count = c.held
-        init = nn.initializers.normal(stddev=INIT_STD)
+        E, k = c.num_experts, c.experts_per_token
         x = u.reshape(B * T, H)
-        # the router's weight is named as its stage is
-        w_router = self.param(obs_names.SCOPE_ROUTER, init, (H, E),
-                              jnp.float32)
-        up = self.param("up", init, (count, H, 2 * W), jnp.float32)
-        down = self.param("down", init, (count, W, H), jnp.float32)
+        # e_score_correction_bias: a buffer the published recipe moves
+        # outside the gradient; zeros, so no bias is handed on
+        scores, weights, experts = tokens3d.linear_router(
+            self, x, E, k, INIT_STD, scoring="sigmoid",
+            scale=c.routed_scaling_factor)
         with _scope(obs_names.SCOPE_ROUTER):
-            # float32 whatever the compute dtype, as the architecture has it
-            logits = jnp.dot(x.astype(jnp.float32), w_router,  # nidt: allow[precision-upcast] -- the router is float32 by the architecture's definition
-                             precision=jax.lax.Precision.HIGHEST)
-            # e_score_correction_bias: a buffer the published recipe
-            # moves outside the gradient; zeros, so no bias is handed on
-            scores, weights, experts = moe.route(
-                logits, k, scoring="sigmoid", scale=c.routed_scaling_factor)
             balance = jnp.mean(moe.sequence_balance_loss(
                 scores.reshape(B, T, E), experts.reshape(B, T, k), E))
-        # the trainer initialises eagerly (Moonlight3D.__call__)
-        y, passed = moe.held_expert_rows(
-            x, weights, experts, up, down, E, first, swiglu(W),
-            buffer=not self.is_initializing())
+        y, passed = tokens3d.held_expert_body(
+            self, x, weights, experts, E, c.held, c.expert_width,
+            gated=True, stds=(INIT_STD, INIT_STD))
         return y.reshape(B, T, H), experts, passed, balance
 
 
@@ -263,17 +240,17 @@ class Layer(nn.Module):
         u = norm("mlp_norm")(h)
         if self.dense:
             with _scope(obs_names.SCOPE_MLP):
-                y = gated_mlp(c.hidden_size, c.intermediate_size, dtype,
-                              "ffn")(u)
+                y = GatedMLP(c.hidden_size, c.intermediate_size, INIT_STD,
+                             dtype, name="ffn")(u)
             return (h + y, jnp.zeros((0, c.experts_per_token), jnp.int32),
                     jnp.zeros((), jnp.int32), jnp.zeros((), jnp.float32),
                     kernels)
         y, experts, passed, balance = HeldGatedExperts(c, dtype,
                                                        name="moe")(u)
         with _scope(obs_names.SCOPE_SHARED_EXPERT):
-            y = y + gated_mlp(c.hidden_size,
-                              c.shared_experts * c.expert_width, dtype,
-                              "shared")(u)
+            y = y + GatedMLP(c.hidden_size,
+                             c.shared_experts * c.expert_width, INIT_STD,
+                             dtype, name="shared")(u)
         return h + y, experts, passed, balance, kernels
 
 
@@ -288,62 +265,32 @@ class Moonlight3D(nn.Module):
 
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", *aux_counters})
-    #: the integer entries of the auxiliary dict, summed over a round's
-    #: real steps into round outputs of these names (core/trainer.py)
     aux_counters = ("expert_tokens", "held_overflow_calls",
                     "attn_kernel_calls")
 
     @property
     def held_experts(self) -> tuple[int, int]:
-        """``(first, count)`` of the experts whose rows are computed
-        here: the round driver counts ``rows_held`` over them."""
         return self.widths.held
 
     def row_tokens(self, row_shape) -> int:
-        """Tokens of one volume ``[D, H, W, ...]``: what a row of an
-        evaluation batch costs (core/trainer.py ``eval_batch_rows``, the
-        cap under which ``eval_batches`` balances a client's rows)."""
-        return tokens3d.token_count((1, *row_shape), self.widths.patch)
+        return tokens3d.row_tokens(row_shape, self.widths.patch)
 
     def held_capacity_rows(self, batch_shape) -> int | None:
-        """The rows of the held runs' buffer for a batch ``[B, D, H, W,
-        ...]`` of volumes (ops/moe.py ``held_capacity``), ``None`` where
-        such a batch is computed by the full sort alone."""
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         c = self.widths
-        return moe.held_capacity(
-            c.experts_per_token * tokens3d.token_count(batch_shape, c.patch),
-            c.held[1], c.num_experts)
+        return tokens3d.held_capacity_rows(
+            batch_shape, c.patch, c.experts_per_token, c.held, c.num_experts)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         c = self.widths
-        init = nn.initializers.normal(stddev=INIT_STD)
+        init = tokens3d.normal(INIT_STD)
         h = tokens3d.patch_embed(x, c.hidden_size, c.patch, c.rms_eps,
                                  self.dtype, init)
-        # not while initialising: the trainer initialises eagerly, and a
-        # rematerialised layer run eagerly compiles its body anew on
-        # every call (models/nemotronh3d.py); the parameter tree is the
-        # same
-        remat = self.remat_layers and not self.is_initializing()
-        layer = nn.remat(Layer) if remat else Layer
-        chosen, passed, balance, kernels = [], [], [], []
-        for i in range(c.dense_layers + c.expert_layers):
-            h, experts, over, aux, took = layer(
-                i < c.dense_layers, c, self.dtype, name=f"layers_{i}")(h)
-            chosen.append(experts)
-            passed.append(over)
-            balance.append(aux)
-            kernels.append(took)
+        kinds = [True] * c.dense_layers + [False] * c.expert_layers
+        (h,), (chosen, passed, balance, kernels) = tokens3d.layer_stack(
+            self, Layer, [(dense, c, self.dtype) for dense in kinds], (h,))
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps, init)
         with _scope(obs_names.SCOPE_ROUTER):
-            aux = {
-                "loss": c.aux_alpha * sum(balance),
-                "expert_tokens": jnp.bincount(
-                    jnp.concatenate(chosen).reshape(-1),
-                    length=c.num_experts).astype(jnp.int32),
-                "held_overflow_calls": sum(passed),
-                "attn_kernel_calls": sum(kernels),
-            }
-        return logits, aux
+            loss, kernels = c.aux_alpha * sum(balance), sum(kernels)
+        return logits, tokens3d.held_aux(loss, chosen, passed, c.num_experts,
+                                         attn_kernel_calls=kernels)

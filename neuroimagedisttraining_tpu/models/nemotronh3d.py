@@ -71,18 +71,16 @@ import jax
 import jax.numpy as jnp
 
 from neuroimagedisttraining_tpu.models import tokens3d
-from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
+from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm, relu2
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops import attention, ssd
 
 Dtype = Any
 _scope = jax.named_scope
+_normal = tokens3d.normal
 #: the first nine layers of the published 52-layer pattern
 PATTERN = "MEMEM*EME"
 KINDS = "ME*"  # Mamba-2, expert layer, attention
-
-
-def _normal(std):
-    return nn.initializers.normal(stddev=std)
 
 
 def _dt_bias_init(dt_min, dt_max, dt_floor):
@@ -128,8 +126,6 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, a):
-        from neuroimagedisttraining_tpu.ops import ssd  # ops imports models
-
         B, T, d = a.shape
         H, P, G, N = (self.num_heads, self.head_dim, self.n_groups,
                       self.state_size)
@@ -157,7 +153,7 @@ class Mamba2Mixer(nn.Module):
             # float32: the step and the decay rate (time_step_limit is
             # (0, inf): nothing to clip)
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias)  # nidt: allow[precision-upcast] -- the scan's step size, float32 like its decays (ops/ssd.py)
-            # the trainer initialises eagerly (NemotronH3D.__call__)
+            # the trainer initialises eagerly (tokens3d.layer_stack)
             y = ssd.ssd_chunked(
                 x.reshape(B, T, H, P), dt, -jnp.exp(A_log),
                 Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N), D,
@@ -188,10 +184,6 @@ class Mamba2Mixer(nn.Module):
                             name="out_proj")(y)
 
 
-def relu2(x):
-    return jnp.square(nn.relu(x))
-
-
 class HeldExperts(nn.Module):
     """The routed part of the ``E`` layer for the experts this chip
     holds: ``(y [B, T, d], experts [B*T, k], passed)``. Routes over all
@@ -210,30 +202,17 @@ class HeldExperts(nn.Module):
 
     @nn.compact
     def __call__(self, m):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         B, T, d = m.shape
-        E, W = self.num_experts, self.expert_width
-        first, count = self.held
+        E = self.num_experts
         x = m.reshape(B * T, d)
-        w_router = self.param(obs_names.SCOPE_ROUTER, _normal(0.02), (d, E),
-                              jnp.float32)
-        up = self.param("up", _normal(0.02), (count, d, W), jnp.float32)
-        down = self.param("down", _normal(self.out_std), (count, W, d),
-                          jnp.float32)
-        with _scope(obs_names.SCOPE_ROUTER):
-            # float32 whatever the compute dtype, as the architecture has it
-            logits = jnp.dot(x.astype(jnp.float32), w_router,  # nidt: allow[precision-upcast] -- the router is float32 by the architecture's definition
-                             precision=jax.lax.Precision.HIGHEST)
-            # e_score_correction_bias: a buffer the published recipe
-            # moves outside the gradient; zeros, so no bias is handed on
-            _, weights, experts = moe.route(
-                logits, self.experts_per_token, scoring="sigmoid",
-                scale=self.scaling)
-        # the trainer initialises eagerly (NemotronH3D.__call__)
-        y, passed = moe.held_expert_rows(
-            x, weights, experts, up, down, E, first, relu2,
-            buffer=not self.is_initializing())
+        # e_score_correction_bias: a buffer the published recipe moves
+        # outside the gradient; zeros, so no bias is handed on
+        _, weights, experts = tokens3d.linear_router(
+            self, x, E, self.experts_per_token, 0.02, scoring="sigmoid",
+            scale=self.scaling)
+        y, passed = tokens3d.held_expert_body(
+            self, x, weights, experts, E, self.held, self.expert_width,
+            gated=False, stds=(0.02, self.out_std))
         return y.reshape(B, T, d), experts, passed
 
 
@@ -275,8 +254,11 @@ class GQAttention(nn.Module):
             q = dense(Hq * hd, "q_proj")(a).reshape(B, T, Hkv, Hq // Hkv, hd)
             k = dense(Hkv * hd, "k_proj")(a).reshape(B, T, Hkv, hd)
             v = dense(Hkv * hd, "v_proj")(a).reshape(B, T, Hkv, hd)
+            # one block of scores (640 tokens); the kernel for grouped
+            # heads is a measured change (ROADMAP D17)
             return dense(d, "o_proj", self.out_std)(
-                tokens3d.causal_gq_attention(q, k, v, self.dtype))
+                attention.causal_attention(q, k, v, T, self.dtype,
+                                           kernel=False))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -359,52 +341,25 @@ class NemotronH3D(nn.Module):
 
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", *aux_counters})
-    #: the integer entries of the auxiliary dict, summed over a round's
-    #: real steps into round outputs of these names (core/trainer.py)
     aux_counters = ("expert_tokens", "held_overflow_calls")
 
     @property
     def held_experts(self) -> tuple[int, int]:
-        """``(first, count)`` of the experts whose rows are computed
-        here: the round driver counts ``rows_held`` over them."""
         return self.widths.held
 
     def held_capacity_rows(self, batch_shape) -> int | None:
-        """The rows of the held runs' buffer for a batch ``[B, D, H, W,
-        ...]`` of volumes (ops/moe.py ``held_capacity``), ``None`` where
-        such a batch is computed by the full sort alone."""
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         c = self.widths
-        return moe.held_capacity(
-            c.experts_per_token * tokens3d.token_count(batch_shape, c.patch),
-            c.held[1], c.num_experts)
+        return tokens3d.held_capacity_rows(
+            batch_shape, c.patch, c.experts_per_token, c.held, c.num_experts)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         c = self.widths
         h = tokens3d.patch_embed(x, c.hidden_size, c.patch, c.rms_eps,
                                  self.dtype, _normal(0.02))
-        # not while initialising: the trainer initialises eagerly, and a
-        # rematerialised layer run eagerly compiles its body anew on
-        # every call (four compilations inside the benchmark's measured
-        # window, my chip run, PR 29); the parameter tree is the same
-        remat = self.remat_layers and not self.is_initializing()
-        layer = nn.remat(Layer) if remat else Layer
-        chosen, passed = [], []
-        for i, kind in enumerate(c.pattern):
-            h, experts, over = layer(kind, c, self.dtype,
-                                     name=f"layers_{i}")(h)
-            chosen.append(experts)
-            passed.append(over)
+        (h,), (chosen, passed) = tokens3d.layer_stack(
+            self, Layer, [(kind, c, self.dtype) for kind in c.pattern], (h,))
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps,
                                         _normal(0.02))
-        with _scope(obs_names.SCOPE_ROUTER):
-            aux = {
-                "loss": jnp.zeros((), jnp.float32),
-                "expert_tokens": jnp.bincount(
-                    jnp.concatenate(chosen).reshape(-1),
-                    length=c.num_experts).astype(jnp.int32),
-                "held_overflow_calls": sum(passed),
-            }
-        return logits, aux
+        return logits, tokens3d.held_aux(jnp.zeros((), jnp.float32), chosen,
+                                         passed, c.num_experts)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops.pooling import max_pool_3d_nonoverlap
+from neuroimagedisttraining_tpu.ops.stemconv import stem_block, stem_conv3d
 
 Dtype = Any
 
@@ -43,10 +45,6 @@ def _pool(x, kind: str, k: int, s: int):
             # non-overlapping pools: ~4% faster step but carries extra
             # residual memory — see ops/pooling.py for the measured
             # trade-off and why it is not the default
-            from neuroimagedisttraining_tpu.ops.pooling import (
-                max_pool_3d_nonoverlap,
-            )
-
             return max_pool_3d_nonoverlap(x, k)
         return nn.max_pool(x, (k,) * 3, strides=(s,) * 3)
     return nn.avg_pool(x, (k,) * 3, strides=(s,) * 3)
@@ -74,8 +72,6 @@ class _StemConv(nn.Module):
                                jnp.float32) if self.use_bias else None
 
     def __call__(self, x):
-        from neuroimagedisttraining_tpu.ops.stemconv import stem_conv3d
-
         y = stem_conv3d(x.astype(self.dtype), self.kernel.astype(self.dtype))
         return y + self.bias.astype(self.dtype)
 
@@ -117,8 +113,6 @@ def _stem_stage(x, conv: _StemConv, bn: _StemNorm, train: bool, *,
     ``pad``, ``pool`` (``stem_block``'s), the norm's output dtype. ``conv``
     and ``bn`` hold the trees ``nn.Conv`` and ``nn.BatchNorm`` would
     declare under their names."""
-    from neuroimagedisttraining_tpu.ops.stemconv import stem_block
-
     if conv.is_mutable_collection("intermediates"):
         # ops/flops.py counts a convolution from its module's captured
         # output, which this route never materialises

@@ -52,10 +52,12 @@ from neuroimagedisttraining_tpu.models.tokens3d import (
     RMSNorm, apply_rope, rope_tables,
 )
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops import attention, moe
 
 Dtype = Any
 _scope = jax.named_scope
-_init = nn.initializers.normal(stddev=0.02)  # OLMoE's, every matrix
+INIT_STD = 0.02  # OLMoE's, every matrix
+_init = tokens3d.normal(INIT_STD)
 
 
 class Attention(nn.Module):
@@ -79,14 +81,10 @@ class Attention(nn.Module):
         cos, sin = rope_tables(T, d, self.rope_theta)
         q = apply_rope(heads(q), cos, sin)
         k = apply_rope(heads(k), cos, sin)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(d))
-        causal = jnp.tril(jnp.ones((T, T), bool))
-        scores = jnp.where(causal[None, None], scores, -jnp.inf)
-        p = jax.nn.softmax(scores, axis=-1).astype(self.dtype)
-        out = jnp.einsum("bhqk,bkhd->bqhd", p, heads(v))
-        return dense("o_proj")(out.reshape(B, T, H))
+        # one block of scores (640 tokens); the kernel for such heads is
+        # a measured change (ROADMAP D17)
+        return dense("o_proj")(attention.causal_attention(
+            q, k, heads(v), T, self.dtype, kernel=False))
 
 
 class SparseExperts(nn.Module):
@@ -101,24 +99,14 @@ class SparseExperts(nn.Module):
 
     @nn.compact
     def __call__(self, m):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         B, T, H = m.shape
         E, W = self.num_experts, self.expert_width
         x = m.reshape(B * T, H)
-        # the router's weight is named as its stage is
-        w_router = self.param(obs_names.SCOPE_ROUTER, _init, (H, E),
-                              jnp.float32)
+        probs, weights, experts = tokens3d.linear_router(
+            self, x, E, self.experts_per_token, INIT_STD)
         gate = self.param("gate", _init, (E, H, W), jnp.float32)
         up = self.param("up", _init, (E, H, W), jnp.float32)
         down = self.param("down", _init, (E, W, H), jnp.float32)
-        with _scope(obs_names.SCOPE_ROUTER):
-            # float32 whatever the compute dtype: bf16 logits flip
-            # near-tied experts, and the router is 0.2% of the FLOPs
-            logits = jnp.dot(x.astype(jnp.float32), w_router,  # nidt: allow[precision-upcast] -- see above
-                             precision=jax.lax.Precision.HIGHEST)
-            probs, weights, experts = moe.route(logits,
-                                                self.experts_per_token)
         with _scope(obs_names.SCOPE_DISPATCH):
             plan = moe.dispatch_plan(experts, E)
             xs = moe.gather_slots(x, plan)
@@ -181,8 +169,6 @@ class OLMoE3D(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         h = tokens3d.patch_embed(x, self.hidden_size, self.patch,
                                  self.rms_eps, self.dtype, _init)
         block = nn.remat(Block) if self.remat else Block

@@ -1,22 +1,31 @@
-"""What the token trunks share (models/olmoe3d.py, models/nemotronh3d.py,
-models/zaya3d.py, models/evabyte3d.py): how a decoder trunk meets a volume,
-and how its one logit is read.
+"""What the token trunks share (models/olmoe3d.py, nemotronh3d.py,
+zaya3d.py, evabyte3d.py, moonlight3d.py): how a decoder trunk meets a
+volume, how its one logit is read, and what more than one of them computes
+the same way between the two. No trunk file imports another: each brings
+its attention, its router and its ``Widths``, and takes the rest from here
+and from ``ops/`` (exact causal attention: ops/attention.py).
 
     x uint8 [B,121,145,121] -> (x - mean) / std of the volume, zero-pad
                                to a multiple of the patch
     tokens = patches(P^3, raster order D,H,W) @ W_pe + b_pe    (the ``stem``)
-    ... the trunk's layers ...
+    ... the trunk's layers ...                                 (``layer_stack``)
     logit = mean_t(RMSNorm(h)) @ W_head, in float32            (the ``head``)
 
 as vision-language models feed a decoder (``inputs_embeds``) and as
 embedding models read one (the mean of the final hidden states). Why the
 volume is standardised and the read-out pooled is in
 benchmark/configs/olmoe-abcd.json (``assumed``). The helpers create their
-flax modules in the calling ``@nn.compact`` method, under the names the
-trunks' parameter trees have always had (``patch_embed``, ``final_norm``,
-``head``). Beside them, what more than one trunk computes the same way:
-the rotary tables (for a whole head, or for its first part) and the
-causal depthwise convolution over the token axis.
+flax parameters and modules in the calling ``@nn.compact`` method, under
+the names the trunks' parameter trees have always had (``patch_embed``,
+``final_norm``, ``head``, ``layers_{i}``, ``up``, ``down``).
+
+A trunk that holds a share of its experts says so to core/trainer.py and
+engines/fedavg.py, which read by name: ``held_experts`` ``(first,
+count)``, over which the round driver counts ``rows_held``;
+``held_capacity_rows(batch_shape)``; ``aux_counters``, the integer
+entries of its auxiliary dict (``held_aux``), summed over a round's real
+steps. ``row_tokens(row_shape)`` is what a row of an evaluation batch
+costs, for a trunk whose rows cost more than the default allows.
 """
 
 from __future__ import annotations
@@ -29,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops import moe
 
 Dtype = Any
 _scope = jax.named_scope
@@ -95,22 +105,6 @@ def causal_depthwise_conv(x, kernel):
                for j in range(K))
 
 
-def causal_gq_attention(q, k, v, dtype):
-    """Causal softmax attention over grouped heads: ``q [B, T, Hkv, G,
-    d]`` (query head ``g * G + r`` reads key/value head ``g``), ``k, v
-    [B, T, Hkv, d]`` -> ``[B, T, Hkv * G * d]``; scores and softmax in
-    float32, scaled by ``d^-1/2``."""
-    B, T, Hkv, G, d = q.shape
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d))
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    p = jax.nn.softmax(scores, axis=-1).astype(dtype)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
-    return out.reshape(B, T, Hkv * G * d)
-
-
 def token_count(batch_shape, patch: int) -> int:
     """Tokens in a batch ``[B, D, H, W, ...]`` of volumes cut into
     ``patch``-cubes (each axis padded up to a multiple)."""
@@ -156,39 +150,123 @@ def pooled_logits(h, num_classes: int, eps: float, init,
                         name="head")(pooled)
 
 
-def blocked_causal_attention(q, k, v, block: int, dtype):
-    """Causal softmax attention, exact over the whole sequence, a block
-    of queries at a time: ``q, k [B, T, A, dk]``, ``v [B, T, A, dv]`` (the
-    score width and the value width apart) -> ``[B, T, A * dv]``; scores
-    and softmax in float32, scaled by ``dk^-1/2``.
+def row_tokens(row_shape, patch: int) -> int:
+    """Tokens of one volume ``[D, H, W, ...]`` (core/trainer.py
+    ``eval_batch_rows``: the cap under which ``eval_batches`` balances a
+    client's rows)."""
+    return token_count((1, *row_shape), patch)
 
-    Beside :func:`causal_gq_attention`, for sequences whose ``[T, T]``
-    scores of all heads do not fit: queries ``start .. start + block - 1``
-    read the keys ``0 .. start + block - 1`` and no later one, so no pair
-    above the diagonal's blocks is computed and a ``[B, A, block, start +
-    block]`` block of scores is the largest that is ever alive (a Python
-    loop over static extents; the last block is what is left). Each block
-    is rematerialised in the backward pass (``jax.checkpoint``): only
-    ``q``, ``k``, ``v`` are kept, not the causal triangle of
-    probabilities (1.5 GB a layer in float32 at 2 x 16 heads x 4,864
-    tokens). No key is dropped and nothing is summarised."""
-    T, dk = q.shape[1], q.shape[-1]
-    scale = 1.0 / math.sqrt(dk)
 
-    def rows_from(start):
-        def rows(qb, kb, vb):
-            s = jnp.einsum("bqad,bkad->baqk", qb, kb,
-                           preferred_element_type=jnp.float32) * scale
-            seen = (start + jnp.arange(qb.shape[1]))[:, None] \
-                >= jnp.arange(kb.shape[1])[None]
-            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-            return jnp.einsum("baqk,bkad->bqad", p.astype(dtype), vb)
-        return jax.checkpoint(rows)
+def normal(std: float):
+    return nn.initializers.normal(stddev=std)
 
-    outs = []
-    for start in range(0, T, block):
-        end = min(start + block, T)
-        outs.append(rows_from(start)(q[:, start:end], k[:, :end],
-                                     v[:, :end]))
-    out = jnp.concatenate(outs, axis=1)
-    return out.reshape(out.shape[0], T, -1)
+
+def relu2(x):
+    return jnp.square(nn.relu(x))
+
+
+def swiglu(width: int):
+    """``silu(gate) * up`` of a ``[rows, 2 * width]`` product whose first
+    ``width`` columns are the gate's."""
+    return lambda u: nn.silu(u[:, :width]) * u[:, width:]
+
+
+class GatedMLP(nn.Module):
+    """``(silu(x W_gate) * (x W_up)) W_down`` at ``width``, no bias."""
+
+    hidden: int
+    width: int
+    init_std: float
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(
+            n, use_bias=False, dtype=self.dtype, name=name,
+            kernel_init=normal(self.init_std))
+        gated = nn.silu(dense(self.width, "gate_proj")(x)) \
+            * dense(self.width, "up_proj")(x)
+        return dense(self.hidden, "down_proj")(gated)
+
+
+def linear_router(module, x, outputs: int, k: int, std: float, **route):
+    """``moe.route(x W_r, k, **route)`` -> ``(scores, weights, experts)``:
+    one matrix ``[d, outputs]``, named as its stage is, in float32
+    whatever the compute dtype (the architectures say so: bf16 logits
+    flip near-tied experts, and the router is 0.2% of the FLOPs)."""
+    w_router = module.param(obs_names.SCOPE_ROUTER, normal(std),
+                            (x.shape[-1], outputs), jnp.float32)
+    with _scope(obs_names.SCOPE_ROUTER):
+        logits = jnp.dot(x.astype(jnp.float32), w_router,  # nidt: allow[precision-upcast] -- see above
+                         precision=jax.lax.Precision.HIGHEST)
+        return moe.route(logits, k, **route)
+
+
+def held_expert_body(module, x, weights, experts, outputs: int, held,
+                     width: int, gated: bool, stds):
+    """An expert layer's routed part for the ``held = (first, count)``
+    experts this chip holds, after the trunk's own router over
+    ``outputs`` outputs: ``x [rows, d]``, ``weights, experts [rows, k]``
+    -> ``(y [rows, d], passed)`` (ops/moe.py ``held_expert_rows``).
+    Declares ``up [count, d, width]`` and ``down [count, width, d]`` in
+    ``module`` at the standard deviations ``stds``; ``gated``: gate and
+    up side by side in ``up [count, d, 2 * width]`` (:func:`swiglu`),
+    else :func:`relu2`."""
+    first, count = held
+    d = x.shape[-1]
+    up = module.param("up", normal(stds[0]),
+                      (count, d, 2 * width if gated else width), jnp.float32)
+    down = module.param("down", normal(stds[1]), (count, width, d),
+                        jnp.float32)
+    # no buffer while initialising: the trainer initialises eagerly, and
+    # an eager loop compiles anew on every call (as layer_stack's remat)
+    return moe.held_expert_rows(
+        x, weights, experts, up, down, outputs, first,
+        swiglu(width) if gated else relu2,
+        buffer=not module.is_initializing())
+
+
+def held_capacity_rows(batch_shape, patch: int, slots_per_token: int,
+                       held, outputs: int) -> int | None:
+    """The rows of the held runs' buffer for a batch ``[B, D, H, W, ...]``
+    of volumes (ops/moe.py ``held_capacity``), ``None`` where such a
+    batch is computed by the full sort alone."""
+    return moe.held_capacity(
+        slots_per_token * token_count(batch_shape, patch), held[1], outputs)
+
+
+def held_aux(loss, chosen, passed, outputs: int, **counters) -> dict:
+    """``loss`` as the trunk weighted it, ``expert_tokens`` the slots
+    routed to each of the ``outputs`` router outputs (``chosen``: ``[rows,
+    k]`` a layer), ``held_overflow_calls`` the layers whose held rows
+    ``passed`` the buffer in this call, and the trunk's own ``counters``."""
+    with _scope(obs_names.SCOPE_ROUTER):
+        return {
+            "loss": loss,
+            "expert_tokens": jnp.bincount(
+                jnp.concatenate(chosen).reshape(-1),
+                length=outputs).astype(jnp.int32),
+            "held_overflow_calls": sum(passed),
+            **counters,
+        }
+
+
+def layer_stack(module, layer_cls, layer_args, carry):
+    """Layer ``i`` is ``layer_cls(*layer_args[i], name="layers_{i}")``,
+    called on the tuple ``carry`` (the stream, and what else a layer
+    hands the next) and returning it anew, alone or followed by outputs
+    of its own -> ``(carry, outputs)``, a list over the layers for each
+    of those. Rematerialised where ``module.remat_layers`` says so, but
+    not while initialising: the trainer initialises eagerly, and a
+    rematerialised layer run eagerly compiles its body anew on every
+    call (four compilations inside the benchmark's measured window, my
+    chip run, PR 29); the parameter tree is the same."""
+    remat = module.remat_layers and not module.is_initializing()
+    layer = nn.remat(layer_cls) if remat else layer_cls
+    outputs = []
+    for i, args in enumerate(layer_args):
+        out = layer(*args, name=f"layers_{i}")(*carry)
+        out = out if isinstance(out, tuple) else (out,)
+        carry = out[:len(carry)]
+        outputs.append(out[len(carry):])
+    return carry, [list(column) for column in zip(*outputs)]

@@ -77,14 +77,12 @@ import jax.numpy as jnp
 from neuroimagedisttraining_tpu.models import tokens3d
 from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
 from neuroimagedisttraining_tpu.obs import names as obs_names
+from neuroimagedisttraining_tpu.ops import attention, moe
 
 Dtype = Any
 _scope = jax.named_scope
+_normal = tokens3d.normal
 HIGHEST = jax.lax.Precision.HIGHEST
-
-
-def _normal(std):
-    return nn.initializers.normal(stddev=std)
 
 
 def _conv_init(fan_in):
@@ -189,8 +187,11 @@ class CCAttention(nn.Module):
             q = q.reshape(B, T, Hkv, G, hd).astype(self.dtype)
             k = k.astype(self.dtype)
         with _scope(obs_names.SCOPE_ATTN):
+            # one block of scores (640 tokens); the kernel for grouped
+            # heads is a measured change (ROADMAP D17)
             return dense(d, "o_proj", self.out_std)(
-                tokens3d.causal_gq_attention(q, k, v, self.dtype))
+                attention.causal_attention(q, k, v, T, self.dtype,
+                                           kernel=False))
 
 
 class ZayaRouter(nn.Module):
@@ -205,8 +206,6 @@ class ZayaRouter(nn.Module):
 
     @nn.compact
     def __call__(self, a, r_prev):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         f32 = jnp.float32
         # orthogonal matrices, the first hidden layer at a gain that keeps
         # GELU in its linear range: every output's logit is then an
@@ -234,12 +233,6 @@ class ZayaRouter(nn.Module):
         return r, weights, experts
 
 
-def swiglu(width: int):
-    """``silu(gate) * up`` of a ``[rows, 2 * width]`` product whose first
-    ``width`` columns are the gate's."""
-    return lambda u: nn.silu(u[:, :width]) * u[:, width:]
-
-
 class HeldGatedExperts(nn.Module):
     """The expert sublayer's routed part for the experts this chip
     holds: ``(a [B, T, d], r_prev [B*T, R])`` -> ``(y [B, T, d], r,
@@ -253,24 +246,16 @@ class HeldGatedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, a, r_prev):
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         c = self.w
         B, T, d = a.shape
-        W = c.expert_width
-        first, count = c.held
         x = a.reshape(B * T, d)
         r, weights, experts = ZayaRouter(
             c.num_experts, c.router_hidden_size, c.rms_eps,
             name=obs_names.SCOPE_ROUTER)(x, r_prev)
-        up = self.param("up", _normal(0.02), (count, d, 2 * W), jnp.float32)
-        down = self.param("down", _normal(self.out_std), (count, W, d),
-                          jnp.float32)
-        # the skip output is an expert that no chip holds; the trainer
-        # initialises eagerly (Zaya3D.__call__)
-        y, passed = moe.held_expert_rows(
-            x, weights, experts, up, down, c.num_experts + 1, first,
-            swiglu(W), buffer=not self.is_initializing())
+        # the skip output is an expert that no chip holds
+        y, passed = tokens3d.held_expert_body(
+            self, x, weights, experts, c.num_experts + 1, c.held,
+            c.expert_width, gated=True, stds=(0.02, self.out_std))
         return y.reshape(B, T, d), r, experts, passed
 
 
@@ -324,14 +309,10 @@ class Zaya3D(nn.Module):
 
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", *aux_counters})
-    #: the integer entries of the auxiliary dict, summed over a round's
-    #: real steps into round outputs of these names (core/trainer.py)
     aux_counters = ("expert_tokens", "held_overflow_calls")
 
     @property
     def held_experts(self) -> tuple[int, int]:
-        """``(first, count)`` of the experts whose rows are computed
-        here: the round driver counts ``rows_held`` over them."""
         return self.widths.held
 
     @property
@@ -341,42 +322,20 @@ class Zaya3D(nn.Module):
         return self.widths.num_experts
 
     def held_capacity_rows(self, batch_shape) -> int | None:
-        """The rows of the held runs' buffer for a batch ``[B, D, H, W,
-        ...]`` of volumes (ops/moe.py ``held_capacity``), ``None`` where
-        such a batch is computed by the full sort alone."""
-        from neuroimagedisttraining_tpu.ops import moe  # ops imports models
-
         c = self.widths
-        return moe.held_capacity(tokens3d.token_count(batch_shape, c.patch),
-                                 c.held[1], c.num_experts + 1)
+        return tokens3d.held_capacity_rows(batch_shape, c.patch, 1, c.held,
+                                           c.num_experts + 1)
 
     @nn.compact
     def __call__(self, x, train: bool = False):
         c = self.widths
         h = tokens3d.patch_embed(x, c.hidden_size, c.patch, c.rms_eps,
                                  self.dtype, _normal(0.02))
-        # not while initialising: the trainer initialises eagerly, and a
-        # rematerialised layer run eagerly compiles its body anew on
-        # every call (models/nemotronh3d.py); the parameter tree is the
-        # same
-        remat = self.remat_layers and not self.is_initializing()
-        layer = nn.remat(Layer) if remat else Layer
         r = jnp.zeros((h.shape[0] * h.shape[1], c.router_hidden_size),
                       jnp.float32)
-        chosen, passed = [], []
-        for i in range(c.layers):
-            h, r, experts, over = layer(c, self.dtype,
-                                        name=f"layers_{i}")(h, r)
-            chosen.append(experts)
-            passed.append(over)
+        (h, r), (chosen, passed) = tokens3d.layer_stack(
+            self, Layer, [(c, self.dtype)] * c.layers, (h, r))
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps,
                                         _normal(0.02))
-        with _scope(obs_names.SCOPE_ROUTER):
-            aux = {
-                "loss": jnp.zeros((), jnp.float32),
-                "expert_tokens": jnp.bincount(
-                    jnp.concatenate(chosen).reshape(-1),
-                    length=c.num_experts + 1).astype(jnp.int32),
-                "held_overflow_calls": sum(passed),
-            }
-        return logits, aux
+        return logits, tokens3d.held_aux(jnp.zeros((), jnp.float32), chosen,
+                                         passed, c.num_experts + 1)

@@ -1,1 +1,2 @@
-from neuroimagedisttraining_tpu.ops import masks, snip, topk, flops  # noqa: F401
+"""Kernels and array routines: a leaf. Nothing here imports ``models``,
+``core`` or ``engines``; every user imports the submodule it needs."""

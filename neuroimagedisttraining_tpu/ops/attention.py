@@ -10,19 +10,25 @@ against ``ks [B, T, 1, ds]``: Moonlight's rotary key,
 models/moonlight3d.py). Exact over the whole sequence: no key is dropped
 and nothing is summarised.
 
-What runs where. :func:`causal_attention` is the one entry. On a TPU, for
-shapes :func:`kernel_tiles` passes (the published Moonlight layer does), it
-is :func:`attention_kernel`: one Pallas kernel forward and one backward, in
-which a ``[block, block]`` tile of scores is made, exponentiated and
-multiplied into the values inside vector memory. The plain form
-(models/tokens3d.py ``blocked_causal_attention``, a block of queries at a
-time under ``jax.checkpoint``) writes every block's float32 scores to HBM
-and reads them back, in the forward, the layer's rematerialised forward, the
-block's own and twice in the backward: 254.9 ms of a 520 ms step at 8.7% of
-the roofline (PERF.md, PR 40), where the kernels take 95.5 of 360 at 23.2%
-(PR 42). Off the TPU (the CPU tests), for other
-shapes and for an eager caller it is that plain form on the concatenated
-``[q, qs]`` and ``[k, ks repeated a head]``, with autodiff's backward.
+What runs where. :func:`causal_attention` is the one entry, for every
+trunk's exact causal attention (models/olmoe3d.py, nemotronh3d.py,
+zaya3d.py, moonlight3d.py), with the heads alone (``q [B, T, A, dk]``) or
+grouped over their key/value heads (``q [B, T, Hkv, G, dk]``: query head
+``g * G + r`` reads key/value head ``g``). On a TPU, for shapes
+:func:`kernel_tiles` passes (the published Moonlight layer does) and a
+caller that does not say ``kernel=False``, it is :func:`attention_kernel`:
+one Pallas kernel forward and one backward, in which a ``[block, block]``
+tile of scores is made, exponentiated and multiplied into the values
+inside vector memory. Everywhere else it is one of the two plain forms
+beside it here: :func:`causal_gq_attention`, one ``[T, T]`` block of
+scores, where the sequence is no longer than ``block``; beyond,
+:func:`blocked_causal_attention`, a block of queries at a time under
+``jax.checkpoint``, which writes every block's float32 scores to HBM and
+reads them back, in the forward, the layer's rematerialised forward, the
+block's own and twice in the backward: 254.9 ms of a 520 ms step at 8.7%
+of the roofline (PERF.md, PR 40), where the kernels take 95.5 of 360 at
+23.2% (PR 42). The plain forms take the shared part concatenated (``[q,
+qs]`` and ``[k, ks repeated a head]``) and autodiff's backward.
 
 **Forward** (grid: volume, head, block of queries). A head's keys and
 values wait in vector memory whole (3.7 MB at 4,864 tokens, fetched once a
@@ -68,7 +74,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from neuroimagedisttraining_tpu.models import tokens3d
 from neuroimagedisttraining_tpu.ops.ssd import _NT, _dot  # a @ b, a @ b.T
 
 _LANES = 128
@@ -112,23 +117,99 @@ def takes_kernel(T: int, dk: int, ds: int, dv: int, kernel: bool) -> bool:
 
 def causal_attention(q, k, v, block: int, dtype, *, q_shared=None,
                      k_shared=None, kernel: bool = True):
-    """``q, k [B, T, A, dk]``, ``v [B, T, A, dv]`` and optionally
-    ``q_shared [B, T, A, ds]`` with ``k_shared [B, T, 1, ds]`` -> ``[B, T,
-    A * dv]``.
+    """``q [B, T, A, dk]`` or grouped ``[B, T, Hkv, G, dk]``, ``k [B, T,
+    A | Hkv, dk]``, ``v [B, T, A | Hkv, dv]`` and optionally ``q_shared
+    [B, T, A, ds]`` with ``k_shared [B, T, 1, ds]`` -> ``[B, T, heads *
+    dv]``, the probabilities cast to ``dtype`` to meet ``v``.
 
-    On a TPU, for shapes :func:`kernel_tiles` passes, this is
-    :func:`attention_kernel`; everywhere else, and for a caller that says
-    ``kernel=False`` (an EAGER call: a kernel is compiled anew on every
-    one), ``tokens3d.blocked_causal_attention`` with ``block`` queries a
-    block, its probabilities cast to ``dtype``."""
+    On a TPU, for ungrouped shapes :func:`kernel_tiles` passes, this is
+    :func:`attention_kernel`, unless the caller says ``kernel=False`` (an
+    EAGER call: a kernel is compiled anew on every one; a grouped caller:
+    the kernels' index maps hold no head map yet, ROADMAP D17). Everywhere
+    else one block of scores where ``T <= block``, and ``block`` queries a
+    block beyond."""
     ds = 0 if q_shared is None else q_shared.shape[-1]
-    if takes_kernel(q.shape[1], q.shape[-1], ds, v.shape[-1], kernel):
+    if q.ndim == 4 and takes_kernel(q.shape[1], q.shape[-1], ds,
+                                    v.shape[-1], kernel):
         return attention_kernel(q, k, v, q_shared, k_shared)
     if ds:
         q = jnp.concatenate([q, q_shared], axis=-1)
         k = jnp.concatenate(
             [k, jnp.broadcast_to(k_shared, k.shape[:-1] + (ds,))], axis=-1)
-    return tokens3d.blocked_causal_attention(q, k, v, block, dtype)
+    if q.shape[1] <= block:
+        return causal_gq_attention(q, k, v, dtype)
+    return blocked_causal_attention(q, k, v, block, dtype)
+
+
+# ---------- the plain forms ----------
+
+
+def _spellings(q):
+    """The two products' einsums, ``(scores, values)``: heads ``h`` alone
+    for ``q [B, T, A, d]``, grouped ``gr`` for ``q [B, T, Hkv, G, d]``
+    (the letters the trunks' own lines had: a product's spelling is its
+    name in a trace)."""
+    h = "gr" if q.ndim == 5 else "h"
+    return f"bq{h}d,bk{h[0]}d->b{h}qk", f"b{h}qk,bk{h[0]}d->bq{h}d"
+
+
+def causal_gq_attention(q, k, v, dtype):
+    """Causal softmax attention as ONE block of scores: ``q [B, T, A,
+    d]``, or over grouped heads ``[B, T, Hkv, G, d]`` (query head ``g * G
+    + r`` reads key/value head ``g``), ``k [B, T, A | Hkv, d]``, ``v [B,
+    T, A | Hkv, dv]`` -> ``[B, T, heads * dv]``; scores and softmax in
+    float32, scaled by ``d^-1/2``."""
+    B, T, d = q.shape[0], q.shape[1], q.shape[-1]
+    to_scores, to_values = _spellings(q)
+    scores = jnp.einsum(to_scores, q, k, preferred_element_type=jnp.float32)
+    scores = scores / jnp.sqrt(jnp.float32(d))
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    if q.ndim == 4:
+        # OLMoE's spelling: without the reshape its step is the same
+        # program numbered otherwise, and tests/test_tpu_compile.py
+        # PARENT_STEPS holds the numbers too (ROADMAP D17)
+        causal = causal[None, None]
+    scores = jnp.where(causal, scores, -jnp.inf)
+    p = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum(to_values, p, v).reshape(B, T, -1)
+
+
+def blocked_causal_attention(q, k, v, block: int, dtype):
+    """Causal softmax attention, exact over the whole sequence, a block
+    of queries at a time: the operands of :func:`causal_gq_attention`
+    (the score width and the value width apart) -> ``[B, T, heads *
+    dv]``; scores and softmax in float32, scaled by ``dk^-1/2``.
+
+    For sequences whose ``[T, T]`` scores of all heads do not fit: queries
+    ``start .. start + block - 1`` read the keys ``0 .. start + block - 1``
+    and no later one, so no pair above the diagonal's blocks is computed
+    and a ``[B, A, block, start + block]`` block of scores is the largest
+    that is ever alive (a Python loop over static extents; the last block
+    is what is left). Each block is rematerialised in the backward pass
+    (``jax.checkpoint``): only ``q``, ``k``, ``v`` are kept, not the
+    causal triangle of probabilities (1.5 GB a layer in float32 at 2 x 16
+    heads x 4,864 tokens). No key is dropped and nothing is summarised."""
+    T, dk = q.shape[1], q.shape[-1]
+    scale = 1.0 / math.sqrt(dk)
+    to_scores, to_values = _spellings(q)
+
+    def rows_from(start):
+        def rows(qb, kb, vb):
+            s = jnp.einsum(to_scores, qb, kb,
+                           preferred_element_type=jnp.float32) * scale
+            seen = (start + jnp.arange(qb.shape[1]))[:, None] \
+                >= jnp.arange(kb.shape[1])[None]
+            p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+            return jnp.einsum(to_values, p.astype(dtype), vb)
+        return jax.checkpoint(rows)
+
+    outs = []
+    for start in range(0, T, block):
+        end = min(start + block, T)
+        outs.append(rows_from(start)(q[:, start:end], k[:, :end],
+                                     v[:, :end]))
+    out = jnp.concatenate(outs, axis=1)
+    return out.reshape(out.shape[0], T, -1)
 
 
 # ---------- the kernels ----------

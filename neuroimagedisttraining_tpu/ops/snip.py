@@ -20,13 +20,12 @@ all-reduce.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import TYPE_CHECKING, Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from neuroimagedisttraining_tpu.core.trainer import ClientState, LocalTrainer
 from neuroimagedisttraining_tpu.obs import names as obs_names
 from neuroimagedisttraining_tpu.ops.masks import is_weight_kernel
 from neuroimagedisttraining_tpu.ops.topk import kth_largest
@@ -34,6 +33,11 @@ from neuroimagedisttraining_tpu.utils.pytree import (
     tree_by_name as _get,
     tree_map_with_path_names,
 )
+
+if TYPE_CHECKING:  # annotations only: ops/ imports nothing above it
+    from neuroimagedisttraining_tpu.core.trainer import (
+        ClientState, LocalTrainer,
+    )
 
 PyTree = Any
 

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from neuroimagedisttraining_tpu.models import tokens3d
 from neuroimagedisttraining_tpu.ops import attention
 
 DN, DR, DV, BLOCK = 128, 64, 128, 128
@@ -71,7 +70,7 @@ def _joined(qn, qr, kn, kr):
 
 def xla_form(qn, qr, kn, kr, v, dtype=jnp.float32):
     q, k = _joined(qn, qr, kn, kr)
-    return tokens3d.blocked_causal_attention(q, k, v, BLOCK, dtype)
+    return attention.blocked_causal_attention(q, k, v, BLOCK, dtype)
 
 
 def kernel(qn, qr, kn, kr, v):
@@ -139,7 +138,7 @@ def test_without_a_shared_part_it_is_plain_causal_attention():
     (got, g_got), (want, g_want) = (
         f(lambda q, k, v: attention.attention_kernel(q, k, v,
                                                      interpret=True)),
-        f(lambda q, k, v: tokens3d.blocked_causal_attention(
+        f(lambda q, k, v: attention.blocked_causal_attention(
             q, k, v, BLOCK, jnp.float32)))
     np.testing.assert_allclose(got, want, rtol=TOL)
     for g, h in zip(g_got, g_want):
@@ -227,7 +226,7 @@ def test_one_shared_key_serves_every_head(t):
 
 def xla_form_own(qn, qr, kn, kr_own, v):
     """The XLA form with a rotary key a head, ``[B, T, A, dr]``."""
-    return tokens3d.blocked_causal_attention(
+    return attention.blocked_causal_attention(
         jnp.concatenate([qn, qr], -1), jnp.concatenate([kn, kr_own], -1),
         v, BLOCK, jnp.float32)
 

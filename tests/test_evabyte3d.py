@@ -23,11 +23,12 @@ import pytest
 
 from neuroimagedisttraining_tpu.config import OptimConfig
 from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
-from neuroimagedisttraining_tpu.models import create_model, tokens3d
+from neuroimagedisttraining_tpu.models import create_model
 from neuroimagedisttraining_tpu.models.evabyte3d import (
     EvaAttention, EvaByte3D, GatedMLP, Widths, eva_attention,
 )
 from neuroimagedisttraining_tpu.models.tokens3d import RMSNorm
+from neuroimagedisttraining_tpu.ops import attention
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEADS, HD, WINDOW, CHUNK = 4, 16, 32, 4
@@ -170,7 +171,8 @@ def test_rematerialised_layers_give_the_same_tree_logits_and_gradients():
 def test_a_sequence_inside_one_window_is_causal_attention(tokens):
     q, k, v, phi, mu = _qkv(2, tokens)
     got = eva_attention(q, k, v, phi, mu, WINDOW, CHUNK, jnp.float32)
-    want = tokens3d.causal_gq_attention(q[:, :, :, None], k, v, jnp.float32)
+    want = attention.causal_gq_attention(q[:, :, :, None], k, v,
+                                         jnp.float32)
     _close(got, want, rtol=1e-5, atol=1e-6)
 
 

@@ -288,3 +288,87 @@ def test_lenet5_flatten_matches_caffe_5x5_to_4x4():
     x = jnp.zeros((1, 28, 28, 1))
     variables, _, _ = _init_and_apply(model, x)
     assert variables["params"]["fc3"]["kernel"].shape[0] == 50 * 4 * 4
+
+
+# ---------- the arrows between ops/, models/ and core/ (PR 43) ----------
+
+_PKG = "neuroimagedisttraining_tpu"
+
+
+def _imports(path):
+    """``(module, inside a function, under TYPE_CHECKING)`` for every
+    import statement of a file, from its syntax alone (nothing is
+    imported); ``from pkg.a import b`` counts as ``pkg.a.b`` too."""
+    import ast
+
+    found = []
+
+    def walk(node, local, typing_only):
+        for child in ast.iter_child_nodes(node):
+            if isinstance(child, ast.Import):
+                found.extend((a.name, local, typing_only)
+                             for a in child.names)
+            elif isinstance(child, ast.ImportFrom):
+                assert child.level == 0, f"{path}: a relative import"
+                found.append((child.module, local, typing_only))
+                found.extend((f"{child.module}.{a.name}", local, typing_only)
+                             for a in child.names)
+            elif isinstance(child, ast.If) and "TYPE_CHECKING" in ast.dump(
+                    child.test):
+                walk(child, local, True)
+            else:
+                walk(child, local or isinstance(
+                    child, (ast.FunctionDef, ast.AsyncFunctionDef,
+                            ast.Lambda)), typing_only)
+
+    with open(path) as f:
+        walk(ast.parse(f.read()), False, False)
+    return found
+
+
+def import_violations(package_dir):
+    """What points the wrong way under ``package_dir``, a rule each:
+    ``ops/`` is a leaf, no trunk file imports another, and no model
+    file hides an ``ops`` import inside a function."""
+    import glob
+    import os
+
+    bad = {"ops_is_a_leaf": [], "no_model_imports_a_model": [],
+           "no_local_ops_import_in_models": []}
+    files = lambda sub: sorted(glob.glob(os.path.join(package_dir, sub,
+                                                      "*.py")))
+    for path in files("ops"):
+        for module, _, typing_only in _imports(path):
+            if not typing_only and module.startswith(
+                    (f"{_PKG}.models", f"{_PKG}.core", f"{_PKG}.engines")):
+                bad["ops_is_a_leaf"].append((path, module))
+    trunks = {os.path.basename(p)[:-3] for p in files("models")
+              if p.endswith("3d.py")} - {"tokens3d"}
+    for path in files("models"):
+        own = os.path.basename(path)[:-3]
+        for module, local, _ in _imports(path):
+            parts = module.split(".")
+            if own != "__init__" and parts[:2] == [_PKG, "models"] \
+                    and len(parts) > 2 and parts[2] in trunks - {own}:
+                bad["no_model_imports_a_model"].append((path, module))
+            if local and parts[:2] == [_PKG, "ops"]:
+                bad["no_local_ops_import_in_models"].append((path, module))
+    return bad
+
+
+@pytest.mark.parametrize("rule", ["ops_is_a_leaf",
+                                  "no_model_imports_a_model",
+                                  "no_local_ops_import_in_models"])
+def test_imports_point_one_way(rule):
+    """ARCHITECTURE.md's layer map, held: ``ops`` below ``models`` below
+    ``core``. At PR 42 this found ``ops/snip.py`` -> ``core.trainer``,
+    ``ops/attention.py`` -> ``models.tokens3d``, ``moonlight3d`` ->
+    ``evabyte3d`` and ``zaya3d``, and fourteen ``ops`` imports inside
+    functions of five model files (eleven of them marked ``# ops imports
+    models``)."""
+    import os
+
+    import neuroimagedisttraining_tpu
+
+    package_dir = os.path.dirname(neuroimagedisttraining_tpu.__file__)
+    assert import_violations(package_dir)[rule] == []

@@ -27,7 +27,7 @@ from neuroimagedisttraining_tpu.models import create_model, tokens3d
 from neuroimagedisttraining_tpu.models.moonlight3d import (
     HeldGatedExperts, LatentAttention, Layer, Moonlight3D, Widths, mla_core,
 )
-from neuroimagedisttraining_tpu.ops import moe
+from neuroimagedisttraining_tpu.ops import attention, moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEADS, DN, DR, DV, E, K, BLOCK = 4, 16, 8, 16, 16, 4, 32
@@ -274,19 +274,24 @@ def test_blocked_attention_equals_one_dense_masked_block(tokens):
     q = jnp.concatenate([qn, qr], -1)
     k = jnp.concatenate([kn, jnp.broadcast_to(kr, qr.shape)], -1)
     assert q.shape[-1] == 24 and v.shape[-1] == 16
-    blocked = lambda *a: tokens3d.blocked_causal_attention(
+    blocked = lambda *a: attention.blocked_causal_attention(
         *a, BLOCK, jnp.float32)
     with jax.default_matmul_precision("highest"):
         got = jax.jit(blocked)(q, k, v)
         core = jax.jit(lambda *a: mla_core(*a, BLOCK, jnp.float32)[0])(
             qn, qr, kn, kr, v)
         want = _dense_masked(q, k, v)
+        # the entry's plain form: one block of scores up to BLOCK tokens
+        entry = got if tokens > BLOCK else jax.jit(
+            attention.causal_gq_attention, static_argnums=3)(
+                q, k, v, jnp.float32)
         f = lambda fn: jax.jit(jax.grad(
             lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=(0, 1, 2)))(q, k, v)
         g_got, g_want = f(blocked), f(_dense_masked)
     assert got.shape == (2, tokens, HEADS * DV)
     _close(got, want)
-    np.testing.assert_array_equal(core, got)
+    _close(entry, want)
+    np.testing.assert_array_equal(core, entry)
     for g, h in zip(g_got, g_want):
         _close(g, h, rtol=F32_RTOL * 10,
                atol=F32_ATOL * float(jnp.max(jnp.abs(h))) * 20)
@@ -519,7 +524,7 @@ def test_folded_train_logs_the_attention_kernels_calls(tmp_path, monkeypatch,
             attention, "attention_kernel",
             lambda q, k, v, qs, ks: eager.append(
                 not isinstance(q, jax.core.Tracer))
-            or tokens3d.blocked_causal_attention(
+            or attention.blocked_causal_attention(
                 jnp.concatenate([q, qs], -1),
                 jnp.concatenate([k, jnp.broadcast_to(ks, qs.shape)], -1),
                 v, BLOCK, q.dtype))

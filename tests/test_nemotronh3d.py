@@ -24,8 +24,9 @@ from neuroimagedisttraining_tpu.config import OptimConfig
 from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
 from neuroimagedisttraining_tpu.models import create_model, primary_logits
 from neuroimagedisttraining_tpu.models.nemotronh3d import (
-    PATTERN, HeldExperts, NemotronH3D, Widths, relu2,
+    PATTERN, HeldExperts, NemotronH3D, Widths,
 )
+from neuroimagedisttraining_tpu.models.tokens3d import relu2
 from neuroimagedisttraining_tpu.ops import moe
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

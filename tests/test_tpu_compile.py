@@ -264,7 +264,7 @@ def test_held_runs_buffer_compiles_at_the_published_widths(chip,
     no array
     of all 61,440 rows anywhere, and a third of the full sort's
     temporaries."""
-    from neuroimagedisttraining_tpu.models.nemotronh3d import relu2
+    from neuroimagedisttraining_tpu.models.tokens3d import relu2
     from neuroimagedisttraining_tpu.ops import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -312,7 +312,7 @@ def test_which_paths_a_held_layer_traces_follows_shapes(monkeypatch, rows,
     """Where every expert is held, or the buffer would be no smaller than
     the sort, the layer's program holds no loop over windows: the full
     sort is what is traced, and ``olmoe3d``'s step stays what it was."""
-    from neuroimagedisttraining_tpu.models.nemotronh3d import relu2
+    from neuroimagedisttraining_tpu.models.tokens3d import relu2
     from neuroimagedisttraining_tpu.ops import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -433,14 +433,30 @@ PARENT_STEPS = {
     "nemotronh3d": (
         20542, 44,
         "d07dfedba13a53cb752af3189894fb8c6fec08fbe96fda615febbd4179073996"),
+    # PR 43 (what five trunks copied lives once in models/tokens3d.py and
+    # ops/attention.py): the three other trunks as PR 42 (79b67d3)
+    # compiles them, taken from a checkout of that commit before any edit
+    "zaya3d": (
+        39862, 50,
+        "51f60dd88521497d6127654ffdabc170c0146b4d9da4af827d12f41d2d293e74"),
+    "evabyte3d": (
+        12945, 0,
+        "6891b4bc7ec36f48e9f1aec2d8f9e7e71c7d82bf8cbbfea97a64566aa03b98af"),
+    "moonlight3d": (
+        26621, 58,
+        "9882b303ac59b2eb6052a6c155dccd607c22e0ff49d39470282b937a393cea37"),
 }
+#: the cells' batch where it is not 16 (``_STEPS`` holds one step a name:
+#: the tests below compile these two at 2 as well)
+STEP_BATCH = {"evabyte3d": 2, "moonlight3d": 2}
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_STEPS))
 def test_training_step_compiles_to_the_parents_program(chip, monkeypatch,
                                                        name):
-    assert _program_of(_compiled_step(chip, monkeypatch, name)) == \
-        PARENT_STEPS[name]
+    compiled = _compiled_step(chip, monkeypatch, name,
+                              batch=STEP_BATCH.get(name, 16))
+    assert _program_of(compiled) == PARENT_STEPS[name]
 
 
 def test_nemotronh3d_step_holds_no_decay_matrix_and_fits(chip, monkeypatch):
