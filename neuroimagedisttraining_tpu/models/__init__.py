@@ -134,6 +134,15 @@ def create_model(name: str, num_classes: int = 1, dtype=jnp.float32,
         from neuroimagedisttraining_tpu.models.moonlight3d import Moonlight3D
 
         return Moonlight3D(num_classes=num_classes, dtype=dtype)
+    if name == "trinity3d":
+        # added here, not ported (models/trinity3d.py): Trinity-Mini's
+        # leading dense layer and four of its expert layers at the
+        # published widths (three of sliding-window attention, one of
+        # full), experts 0-15 of each layer's 128 held; rematerialised by
+        # its own declaration, like nemotronh3d
+        from neuroimagedisttraining_tpu.models.trinity3d import Trinity3D
+
+        return Trinity3D(num_classes=num_classes, dtype=dtype)
     if name in ("resnet18", "customized_resnet18"):
         return customized_resnet18(num_classes=num_classes, dtype=dtype)
     if name == "original_resnet18":

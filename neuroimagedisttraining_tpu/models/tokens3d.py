@@ -1,5 +1,5 @@
 """What the token trunks share (models/olmoe3d.py, nemotronh3d.py,
-zaya3d.py, evabyte3d.py, moonlight3d.py): how a decoder trunk meets a
+zaya3d.py, evabyte3d.py, moonlight3d.py, trinity3d.py): how a decoder trunk meets a
 volume, how its one logit is read, and what more than one of them computes
 the same way between the two. No trunk file imports another: each brings
 its attention, its router and its ``Widths``, and takes the rest from here

@@ -286,14 +286,26 @@ SCOPE_MLP = "mlp"                # gate, up, SiLU, down
 SCOPE_MLA_LATENT = "mla_latent"  # W_dkv, the latent's norm, the shared key's rotary, W_ukv
 SCOPE_MLA_CORE = "mla_core"      # scores, softmax and values of every query block
 
-#: the model scopes of the five tables above (disjoint from DEVICE_SCOPES)
+# attention of two kinds in one trunk, with per-head QK norms and a gated
+# output (models/trinity3d.py, PR 44; benchmark/metrics/trinity_scopes.json).
+# All four lie inside SCOPE_ATTN, which keeps W_q, W_k, W_v, the rotary
+# embedding and W_o. The leading dense layer's feed-forward reuses
+# SCOPE_MLP, the shared expert SCOPE_SHARED_EXPERT, the held experts the
+# four expert scopes above.
+SCOPE_SWA_CORE = "swa_core"    # scores, softmax and values of a sliding-window layer
+SCOPE_FULL_CORE = "full_core"  # the same of a full-attention layer
+SCOPE_QK_NORM = "qk_norm"      # the per-head RMS norms of q and k
+SCOPE_ATTN_GATE = "attn_gate"  # W_g, the sigmoid and its product with the heads' output
+
+#: the model scopes of the six tables above (disjoint from DEVICE_SCOPES)
 MODEL_SCOPES: frozenset[str] = frozenset(
     (SCOPE_ATTN, SCOPE_ROUTER, SCOPE_DISPATCH, SCOPE_EXPERTS,
      SCOPE_COMBINE, SCOPE_SSM_IN_PROJ, SCOPE_SSM_CONV, SCOPE_SSD,
      SCOPE_SSM_GATE_NORM, SCOPE_SSM_OUT_PROJ, SCOPE_SHARED_EXPERT,
      SCOPE_CCA_PROJ, SCOPE_CCA_CONV, SCOPE_CCA_MIX,
      SCOPE_EVA_POOL, SCOPE_EVA_LOCAL, SCOPE_EVA_REMOTE, SCOPE_MLP,
-     SCOPE_MLA_LATENT, SCOPE_MLA_CORE))
+     SCOPE_MLA_LATENT, SCOPE_MLA_CORE,
+     SCOPE_SWA_CORE, SCOPE_FULL_CORE, SCOPE_QK_NORM, SCOPE_ATTN_GATE))
 
 # A layout marker, not a stage: ops/stemconv.py's stem block names the ops
 # of its batched rule (the client-merged lanes a client-axis ``vmap``

@@ -145,8 +145,25 @@ def _small_moonlight3d(name, num_classes=1):
         experts_per_token=4, expert_width=16, block=16, patch=4))
 
 
+def _small_trinity3d(name, num_classes=1):
+    """Trinity-Mini's layers at a small size (models/trinity3d.py): 12 x
+    14 x 12 volumes in patches of 4 are 36 tokens, two windows of 16 and
+    4 more, two query blocks of 16 and one of 4; a dense sliding layer,
+    then a full and a sliding expert layer that hold 4 of 16 experts."""
+    from neuroimagedisttraining_tpu.models.trinity3d import (
+        FULL, SLIDING, Trinity3D, Widths,
+    )
+
+    return Trinity3D(num_classes=num_classes, widths=Widths(
+        layer_types=(SLIDING, FULL, SLIDING), dense_layers=1, hidden_size=32,
+        heads=4, kv_heads=2, head_dim=8, sliding_window=16,
+        intermediate_size=48, num_experts=16, held=(0, 4),
+        experts_per_token=4, expert_width=16, block=16, patch=4))
+
+
 SMALL_MODELS = {"evabyte3d_small": _small_evabyte3d,
-                "moonlight3d_small": _small_moonlight3d}
+                "moonlight3d_small": _small_moonlight3d,
+                "trinity3d_small": _small_trinity3d}
 
 
 @pytest.mark.parametrize("model", ["3dcnn_tiny", *sorted(SMALL_MODELS)])

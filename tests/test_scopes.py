@@ -324,23 +324,26 @@ def test_names_table_is_complete():
 def test_model_scopes_table():
     """MODEL_SCOPES (the stages of the sparse-expert block, PR 25, of the
     hybrid trunk, PR 29, of compressed convolutional attention, PR 31, of
-    EVA attention with its dense feed-forward, PR 38, and of latent
-    attention of the reconstructing kind, PR 40)
+    EVA attention with its dense feed-forward, PR 38, of latent
+    attention of the reconstructing kind, PR 40, and of gated attention of
+    two kinds under QK norms, PR 44)
     is a second table, disjoint from DEVICE_SCOPES (which
     benchmark/scopes.json pins); every constant is used at least once in
-    models/, none is spelled as a literal there, and the benchmark's five
+    models/, none is spelled as a literal there, and the benchmark's six
     rules files name each between them: the OLMoE block's five in
     olmoe_scopes.json, the hybrid trunk's eleven in nemotronh_scopes.json,
     ZAYA1's layer's eight (three of them new) in zaya_scopes.json,
     EvaByte's layer's five (four of them new) in evabyte_scopes.json,
-    Moonlight's layer's nine (two of them new) in moonlight_scopes.json."""
+    Moonlight's layer's nine (two of them new) in moonlight_scopes.json,
+    Trinity-Mini's layers' eleven (four of them new) in
+    trinity_scopes.json."""
     import glob
     import json
     import os
 
     consts = {k: v for k, v in vars(names).items()
               if k.startswith("SCOPE_") and v in names.MODEL_SCOPES}
-    assert len(consts) == len(names.MODEL_SCOPES) == 20
+    assert len(consts) == len(names.MODEL_SCOPES) == 24
     assert not names.MODEL_SCOPES & names.DEVICE_SCOPES
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sources = {p: open(p).read() for p in glob.glob(os.path.join(
@@ -354,7 +357,8 @@ def test_model_scopes_table():
                             ("nemotronh_scopes.json", "trunk"),
                             ("zaya_scopes.json", "layer"),
                             ("evabyte_scopes.json", "layer"),
-                            ("moonlight_scopes.json", "layer")):
+                            ("moonlight_scopes.json", "layer"),
+                            ("trinity_scopes.json", "layer")):
         rules = json.load(open(os.path.join(
             root, "benchmark", "metrics", file)))
         named[file] = {s for k, v in rules["scope_names"].items()
@@ -373,6 +377,10 @@ def test_model_scopes_table():
     mla = {names.SCOPE_MLA_LATENT, names.SCOPE_MLA_CORE}
     assert named["moonlight_scopes.json"] == expert_layer | mla | {
         names.SCOPE_ATTN, names.SCOPE_MLP, names.SCOPE_SHARED_EXPERT}
+    gated = {names.SCOPE_SWA_CORE, names.SCOPE_FULL_CORE,
+             names.SCOPE_QK_NORM, names.SCOPE_ATTN_GATE}
+    assert named["trinity_scopes.json"] == expert_layer | gated | {
+        names.SCOPE_ATTN, names.SCOPE_MLP, names.SCOPE_SHARED_EXPERT}
     assert named["nemotronh_scopes.json"] == \
-        set(names.MODEL_SCOPES) - cca - eva - mla
+        set(names.MODEL_SCOPES) - cca - eva - mla - gated
     assert set().union(*named.values()) == set(names.MODEL_SCOPES)

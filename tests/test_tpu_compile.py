@@ -400,6 +400,25 @@ def _compiled_step(chip, monkeypatch, name, batch=16, clients=0):
     return _STEPS[name, clients]
 
 
+def _traced_step(monkeypatch, name, batch=2):
+    """``--model name``'s training step as a TPU traces it (a jaxpr: the
+    bodies of its ``pallas_call``s are equations there, where the compiled
+    text holds them serialized)."""
+    from neuroimagedisttraining_tpu.config import OptimConfig
+    from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
+
+    trainer = LocalTrainer(
+        create_model(name, 1, dtype=jnp.bfloat16),
+        OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
+                    grad_clip=10.0, batch_size=batch), 1)
+    state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
+                           jnp.zeros((1,) + SHAPE, jnp.float32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jax.make_jaxpr(trainer.loss_and_grad)(
+        state, jax.ShapeDtypeStruct((batch,) + SHAPE, jnp.uint8),
+        jax.ShapeDtypeStruct((batch,), jnp.int32))
+
+
 def _program_of(compiled) -> tuple[int, int, str]:
     """``(instructions, Mosaic kernels, sha256)`` of a compiled program's
     instructions in order, without what a checkout's path or a line
@@ -445,10 +464,16 @@ PARENT_STEPS = {
     "moonlight3d": (
         26621, 58,
         "9882b303ac59b2eb6052a6c155dccd607c22e0ff49d39470282b937a393cea37"),
+    # PR 44's own row: the new trunk's step as this PR compiles it (the
+    # window's loop bounds and the head map are in the kernels' bodies,
+    # which the hash masks: tests/test_attention_kernel.py holds those)
+    "trinity3d": (
+        29042, 55,
+        "1c12a723fd1ead7169c6edc3659a91231269f7cfca33c589ca0fe873d3e0e92c"),
 }
 #: the cells' batch where it is not 16 (``_STEPS`` holds one step a name:
-#: the tests below compile these two at 2 as well)
-STEP_BATCH = {"evabyte3d": 2, "moonlight3d": 2}
+#: the tests below compile these three at 2 as well)
+STEP_BATCH = {"evabyte3d": 2, "moonlight3d": 2, "trinity3d": 2}
 
 
 @pytest.mark.parametrize("name", sorted(PARENT_STEPS))
@@ -581,21 +606,74 @@ def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
     assert re.search(r" = f32\[9728,64\]\S* exponential\(", text)
     assert not re.search(r" = \w+\[2,16,\d+,\d+\]\S* exponential\(", text)
 
-    from neuroimagedisttraining_tpu.config import OptimConfig
-    from neuroimagedisttraining_tpu.core.trainer import LocalTrainer
     from tests.test_moonlight3d import attention_kernel_bodies
 
-    trainer = LocalTrainer(
-        create_model("moonlight3d", 1, dtype=jnp.bfloat16),
-        OptimConfig(precision="bf16_mixed", lr=0.01, momentum=0.9, wd=5e-4,
-                    grad_clip=10.0, batch_size=2), 1)
-    state = jax.eval_shape(trainer.init_client_state, jax.random.key(0),
-                           jnp.zeros((1,) + SHAPE, jnp.float32))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    traced = jax.make_jaxpr(trainer.loss_and_grad)(
-        state, jax.ShapeDtypeStruct((2,) + SHAPE, jnp.uint8),
-        jax.ShapeDtypeStruct((2,), jnp.int32))
-    attention_kernel_bodies(traced, layers=6)
+    attention_kernel_bodies(_traced_step(monkeypatch, "moonlight3d"),
+                            layers=6)
+
+
+def test_trinity3d_training_step_fits_at_the_published_widths(chip,
+                                                              monkeypatch):
+    """``--model trinity3d``'s step at 604 M parameters and the cell's
+    batch of 2 x 4,864 tokens: the held runs' buffer of 19,456 rows in
+    every expert layer (forward, rematerialised forward and backward: 10
+    kernels a layer), the attention's kernels in all five layers (forward,
+    rematerialised forward, backward: ops/attention.py with the window and
+    the head map, PR 44), twelve under ``swa_core`` and three under
+    ``full_core``; grouped ``k`` and ``v`` ``[2, 4864, 512]`` reach the
+    kernels as they are (none repeated over its group to ``[..., 4096]``
+    outside them: ``dk``, ``dv`` come back float32 and are cast); no
+    float32 block of scores is left in the program; and code +
+    temporaries (281.0 MiB and 1.655 GiB, rehearsal compile, PR 44) that
+    leave room for the folded round's 11.25 GiB of state."""
+    import re
+
+    compiled = _compiled_step(chip, monkeypatch, "trinity3d", batch=2)
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count(KERNEL_MARK) == 4 * 10 + 5 * 3
+    calls = [line for line in text.splitlines() if KERNEL_MARK in line]
+    attention = [c for c in calls if "/attention_" in c]
+    assert len(attention) == 5 * 3
+    assert sum("/swa_core/attention_" in c for c in attention) == 4 * 3
+    assert sum("/full_core/attention_" in c for c in attention) == 3
+    backward = [c for c in attention if "/attention_backward" in c]
+    assert len(backward) == 5
+    assert all("f32[2,19,256,512]" in c.split(" custom-call(")[0]
+               for c in backward)  # dk, dv of the four key/value heads
+    assert "bf16[19456,2048]" in text
+    assert not re.search(r"f32\[2,(32|4,8),\d+,\d+\]", text)
+    assert "4864,4864]" not in text
+    assert mem.temp_size_in_bytes < 2.0 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 290 * 2 ** 20
+
+
+def test_trinity3d_step_keeps_scores_softmax_qk_norms_and_router_float32(
+        chip, monkeypatch):
+    """The configuration states float32 scores, softmax, QK norm
+    statistics and router under ``bf16_mixed``: the program the cell times
+    says so itself. Nothing over the router's ``[9728, 128]`` scores is
+    bfloat16, and its product is float32; the QK norms' reciprocal roots
+    (one a token and head: ``[2, 4864, 32]`` and ``[2, 4864, 4]``)
+    are float32; the attention's scores live inside the kernels: in the
+    step as traced for the chip, inside the bodies of its 15
+    ``pallas_call``s, every exponential, logarithm, maximum and sum is
+    float32 and every product accumulates in float32; and no exponential
+    over a block of scores is left outside them."""
+    import re
+
+    text = _compiled_step(chip, monkeypatch, "trinity3d",
+                          batch=2).as_text()
+    assert "bf16[9728,128]" not in text
+    assert re.search(r" = f32\[9728,128\]\S* convolution\(", text)
+    assert re.search(r" = f32\[9728,128\]\S* exponential\(", text)
+    assert not re.search(r" = \w+\[2,(32|4,8),\d+,\d+\]\S* exponential\(",
+                         text)
+    roots = re.findall(r" = (\w+)\[2,4864,(?:32|4)\]\S* rsqrt\(", text)
+    assert roots and set(roots) == {"f32"}
+
+    from tests.test_moonlight3d import attention_kernel_bodies
+
+    attention_kernel_bodies(_traced_step(monkeypatch, "trinity3d"), layers=5)
 
 
 def test_resnet3d_vmapped_step_stays_client_merged(chip, monkeypatch):
