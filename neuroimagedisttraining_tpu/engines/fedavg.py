@@ -41,11 +41,16 @@ HELD_OVERFLOW_CALLS = "held_overflow_calls"
 #: Moonlight's third: the attention calls of those steps that ran as the
 #: kernel (ops/attention.py ``causal_attention``; 0 on its XLA form)
 ATTN_KERNEL_CALLS = "attn_kernel_calls"
+#: and its fourth: those among them whose forward kernel the backward pass
+#: did not run again, because the layer's rematerialisation kept the
+#: kernel's outputs (models/tokens3d.py ``layer_stack``)
+ATTN_OUTPUTS_KEPT = "attn_outputs_kept"
 
 
 def expert_load(expert_tokens, held: tuple[int, int] | None = None,
                 overflow_calls=None, capacity: int | None = None,
-                skip: int | None = None, kernel_calls=None) -> dict:
+                skip: int | None = None, kernel_calls=None,
+                outputs_kept=None) -> dict:
     """The round's expert-load counters from the round program's
     ``expert_tokens`` output, as host numbers for the ``round_log``
     span: slots routed, and the busiest and the idlest expert's load
@@ -63,7 +68,8 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None,
     experts' load. For a model whose attention is a kernel where the
     platform and the shapes allow (``kernel_calls``: its third output;
     models/moonlight3d.py) ``attn_kernel_calls``, the calls that took
-    it."""
+    it, and ``attn_outputs_kept`` (``outputs_kept``: its fourth), those
+    of them whose outputs the layer kept for its backward pass."""
     tokens = np.asarray(expert_tokens, np.float64)
     out = {"tokens_routed": int(tokens.sum())}
     if skip is not None:
@@ -82,6 +88,8 @@ def expert_load(expert_tokens, held: tuple[int, int] | None = None,
         out["held_capacity_rows"] = capacity or 0
     if kernel_calls is not None:
         out[ATTN_KERNEL_CALLS] = int(kernel_calls)
+    if outputs_kept is not None:
+        out[ATTN_OUTPUTS_KEPT] = int(outputs_kept)
     return out
 
 
@@ -182,7 +190,7 @@ class FedAvgEngine(FederatedEngine):
             getattr(self.trainer.model, "held_experts", None),
             named.get(HELD_OVERFLOW_CALLS), self._held_capacity_rows,
             getattr(self.trainer.model, "skip_output", None),
-            named.get(ATTN_KERNEL_CALLS))
+            named.get(ATTN_KERNEL_CALLS), named.get(ATTN_OUTPUTS_KEPT))
 
     # ---------- legacy-signature program adapters ----------
     # The builder's compiled programs take structured (carry, data,

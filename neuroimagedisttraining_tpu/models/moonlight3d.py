@@ -75,8 +75,11 @@ models/olmoe3d.py's term counts their tokens), ``aux["expert_tokens"]``
 the slots routed to each of the 64 experts, summed over the expert
 layers, ``aux["held_overflow_calls"]`` the layers whose held rows passed
 the buffer in this call, ``aux["attn_kernel_calls"]`` the layers whose
-attention ran as the kernel. Every layer is rematerialised
-(``remat_layers``, the model's own declaration).
+attention ran as the kernel, ``aux["attn_outputs_kept"]`` those of them
+whose forward kernel the backward pass does not run again. Every layer is
+rematerialised (``remat_layers``, the model's own declaration) but for the
+attention kernel's two outputs, which it keeps (models/tokens3d.py
+``layer_stack``: 40.5 MB a layer and step for 3.87 ms of forward kernel).
 
 Device scopes (obs/names.py MODEL_SCOPES): ``attn`` (W_q, its rotary,
 W_o) with ``mla_latent`` and ``mla_core`` inside it; ``mlp`` (layer 0);
@@ -266,7 +269,7 @@ class Moonlight3D(nn.Module):
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", *aux_counters})
     aux_counters = ("expert_tokens", "held_overflow_calls",
-                    "attn_kernel_calls")
+                    "attn_kernel_calls", "attn_outputs_kept")
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -292,5 +295,6 @@ class Moonlight3D(nn.Module):
         logits = tokens3d.pooled_logits(h, self.num_classes, c.rms_eps, init)
         with _scope(obs_names.SCOPE_ROUTER):
             loss, kernels = c.aux_alpha * sum(balance), sum(kernels)
-        return logits, tokens3d.held_aux(loss, chosen, passed, c.num_experts,
-                                         attn_kernel_calls=kernels)
+        return logits, tokens3d.held_aux(
+            loss, chosen, passed, c.num_experts,
+            **tokens3d.attention_counters(kernels))

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from neuroimagedisttraining_tpu.obs import names as obs_names
-from neuroimagedisttraining_tpu.ops import moe
+from neuroimagedisttraining_tpu.ops import attention, moe
 
 Dtype = Any
 _scope = jax.named_scope
@@ -260,9 +260,25 @@ def layer_stack(module, layer_cls, layer_args, carry):
     not while initialising: the trainer initialises eagerly, and a
     rematerialised layer run eagerly compiles its body anew on every
     call (four compilations inside the benchmark's measured window, my
-    chip run, PR 29); the parameter tree is the same."""
+    chip run, PR 29); the parameter tree is the same.
+
+    A rematerialised layer keeps its input and, by name, the attention
+    kernel's two outputs (ops/attention.py ``KEPT``: ``o`` and the rows'
+    log-sum-exp, 40.5 MB a Moonlight layer and step, 81.0 MB a Trinity-Mini
+    one); the backward pass computes everything else again. Those two are
+    all the backward kernel needs that the layer's second forward would
+    otherwise run the forward kernel for (3.9-6.6 ms a layer, PR 45), so a
+    layer and step run it once. The names exist only where
+    ``causal_attention`` took the kernel: a trunk on the plain forms
+    (``kernel=False``, off the TPU, models/evabyte3d.py's own attention)
+    traces none, the policy then keeps nothing, and its step is the
+    program ``nn.remat(layer_cls)`` alone compiles to
+    (tests/test_tpu_compile.py ``PARENT_STEPS``)."""
     remat = module.remat_layers and not module.is_initializing()
-    layer = nn.remat(layer_cls) if remat else layer_cls
+    layer = nn.remat(
+        layer_cls,
+        policy=jax.checkpoint_policies.save_only_these_names(*attention.KEPT),
+    ) if remat else layer_cls
     outputs = []
     for i, args in enumerate(layer_args):
         out = layer(*args, name=f"layers_{i}")(*carry)
@@ -270,3 +286,15 @@ def layer_stack(module, layer_cls, layer_args, carry):
         carry = out[:len(carry)]
         outputs.append(out[len(carry):])
     return carry, [list(column) for column in zip(*outputs)]
+
+
+def attention_counters(kernel_calls) -> dict:
+    """A trunk's two attention counters from ``kernel_calls``, its layers'
+    attention calls that ran as the kernel: ``attn_kernel_calls`` itself,
+    and ``attn_outputs_kept``, those among them whose forward kernel the
+    backward pass does not run again. Under :func:`layer_stack` that is
+    every one (a rematerialised layer keeps the kernel's outputs, any
+    other keeps all its residuals), and none on the plain forms, which
+    have no kernel to keep the outputs of."""
+    return {"attn_kernel_calls": kernel_calls,
+            "attn_outputs_kept": kernel_calls}

@@ -67,9 +67,12 @@ The model returns ``(logits, aux)``: ``aux["loss"]`` zero,
 ``aux["expert_tokens"]`` the slots routed to each of the 128 experts,
 summed over the expert layers, ``aux["held_overflow_calls"]`` the layers
 whose held rows passed the buffer in this call,
-``aux["attn_kernel_calls"]`` the layers whose attention ran as the kernel.
-Every layer is rematerialised (``remat_layers``, the model's own
-declaration).
+``aux["attn_kernel_calls"]`` the layers whose attention ran as the kernel,
+``aux["attn_outputs_kept"]`` those of them whose forward kernel the
+backward pass does not run again. Every layer is rematerialised
+(``remat_layers``, the model's own declaration) but for the attention
+kernel's two outputs, which it keeps (models/tokens3d.py ``layer_stack``:
+81.0 MB a layer and step for 5.0-6.6 ms of forward kernel).
 
 Device scopes (obs/names.py MODEL_SCOPES): ``attn`` (W_q, W_k, W_v, the
 rotary embedding, W_o) with ``qk_norm``, ``swa_core`` or ``full_core``, and
@@ -258,7 +261,7 @@ class Trinity3D(nn.Module):
     input_rank = 5  # [B, D, H, W, C]
     returns_aux = True  # (logits, {"loss", *aux_counters})
     aux_counters = ("expert_tokens", "held_overflow_calls",
-                    "attn_kernel_calls")
+                    "attn_kernel_calls", "attn_outputs_kept")
 
     @property
     def held_experts(self) -> tuple[int, int]:
@@ -286,4 +289,4 @@ class Trinity3D(nn.Module):
             kernels = sum(kernels)
         return logits, tokens3d.held_aux(
             jnp.zeros((), jnp.float32), chosen, passed, c.num_experts,
-            attn_kernel_calls=kernels)
+            **tokens3d.attention_counters(kernels))

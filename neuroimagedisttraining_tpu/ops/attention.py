@@ -32,9 +32,11 @@ scores, where the sequence is no longer than ``block``; beyond,
 ``jax.checkpoint``, which writes every block's float32 scores to HBM and
 reads them back, in the forward, the layer's rematerialised forward, the
 block's own and twice in the backward: 254.9 ms of a 520 ms step at 8.7%
-of the roofline (PERF.md, PR 40), where the kernels take 95.5 of 360 at
-23.2% (PR 42). The plain forms take the shared part concatenated (``[q,
-qs]`` and ``[k, ks repeated a head]``) and autodiff's backward.
+of the roofline (PERF.md, PR 40), where the kernels took 95.5 of 360 at
+23.2% (PR 42: a forward, the rematerialised layer's second forward and a
+backward a layer) and take one forward and one backward a layer since PR
+45 (``KEPT``, below). The plain forms take the shared part concatenated
+(``[q, qs]`` and ``[k, ks repeated a head]``) and autodiff's backward.
 
 **Forward** (grid: volume, head, block of queries). A head's keys and
 values wait in vector memory whole (3.7 MB at 4,864 tokens, fetched once a
@@ -51,6 +53,10 @@ rows' log-sum-exp ``[B, A, T]``, float32.
 
 **Backward** (a ``custom_vjp``; grid: volume, head, block of keys). The
 residuals are the operands, ``o`` and the log-sum-exp: nothing ``[T, T]``.
+The last two carry names (``KEPT``) by which a rematerialised layer keeps
+them, so that its second forward does not run the forward kernel for them
+again (models/tokens3d.py ``layer_stack``); under no such policy the names
+are identities.
 One sweep: a program holds its block of keys and values, loops over the
 query blocks from its diagonal down, remakes each tile of probabilities
 from the log-sum-exp (TRANSPOSED, keys down the sublanes: the rows'
@@ -86,6 +92,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +106,13 @@ _MASKED = -1e30
 #: tile of scores: the largest of these the sequence is whole blocks of
 #: (PERF.md, PR 42: how 256 was picked)
 _BLOCKS = (256, 128)
+#: the forward kernel's two outputs as a rematerialisation policy can ask
+#: for them (``jax.checkpoint_policies.save_only_these_names(*KEPT)``:
+#: models/tokens3d.py ``layer_stack``): ``o`` and the rows' log-sum-exp are
+#: the backward kernel's only residuals that are not the layer's own
+#: operands, so a layer that keeps them does not run the forward kernel
+#: again. Names no policy asks for are identities.
+KEPT = ("attention_o", "attention_lse")
 #: a head's operands wait in vector memory whole: 23 MB in the backward at
 #: 4,864 tokens, above the compiler's default allowance of 16
 _VMEM_LIMIT = 64 * 2 ** 20
@@ -534,7 +548,8 @@ def _attend(A, ds, interpret, window, groups, q, qs, k, ks, v):
 
 
 def _attend_fwd(A, ds, interpret, window, groups, q, qs, k, ks, v):
-    o, lse = _forward(A, ds, interpret, window, groups, q, qs, k, ks, v)
+    o, lse = map(checkpoint_name, _forward(
+        A, ds, interpret, window, groups, q, qs, k, ks, v), KEPT)
     return o, (q, qs, k, ks, v, o, lse)
 
 

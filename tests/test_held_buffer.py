@@ -115,6 +115,13 @@ def test_folded_train_logs_the_held_rows_every_round(tmp_path):
     load = expert_load(np.asarray([1, 2]), (0, 1), np.int32(0), 512,
                        kernel_calls=np.int32(144))
     assert load["attn_kernel_calls"] == 144
+    assert "attn_outputs_kept" not in load
+    # and its fourth: those of them whose outputs the layer kept
+    load = expert_load(np.asarray([1, 2]), (0, 1), np.int32(0), 512,
+                       kernel_calls=np.int32(144),
+                       outputs_kept=np.int32(144))
+    assert (load["attn_kernel_calls"], load["attn_outputs_kept"]) == (144,
+                                                                      144)
     assert load["held_overflow_calls"] == 0 and load["rows_held"] == 1
 
 

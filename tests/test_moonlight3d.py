@@ -143,6 +143,7 @@ def test_float32_logits_loss_balance_and_every_gradient(ref, tokens):
     assert int(aux["expert_tokens"].sum()) == 2 * B * tokens * K
     assert int(aux["held_overflow_calls"]) == 0
     assert int(aux["attn_kernel_calls"]) == 0  # off the TPU: the XLA form
+    assert int(aux["attn_outputs_kept"]) == 0  # no kernel, no outputs kept
     flat = jax.tree_util.tree_flatten_with_path(got_grads)[0]
     flat_ref = jax.tree.leaves(want_grads)
     # attention 5 + two norms a layer; layer 0's feed-forward 3; an expert
@@ -220,7 +221,8 @@ def _all_eqns(jaxpr):
 
 def attention_kernel_bodies(traced, layers: int):
     """The attention's ``pallas_call``s of a traced gradient step (a layer:
-    forward, rematerialised forward, backward) and the equations inside
+    forward and backward; the rematerialised layer keeps the forward's
+    outputs and does not run it again, PR 45) and the equations inside
     their bodies, held to the stated precision: every exponential,
     logarithm, maximum, sum and product's result float32, every product's
     operands the compute dtype's (bfloat16). Also used by
@@ -229,13 +231,13 @@ def attention_kernel_bodies(traced, layers: int):
              if e.primitive.name == "pallas_call"
              and (e.params["name"] or "").startswith("attention_")]
     assert sorted(e.params["name"] for e in calls) == (
-        ["attention_backward"] * layers + ["attention_forward"] * 2 * layers)
+        ["attention_backward"] * layers + ["attention_forward"] * layers)
     inside = [e for call in calls for e in _all_eqns(call.params["jaxpr"])]
     by = lambda *names: [e for e in inside if e.primitive.name in names]
     # a forward: the running maximum's and the tile's, in its loop and on
     # its diagonal; a backward: the tile's, twice
-    assert len(by("exp")) >= layers * (2 * 4 + 2)
-    assert len(by("log")) == 2 * layers
+    assert len(by("exp")) >= layers * (4 + 2)
+    assert len(by("log")) == layers
     for e in by("exp", "log", "reduce_max", "reduce_sum", "max",
                 "dot_general"):
         # (a windowed kernel's loop bounds are integer maxima)
@@ -472,7 +474,7 @@ def test_the_model_declares_what_the_trainer_reads():
         1, 5, (0, 8), 8)
     assert model.returns_aux and model.remat_layers
     assert model.aux_counters == ("expert_tokens", "held_overflow_calls",
-                                  "attn_kernel_calls")
+                                  "attn_kernel_calls", "attn_outputs_kept")
     assert model.held_experts == (0, 8)
     assert model.row_tokens((121, 145, 121)) == 4864
     assert LocalTrainer(model, OptimConfig(), 1).eval_batch_rows(
@@ -499,11 +501,12 @@ def test_folded_train_logs_the_attention_kernels_calls(tmp_path, monkeypatch,
                                                        kernel):
     """Every round's ``round_log`` span carries ``attn_kernel_calls``
     beside the routing counters: the attention calls of the round's real
-    steps that ran as the kernel. 0 here as it stands (off the TPU the XLA
-    form runs); with the choice answered as a TPU answers it at widths the
-    blocks tile (and the kernel stood in for, the small widths tile no
-    block), three layers a real step, none from the eager initialisation
-    or the evaluation."""
+    steps that ran as the kernel, and ``attn_outputs_kept``, those of them
+    whose outputs the layer kept for its backward pass (PR 45: all of
+    them). Both 0 here as it stands (off the TPU the XLA form runs); with
+    the choice answered as a TPU answers it at widths the blocks tile (and
+    the kernel stood in for, the small widths tile no block), three layers
+    a real step, none from the eager initialisation or the evaluation."""
     from neuroimagedisttraining_tpu.config import (
         DataConfig, ExperimentConfig, FedConfig, OptimConfig,
     )
@@ -558,6 +561,7 @@ def test_folded_train_logs_the_attention_kernels_calls(tmp_path, monkeypatch,
     real_steps = int(np.ceil(np.asarray(eng.data.n_train) / 4).sum())
     for a in logs:
         assert a["attn_kernel_calls"] == (real_steps * 3 if kernel else 0)
+        assert a["attn_outputs_kept"] == a["attn_kernel_calls"]
         assert a["held_overflow_calls"] >= 0 and a["rows_held"] > 0
         assert a["tokens_routed"] == real_steps * 4 * 24 * K * 2
     assert not any(eager)  # the eager initialisation says kernel=False
@@ -566,9 +570,10 @@ def test_folded_train_logs_the_attention_kernels_calls(tmp_path, monkeypatch,
 def test_on_a_tpu_the_kernels_bodies_keep_scores_and_softmax_float32(
         monkeypatch):
     """Where the scores live since PR 42: at widths the kernel's blocks
-    tile, traced as a TPU traces it, every layer's attention is three
-    ``pallas_call``s of the gradient step (forward, rematerialised
-    forward, backward) and no block of scores is left outside them; inside
+    tile, traced as a TPU traces it, every layer's attention is two
+    ``pallas_call``s of the gradient step (forward and backward: the
+    rematerialised layer keeps the forward's outputs, PR 45) and no block
+    of scores is left outside them; inside
     their bodies every exponential, logarithm, maximum and sum is float32
     and every product accumulates in float32 under ``bf16_mixed``:
     bfloat16 enters a product as an operand and leaves a body as an
@@ -626,6 +631,7 @@ def test_through_the_interpreted_kernel_the_model_is_the_xla_forms(
 
     (_, (want, want_aux)), want_grads = run(params)
     assert int(want_aux["attn_kernel_calls"]) == 0
+    assert int(want_aux["attn_outputs_kept"]) == 0
     real = attention.attention_kernel
     monkeypatch.setattr(attention, "takes_kernel",
                         lambda T, dk, ds, dv, kernel, *a: kernel
@@ -634,6 +640,7 @@ def test_through_the_interpreted_kernel_the_model_is_the_xla_forms(
                         functools.partial(real, interpret=True))
     (_, (got, aux)), got_grads = run(params)
     assert int(aux["attn_kernel_calls"]) == 3
+    assert int(aux["attn_outputs_kept"]) == 3
     _close(got, want)
     _close(aux["loss"], want_aux["loss"])
     np.testing.assert_array_equal(aux["expert_tokens"],

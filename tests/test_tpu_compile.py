@@ -461,15 +461,18 @@ PARENT_STEPS = {
     "evabyte3d": (
         12945, 0,
         "6891b4bc7ec36f48e9f1aec2d8f9e7e71c7d82bf8cbbfea97a64566aa03b98af"),
+    # PR 45's own rows: a rematerialised layer keeps the attention kernel's
+    # outputs (models/tokens3d.py ``layer_stack``), so the two trunks on the
+    # kernels lose a forward kernel a layer (the parent, a1da0d2: 26,621
+    # instructions, 58 kernels, 9882b303... and 29,042, 55, 1c12a723...)
+    # and gain the counter ``attn_outputs_kept``; the four rows above, the
+    # same ``layer_stack`` with no named value to keep, hold untouched
     "moonlight3d": (
-        26621, 58,
-        "9882b303ac59b2eb6052a6c155dccd607c22e0ff49d39470282b937a393cea37"),
-    # PR 44's own row: the new trunk's step as this PR compiles it (the
-    # window's loop bounds and the head map are in the kernels' bodies,
-    # which the hash masks: tests/test_attention_kernel.py holds those)
+        26625, 52,
+        "e4549d7f8f7c0ab6d3b985042a2b01dab9c34dcc7d2fb993d800ab7ccb2d051c"),
     "trinity3d": (
-        29042, 55,
-        "1c12a723fd1ead7169c6edc3659a91231269f7cfca33c589ca0fe873d3e0e92c"),
+        29142, 50,
+        "6d613ea964e08936e7505e92d11269808dcaf50385abad727c6d0ed7e5ddccb3"),
 }
 #: the cells' batch where it is not 16 (``_STEPS`` holds one step a name:
 #: the tests below compile these three at 2 as well)
@@ -562,28 +565,35 @@ def test_moonlight3d_training_step_fits_at_the_published_widths(chip,
     """``--model moonlight3d``'s step at 586 M parameters and the cell's
     batch of 2 x 4,864 tokens: the held runs' buffer of 14,848 rows in
     every expert layer (forward, rematerialised forward and backward: 8
-    kernels a layer), the attention's kernels in all six layers (forward,
-    rematerialised forward, backward: ops/attention.py, PR 42), every one
-    under the scope ``mla_core``; no float32 block of scores ``[2, 16,
-    queries, keys]`` of any extent is left in the program (the XLA form
-    held ``[2, 16, 512, 4608]`` and nine more), never ``[4864, 4864]``;
-    and code + temporaries under what the XLA form took (PERF.md, PR 40:
-    334.8 MiB and 1.792 GiB; 225.9 MiB and 1.648 GiB with the kernels),
-    beside the folded round's 10.9 GiB of state."""
+    kernels a layer), the attention's kernels in all six layers (forward
+    and backward, TWO a layer: ops/attention.py, PR 42; the rematerialised
+    layer keeps the forward's ``o`` and log-sum-exp and does not run it
+    again, PR 45), every one under the scope ``mla_core``; no float32
+    block of scores ``[2, 16, queries, keys]`` of any extent is left in
+    the program (the XLA form held ``[2, 16, 512, 4608]`` and nine more),
+    never ``[4864, 4864]``; and code + temporaries under what the XLA form
+    took (PERF.md, PR 40: 334.8 MiB and 1.792 GiB; 225.9 MiB and 1.648 GiB
+    with the kernels; 227.9 MiB and 1.647 GiB with their outputs kept,
+    rehearsal compile, PR 45: the six layers' 243 MB do not raise the
+    step's peak), beside the folded round's 10.9 GiB of state."""
     import re
 
     compiled = _compiled_step(chip, monkeypatch, "moonlight3d", batch=2)
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert text.count(KERNEL_MARK) == 5 * 8 + 6 * 3
+    assert text.count(KERNEL_MARK) == 5 * 8 + 6 * 2
     calls = [line for line in text.splitlines() if KERNEL_MARK in line]
     attention = [c for c in calls if "/attention_" in c]
-    assert len(attention) == 6 * 3
+    assert len(attention) == 6 * 2
+    assert sum("/attention_forward" in c for c in attention) == 6
     assert all("/mla_core/attention_" in c for c in attention)
     assert "bf16[14848,2048]" in text and "bf16[14848,2816]" in text
-    assert not re.search(r"f32\[2,16,\d+,\d+\]", text)
+    # (the kept log-sum-exp, a number a query and head, waits as its 19
+    # blocks of 256: rows' statistics, no block of scores)
+    assert set(re.findall(r"f32\[2,16,\d+,\d+\]", text)) <= {
+        "f32[2,16,19,256]"}
     assert "[2,16,4864,4864]" not in text
-    assert mem.temp_size_in_bytes < 1.792 * 2 ** 30
-    assert mem.generated_code_size_in_bytes < 335 * 2 ** 20
+    assert mem.temp_size_in_bytes < 1.7 * 2 ** 30
+    assert mem.generated_code_size_in_bytes < 240 * 2 ** 20
 
 
 def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
@@ -593,7 +603,7 @@ def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
     the router's ``[9728, 64]`` scores is bfloat16, and its product is
     float32. The attention's scores live inside the kernels since PR 42,
     where the compiled text does not look: in the step as traced for the
-    chip, inside the bodies of its 18 ``pallas_call``s, every exponential,
+    chip, inside the bodies of its 12 ``pallas_call``s, every exponential,
     logarithm, maximum and sum is float32 and every product accumulates in
     float32 (bfloat16 there is an operand of a product or an output); and
     no exponential over a ``[2, 16, ...]`` block is left outside them."""
@@ -617,33 +627,41 @@ def test_trinity3d_training_step_fits_at_the_published_widths(chip,
     """``--model trinity3d``'s step at 604 M parameters and the cell's
     batch of 2 x 4,864 tokens: the held runs' buffer of 19,456 rows in
     every expert layer (forward, rematerialised forward and backward: 10
-    kernels a layer), the attention's kernels in all five layers (forward,
-    rematerialised forward, backward: ops/attention.py with the window and
-    the head map, PR 44), twelve under ``swa_core`` and three under
-    ``full_core``; grouped ``k`` and ``v`` ``[2, 4864, 512]`` reach the
-    kernels as they are (none repeated over its group to ``[..., 4096]``
-    outside them: ``dk``, ``dv`` come back float32 and are cast); no
-    float32 block of scores is left in the program; and code +
-    temporaries (281.0 MiB and 1.655 GiB, rehearsal compile, PR 44) that
-    leave room for the folded round's 11.25 GiB of state."""
+    kernels a layer), the attention's kernels in all five layers (forward
+    and backward, TWO a layer: ops/attention.py with the window and the
+    head map, PR 44; the rematerialised layer keeps the forward's ``o``
+    and log-sum-exp and does not run it again, PR 45), eight under
+    ``swa_core`` and two under ``full_core``; grouped ``k`` and ``v`` ``[2,
+    4864, 512]`` reach the kernels as they are (none repeated over its
+    group to ``[..., 4096]`` outside them: ``dk``, ``dv`` come back float32
+    and are cast); no float32 block of scores is left in the program; and
+    code + temporaries (281.9 MiB and 1.8055 GiB = 1,938,650,624 bytes,
+    rehearsal compile, PR 45: the parent's 281.0 MiB and 1.655 GiB plus
+    0.15 GiB of the five layers' 0.38 GiB of kept outputs, the rest lies
+    under the peak the step already had) that leave room for the folded
+    round's 11.25 GiB of state: the chip held 14.917 GiB at its
+    peak of 15.75 (PERF.md, PR 45)."""
     import re
 
     compiled = _compiled_step(chip, monkeypatch, "trinity3d", batch=2)
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert text.count(KERNEL_MARK) == 4 * 10 + 5 * 3
+    assert text.count(KERNEL_MARK) == 4 * 10 + 5 * 2
     calls = [line for line in text.splitlines() if KERNEL_MARK in line]
     attention = [c for c in calls if "/attention_" in c]
-    assert len(attention) == 5 * 3
-    assert sum("/swa_core/attention_" in c for c in attention) == 4 * 3
-    assert sum("/full_core/attention_" in c for c in attention) == 3
+    assert len(attention) == 5 * 2
+    assert sum("/swa_core/attention_" in c for c in attention) == 4 * 2
+    assert sum("/full_core/attention_" in c for c in attention) == 2
     backward = [c for c in attention if "/attention_backward" in c]
     assert len(backward) == 5
     assert all("f32[2,19,256,512]" in c.split(" custom-call(")[0]
                for c in backward)  # dk, dv of the four key/value heads
     assert "bf16[19456,2048]" in text
-    assert not re.search(r"f32\[2,(32|4,8),\d+,\d+\]", text)
+    # (the kept log-sum-exp, a number a query and head, waits as its 19
+    # blocks of 256: rows' statistics, no block of scores)
+    assert set(m.group() for m in re.finditer(
+        r"f32\[2,(32|4,8),\d+,\d+\]", text)) <= {"f32[2,32,19,256]"}
     assert "4864,4864]" not in text
-    assert mem.temp_size_in_bytes < 2.0 * 2 ** 30
+    assert mem.temp_size_in_bytes < 1.85 * 2 ** 30
     assert mem.generated_code_size_in_bytes < 290 * 2 ** 20
 
 
@@ -655,7 +673,7 @@ def test_trinity3d_step_keeps_scores_softmax_qk_norms_and_router_float32(
     bfloat16, and its product is float32; the QK norms' reciprocal roots
     (one a token and head: ``[2, 4864, 32]`` and ``[2, 4864, 4]``)
     are float32; the attention's scores live inside the kernels: in the
-    step as traced for the chip, inside the bodies of its 15
+    step as traced for the chip, inside the bodies of its 10
     ``pallas_call``s, every exponential, logarithm, maximum and sum is
     float32 and every product accumulates in float32; and no exponential
     over a block of scores is left outside them."""

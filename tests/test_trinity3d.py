@@ -128,6 +128,7 @@ def test_float32_logits_loss_and_every_gradient(ref, tokens):
     assert int(aux["expert_tokens"].sum()) == 4 * B * tokens * K
     assert int(aux["held_overflow_calls"]) == 0
     assert int(aux["attn_kernel_calls"]) == 0  # off the TPU: the plain form
+    assert int(aux["attn_outputs_kept"]) == 0  # no kernel, no outputs kept
     flat = jax.tree_util.tree_flatten_with_path(got_grads)[0]
     flat_ref = jax.tree.leaves(want_grads)
     # attention 7 + four norms a layer; layer 0's feed-forward 3; an expert
@@ -500,7 +501,7 @@ def test_the_model_declares_what_the_trainer_reads():
     assert (w.held, w.patch) == ((0, 16), 8)
     assert model.returns_aux and model.remat_layers
     assert model.aux_counters == ("expert_tokens", "held_overflow_calls",
-                                  "attn_kernel_calls")
+                                  "attn_kernel_calls", "attn_outputs_kept")
     assert model.held_experts == (0, 16)
     assert model.row_tokens((121, 145, 121)) == 4864
     assert LocalTrainer(model, OptimConfig(), 1).eval_batch_rows(
@@ -532,6 +533,22 @@ def test_the_published_shapes_take_the_kernels_on_a_tpu(monkeypatch):
     assert not takes(2048)
 
 
+def _tiling_trunks():
+    """Both kernel trunks at the smallest widths the kernels' blocks tile,
+    three layers each: ``name -> (model, tokens, (heads, value width))``."""
+    from tests import test_moonlight3d as moonlight
+
+    return {
+        "moonlight3d": (moonlight.Moonlight3D(widths=dataclasses.replace(
+            moonlight.SMALL, heads=2, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, block=128)), 256, (2, 128)),
+        "trinity3d": (Trinity3D(widths=dataclasses.replace(
+            SMALL, layer_types=(SLIDING, FULL, SLIDING), heads=4,
+            kv_heads=2, head_dim=128, sliding_window=128, block=128)), 384,
+            (4, 128)),
+    }
+
+
 def test_through_the_interpreted_kernel_the_model_is_the_plain_forms(
         monkeypatch):
     """The small trunk at widths the kernel's blocks tile (384 tokens:
@@ -542,13 +559,10 @@ def test_through_the_interpreted_kernel_the_model_is_the_plain_forms(
     gradient, under ``nn.remat`` as the trainer runs it."""
     import functools
 
-    tile = dataclasses.replace(
-        SMALL, layer_types=(SLIDING, FULL, SLIDING), heads=4, kv_heads=2,
-        head_dim=128, sliding_window=128, block=128)
-    model = Trinity3D(widths=tile)
-    x, y = _batch(7, 384, rows=2)
+    model, tokens, _ = _tiling_trunks()["trinity3d"]
+    x, y = _batch(7, tokens, rows=2)
     params = _jitter(model.init(jax.random.key(7), jnp.zeros(
-        (1,) + _shape(384) + (1,)))["params"], 7)
+        (1,) + _shape(tokens) + (1,)))["params"], 7)
 
     def run(p):
         def loss(p):
@@ -558,6 +572,7 @@ def test_through_the_interpreted_kernel_the_model_is_the_plain_forms(
 
     (_, (want, want_aux)), want_grads = run(params)
     assert int(want_aux["attn_kernel_calls"]) == 0
+    assert int(want_aux["attn_outputs_kept"]) == 0
     real = attention.attention_kernel
     monkeypatch.setattr(attention, "takes_kernel",
                         lambda T, dk, ds, dv, kernel, *a: kernel
@@ -566,6 +581,7 @@ def test_through_the_interpreted_kernel_the_model_is_the_plain_forms(
                         functools.partial(real, interpret=True))
     (_, (got, aux)), got_grads = run(params)
     assert int(aux["attn_kernel_calls"]) == 3
+    assert int(aux["attn_outputs_kept"]) == 3
     _close(got, want)
     np.testing.assert_array_equal(aux["expert_tokens"],
                                   want_aux["expert_tokens"])
@@ -575,3 +591,79 @@ def test_through_the_interpreted_kernel_the_model_is_the_plain_forms(
         top = float(jnp.max(jnp.abs(h)))
         _close(g, h, rtol=F32_RTOL * 10, atol=F32_ATOL * max(top, 1e-30)
                * 20)
+
+
+# ---------- what a rematerialised layer keeps (PR 45) ----------
+
+@pytest.mark.parametrize("name", ["moonlight3d", "trinity3d"])
+def test_a_rematerialised_layer_keeps_the_forward_kernels_outputs(
+        monkeypatch, capsys, name):
+    """``tokens3d.layer_stack``'s policy keeps, by name, the attention
+    kernel's ``o`` and log-sum-exp of every layer (ops/attention.py
+    ``KEPT``) and nothing else of a layer but its input: the gradient
+    step's forward kernel is traced once a layer, none of them inside a
+    rematerialised body (``remat2``), where ``nn.remat`` with no policy
+    traces it a second time there; and since the kept ``o`` IS the array
+    the first forward wrote, the loss and every gradient leaf are
+    bitwise what full rematerialisation gives (the kernels' own bodies,
+    through Pallas' interpreter)."""
+    import functools
+
+    model, tokens, got_o = _tiling_trunks()[name]
+    x, y = _batch(11, tokens, rows=2)
+    params = _jitter(model.init(jax.random.key(11), jnp.zeros(
+        (1,) + _shape(tokens) + (1,)))["params"], 11)
+    monkeypatch.setattr(attention, "takes_kernel",
+                        lambda T, dk, ds, dv, kernel, *a: kernel
+                        and attention.kernel_tiles(T, dk, ds, dv, *a))
+    monkeypatch.setattr(attention, "attention_kernel", functools.partial(
+        attention.attention_kernel, interpret=True))
+
+    def loss(p):
+        logits, aux = _apply(model, p, x)
+        return jnp.sum(logits * (2.0 * y[:, None] - 1)) + aux["loss"]
+
+    def forwards(traced, inside_remat=False):
+        """The ``attention_forward`` calls of a jaxpr: (all, those inside
+        a rematerialised body)."""
+        found = []
+        for e in traced.eqns:
+            if (e.primitive.name == "pallas_call"
+                    and e.params["name"] == "attention_forward"):
+                found.append(inside_remat)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                found += forwards(sub, inside_remat
+                                  or e.primitive.name == "remat2")
+        return found
+
+    # a function of its own a trace: jit's cache would answer the second
+    # with the first's jaxpr
+    step = lambda: jax.value_and_grad(lambda p: loss(p))
+    kept = forwards(jax.make_jaxpr(step())(params).jaxpr)
+    assert kept == [False] * 3
+    # what the step keeps of ops/attention.py's: the two a layer, and no
+    # operand of the kernel (``o`` is read on, by W_o, and so reaches the
+    # list behind the barrier JAX puts on such a residual's producer)
+    jax.ad_checkpoint.print_saved_residuals(loss, params)
+    ours = [line for line in capsys.readouterr().out.splitlines()
+            if "ops/attention.py" in line]
+    assert len(ours) == 2 * 3
+    assert sum(f"named '{attention.KEPT[1]}'" in line
+               for line in ours) == 3
+    heads, dv = got_o
+    assert sum(line.startswith(f"f32[2,{tokens},{heads * dv}] output of "
+                               "reduce_precision") for line in ours) == 3
+    got, got_grads = jax.jit(step())(params)
+
+    # full rematerialisation: the policy that keeps nothing
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    full = forwards(jax.make_jaxpr(step())(params).jaxpr)
+    assert sorted(full) == [False] * 3 + [True] * 3
+    want, want_grads = jax.jit(step())(params)
+    np.testing.assert_array_equal(got, want)
+    assert jax.tree.structure(got_grads) == jax.tree.structure(want_grads)
+    for (path, g), h in zip(
+            jax.tree_util.tree_flatten_with_path(got_grads)[0],
+            jax.tree.leaves(want_grads)):
+        np.testing.assert_array_equal(g, h, err_msg=str(path))
