@@ -178,6 +178,24 @@ def gather_slots(x: jax.Array, plan: DispatchPlan) -> jax.Array:
 #: rows in it, so its time follows the routing: alone, one forward call
 #: reads 2.00 ms when every group boundary falls on a tile boundary and
 #: 2.74 when none does.
+#:
+#: From the chip runs of PR 46 and PR 47 at Moonlight's widths (a
+#: 14,848-row buffer, 8 groups, 2048 x 2816 "up" and 1408 x 2048 "down";
+#: the real training step, forward and backward, by the matrix tiles of
+#: (up, down); the eight kernels of one layer in ms: forward up + down,
+#: the backward's recomputation up + down, dx down + up, dW up + down; PR
+#: 46's call, whose change was refused unmeasured and asked again as PR
+#: 47, which read the first and third rows again: 323.4 and 284.4 ms,
+#: 13.10 and 5.19):
+#:
+#:   (256, 128)   323.1 ms a step   1.50+1.76  1.51+1.55  1.90+1.31  1.65+1.92 = 13.09
+#:   (512, 512)   288.4             0.93+0.46  1.01+0.48  0.48+0.93  1.01+0.51 =  5.80
+#:   (1024, 512)  284.7             0.79+0.46  0.80+0.48  0.48+0.83  0.84+0.51 =  5.19
+#:   (1024, 768)  285.2             0.79+0.47  0.79+0.53  0.48+0.83  0.84+0.50 =  5.23
+#:
+#: (256, 128) is what the least-padding rule chose: a grid step of 17-67
+#: MFLOP costs what it costs whatever it multiplies. A tile that pads a
+#: dimension by a tenth loses less than that (2816 -> 3072, 1408 -> 1536).
 GMM_ROW_TILES = (512, 256, 128)
 GMM_TILE_MAX = 1024
 _LANES = 128
@@ -194,12 +212,21 @@ def gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
 
     One tile ``t`` serves both matrix dimensions, because the kernel's
     own backward pass (``dx`` against the transposed weights) swaps them
-    under the same tuple. ``t`` is the multiple of 128 lanes, at most
-    :data:`GMM_TILE_MAX`, that pads the two dimensions least (the kernel
-    masks a ragged last tile and still computes it whole), the widest on
-    a tie: 1024 for OLMoE's 2048 x 1024 (the measured choice above), 384
-    for Nemotron-H's 2688 x 1856 (2688 = 7 x 384; 1856 = 29 x 64 has no
-    128-multiple divisor, 5 x 384 pads it by 3.4%)."""
+    under the same tuple. ``t`` is the WIDEST multiple of 128 lanes, at
+    most :data:`GMM_TILE_MAX`, that pads neither dimension by more than a
+    tenth (the kernel masks a ragged last tile and still computes it
+    whole, so padding is work; a narrow tile is grid steps, each with its
+    own cost whatever it multiplies): 1024 for OLMoE's 2048 x 1024 (the
+    measured choice above), for ZAYA1's 2048 x 4096 and 2048 x 2048 and
+    for Trinity-Mini's 2048 x 2048 and 1024 x 2048; 384 for Nemotron-H's
+    2688 x 1856 (2688 = 7 x 384, 5 x 384 pads 1856 by 3.4%, and every
+    wider tile pads one of the two by 14% or more); 1024 for Moonlight's
+    2048 x 2816 and 512 for its 1408 x 2048 (9.1% more columns each: 1408
+    = 11 x 128 has no wider divisor, and the tiles that pad them least,
+    256 and 128, are about 23,000 grid steps a layer and step of 17-67
+    MFLOP each: 13.1 ms of kernels for 5.2, the second table above
+    :data:`GMM_ROW_TILES`). Where no tile pads both within a tenth (136 x
+    1024), the tile that pads them least, the widest on a tie."""
     rows = next((t for t in GMM_ROW_TILES if m % t == 0), None)
     if rows is None:
         raise ValueError(
@@ -211,11 +238,14 @@ def gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
             f"(each dimension has to be a multiple of 8 and at least "
             f"{_LANES})")
 
-    def padded(t):
-        return -(-k // t) * t / k + -(-n // t) * t / n
+    def padded(d, t):
+        return -(-d // t) * t
 
-    t = min(range(_LANES, GMM_TILE_MAX + 1, _LANES),
-            key=lambda t: (padded(t), -t))
+    tiles = range(_LANES, GMM_TILE_MAX + 1, _LANES)
+    within_a_tenth = [t for t in tiles if all(
+        10 * padded(d, t) <= 11 * d for d in (k, n))]
+    t = within_a_tenth[-1] if within_a_tenth else min(
+        tiles, key=lambda t: (padded(k, t) / k + padded(n, t) / n, -t))
     return rows, t, t
 
 

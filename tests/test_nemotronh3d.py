@@ -449,9 +449,73 @@ def test_held_window_of_the_grouped_matmul():
     (5120, 2048, 1024, 512, 1024),   # its one-volume initialisation
     (61440, 2688, 1856, 512, 384), (61440, 1856, 2688, 512, 384),
     (3840, 2688, 1856, 256, 384),    # Nemotron-H's one-volume initialisation
-    (384, 512, 512, 128, 512)])
+    (384, 512, 512, 128, 512),
+    # Moonlight (PR 47): 1408 = 11 x 128 has no wider divisor, and the
+    # least-padding rule gave it tiles of 128 and its neighbour 256
+    (14848, 2048, 2816, 512, 1024), (14848, 2816, 2048, 512, 1024),
+    (14848, 1408, 2048, 512, 512), (14848, 2048, 1408, 512, 512),
+    (29184, 2048, 2816, 512, 1024),  # evaluation at 4 rows, and the
+    (29184, 1408, 2048, 512, 512),   # one-volume initialisation's full sort
+    (19456, 2048, 2048, 512, 1024), (19456, 1024, 2048, 512, 1024),  # Trinity
+    (512, 136, 1024, 512, 256)])     # every tile pads 136 by 88% or more
 def test_gmm_tiles_follow_the_operand_shapes(m, k, n, rows, tile):
     assert moe.gmm_tiling(m, k, n) == (rows, tile, tile)
+
+
+def test_gmm_tile_is_the_widest_that_pads_within_a_tenth():
+    """Over every ``(k, n)`` of multiples of 8 from 128 to 4096: the tile
+    is a multiple of 128 lanes and at most 1024, it pads neither
+    dimension by more than a tenth wherever any tile can do that, and no
+    wider tile can."""
+    def pads_within_a_tenth(t, k, n):
+        return all(10 * -(-d // t) * t <= 11 * d for d in (k, n))
+
+    tiles = range(128, 1025, 128)
+    for k in range(128, 4097, 8):
+        for n in range(128, 4097, 8):
+            _, t, tn = moe.gmm_tiling(512, k, n)
+            assert t == tn and t in tiles, (k, n, t)
+            fit = [u for u in tiles if pads_within_a_tenth(u, k, n)]
+            if fit:
+                assert t == fit[-1], (k, n, t)
+
+
+def test_gmm_kernel_at_a_ragged_wide_tile():
+    """``megablox.gmm`` in Pallas' interpreter at a tile that divides
+    neither matrix dimension (384 x 640 at 256: what 1408 x 2048 at 512
+    and 2048 x 2816 at 1024 are on the chip), three groups that do not
+    fill the rows: forward, ``dx`` and ``dW`` are ``jax.lax.ragged_dot``'s
+    over the rows the groups reach. The rows past them are whatever the
+    memory held (``ops/moe.py`` ``_window_experts`` masks them)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    r = np.random.RandomState(0)
+    M, K, N, tile = 256, 384, 640, 256
+    sizes = jnp.asarray([70, 100, 40], jnp.int32)
+    reach = int(jnp.sum(sizes))
+    xs = jnp.asarray(r.randn(M, K), jnp.float32)
+    w = jnp.asarray(r.randn(3, K, N) / np.sqrt(K), jnp.float32)
+    g = jnp.asarray(r.randn(M, N), jnp.float32)
+    valid = (jnp.arange(M) < reach)[:, None]
+
+    def kernel(xs, w):
+        ys = megablox.gmm(xs, w, sizes, jnp.float32, (128, tile, tile),
+                          None, None, False, True)  # interpret
+        return jnp.where(valid, ys, 0)
+
+    def plain(xs, w):
+        rest = jnp.zeros((1,) + w.shape[1:], w.dtype)
+        ys = jax.lax.ragged_dot(xs, jnp.concatenate([w, rest]),
+                                jnp.append(sizes, M - reach))
+        return jnp.where(valid, ys, 0)
+
+    y, transpose = jax.vjp(kernel, xs, w)
+    y0, transpose0 = jax.vjp(plain, xs, w)
+    (dx, dw), (dx0, dw0) = transpose(g), transpose0(g)
+    # float32 sums of 384, 640 and up to 100 products in another order
+    np.testing.assert_allclose(y, y0, rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(dx[:reach], dx0[:reach], rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(dw, dw0, rtol=1e-5, atol=2e-4)
 
 
 @pytest.mark.parametrize("m,k,n,words", [

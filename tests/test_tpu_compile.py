@@ -467,12 +467,17 @@ PARENT_STEPS = {
     # instructions, 58 kernels, 9882b303... and 29,042, 55, 1c12a723...)
     # and gain the counter ``attn_outputs_kept``; the four rows above, the
     # same ``layer_stack`` with no named value to keep, hold untouched
-    "moonlight3d": (
-        26625, 52,
-        "e4549d7f8f7c0ab6d3b985042a2b01dab9c34dcc7d2fb993d800ab7ccb2d051c"),
     "trinity3d": (
         29142, 50,
         "6d613ea964e08936e7505e92d11269808dcaf50385abad727c6d0ed7e5ddccb3"),
+    # PR 47's own row: ``gmm_tiling`` gives Moonlight's 2048 x 2816 and
+    # 1408 x 2048 the widest tile that pads them by at most a tenth (1024
+    # and 512 for 256 and 128; the parent, 21495ac, PR 45's row: 26,625
+    # instructions, 52 kernels, e4549d7f...); every other trunk's products
+    # keep their tiles and the five other rows hold untouched
+    "moonlight3d": (
+        26686, 52,
+        "651618fb7cca21bde3a2ab86d3a0bae2f8e14848ceeba259bc2ae18b9a359aa3"),
 }
 #: the cells' batch where it is not 16 (``_STEPS`` holds one step a name:
 #: the tests below compile these three at 2 as well)
@@ -575,7 +580,11 @@ def test_moonlight3d_training_step_fits_at_the_published_widths(chip,
     took (PERF.md, PR 40: 334.8 MiB and 1.792 GiB; 225.9 MiB and 1.648 GiB
     with the kernels; 227.9 MiB and 1.647 GiB with their outputs kept,
     rehearsal compile, PR 45: the six layers' 243 MB do not raise the
-    step's peak), beside the folded round's 10.9 GiB of state."""
+    step's peak; 248.3 MiB and 1.650 GiB with the expert products in
+    tiles of 1024 and 512, PR 47: the same 52 kernels, each unrolled over
+    wider tiles), beside the folded round's 10.9 GiB of state: the chip
+    then held 13.952 GiB at its peak for the parent's 13.897, PERF.md
+    section 6."""
     import re
 
     compiled = _compiled_step(chip, monkeypatch, "moonlight3d", batch=2)
@@ -593,7 +602,7 @@ def test_moonlight3d_training_step_fits_at_the_published_widths(chip,
         "f32[2,16,19,256]"}
     assert "[2,16,4864,4864]" not in text
     assert mem.temp_size_in_bytes < 1.7 * 2 ** 30
-    assert mem.generated_code_size_in_bytes < 240 * 2 ** 20
+    assert mem.generated_code_size_in_bytes < 260 * 2 ** 20
 
 
 def test_moonlight3d_step_keeps_scores_softmax_and_router_float32(
